@@ -32,7 +32,7 @@ from __future__ import annotations
 import functools
 from typing import Tuple
 
-import tensorflow as tf
+from byol_tpu.data.tf_host import tf
 
 
 def _uniform(seed, shape=(), lo=0.0, hi=1.0):

@@ -27,9 +27,10 @@ splits the same key the same way as before), with ONE deliberate
 numerical exception: the hue rotation is rewritten scalar-unrolled (a
 kernel body cannot capture the constant YIQ matrices), replacing three
 einsums with the equivalent per-channel arithmetic — identical math,
-fp-rounding-level differences only (and on TPU the old einsums were
-MXU-eligible, so pre-refactor seed-for-seed trajectories there are
-reproduced to tolerance, not bit-for-bit).
+fp-rounding-level differences only.  The color arithmetic is written on
+three channel PLANES (:func:`jitter_planes`, :func:`luminance`), because
+the chip's compiler refuses the kernel on ``(H, W, 3)`` blocks: the
+unfused path stacks the planes back into an ``(..., 3)`` image.
 """
 from __future__ import annotations
 
@@ -84,15 +85,19 @@ def random_resized_crop(key, image: jnp.ndarray, size: int,
     return apply_crop(image, y0, x0, ch, cw, size)
 
 
-def _gray(image):
-    lum = (0.2989 * image[..., 0] + 0.587 * image[..., 1]
-           + 0.114 * image[..., 2])
-    return lum[..., None]
+def luminance(r, g, b):
+    """Luma of three channel planes (the torchvision grayscale weights)."""
+    return 0.2989 * r + 0.587 * g + 0.114 * b
+
+
+def _planes(image):
+    return image[..., 0], image[..., 1], image[..., 2]
 
 
 def apply_grayscale(image: jnp.ndarray) -> jnp.ndarray:
     """Three-channel grayscale (the torchvision RandomGrayscale branch)."""
-    return jnp.tile(_gray(image), (1, 1, 3))
+    lum = luminance(*_planes(image))
+    return jnp.stack([lum, lum, lum], axis=-1)
 
 
 def jitter_params(key, strength: float):
@@ -109,33 +114,45 @@ def jitter_params(key, strength: float):
     return fb, fc, fs, theta
 
 
-def apply_color_jitter(image: jnp.ndarray, fb, fc, fs, theta, *,
-                       hue: bool) -> jnp.ndarray:
-    """brightness/contrast/saturation (.8s) + hue (.2s), torch semantics
-    (multiplicative brightness; blend-based contrast/saturation); pure
-    arithmetic on pre-drawn factors, shared verbatim by the fused
-    augmentation kernel body."""
-    image = jnp.clip(image * fb, 0., 1.)
-    image = jnp.clip(fc * image + (1 - fc) * jnp.mean(_gray(image)), 0., 1.)
-    image = jnp.clip(fs * image + (1 - fs) * _gray(image), 0., 1.)
+def jitter_planes(r, g, b, fb, fc, fs, cos, sin, *, hue: bool):
+    """The color-jitter arithmetic on three channel PLANES with pre-drawn
+    factors and the hue angle given as ``(cos, sin)``: brightness/
+    contrast/saturation (.8s) + hue (.2s), torch semantics (multiplicative
+    brightness; blend-based contrast/saturation).  Planes, not an
+    ``(..., 3)`` image, because this function IS the fused augmentation
+    kernel's jitter stage (ops/fused_augment.py): on the TPU a minor
+    dimension of 3 wastes 125 of 128 lanes and Mosaic refuses the reshapes
+    around it, and a kernel body has no scalar ``cos``/``sin``."""
+    r, g, b = (jnp.clip(c * fb, 0., 1.) for c in (r, g, b))
+    mean = jnp.mean(luminance(r, g, b))
+    r, g, b = (jnp.clip(fc * c + (1 - fc) * mean, 0., 1.)
+               for c in (r, g, b))
+    lum = luminance(r, g, b)
+    r, g, b = (jnp.clip(fs * c + (1 - fs) * lum, 0., 1.)
+               for c in (r, g, b))
     if hue:
         # hue rotation in YIQ space (equivalent to HSV hue shift, cheaper
-        # and branch-free on TPU), written in scalar-unrolled form: a
-        # Pallas kernel body cannot capture array constants, and this
-        # function IS the fused augmentation kernel's jitter stage
-        # (ops/fused_augment.py) — scalar coefficients inline fine and the
-        # channel mixes stay pure VPU arithmetic either way
-        r, g, b_ = image[..., 0], image[..., 1], image[..., 2]
-        y = 0.299 * r + 0.587 * g + 0.114 * b_
-        i = 0.596 * r - 0.274 * g - 0.322 * b_
-        q = 0.211 * r - 0.523 * g + 0.312 * b_
-        cos, sin = jnp.cos(theta), jnp.sin(theta)
+        # and branch-free on TPU), scalar-unrolled: a Pallas kernel body
+        # cannot capture array constants, scalar coefficients inline fine
+        # and the channel mixes stay pure VPU arithmetic
+        y = 0.299 * r + 0.587 * g + 0.114 * b
+        i = 0.596 * r - 0.274 * g - 0.322 * b
+        q = 0.211 * r - 0.523 * g + 0.312 * b
         i, q = cos * i + sin * q, -sin * i + cos * q
-        image = jnp.stack([y + 0.956 * i + 0.621 * q,
-                           y - 0.272 * i - 0.647 * q,
-                           y - 1.106 * i + 1.703 * q], axis=-1)
-        image = jnp.clip(image, 0.0, 1.0)
-    return image
+        r, g, b = (jnp.clip(c, 0.0, 1.0)
+                   for c in (y + 0.956 * i + 0.621 * q,
+                             y - 0.272 * i - 0.647 * q,
+                             y - 1.106 * i + 1.703 * q))
+    return r, g, b
+
+
+def apply_color_jitter(image: jnp.ndarray, fb, fc, fs, theta, *,
+                       hue: bool) -> jnp.ndarray:
+    """:func:`jitter_planes` on an ``(..., 3)`` image — the unfused path's
+    spelling of the arithmetic the fused kernel shares."""
+    return jnp.stack(
+        jitter_planes(*_planes(image), fb, fc, fs, jnp.cos(theta),
+                      jnp.sin(theta), hue=hue), axis=-1)
 
 
 def color_jitter(key, image: jnp.ndarray, strength: float) -> jnp.ndarray:

@@ -43,7 +43,7 @@ def scan_image_folder(root: str) -> Tuple[List[str], List[int], List[str]]:
 
 
 def _decode_full(data, channels=3):
-    import tensorflow as tf
+    from byol_tpu.data.tf_host import tf
     img = tf.io.decode_image(data, channels=channels, expand_animations=False)
     img.set_shape([None, None, channels])
     return tf.image.convert_image_dtype(img, tf.float32)
@@ -54,7 +54,7 @@ def _fused_decode_random_crop(data, seed, size: int,
     """Sample the crop window from the JPEG header, decode ONLY the window
     (tf.image.decode_and_crop_jpeg), then resize — DALI's fused
     decode+crop equivalent on the host."""
-    import tensorflow as tf
+    from byol_tpu.data.tf_host import tf
     shape = tf.image.extract_jpeg_shape(data)
     bbox = tf.zeros((1, 1, 4), tf.float32)
     begin, sz, _ = tf.image.stateless_sample_distorted_bounding_box(
@@ -69,7 +69,7 @@ def _fused_decode_random_crop(data, seed, size: int,
 
 
 def _is_jpeg(path):
-    import tensorflow as tf
+    from byol_tpu.data.tf_host import tf
     lower = tf.strings.lower(path)
     return tf.strings.regex_full_match(lower, r".*\.(jpg|jpeg)")
 
@@ -84,7 +84,7 @@ def image_folder_loader(cfg: Config, *, host_batch: int,
     decode→augment hot path without TF dispatch (reference main.py:356-382).
     """
     import jax
-    import tensorflow as tf
+    from byol_tpu.data.tf_host import tf
 
     from byol_tpu.data import augment
     from byol_tpu.data.loader import LoaderBundle

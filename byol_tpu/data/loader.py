@@ -154,7 +154,7 @@ def _array_pipeline(images: np.ndarray, labels: np.ndarray, *,
     Train: two independently-augmented views; test: one resize applied to
     both view slots so eval code paths stay identical (the reference's eval
     also runs the full two-view forward, main.py:589-606)."""
-    import tensorflow as tf
+    from byol_tpu.data.tf_host import tf
 
     from byol_tpu.data import augment
 

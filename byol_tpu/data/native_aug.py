@@ -12,6 +12,7 @@ backend, so the native path is strictly opt-in acceleration
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,7 +22,19 @@ import numpy as np
 
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "native")
 _SRC = os.path.join(_SRC_DIR, "image_pipeline.cpp")
-_LIB = os.path.join(_SRC_DIR, "libbyol_aug.so")
+
+
+def _lib_path() -> str:
+    """The binary's name carries a hash of its source: a copied tree can
+    hold a stale ``.so`` whose mtime looks fresh (the binary is git-ignored
+    and built where it runs), and a name that does not exist can only be
+    built, never mistaken for current."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha1(f.read()).hexdigest()[:12]
+    return os.path.join(_SRC_DIR, f"libbyol_aug.{digest}.so")
+
+
+_LIB = _lib_path()
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -52,8 +65,7 @@ def load(rebuild: bool = False) -> ctypes.CDLL:
         if _build_error and not rebuild:
             raise RuntimeError(_build_error)
         try:
-            if rebuild or not os.path.exists(_LIB) or (
-                    os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
+            if rebuild or not os.path.exists(_LIB):
                 _build()
             lib = ctypes.CDLL(_LIB)
             u8p = ctypes.POINTER(ctypes.c_uint8)

@@ -73,6 +73,12 @@ EVENT_KINDS: Dict[str, tuple] = {
 SHARDING_PLAN_FIELDS = ("mesh_shape", "axis_names", "zero1",
                         "donate_argnums")
 
+# run_header.device (core/preflight.describe_device()): what the run ran
+# on, as JAX reported it.  OPTIONAL like sharding_plan — bench.py stamps
+# its header before the backend exists — but when present it names all
+# three, so no log can claim "a TPU" without saying which and how many.
+DEVICE_FIELDS = ("platform", "kind", "count")
+
 
 def sanitize(obj: Any) -> Any:
     """JSON-strict deep copy of a payload: non-finite floats become the
@@ -156,6 +162,13 @@ def validate_event(event: Any) -> Dict[str, Any]:
             raise ValueError(
                 f"run_header.sharding_plan.zero1 must be 'off'|'on', got "
                 f"{sp.get('zero1')!r}")
+    if kind == "run_header" and "device" in event:
+        dev = event["device"]
+        if not isinstance(dev, dict) or any(f not in dev
+                                            for f in DEVICE_FIELDS):
+            raise ValueError(
+                f"run_header.device must be an object with fields "
+                f"{list(DEVICE_FIELDS)}, got {dev!r}")
     if kind == "goodput":
         bp = event["badput"]
         if not isinstance(bp, dict):

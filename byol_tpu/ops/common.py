@@ -8,9 +8,9 @@ they cannot drift per kernel:
    defaults to "on iff no TPU backend", so CPU tier-1 and CI execute the
    REAL kernel code under the Pallas interpreter instead of skipping it —
    the discipline graphlint GL109 enforces tree-wide.
-2. **shard_map shim** (:func:`shard_map_compat`): GSPMD cannot partition
-   a ``pallas_call``, so every kernel that meets a multi-device mesh
-   wraps itself in ``shard_map`` — through one version shim, not a copy
+2. **shard_map wrapper** (:func:`shard_map_unchecked`): GSPMD cannot
+   partition a ``pallas_call``, so every kernel that meets a multi-device
+   mesh wraps itself in ``shard_map`` — through one helper, not a copy
    per kernel.
 3. **Grid sizing** (:func:`resolve_block_rows` / :func:`fat_tile`): the
    interpreter pays per GRID STEP (each step re-stages its operands, so a
@@ -48,17 +48,13 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
             else interpret)
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """Version shim (the ring_attention pattern): ``jax.shard_map`` on
-    jax >= 0.5, the experimental module before.  Replication checking is
-    disabled either way — pallas_call has no replication rule, and every
-    cross-shard value in the in-tree kernels is an explicit psum."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+def shard_map_unchecked(fn, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the varying-manual-axes check off —
+    pallas_call has no replication rule, and every cross-shard value in
+    the in-tree kernels (and in ring attention) is an explicit
+    collective."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def fat_tile(count: int, *, align: int = 1,

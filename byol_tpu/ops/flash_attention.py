@@ -35,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from byol_tpu.ops import common as ops_common
+
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() well-defined
                  # when an entire tile is masked (all-padding tail block)
 
@@ -100,8 +102,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
     """(B, H, S, D) x3 -> (B, H, S, D); same contract as dense_attention."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = ops_common.resolve_interpret(interpret)
     b, h, s, d = q.shape
     scale = d ** -0.5
 

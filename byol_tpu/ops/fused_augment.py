@@ -23,14 +23,21 @@ so the step's input tax stops scaling with its length:
    ``device_augment.apply_crop`` (triangle kernel, antialiased — faithful
    to jax's ``compute_weight_mat``), with the horizontal flip FOLDED into
    the column order of the width matrix (a column permutation — exact).
-   The kernel's crop is then one einsum per view, which is both
-   bitwise-reproducible against the unfused path and MXU-shaped.
+   The kernel's crop is then two matmuls per view (rows, then columns —
+   the order scale_and_translate contracts in), which is both
+   bitwise-reproducible against the unfused path on the CPU backend and
+   MXU-shaped.
 3. **One kernel invocation per image produces BOTH views**
    (:func:`_two_view_kernel`): the raw uint8 image is read once,
    converted to float32/255 in VMEM, and each view's crop-resample, color
-   jitter (via the shared ``apply_color_jitter`` arithmetic), and
-   grayscale run per tile without ever materializing an intermediate
-   full-size float image in HBM.
+   jitter (via the shared ``jitter_planes`` arithmetic), and grayscale
+   run per tile without ever materializing an intermediate full-size
+   float image in HBM.  The kernel works on channel PLANES laid side by
+   side along the lanes (:func:`planar_rows`), never on ``(H, W, 3)``
+   blocks: the chip's compiler refused those (PR 22: no uint8 -> float32
+   cast, no vector layout for the reshapes around a minor dimension of
+   3).  The transposes into and out of that layout are XLA ops on either
+   side of the call.
 4. **The separable gaussian blur stays an MXU depthwise conv applied to
    the kernel's output** — it is the one op that genuinely wants the MXU
    conv path (and XLA fuses the final clip into its epilogue), so fusing
@@ -52,15 +59,14 @@ the REAL kernel code (GL109).  NB the interpreter dispatches one XLA op
 per kernel instruction: CPU timings document mechanism, not speed — the
 ``bench.py --augment-ab`` TPU row is the perf claim.
 
-Known costs not yet measured on silicon: the per-image weight matrices
+Known costs not yet measured on the chip: the per-image weight matrices
 are an HBM transient the unfused path does not pay (2 views x (H+W) x
 size x 4 B per image ≈ 1.6 MiB at 224px — ~100 MiB per 256-image
 microbatch, vs the ~1.2 MiB of float32 views the kernel avoids holding
-per chain stage), and Mosaic's lowering of the channels-last (size, 3)
-tiles is unexercised until the queued TPU capture (the same caveat
-fused_update.py shipped under).  If the weight transient eats the win,
-the fallback is the 2-tap index/weight form (exact only for the
-upsampling crops where ``ch <= size``).
+per chain stage), and the planar layout adds one uint8 transpose before
+the call and one float32 transpose per view after it.  If the weight
+transient eats the win, the fallback is the 2-tap index/weight form
+(exact only for the upsampling crops where ``ch <= size``).
 """
 from __future__ import annotations
 
@@ -72,16 +78,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from byol_tpu.data import device_augment
 from byol_tpu.ops import common as ops_common
 from byol_tpu.parallel.mesh import DATA_AXIS
 
-# Per-view scalar-parameter vector layout (the kernel's prm operand):
-# gates ride as 0/1 float32 and are compared > 0.5 in-kernel.
-_JITTER, _FB, _FC, _FS, _THETA, _GRAY = range(6)
-_NPARAM = 6
+# Per-view scalar-parameter vector layout (the kernel's SMEM table): gates
+# ride as 0/1 float32 and are compared > 0.5 in-kernel; the hue angle
+# rides as (cos, sin) — a kernel body has no scalar transcendentals.
+_JITTER, _FB, _FC, _FS, _COS, _SIN, _GRAY = range(7)
+_NPARAM = 7
 
 # jax.image's degenerate-weight threshold (1000 * fp32 eps), hoisted to a
 # host-time constant so the traced weight builder touches no numpy.
@@ -145,75 +153,137 @@ def view_kernel_inputs(keys, h: int, w: int, size: int, strength: float):
         p = device_augment.view_params(key, h, w, strength)
         wy, wx = crop_weight_mats(p, h, w, size)
         prm = jnp.stack([p.jitter.astype(jnp.float32), p.fb, p.fc, p.fs,
-                         p.theta, p.gray.astype(jnp.float32)])
+                         jnp.cos(p.theta), jnp.sin(p.theta),
+                         p.gray.astype(jnp.float32)])
         return wy, wx, prm, p.blur, p.sigma
     return jax.vmap(one)(keys)
+
+
+# ---------------------------------------------------------------------------
+# lane-dense layout (host side of the call)
+# ---------------------------------------------------------------------------
+#
+# The chip's compiler refuses the natural ``(H, W, 3)`` block: a minor
+# dimension of 3 sits on 128 lanes, and Mosaic has no layout for the
+# reshapes the crop contraction needs around it.  The kernel therefore
+# works on channel PLANES laid side by side along the lanes: an image is
+# ``(H, 3*Wp)`` with ``Wp`` = W rounded up to the lane width, channel c in
+# columns ``[c*Wp, c*Wp + W)`` and zeros after (inert under the
+# contraction: the matching rows of ``wx`` are zero too).
+
+def _lane_pad(w: int) -> int:
+    return -(-w // ops_common.LANES) * ops_common.LANES
+
+
+def planar_rows(images: jnp.ndarray) -> jnp.ndarray:
+    """``(..., H, W, C)`` -> ``(..., H, C*Wp)`` channel planes side by side
+    along the last axis (dtype kept: the uint8 contract stays uint8)."""
+    w = images.shape[-2]
+    x = jnp.swapaxes(images, -1, -2)                       # (..., H, C, W)
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, _lane_pad(w) - w)])
+    return x.reshape(x.shape[:-2] + (-1,))
+
+
+def _pad_wx(wx: jnp.ndarray) -> jnp.ndarray:
+    """Zero rows for the lane padding of :func:`planar_rows`."""
+    w = wx.shape[-2]
+    return jnp.pad(wx, [(0, 0)] * (wx.ndim - 2)
+                   + [(0, _lane_pad(w) - w), (0, 0)])
 
 
 # ---------------------------------------------------------------------------
 # kernel
 # ---------------------------------------------------------------------------
 
+def _view_planes(x, wyt, wx, prm, *, hue: bool):
+    """One view's op chain on a loaded ``(h, 3*wp)`` float32 planar image:
+    crop-resample as two matmuls + clip, then the gated jitter/grayscale
+    arithmetic — pure jnp, shared by the kernel body and (through
+    :func:`_view_pipeline`) the decomposition tests.  ``wyt``: (size, h);
+    ``wx``: (wp, size); ``prm``: the seven scalars of the layout above.
+    Returns the three (size, size) planes."""
+    size, wp = wyt.shape[0], wx.shape[0]
+    hi = jax.lax.Precision.HIGHEST
+    # scale_and_translate contracts rows first, all channels at once, then
+    # columns; the same order here (channels side by side on the lanes for
+    # the row contraction, stacked on the sublanes for the column one)
+    # reproduces device_augment.apply_crop bit-for-bit on the CPU backend
+    rows = jnp.dot(wyt, x, precision=hi)                   # (size, 3*wp)
+    stacked = jnp.concatenate(
+        [rows[:, c * wp:(c + 1) * wp] for c in range(3)], axis=0)
+    crop = jnp.clip(jnp.dot(stacked, wx, precision=hi), 0.0, 1.0)
+    r, g, b = (crop[c * size:(c + 1) * size] for c in range(3))
+    jittered = device_augment.jitter_planes(
+        r, g, b, prm[_FB], prm[_FC], prm[_FS], prm[_COS], prm[_SIN],
+        hue=hue)
+    jitter_on = prm[_JITTER] > 0.5
+    r, g, b = (jnp.where(jitter_on, j, c)
+               for j, c in zip(jittered, (r, g, b)))
+    lum = device_augment.luminance(r, g, b)
+    gray_on = prm[_GRAY] > 0.5
+    return tuple(jnp.where(gray_on, lum, c) for c in (r, g, b))
+
+
 def _view_pipeline(img, wy, wx, prm, *, hue: bool):
-    """One view's in-kernel op chain on a loaded (h, w, c) float32 image:
-    crop-resample einsum + clip, then the gated jitter/grayscale
-    arithmetic — shared (pure-jnp) with the decomposition tests, which
-    call it directly with forced gates so an equivalence failure names
-    the op."""
-    # the exact contraction scale_and_translate performs with the same
-    # weight matrices (jnp.einsum(x, [0,1,2], wy, [0,3], wx, [1,4],
-    # [3,4,2]) at HIGHEST precision), so the crop is reproducible
-    # bit-for-bit against device_augment.apply_crop
-    crop = jnp.clip(
-        jnp.einsum(img, [0, 1, 2], wy, [0, 3], wx, [1, 4], [3, 4, 2],
-                   precision=jax.lax.Precision.HIGHEST),
-        0.0, 1.0)
-    v = jnp.where(prm[_JITTER] > 0.5,
-                  device_augment.apply_color_jitter(
-                      crop, prm[_FB], prm[_FC], prm[_FS], prm[_THETA],
-                      hue=hue),
-                  crop)
-    return jnp.where(prm[_GRAY] > 0.5, device_augment.apply_grayscale(v), v)
+    """:func:`_view_planes` on an ``(h, w, c)`` float32 image with
+    ``view_kernel_inputs``-shaped operands — the decomposition tests call
+    this with forced gates so an equivalence failure names the op."""
+    planes = _view_planes(planar_rows(img), wy.T, _pad_wx(wx), prm,
+                          hue=hue)
+    return jnp.stack(planes, axis=-1)
 
 
-def _two_view_kernel(img_ref, wy_ref, wx_ref, prm_ref, o1_ref, o2_ref, *,
+def _two_view_kernel(prm_ref, img_ref, wyt_ref, wx_ref, o1_ref, o2_ref, *,
                      uint8_in: bool, hue: bool):
     """One image -> both pre-blur views.
 
-    The uint8 source is read ONCE and converted to float32/255 in VMEM;
-    each view then runs :func:`_view_pipeline` on it.  No randomness in
-    here (GL111): every stochastic choice arrived as an operand.
+    The uint8 source is read ONCE and converted to float32/255 in VMEM
+    (widened through int32: Mosaic has no uint8 -> float32 cast); each
+    view then runs :func:`_view_planes` on it.  Scalars come from the
+    prefetched SMEM table.  No randomness in here (GL111): every
+    stochastic choice arrived as an operand.
     """
-    img = img_ref[0].astype(jnp.float32)
+    i = pl.program_id(0)
+    x = img_ref[0]
     if uint8_in:
-        img = img / 255.0
+        x = x.astype(jnp.int32).astype(jnp.float32) / 255.0
+    size = wyt_ref.shape[2]
     for view, out_ref in ((0, o1_ref), (1, o2_ref)):
-        v = _view_pipeline(img, wy_ref[0, view], wx_ref[0, view],
-                           prm_ref[0, view], hue=hue)
-        out_ref[...] = v[None]
+        base = (i * 2 + view) * _NPARAM
+        prm = [prm_ref[base + k] for k in range(_NPARAM)]
+        planes = _view_planes(x, wyt_ref[0, view], wx_ref[0, view], prm,
+                              hue=hue)
+        for c, plane in enumerate(planes):
+            out_ref[0, c * size:(c + 1) * size, :] = plane
 
 
-def _call_kernel(images, wy, wx, prm, *, size: int, uint8_in: bool,
-                 hue: bool, interpret: bool):
-    """Grid over the (local) batch: one image, both views, per step."""
-    n, h, w, c = images.shape
-    out_struct = jax.ShapeDtypeStruct((n, size, size, c), jnp.float32)
+def _call_kernel(prm, x, wyt, wx, *, uint8_in: bool, hue: bool,
+                 interpret: bool):
+    """Grid over the (local) batch: one image, both views, per step.
+    ``prm`` is the flat ``(n*2*_NPARAM,)`` scalar table (SMEM, scalar
+    prefetch); outputs are ``(n, 3*size, size)`` stacked planes."""
+    n, h, lanes = x.shape
+    size, wp = wyt.shape[2], wx.shape[2]
+    out_struct = jax.ShapeDtypeStruct((n, 3 * size, size), jnp.float32)
     kernel = functools.partial(_two_view_kernel, uint8_in=uint8_in,
                                hue=hue)
-    out_spec = pl.BlockSpec((1, size, size, c), lambda i: (i, 0, 0, 0))
+    out_spec = pl.BlockSpec((1, 3 * size, size), lambda i, prm: (i, 0, 0))
     return pl.pallas_call(
         kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, 2, h, size), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, 2, w, size), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, 2, _NPARAM), lambda i: (i, 0, 0)),
-        ],
-        out_specs=[out_spec, out_spec],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n,),
+            in_specs=[
+                pl.BlockSpec((1, h, lanes), lambda i, prm: (i, 0, 0)),
+                pl.BlockSpec((1, 2, size, h),
+                             lambda i, prm: (i, 0, 0, 0)),
+                pl.BlockSpec((1, 2, wp, size),
+                             lambda i, prm: (i, 0, 0, 0)),
+            ],
+            out_specs=[out_spec, out_spec]),
         out_shape=[out_struct, out_struct],
         interpret=interpret,
-    )(images, wy, wx, prm)
+    )(prm, x, wyt, wx)
 
 
 # ---------------------------------------------------------------------------
@@ -241,21 +311,29 @@ def fused_two_view(key, images: jnp.ndarray, size: int, *,
     k1, k2 = jax.random.split(key)
     per_view = [view_kernel_inputs(jax.random.split(k, b), h, w, size,
                                    strength) for k in (k1, k2)]
-    # (B, 2, ...) stacks: one kernel operand per tensor, both views
-    wy = jnp.stack([per_view[0][0], per_view[1][0]], axis=1)
-    wx = jnp.stack([per_view[0][1], per_view[1][1]], axis=1)
-    prm = jnp.stack([per_view[0][2], per_view[1][2]], axis=1)
+    # (B, 2, ...) stacks: one kernel operand per tensor, both views —
+    # in the kernel's lane-dense layout (rows pre-transposed, columns
+    # lane-padded, scalars as one flat SMEM table)
+    wyt = jnp.swapaxes(
+        jnp.stack([per_view[0][0], per_view[1][0]], axis=1), -1, -2)
+    wx = _pad_wx(jnp.stack([per_view[0][1], per_view[1][1]], axis=1))
+    prm = jnp.stack([per_view[0][2], per_view[1][2]], axis=1).reshape(-1)
 
-    call = functools.partial(_call_kernel, size=size, uint8_in=uint8_in,
-                             hue=hue, interpret=interpret)
+    call = functools.partial(_call_kernel, uint8_in=uint8_in, hue=hue,
+                             interpret=interpret)
     if mesh is not None and math.prod(mesh.shape.values()) > 1:
         # GSPMD cannot partition a pallas_call: run it shard-local over
         # the data axis (augmentation is per-image — no cross-shard data)
         sh = P(DATA_AXIS)
-        call = ops_common.shard_map_compat(call, mesh,
-                                           in_specs=(sh, sh, sh, sh),
-                                           out_specs=(sh, sh))
-    v1_pre, v2_pre = call(images, wy, wx, prm)
+        call = ops_common.shard_map_unchecked(call, mesh,
+                                              in_specs=(sh, sh, sh, sh),
+                                              out_specs=(sh, sh))
+
+    def nhwc(planes):   # (B, 3*size, size) stacked planes -> (B, s, s, 3)
+        return jnp.transpose(planes.reshape(b, 3, size, size),
+                             (0, 2, 3, 1))
+
+    v1_pre, v2_pre = map(nhwc, call(prm, planar_rows(images), wyt, wx))
 
     # blur stays an MXU depthwise conv on the kernel's output; the final
     # clip fuses into its epilogue under XLA

@@ -95,8 +95,8 @@ from byol_tpu.ops.common import (LANES as _LANES, TPU_BLOCK_ROWS,
 from byol_tpu.parallel.mesh import DATA_AXIS
 
 
-# shared shard_map version shim (ops/common.py)
-_shard_map = ops_common.shard_map_compat
+# shared shard_map wrapper (ops/common.py)
+_shard_map = ops_common.shard_map_unchecked
 
 
 # ---------------------------------------------------------------------------

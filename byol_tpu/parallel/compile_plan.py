@@ -90,6 +90,8 @@ class CompilePlan:
     _canon_templates: Any = None     # {field: canonical template tree}
     _flat_templates: Any = None      # {field: flat template tree}
     _flat_layout: Any = None         # FlatLayout (flat_resident only)
+    # jitted layout conversions of the checkpoint codec, built on first use
+    _codec: Any = dataclasses.field(default_factory=dict)
 
     # -- shardings ---------------------------------------------------------
     @property
@@ -291,13 +293,29 @@ class CompilePlan:
         if not (self.zero1 or self.flat_resident):
             return state
         self._require_prepared("to_canonical()")
-        if self.flat_resident:
-            state = self._unpack_resident(state)
-        elif self.zero1:
-            state = self._convert(state, self._canon_templates,
-                                  self.num_shards)
-        return jax.device_put(
-            state, jax.tree_util.tree_map(lambda _: self.replicated, state))
+        return self._run_codec(
+            "to_canonical", state,
+            self._unpack_resident if self.flat_resident
+            else lambda s: self._convert(s, self._canon_templates,
+                                         self.num_shards),
+            lambda out: jax.tree_util.tree_map(lambda _: self.replicated,
+                                               out))
+
+    def _run_codec(self, name: str, state: Any, convert: Callable,
+                   sharding_of: Callable) -> Any:
+        """Run one whole-state layout conversion as ONE jitted program
+        with the target layout as its out_shardings — not an eager
+        multi-device slice/reshape per leaf followed by a device_put.
+        Hundreds of back-to-back eager 8-device dispatches are what the
+        CPU runtime aborted in under a loaded test run (PR 22), and on a
+        chip they are hundreds of launches per checkpoint.  The jitted
+        callable is kept: every later save or restore is a cache hit."""
+        fn = self._codec.get(name)
+        if fn is None:
+            fn = jax.jit(convert, out_shardings=sharding_of(
+                jax.eval_shape(convert, state)))
+            self._codec[name] = fn
+        return fn(state)
 
     def _unpack_resident(self, state: Any) -> Any:
         """Resident buffers -> shaped canonical trees (the shadow is
@@ -317,12 +335,12 @@ class CompilePlan:
         if not (self.zero1 or self.flat_resident):
             return state
         self._require_prepared("from_canonical()")
-        if self.flat_resident:
-            state = self._pack_resident(state)
-        elif self.zero1:
-            state = self._convert(state, self._flat_templates,
-                                  self.num_shards)
-        return jax.device_put(state, self.state_sharding(state))
+        return self._run_codec(
+            "from_canonical", state,
+            self._pack_resident if self.flat_resident
+            else lambda s: self._convert(s, self._flat_templates,
+                                         self.num_shards),
+            self.state_sharding)
 
     def canonical_template(self, state: Any) -> Any:
         """Abstract canonical-state skeleton for checkpoint restore: shapes

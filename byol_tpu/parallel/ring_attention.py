@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from byol_tpu.ops import common as ops_common
 from byol_tpu.parallel.mesh import DATA_AXIS, SEQUENCE_AXIS
 
 NEG_INF = -1e30
@@ -92,13 +93,8 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             f"parallel size {sp}")
     spec = P(DATA_AXIS, None, SEQUENCE_AXIS, None)
     body = functools.partial(ring_attention_local, axis_name=SEQUENCE_AXIS)
-    if hasattr(jax, "shard_map"):           # jax >= 0.5
-        fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                           out_specs=spec, check_vma=False)
-    else:                                    # jax 0.4.x spelling
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
+    fn = ops_common.shard_map_unchecked(body, mesh, in_specs=(spec, spec, spec),
+                                     out_specs=spec)
     return fn(q, k, v)
 
 

@@ -198,18 +198,16 @@ def main(argv: Optional[List[str]] = None) -> int:
     import signal
     import threading
 
+    import jax
+
     from byol_tpu.core import preflight
     if args.no_cuda:
-        import jax
         jax.config.update("jax_platforms", "cpu")
     if args.cpu_devices:
         preflight.force_cpu_devices(args.cpu_devices)
-    # same killable preflight as train/bench: serving startup must fail
-    # fast against a wedged backend, not hang in native init forever
-    if not preflight.preflight_backend():
-        print("byol_tpu serve: accelerator backend unreachable; pass "
-              "--no-cuda to serve on CPU.", file=sys.stderr)
-        return 2
+    preflight.place_compile_cache()
+    # this process owns the chip; CPU only when asked for
+    preflight.require_tpu("byol_tpu serve")
 
     from byol_tpu.cli import config_from_args
     from byol_tpu.observability import spans as spans_lib
@@ -252,7 +250,6 @@ def main(argv: Optional[List[str]] = None) -> int:
             print(f"serve: trace export failed ({e!r})", file=sys.stderr)
 
     with RunLog(events_path, best_effort=True) as events:
-        import jax
         events.emit("run_header",
                     config={**cfg.to_dict(),
                             "serving": {
@@ -264,7 +261,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                                 "pipeline": args.pipeline,
                                 "http": args.http}},
                     jax_version=jax.__version__,
-                    backend=jax.default_backend())
+                    backend=jax.default_backend(),
+                    device=preflight.describe_device())
         service = build_service(cfg, serve_cfg,
                                 checkpoint_dir=args.checkpoint,
                                 best=args.restore_best, events=events,
@@ -324,7 +322,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                       file=sys.stderr)
             events.emit("run_end", smoke_requests=res.completed,
                         smoke_failed=res.failed,
-                        compile_count=service.engine.compile_count)
+                        compile_count=service.engine.compile_count,
+                        engine=service.engine.describe())
             return 1 if problems else _smoke_rc(res, args.smoke)
 
         # long-running mode: the worker serves; this thread naps and
@@ -351,7 +350,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                 service.stop()
             _export_trace()
             events.emit("run_end",
-                        compile_count=service.engine.compile_count)
+                        compile_count=service.engine.compile_count,
+                        engine=service.engine.describe())
             print("serve: drained — every accepted request resolved",
                   file=sys.stderr)
     return 0

@@ -35,6 +35,7 @@ import numpy as np
 
 from byol_tpu.checkpoint import ModelSaver
 from byol_tpu.core.config import Config, ResolvedConfig, resolve, run_name
+from byol_tpu.core.preflight import describe_device
 from byol_tpu.data.loader import LoaderBundle, get_loader, pad_batch
 from byol_tpu.data.prefetch import prefetch_to_mesh
 from byol_tpu.observability import (Grapher, InputPipelineMeter,
@@ -185,7 +186,8 @@ def fit(cfg: Config, *, loader: Optional[LoaderBundle] = None,
                         best_effort=True)
         events.emit(
             "run_header", config=cfg.to_dict(), jax_version=jax.__version__,
-            backend=jax.default_backend(), run_name=name,
+            backend=jax.default_backend(), device=describe_device(),
+            run_name=name,
             mesh_shape={str(k): int(v) for k, v in mesh.shape.items()},
             n_devices=jax.device_count(),
             steps_per_train_epoch=rcfg.steps_per_train_epoch,

@@ -10,9 +10,8 @@ import sys, os; sys.path.insert(0, "/root/repo")
 import jax
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_compile_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from byol_tpu.core.preflight import place_compile_cache
+place_compile_cache()
 
 TREE = "/tmp/digits_imagefolder"
 

@@ -13,9 +13,8 @@ import jax
 import jax.numpy as jnp
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_compile_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from byol_tpu.core.preflight import place_compile_cache
+place_compile_cache()
 
 from byol_tpu.core.config import (Config, DeviceConfig, ModelConfig, RegularizerConfig,
                                   OptimConfig, TaskConfig)

@@ -17,11 +17,10 @@ import sys
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_num_cpu_devices", 2)
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_compile_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from byol_tpu.core import preflight
+
+preflight.force_cpu_devices(2)
+preflight.place_compile_cache()
 
 
 def main() -> int:

@@ -126,7 +126,8 @@ class TestDecomposition:
         return img, p
 
     def _prm(self, p, *, jitter, gray):
-        return jnp.stack([jnp.float32(jitter), p.fb, p.fc, p.fs, p.theta,
+        return jnp.stack([jnp.float32(jitter), p.fb, p.fc, p.fs,
+                          jnp.cos(p.theta), jnp.sin(p.theta),
                           jnp.float32(gray)])
 
     def test_crop_indices_exact(self):

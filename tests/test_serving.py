@@ -339,45 +339,72 @@ def served(mesh8, tmp_path_factory):
     service.batcher.close()
 
 
-def _extractor_features(served, images16):
+def _extractor_features(served, images, chunk=None):
     """The linear-eval ground truth: extract_features over the SAME
     restored checkpoint params (the offline-protocol path serving must
-    bitwise-reproduce)."""
+    reproduce), fed ``chunk`` rows per compiled batch (default: all rows
+    in one batch)."""
     from byol_tpu.training.linear_eval import (encoder_apply_fn,
                                                extract_features)
     state = types.SimpleNamespace(params=served.params,
                                   batch_stats=served.batch_stats)
     apply_fn = encoder_apply_fn(served.net, state, half=False,
                                 normalize=False)
+    chunk = chunk or len(images)
     feats, labels = extract_features(
         apply_fn,
-        iter([{"view1": images16,
-               "label": np.arange(len(images16), dtype=np.int32)}]))
+        iter([{"view1": images[i:i + chunk],
+               "label": np.arange(len(images[i:i + chunk]), dtype=np.int32)}
+              for i in range(0, len(images), chunk)]))
     return feats
 
 
+def _assert_served_matches_offline(served, got, images, bucket):
+    """Batching, bucket padding, data-sharding, donation and AOT
+    compilation may change WHERE the flops run, not what the user gets.
+
+    Why two comparisons and not one ``assert_array_equal`` (PR 22): the
+    installed XLA CPU backend picks its convolution/matmul kernels by the
+    COMPILED batch shape, and the two paths compile different ones — the
+    service pads to a bucket and splits it over the mesh (``bucket / 4``
+    rows per device here), ``extract_features`` compiles whatever batch it
+    is handed.  Measured on this stack: per-device batches of 3-4 rows
+    round differently from 1, 2, 8, 11 or 16 rows, by 1 ulp of the largest
+    embedding magnitude on ~54% of elements.  So:
+
+    - BITWISE where the compiled per-device batch shape is equal — the
+      offline path fed ``bucket / n_devices`` rows per batch;
+    - <= 4 ulp of the largest embedding magnitude across shapes — the
+      offline path fed everything in one batch.  (ulp of the largest
+      magnitude, not per element: near-zero elements carry the same
+      absolute rounding.)  No relative tolerance anywhere.
+    """
+    per_device = bucket // len(served.mesh4.devices.flat)
+    np.testing.assert_array_equal(
+        got, _extractor_features(served, images, chunk=per_device))
+    expected = _extractor_features(served, images)
+    four_ulp = 4 * np.spacing(np.float32(np.max(np.abs(expected))))
+    assert float(np.max(np.abs(got - expected))) <= four_ulp
+
+
 class TestServingCorrectness:
-    def test_served_embeddings_bitwise_match_linear_eval(self, served):
-        """The acceptance pin: batching, bucket padding, data-sharding,
-        donation, and AOT compilation may change WHERE the flops run, but
-        not a single bit of the embeddings the user gets — and the hot
-        path runs clean under the guard_steps transfer guard (explicit
-        device_put/device_get only)."""
+    def test_served_embeddings_match_linear_eval(self, served):
+        """The acceptance pin (see _assert_served_matches_offline for what
+        'match' means and why) — and the hot path runs clean under the
+        guard_steps transfer guard (explicit device_put/device_get only)."""
         rng = np.random.RandomState(7)
         images = rng.rand(16, 16, 16, 3).astype(np.float32)
-        expected = _extractor_features(served, images)
-
         engine = served.service.engine
         # exact-fill bucket (16 rows -> bucket 16)
-        got_full = guard_steps(engine.embed)(images)
-        np.testing.assert_array_equal(got_full, expected)
+        _assert_served_matches_offline(
+            served, guard_steps(engine.embed)(images), images, 16)
         # padded bucket (11 rows -> bucket 16, 5 pad rows sliced off):
         # pad rows must never bleed into real rows
-        got_padded = guard_steps(engine.embed)(images[:11])
-        np.testing.assert_array_equal(got_padded, expected[:11])
+        _assert_served_matches_offline(
+            served, guard_steps(engine.embed)(images[:11]), images[:11], 16)
         # and below the floor (3 rows -> bucket 8)
-        got_small = guard_steps(engine.embed)(images[:3])
-        np.testing.assert_array_equal(got_small, expected[:3])
+        _assert_served_matches_offline(
+            served, guard_steps(engine.embed)(images[:3]), images[:3], 8)
 
     def test_full_service_roundtrip_matches_too(self, served):
         """Same pin through the THREADED path: queue -> coalesce ->
@@ -386,7 +413,8 @@ class TestServingCorrectness:
         (enqueue -> coalesce -> stage -> dispatch -> readback -> deliver,
         monotonic, with a unique trace id): the ISSUE 9 acceptance pin
         that serving spans cover the full request path under the same
-        scenario as the bitwise-parity check."""
+        scenario as the parity check.  Which requests the worker coalesces
+        is timing-dependent, so only the across-shapes bound applies."""
         from byol_tpu.serving.batcher import LIFECYCLE_PHASES
         rng = np.random.RandomState(8)
         images = rng.rand(6, 16, 16, 3).astype(np.float32)
@@ -396,7 +424,8 @@ class TestServingCorrectness:
             svc.start(warmup=True)
         reqs = [svc.submit(images[i]) for i in range(6)]
         got = np.stack([r.result(timeout=120.0)[0] for r in reqs])
-        np.testing.assert_array_equal(got, expected)
+        four_ulp = 4 * np.spacing(np.float32(np.max(np.abs(expected))))
+        assert float(np.max(np.abs(got - expected))) <= four_ulp
         assert len({r.trace_id for r in reqs}) == len(reqs)
         for r in reqs:
             stamps = [r.marks[p] for p in LIFECYCLE_PHASES]

@@ -1,0 +1,231 @@
+"""Compile-for-the-chip tests: the kernels of the main path, and one whole
+train step, lowered with ``interpret=False`` and compiled by the TPU's own
+compiler for a DESCRIBED ``v5e:2x2`` topology — no chip attached, nothing
+runs.  They guard what the Pallas interpreter cannot see: PR 22 found
+``fused_two_view`` refused here (no uint8 -> float32 cast; no vector layout
+around a minor dimension of 3) after it had passed every interpret-mode
+test.
+
+Rules this file keeps (``on-chip-measurement`` guide, section 2): the
+topology is described inside a module-scoped, non-autouse fixture that
+skips when it cannot be — never at import, in a ``skipif``, in
+``parametrize`` or in conftest — because only one process may load the
+TPU's library, and every xdist worker imports every test file; everything
+built from the topology is built inside a fixture or a test; all these
+tests live in this ONE file; the compiles happen in this process; the
+persistent compilation cache is off around them (such an entry cannot be
+read back without a chip).
+
+A compile that passes is not a chip run: ``python chip_smoke.py`` is.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from byol_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, SEQUENCE_AXIS
+
+V5E_HBM_BYTES = 16 * 2 ** 30
+BATCH, IMAGE, RAW = 256, 224, 256       # the flagship's per-chip batch
+LARS = dict(weight_decay=1e-6, momentum_decay=0.9)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices), (DATA_AXIS,))
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _flagship_rcfg():
+    """ResNet-50 BYOL as byol_tpu/cli.py builds it by default, batch 256."""
+    from byol_tpu.core import config as config_lib
+    c = config_lib.Config()
+    c = c.replace(
+        task=dataclasses.replace(c.task, batch_size=BATCH, epochs=2),
+        model=dataclasses.replace(c.model, arch="resnet50", fuse_views=True),
+        device=dataclasses.replace(c.device, num_replicas=1, half=True))
+    return config_lib.resolve(c, num_train_samples=4 * BATCH,
+                              num_test_samples=BATCH, output_size=10,
+                              input_shape=(IMAGE, IMAGE, 3))
+
+
+@pytest.fixture(scope="module")
+def rn50_params():
+    """The flagship's parameter tree as shapes (no array is ever made)."""
+    from byol_tpu.training.build import build_net, init_variables
+    rcfg = _flagship_rcfg()
+    net = build_net(rcfg)
+    variables = jax.eval_shape(lambda k: init_variables(net, rcfg, k),
+                               jax.random.PRNGKey(0))
+    return variables["params"]
+
+
+def _with(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# ---------------------------------------------------------------------------
+# the fused LARS+EMA update: four entries (ops/fused_update.py)
+# ---------------------------------------------------------------------------
+
+def test_fused_update_transient(no_persistent_cache, one_chip, rn50_params):
+    from byol_tpu.ops import fused_update as fu
+    p = _with(rn50_params, one_chip)
+    s = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    _compile(lambda p, g, m, t, lr, tau: fu.fused_lars_ema_update(
+        p, g, m, t, lr=lr, tau=tau, interpret=False, **LARS),
+        p, p, p, p, s, s)
+
+
+def test_fused_update_resident(no_persistent_cache, one_chip, rn50_params):
+    from byol_tpu.ops import fused_update as fu
+    from byol_tpu.parallel import flat_state
+    layout = flat_state.build_layout(rn50_params, 1, interpret=False)
+    p = _with(rn50_params, one_chip)
+    buf = jax.ShapeDtypeStruct((layout.global_size,), jnp.float32,
+                               sharding=one_chip)
+    s = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    _compile(lambda p, g, m, t, lr, tau: fu.fused_lars_ema_update_resident(
+        p, g, m, t, layout=layout, lr=lr, tau=tau, interpret=False, **LARS),
+        p, p, buf, buf, s, s)
+
+
+def test_fused_update_zero1(no_persistent_cache, mesh4, rn50_params):
+    from byol_tpu.ops import fused_update as fu
+    from byol_tpu.parallel import zero1
+    sharded = NamedSharding(mesh4, P(DATA_AXIS))
+    flat = _with(jax.tree_util.tree_map(
+        lambda t: zero1.flat_struct(t, 4), rn50_params), sharded)
+    s = jax.ShapeDtypeStruct((), jnp.float32,
+                             sharding=NamedSharding(mesh4, P()))
+    compiled = _compile(
+        lambda p, g, m, t, lr, tau: fu.fused_lars_ema_update_zero1(
+            p, g, m, t, param_template=rn50_params, mesh=mesh4,
+            num_shards=4, lr=lr, tau=tau, interpret=False, **LARS),
+        flat, flat, flat, flat, s, s)
+    assert "all-reduce" in compiled.as_text()    # the segment-norm psum
+
+
+def test_fused_update_resident_zero1(no_persistent_cache, mesh4,
+                                     rn50_params):
+    from byol_tpu.ops import fused_update as fu
+    from byol_tpu.parallel import flat_state, zero1
+    layout = flat_state.build_layout(rn50_params, 4, interpret=False)
+    sharded = NamedSharding(mesh4, P(DATA_AXIS))
+    grads = _with(jax.tree_util.tree_map(
+        lambda t: zero1.flat_struct(t, 4), rn50_params), sharded)
+    buf = jax.ShapeDtypeStruct((layout.global_size,), jnp.float32,
+                               sharding=sharded)
+    s = jax.ShapeDtypeStruct((), jnp.float32,
+                             sharding=NamedSharding(mesh4, P()))
+    _compile(
+        lambda p, g, m, t, lr, tau: fu.fused_lars_ema_update_resident_zero1(
+            p, g, m, t, layout=layout, mesh=mesh4, lr=lr, tau=tau,
+            interpret=False, **LARS),
+        buf, grads, buf, buf, s, s)
+
+
+# ---------------------------------------------------------------------------
+# the fused two-view augmentation (ops/fused_augment.py)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("raw,dtype", [(RAW, "uint8"), (IMAGE, "uint8"),
+                                       (RAW, "float32"),
+                                       (IMAGE, "float32")])
+def test_fused_two_view(no_persistent_cache, one_chip, raw, dtype):
+    from byol_tpu.ops import fused_augment
+    images = jax.ShapeDtypeStruct((BATCH, raw, raw, 3), np.dtype(dtype),
+                                  sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    _compile(lambda k, im: fused_augment.fused_two_view(
+        k, im, IMAGE, interpret=False), key, images)
+
+
+# ---------------------------------------------------------------------------
+# flash attention at ViT-B/16 and So400m-like shapes (ops/flash_attention.py)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((64, 12, 197, 64), "bfloat16"),     # ViT-B/16 @224: 196 patches + cls
+    ((64, 12, 197, 64), "float32"),
+    ((8, 16, 256, 72), "bfloat16"),      # head_dim 72: not a lane multiple
+])
+def test_flash_attention(no_persistent_cache, one_chip, shape, dtype):
+    from byol_tpu.ops.flash_attention import flash_attention
+    q = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    _compile(lambda q, k, v: flash_attention(q, k, v, interpret=False),
+             q, q, q)
+
+
+# ---------------------------------------------------------------------------
+# one whole ResNet-50 train step at batch 256 fits the chip
+# ---------------------------------------------------------------------------
+
+def test_resnet50_train_step_fits_16gb(no_persistent_cache, topo):
+    """The flagship's jitted step (``--fuse-views``, bf16, LARS), built
+    from the compile plan exactly as setup_training wires it, compiles for
+    one described chip and its ``memory_analysis()`` fits 16 GB."""
+    from byol_tpu.core.precision import get_policy
+    from byol_tpu.parallel.compile_plan import build_plan
+    from byol_tpu.training.build import (build_net, build_tx,
+                                         init_variables, step_config)
+    from byol_tpu.training.state import create_train_state
+    from byol_tpu.training.steps import make_train_step
+    rcfg = _flagship_rcfg()
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1, 1),
+                (DATA_AXIS, SEQUENCE_AXIS, MODEL_AXIS))
+    net = build_net(rcfg)
+    tx, schedule = build_tx(rcfg)
+    state = jax.eval_shape(
+        lambda k: create_train_state(init_variables(net, rcfg, k), tx),
+        jax.random.PRNGKey(0))
+    plan = build_plan(mesh)
+    step = plan.jit_train_step(
+        make_train_step(net, tx, step_config(rcfg), get_policy(True),
+                        lr_schedule=schedule, mesh=mesh),
+        plan.state_sharding(state))
+    view = jax.ShapeDtypeStruct((BATCH, IMAGE, IMAGE, 3), jnp.float32)
+    batch = {"view1": view, "view2": view,
+             "label": jax.ShapeDtypeStruct((BATCH,), jnp.int32)}
+    with mesh:
+        compiled = step.lower(state, batch).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 0 < total < V5E_HBM_BYTES, f"{total / 2 ** 30:.2f} GiB"

@@ -300,42 +300,93 @@ def test_cli_zero1_flag_and_fsdp_alias():
         config_from_args(args)
 
 
-def test_preflight_cpu_pinned_skips_probe(monkeypatch):
-    """Under an explicit cpu pin (the test conftest) there is nothing to
-    probe — no subprocess may be spawned."""
+def _stub_no_chip(monkeypatch):
+    """A machine whose default backend is not the TPU, with the CPU NOT
+    asked for (the test harness itself runs under JAX_PLATFORMS=cpu, which
+    IS a request — stub it away)."""
+    import jax
+    from byol_tpu.core import preflight
+    monkeypatch.setattr(preflight, "cpu_requested", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+
+
+def _forbid_children(monkeypatch):
     import subprocess
-    from byol_tpu.core.preflight import preflight_backend
 
     def boom(*a, **k):  # pragma: no cover - must not be reached
-        raise AssertionError("probe subprocess must not run under cpu pin")
+        raise AssertionError("start-up spawned a child process")
     monkeypatch.setattr(subprocess, "run", boom)
-    assert preflight_backend() is True
+    monkeypatch.setattr(subprocess, "Popen", boom)
 
 
-def test_cli_fails_fast_when_backend_unreachable(monkeypatch, capsys):
-    """The train CLI must exit 2 (not hang in backend init) against a dead
-    accelerator — the bench has carried this guard since round 3; a capture
-    -pipeline train run hung forever without it."""
+def test_train_cli_refuses_a_backend_that_is_not_tpu(monkeypatch):
+    """No chip and the CPU not asked for: exit non-zero at start-up, before
+    any config, loader or model is built — and without starting a child
+    (one process per chip)."""
     from byol_tpu import cli
-    from byol_tpu.core import preflight
-    monkeypatch.setattr(preflight, "preflight_backend", lambda *a, **k: False)
-    rc = cli.main(["--task", "fake", "--batch-size", "16", "--epochs", "1"])
-    assert rc == 2
-    assert "unreachable" in capsys.readouterr().err
+    _stub_no_chip(monkeypatch)
+    _forbid_children(monkeypatch)
+    monkeypatch.setattr(
+        cli, "config_from_args",
+        lambda a: pytest.fail("built a config without a chip"))
+    with pytest.raises(SystemExit) as exc:
+        cli.main(["--task", "fake", "--batch-size", "16", "--epochs", "1"])
+    assert exc.value.code not in (0, None)
+    assert "not 'tpu'" in str(exc.value.code)
 
 
-def test_cli_skips_preflight_on_multihost(monkeypatch):
-    """A standalone probe child cannot join a slice-wide TPU runtime, so
-    distributed runs must skip the preflight (it would time out and
-    misdiagnose a healthy pod) and go straight to rendezvous."""
-    import pytest
+def test_serve_cli_refuses_a_backend_that_is_not_tpu(monkeypatch):
+    from byol_tpu.serving import cli as serve_cli
+    from byol_tpu.serving import service
+    _stub_no_chip(monkeypatch)
+    _forbid_children(monkeypatch)
+    monkeypatch.setattr(
+        service, "build_service",
+        lambda *a, **k: pytest.fail("built a service without a chip"))
+    with pytest.raises(SystemExit) as exc:
+        serve_cli.main(["--smoke", "4"])
+    assert exc.value.code not in (0, None)
+    assert "not 'tpu'" in str(exc.value.code)
+
+
+@pytest.mark.parametrize("how", ["env", "no_cuda"])
+def test_train_cli_runs_on_cpu_when_asked(monkeypatch, how):
+    """JAX_PLATFORMS=cpu in the environment (the harness) and --no-cuda
+    are both requests for the CPU: start-up passes and goes on to build
+    the config."""
+    import jax
     from byol_tpu import cli
-    from byol_tpu.core import preflight
+    _forbid_children(monkeypatch)
+    if how == "no_cuda":
+        # not asked through the environment: only the flag pins the CPU
+        from byol_tpu.core import preflight
+        asked = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: asked.append((k, v)))
+        monkeypatch.setattr(
+            preflight, "cpu_requested",
+            lambda: ("jax_platforms", "cpu") in asked)
+
+    class Sentinel(Exception):
+        pass
+
+    def reached(args):
+        raise Sentinel()
+    monkeypatch.setattr(cli, "config_from_args", reached)
+    argv = ["--task", "fake", "--batch-size", "16", "--epochs", "1"]
+    with pytest.raises(Sentinel):
+        cli.main(argv + (["--no-cuda"] if how == "no_cuda" else []))
+
+
+def test_cli_rendezvous_precedes_the_backend_check(monkeypatch):
+    """Multi-host: the rendezvous must come before anything initialises
+    the backend — the TPU check included."""
+    import jax
+    from byol_tpu import cli
     from byol_tpu.parallel import mesh as mesh_lib
-
-    def no_probe(*a, **k):
-        raise AssertionError("preflight must not run on multi-host")
-    monkeypatch.setattr(preflight, "preflight_backend", no_probe)
+    monkeypatch.setattr(
+        jax, "default_backend",
+        lambda: pytest.fail("backend touched before the rendezvous"))
 
     class Sentinel(Exception):
         pass
