@@ -859,11 +859,11 @@ def _profile(arch, image_size, candidates, logdir):
     for _ in range(3):                          # compile (cached) + warm
         state, metrics = train_step(state, batch)
     float(metrics["loss_mean"])
-    jax.profiler.start_trace(logdir)
-    for _ in range(5):
-        state, metrics = train_step(state, batch)
-    float(metrics["loss_mean"])                 # readback inside the trace
-    jax.profiler.stop_trace()
+    from byol_tpu.observability import profiling
+    with profiling.trace(logdir):               # device planes only
+        for _ in range(5):
+            state, metrics = train_step(state, batch)
+        float(metrics["loss_mean"])             # readback inside the trace
     print(_json_line({"metric": "profile", "value": bs,
                       "unit": "batch/chip", "vs_baseline": None,
                       "logdir": logdir}))

@@ -9,9 +9,13 @@ readback, startup/compile) without moving the throughput needle (the
 ``bench.py --spans-ab`` budget is < 2%, same bar as telemetry).
 
 Every span also opens the matching :func:`profiling.annotate` region
-(``jax.profiler.TraceAnnotation``), so when an XLA trace is being captured
-the host spans line up with device ops on the same timeline — the flight
-recorder and the profiler tell one story.
+(``jax.profiler.TraceAnnotation``), which a capture with the host tracer on
+shows beside the device ops.  ``profiling.trace`` keeps the host tracer off
+(it slows the host-to-device path threefold), so the two meet by CLOCK: one
+anchor between ``perf_counter`` and the epoch clock, taken when this module
+is imported (:func:`epoch_ns`), puts every span on the clock the device
+trace is stamped in, and :func:`export_chrome_trace` writes epoch
+microseconds — the export overlays a device trace in Perfetto as it is.
 
 Two consumers fold the ring:
 
@@ -47,6 +51,18 @@ from byol_tpu.observability import profiling
 # spans are evicted (``dropped`` counts them) — the recorder must never
 # grow without bound on a week-long run
 _CAPACITY = 1 << 16
+
+# The two host clocks, read together once: spans are taken on perf_counter
+# (monotonic, arbitrary origin), the profiler stamps a trace in epoch
+# nanoseconds.  One anchor per process serves every recorder, and the
+# export takes records, not a recorder.
+_ANCHOR = (time.time_ns(), time.perf_counter())
+
+
+def epoch_ns(t: float) -> int:
+    """The ``perf_counter`` reading ``t`` (a span's ``t0`` / ``t1``) in
+    epoch nanoseconds."""
+    return _ANCHOR[0] + round((t - _ANCHOR[1]) * 1e9)
 
 
 class Span:
@@ -241,10 +257,11 @@ def _json_safe(value: Any) -> Any:
 def export_chrome_trace(records: Iterable[Span], path: str, *,
                         process_name: str = "byol_tpu") -> int:
     """Write spans as Chrome trace events (the ``traceEvents`` JSON array
-    format); returns the event count.  Timestamps are perf_counter-based
-    microseconds — relative, which both ``chrome://tracing`` and Perfetto
-    render fine.  One complete-event (``ph: "X"``) per span; a metadata
-    event names the process so multi-file sessions stay legible."""
+    format); returns the event count.  ``ts`` is in EPOCH microseconds
+    (:func:`epoch_ns`), the clock of a profiler trace, so the file overlays
+    a device trace taken in the same run.  One complete-event (``ph:
+    "X"``) per span; a metadata event names the process so multi-file
+    sessions stay legible."""
     pid = os.getpid()
     events: List[Dict[str, Any]] = [{
         "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
@@ -255,7 +272,7 @@ def export_chrome_trace(records: Iterable[Span], path: str, *,
             "name": r.name,
             "cat": r.name.split("/", 1)[0],
             "ph": "X",
-            "ts": r.t0 * 1e6,
+            "ts": epoch_ns(r.t0) / 1e3,
             "dur": (r.t1 - r.t0) * 1e6,
             "pid": pid,
             "tid": r.tid,

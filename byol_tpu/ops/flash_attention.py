@@ -135,5 +135,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q, d), jnp.float32),        # fp32 accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qr, kr, vr)
     return out.reshape(b, h, s_pad_q, d)[:, :, :s, :]

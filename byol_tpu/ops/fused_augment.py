@@ -283,6 +283,7 @@ def _call_kernel(prm, x, wyt, wx, *, uint8_in: bool, hue: bool,
             out_specs=[out_spec, out_spec]),
         out_shape=[out_struct, out_struct],
         interpret=interpret,
+        name="fused_two_view",
     )(prm, x, wyt, wx)
 
 

@@ -318,6 +318,7 @@ def _fused_update_buffers(p_buf, g_buf, m_buf, t_buf, lr, tau, *,
         out_specs=pl.BlockSpec((br, 2), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((grid_rows, 2), jnp.float32),
         interpret=interpret,
+        name="fused_lars_ema_update_norms",
     )(p_buf, g_buf, wd_rows)
     seg_sums = jax.ops.segment_sum(
         row_sums, jnp.asarray(row_ids),
@@ -353,6 +354,7 @@ def _fused_update_buffers(p_buf, g_buf, m_buf, t_buf, lr, tau, *,
         # bandwidth story
         input_output_aliases={0: 0, 2: 1, 3: 2},
         interpret=interpret,
+        name="fused_lars_ema_update_apply",
     )(p_buf, g_buf, m_buf, t_buf, wd_rows, sc_rows, hp)
     trust = ratios[jnp.asarray(np.nonzero(adapted_np)[0])] \
         if adapted_np.any() else jnp.ones((1,), jnp.float32)

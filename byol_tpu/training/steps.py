@@ -71,6 +71,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from byol_tpu.core import rng as rng_lib
 from byol_tpu.core.precision import Policy, FP32
@@ -87,6 +88,21 @@ from byol_tpu.training.state import TrainState
 # modules receive it as bn_axis_name (build.py) and pmean their statistics
 # across it.
 ACCUM_AXIS = "accum"
+
+# The phases of the train step, as ``jax.named_scope`` names in every HLO
+# instruction's ``op_name`` (``jit(train_step)/target_forward/ResNet/...``;
+# the backward of ``online_forward`` / ``loss`` reads
+# ``transpose(jvp(online_forward))/...``).  Metadata only: nothing runs, no
+# flag.  The device trace is split by these names (PERF.md section 3); the
+# benchmark keeps its own copy of them.
+PHASE_SCOPES = ("augment", "target_forward", "online_forward", "loss",
+                "update")
+
+
+def _phase(name: str):
+    if name not in PHASE_SCOPES:
+        raise ValueError(f"{name!r} is not one of {PHASE_SCOPES}")
+    return jax.named_scope(name)
 
 # ImageNet channel statistics (torchvision convention) behind the
 # ``normalize_inputs`` parity switch (Quirk Q3: the reference feeds raw
@@ -394,29 +410,32 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
 
         # Target branch: outside the differentiated function — autodiff never
         # sees it (vs reference building + detaching the graph, Quirk Q10).
-        tgt1, tgt2, _ = _forward_views(
-            net, target_params, batch_stats, aug1, aug2,
-            train=True, fuse=scfg.fuse_views, update_stats=False)
+        with _phase("target_forward"):
+            tgt1, tgt2, _ = _forward_views(
+                net, target_params, batch_stats, aug1, aug2,
+                train=True, fuse=scfg.fuse_views, update_stats=False)
         target_proj1 = jax.lax.stop_gradient(tgt1["projection"])
         target_proj2 = jax.lax.stop_gradient(tgt2["projection"])
 
         def loss_fn(params):
-            on1, on2, new_bs = _forward_views(
-                net, params, batch_stats, aug1, aug2,
-                train=True, fuse=scfg.fuse_views, update_stats=True)
-            byol_loss = loss_function(
-                on1["prediction"], on2["prediction"],
-                target_proj1, target_proj2, norm_mode=scfg.norm_mode)
-            # Probe on stop-grad features of both views; labels doubled in
-            # train mode (main.py:249-252,596-597, Quirk Q11).
-            reprs = jnp.concatenate(
-                [on1["representation"], on2["representation"]], axis=0)
-            logits = net.apply({"params": params}, reprs,
-                               method="classify")
-            cls_labels = jnp.concatenate([labels, labels], axis=0)
-            cls_loss = cross_entropy(logits, cls_labels)
-            total = byol_loss + cls_loss
-            top1, top5 = topk_accuracy(logits, cls_labels)
+            with _phase("online_forward"):
+                on1, on2, new_bs = _forward_views(
+                    net, params, batch_stats, aug1, aug2,
+                    train=True, fuse=scfg.fuse_views, update_stats=True)
+            with _phase("loss"):
+                byol_loss = loss_function(
+                    on1["prediction"], on2["prediction"],
+                    target_proj1, target_proj2, norm_mode=scfg.norm_mode)
+                # Probe on stop-grad features of both views; labels doubled
+                # in train mode (main.py:249-252,596-597, Quirk Q11).
+                reprs = jnp.concatenate(
+                    [on1["representation"], on2["representation"]], axis=0)
+                logits = net.apply({"params": params}, reprs,
+                                   method="classify")
+                cls_labels = jnp.concatenate([labels, labels], axis=0)
+                cls_loss = cross_entropy(logits, cls_labels)
+                total = byol_loss + cls_loss
+                top1, top5 = topk_accuracy(logits, cls_labels)
             metrics = {"loss_mean": total,
                        "byol_loss_mean": byol_loss,
                        "linear_loss_mean": cls_loss,
@@ -446,22 +465,23 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
         pixels augmented HERE — inside the accumulation scan, so only this
         microbatch's float32 views are ever live — under step placement."""
         if scfg.augment_in_step:
-            if scfg.fused_augment:
-                # Fused augmentation kernel (ops/fused_augment.py): the
-                # SAME keys and augmentation distribution, but the per-
-                # view op chain collapses into one Pallas pass per image
-                # (uint8 convert + crop + flip + jitter + grayscale) with
-                # the blur conv on its output — shard-local over the data
-                # axis on a multi-device mesh (GSPMD cannot partition a
-                # pallas_call).
-                from byol_tpu.ops import fused_augment as fused_aug_lib
-                v1, v2 = fused_aug_lib.fused_two_view(
-                    xs["key"], xs["images"], scfg.image_size,
-                    strength=scfg.color_jitter_strength, mesh=mesh)
-            else:
-                v1, v2 = device_augment.two_view(
-                    xs["key"], xs["images"], scfg.image_size,
-                    strength=scfg.color_jitter_strength)
+            with _phase("augment"):
+                if scfg.fused_augment:
+                    # Fused augmentation kernel (ops/fused_augment.py): the
+                    # SAME keys and augmentation distribution, but the per-
+                    # view op chain collapses into one Pallas pass per image
+                    # (uint8 convert + crop + flip + jitter + grayscale) with
+                    # the blur conv on its output — shard-local over the data
+                    # axis on a multi-device mesh (GSPMD cannot partition a
+                    # pallas_call).
+                    from byol_tpu.ops import fused_augment as fused_aug_lib
+                    v1, v2 = fused_aug_lib.fused_two_view(
+                        xs["key"], xs["images"], scfg.image_size,
+                        strength=scfg.color_jitter_strength, mesh=mesh)
+                else:
+                    v1, v2 = device_augment.two_view(
+                        xs["key"], xs["images"], scfg.image_size,
+                        strength=scfg.color_jitter_strength)
             return v1, v2, xs["label"]
         return xs["view1"], xs["view2"], xs["label"]
 
@@ -493,9 +513,12 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
             bs_in = bs_acc if sequential_bn else state.batch_stats
             g, new_bs, m = micro_step(state, bs_in, x)
             add = lambda a, b: jax.tree_util.tree_map(jnp.add, a, b)
-            grad_sum = add(grad_sum, g)
-            bs_acc = new_bs if sequential_bn else add(bs_acc, new_bs)
-            metric_sum = add(metric_sum, m)
+            # the running sums are the first stage of the update: one
+            # sweep over the gradients per microbatch
+            with _phase("update"):
+                grad_sum = add(grad_sum, g)
+                bs_acc = new_bs if sequential_bn else add(bs_acc, new_bs)
+                metric_sum = add(metric_sum, m)
             return (grad_sum, bs_acc, metric_sum), None
 
         init = (zeros(g_shape),
@@ -506,8 +529,9 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
             lambda x: (x / k).astype(x.dtype), t)
         # Equal-size microbatches: the mean over microbatch means IS the
         # effective-batch mean, for gradients and metrics alike.
-        new_bs = bs_acc if sequential_bn else mean(bs_acc)
-        return mean(grad_sum), new_bs, mean(metric_sum)
+        with _phase("update"):
+            new_bs = bs_acc if sequential_bn else mean(bs_acc)
+            return mean(grad_sum), new_bs, mean(metric_sum)
 
     def accumulate_global(state: TrainState, xs):
         """'global' mode: vmap over microbatches with ACCUM_AXIS bound, so
@@ -533,16 +557,20 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
             # under zero1, replicated otherwise); rebuild the shaped tree
             # just-in-time with the bucketed gather — a handful of
             # coalesced all-gathers instead of one per leaf (and with one
-            # shard, a pure carve with no collective at all).
-            micro_state = state.replace(
-                target_params=flat_ctx.gather_tree(state.target_params))
+            # shard, a pure carve with no collective at all).  Scoped as
+            # ``update``: it is the update's layout cost, paid early.
+            with _phase("update"):
+                micro_state = state.replace(
+                    target_params=flat_ctx.gather_tree(state.target_params))
         elif zero1_ctx is not None:
             # ZeRO-1: the EMA target arrives flat-sharded; gather it
             # just-in-time for the target forwards.  The microbatch paths
             # read the target off the state they are handed, so hand them
             # a view with the gathered tree in place.
-            micro_state = state.replace(target_params=zero1_ctx.gather(
-                state.target_params, zero1_ctx.param_template))
+            with _phase("update"):
+                micro_state = state.replace(
+                    target_params=zero1_ctx.gather(
+                        state.target_params, zero1_ctx.param_template))
         else:
             micro_state = state
         if scfg.augment_in_step:
@@ -565,7 +593,13 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
                           if scfg.accum_bn_mode == "global"
                           else accumulate_scan)
             grads, new_bs, metrics = accumulate(micro_state, xs)
+        with _phase("update"):
+            return apply_update(state, grads, new_bs, metrics)
 
+    def apply_update(state: TrainState, grads, new_bs, metrics):
+        """Everything of the step after the gradients exist, traced under
+        the ``update`` scope: the optimizer (optax chain or a fused kernel
+        entry), the EMA tick, the telemetry vector, the new state."""
         if scfg.fused_update:
             # Fused LARS+EMA update (ops/fused_update.py): trust ratios
             # from the kernel's segment-norm pass, then wd fold-in +
@@ -744,8 +778,15 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
                 target_params=new_target, loss=metrics["loss_mean"],
                 collapse=collapse, trust_ratios=trust)
 
+        # One real attribute on one scalar add.  The persistent compilation
+        # cache keys a program with its debug info stripped, scope names
+        # included, so a step whose scopes alone were renamed would be
+        # served the executable cached before the rename, stale names and
+        # all — and the device trace is read by those names.
+        with set_xla_metadata(phase_scopes=" ".join(PHASE_SCOPES)):
+            next_step = state.step + 1
         new_state = state.replace(
-            step=state.step + 1,
+            step=next_step,
             params=new_params,
             batch_stats=new_bs,
             target_params=new_target,

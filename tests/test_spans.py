@@ -261,6 +261,61 @@ class TestChromeTraceExport:
         # process metadata present (multi-file Perfetto sessions)
         assert any(e.get("ph") == "M" for e in events)
 
+    def test_exported_timestamps_are_epoch_microseconds(self, tmp_path):
+        rec = spans_lib.SpanRecorder()
+        before = time.time_ns()
+        with rec.span("train/dispatch"):
+            time.sleep(0.002)
+        after = time.time_ns()
+        path = str(tmp_path / "trace.json")
+        spans_lib.export_chrome_trace(rec.records(), path)
+        with open(path) as f:
+            (ev,) = [e for e in json.load(f)["traceEvents"]
+                     if e["ph"] == "X"]
+        # the two host clocks agree to well under a millisecond here
+        assert before / 1e3 - 500 <= ev["ts"]
+        assert ev["ts"] + ev["dur"] <= after / 1e3 + 500
+        r = rec.records()[0]
+        assert spans_lib.epoch_ns(r.t1) - spans_lib.epoch_ns(r.t0) == \
+            pytest.approx((r.t1 - r.t0) * 1e9, abs=2)
+
+    def test_span_meets_the_profiler_trace_by_clock(self, tmp_path):
+        """A span round a jitted call, inside ``profiling.trace``, contains
+        that call's op event once the event is shifted by the trace's
+        ``profile_start_time`` — no host tracer needed on the chip (on the
+        CPU backend the ops ARE host events, and ``trace`` keeps them)."""
+        import glob
+
+        import jax
+        import jax.numpy as jnp
+        from jax.profiler import ProfileData
+
+        from byol_tpu.observability import profiling
+        f = jax.jit(lambda x: jnp.sin(x) @ x)
+        x = jnp.ones((256, 256))
+        f(x).block_until_ready()
+        rec = spans_lib.SpanRecorder()
+        with profiling.trace(str(tmp_path)):
+            time.sleep(0.005)
+            with rec.span("train/dispatch"):
+                f(x).block_until_ready()
+            time.sleep(0.005)
+        (pb,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        start, ops = None, []
+        for plane in ProfileData.from_file(pb).planes:
+            if plane.name == "Task Environment":
+                start = dict(plane.stats)["profile_start_time"]
+            for line in plane.lines:
+                ops += [e for e in line.events
+                        if "hlo_op" in dict(e.stats)]
+        assert start and ops
+        (r,) = rec.records()
+        t0, t1 = spans_lib.epoch_ns(r.t0), spans_lib.epoch_ns(r.t1)
+        slack = 200_000                       # ns between the two clocks
+        for e in ops:
+            assert t0 - slack <= start + e.start_ns
+            assert start + e.start_ns + e.duration_ns <= t1 + slack
+
     def test_export_creates_parent_dirs_and_handles_empty(self, tmp_path):
         path = str(tmp_path / "deep" / "dir" / "trace.json")
         n = spans_lib.export_chrome_trace([], path)
