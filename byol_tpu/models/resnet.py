@@ -176,12 +176,15 @@ class ResNet(nn.Module):
     def __call__(self, x, train: bool = True):
         conv = functools.partial(nn.Conv, use_bias=False, dtype=self.dtype,
                                  kernel_init=nn.initializers.he_normal())
-        # BN params/stats stay fp32 (param_dtype default); leaving dtype=None
-        # promotes bf16 inputs to fp32 for the statistics — the apex-O2 "BN in
-        # fp32" rule (SURVEY.md §2.4) by construction.
+        # BN params/stats stay fp32 (param_dtype default), and flax reduces
+        # the statistics and normalises in fp32 whatever dtype is — the
+        # apex-O2 "BN in fp32" rule (SURVEY.md §2.4) by construction.  dtype
+        # only chooses the ONE cast of the result: the compute dtype, so the
+        # ReLU, the residual sum and the skip path's cotangent do not cross
+        # HBM in fp32 under the bf16 policy (as vit.py's LayerNorms).
         norm = functools.partial(nn.BatchNorm, use_running_average=not train,
                                  momentum=self.bn_momentum,
-                                 epsilon=self.bn_epsilon,
+                                 epsilon=self.bn_epsilon, dtype=self.dtype,
                                  axis_name=self.bn_axis_name)
         if self.small_inputs:
             x = conv(self.width, (3, 3), padding=1, name="stem_conv")(x)
