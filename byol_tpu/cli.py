@@ -35,7 +35,10 @@ def build_parser() -> argparse.ArgumentParser:
                    help="image_folder | cifar10 | cifar100 | mnist | "
                         "fashion_mnist | digits (real images bundled with "
                         "sklearn, works offline) | fake | synth "
-                        "(procedural learnable dataset, works offline)")
+                        "(procedural learnable dataset, works offline) | "
+                        "synth_tokens (seeded id sequences, two views by "
+                        "independent 15%% token masking; needs a token "
+                        "--arch and --seq-len)")
     t.add_argument("--batch-size", type=int, default=4096,
                    help="GLOBAL batch size")
     t.add_argument("--epochs", type=int, default=3000)
@@ -51,6 +54,8 @@ def build_parser() -> argparse.ArgumentParser:
     t.add_argument("--num-synth-samples", type=int, default=0,
                    help="dataset size for --task synth (test = 1/10th); "
                         "0 = default 20000")
+    t.add_argument("--seq-len", type=int, default=0,
+                   help="positions per sample for --task synth_tokens")
     t.add_argument("--valid-fraction", type=float, default=0.0,
                    help="hold out this fraction of train as a validation "
                         "split (num_valid_samples contract, reference "
@@ -271,6 +276,16 @@ def build_parser() -> argparse.ArgumentParser:
                    help="ViT attention backend")
     x.add_argument("--pooling", type=str, default="cls",
                    choices=("cls", "gap"), help="ViT feature pooling")
+    x.add_argument("--layer-share", type=str, default="0/1",
+                   help="decoder trunk: 'i/n' = this chip is chip i of the "
+                        "n that share every layer (expert- and head-"
+                        "parallel); the heads, routed experts and "
+                        "vocabulary rows it holds follow from it.  The "
+                        "layer runs without its exchange: what the other "
+                        "chips would add is left out")
+    x.add_argument("--trunk-depth", type=str, default="",
+                   help="decoder trunk: 'D+S' builds D leading dense and S "
+                        "expert layers instead of the published depth")
     x.add_argument("--data-backend", type=str, default="tf",
                    choices=("tf", "native", "device"),
                    help="augmentation pipeline: tf.data host, native C++ "
@@ -341,7 +356,8 @@ def config_from_args(args: argparse.Namespace) -> Config:
             augment_placement=args.augment_placement,
             fused_augment=args.fused_augment,
             num_synth_samples=args.num_synth_samples,
-            valid_fraction=args.valid_fraction),
+            valid_fraction=args.valid_fraction,
+            seq_len=args.seq_len),
         model=ModelConfig(
             arch=args.arch,
             representation_size=(args.representation_size
@@ -355,7 +371,8 @@ def config_from_args(args: argparse.Namespace) -> Config:
             fuse_views=args.fuse_views, remat=args.remat,
             remat_policy=args.remat_policy,
             stem=args.stem,
-            attn_impl=args.attn_impl, pooling=args.pooling),
+            attn_impl=args.attn_impl, pooling=args.pooling,
+            layer_share=args.layer_share, trunk_depth=args.trunk_depth),
         regularizer=RegularizerConfig(
             color_jitter_strength=args.color_jitter_strength,
             aug_spec=args.aug_spec,

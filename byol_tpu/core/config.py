@@ -71,6 +71,9 @@ class TaskConfig:
     # main.py:421-423).  0 = no valid split.  image_folder also accepts an
     # on-disk valid/ root, which wins over the fraction.
     valid_fraction: float = 0.0
+    # Positions per sample of a token task ('synth_tokens'); the backbone
+    # must declare input_kind='tokens' (models/registry.py).
+    seq_len: int = 0
 
 
 @_frozen
@@ -113,6 +116,13 @@ class ModelConfig:
                                         # (XLA), 'flash' (Pallas), 'ring'
                                         # (sequence-parallel over the mesh).
     pooling: str = "cls"                # ViT feature pooling: 'cls' | 'gap'.
+    layer_share: str = "0/1"            # decoder trunk: 'i/n' = this chip is
+                                        # chip i of the n that share every
+                                        # layer; heads, routed experts and
+                                        # vocabulary rows held follow from it
+    trunk_depth: str = ""               # decoder trunk: 'D+S' builds D
+                                        # leading dense and S expert layers;
+                                        # '' = the published depth
 
 
 @_frozen
@@ -304,7 +314,8 @@ class ResolvedConfig:
     through the mutable global ``args`` at main.py:420-425,725)."""
 
     cfg: Config
-    input_shape: Tuple[int, int, int]       # (H, W, C) — NHWC, TPU-native layout
+    input_shape: Tuple[int, ...]            # (H, W, C) — NHWC, TPU-native
+                                            # layout; (S,) for token ids
     num_train_samples: int                  # per-replica (ref main.py:421)
     num_test_samples: int                   # NOT sharded in ref (main.py:422)
     output_size: int                        # number of classes
@@ -335,7 +346,7 @@ class ResolvedConfig:
 
 
 def resolve(cfg: Config, *, num_train_samples: int, num_test_samples: int,
-            output_size: int, input_shape: Tuple[int, int, int],
+            output_size: int, input_shape: Tuple[int, ...],
             representation_size: Optional[int] = None,
             num_valid_samples: int = 0) -> ResolvedConfig:
     """Derive load-bearing quantities exactly as the reference does.
@@ -471,6 +482,13 @@ def resolve(cfg: Config, *, num_train_samples: int, num_test_samples: int,
             "telemetry off nothing would enforce the halt)")
     from byol_tpu.core.remat import resolve_policy_name
     resolve_policy_name(cfg.model.remat, cfg.model.remat_policy)  # fail fast
+    if len(input_shape) == 1:
+        # a token sample: only what the step does to PIXELS is refused
+        if cfg.task.augment_placement == "step" or \
+                cfg.parity.normalize_inputs:
+            raise ValueError(
+                "token input: --augment-placement step and "
+                "--normalize-inputs work on pixels")
     per_replica_batch = cfg.task.batch_size // n_rep
     per_replica_train = num_train_samples // n_rep
     steps_per_epoch = per_replica_train // per_replica_batch

@@ -43,7 +43,7 @@ class LoaderBundle:
 
     make_train_iter: Callable[[int], Iterator[Batch]]  # epoch -> iterator
     make_test_iter: Callable[[int], Iterator[Batch]]
-    input_shape: Tuple[int, int, int]
+    input_shape: Tuple[int, ...]         # (H, W, C), or (S,) for token ids
     num_train_samples: int
     num_test_samples: int
     output_size: int
@@ -289,12 +289,69 @@ def _raw_pipeline(images: np.ndarray, labels: np.ndarray, *,
     return make
 
 
+def _token_pipeline(ids: np.ndarray, labels: np.ndarray, *, batch_size: int,
+                    mask_id: int, seed: int, shuffle: bool
+                    ) -> Callable[[int], Iterator[Batch]]:
+    """epoch -> batches ``{'view1', 'view2': int32 (B, S), 'label'}``: two
+    views of the same sequences under independent 15% masking, reseeded
+    per epoch (drop-remainder, as every pipeline here).  Each batch is
+    made under the host span ``TOKEN_FEED_SPAN``."""
+    from byol_tpu.observability import spans as spans_lib
+
+    def make(epoch: int) -> Iterator[Batch]:
+        rng = np.random.RandomState((seed * 7919 + epoch) % (2 ** 31 - 1))
+        order = rng.permutation(len(ids)) if shuffle else np.arange(len(ids))
+        for i in range(0, len(order) - batch_size + 1, batch_size):
+            with spans_lib.span(spans_lib.TOKEN_FEED_SPAN):
+                rows = order[i:i + batch_size]
+                batch = {"view1": readers.mask_tokens(ids[rows], rng, mask_id),
+                         "view2": readers.mask_tokens(ids[rows], rng, mask_id),
+                         "label": labels[rows].astype(np.int32)}
+            yield batch
+    return make
+
+
+def _token_loader(cfg: Config, *, host_batch: int, num_samples: int,
+                  index: int, count: int, shard_eval: bool) -> LoaderBundle:
+    """``--task synth_tokens``: seeded id sequences for a backbone that
+    takes tokens (models/registry.py ``input_kind``)."""
+    from byol_tpu.models.registry import held_vocab_rows
+    if cfg.task.seq_len < 1:
+        raise ValueError("--task synth_tokens needs --seq-len")
+    if cfg.task.augment_placement != "loader" or \
+            cfg.task.valid_fraction > 0:
+        raise ValueError("--task synth_tokens masks its views in the "
+                         "loader and carves no validation split")
+    vocab = held_vocab_rows(cfg.model.arch, cfg.model.layer_share)
+    seed, n_classes = cfg.device.seed, 10
+    x_tr, y_tr = readers.load_synth_tokens(
+        num_samples, cfg.task.seq_len, vocab, n_classes, seed, train=True)
+    x_te, y_te = readers.load_synth_tokens(
+        max(num_samples // 10, host_batch), cfg.task.seq_len, vocab,
+        n_classes, seed, train=False)
+    n_train, n_test = len(x_tr), len(x_te)
+    x_trs, y_trs = _shard_arrays(x_tr, y_tr, index, count)
+    if shard_eval:
+        x_te, y_te = _shard_arrays(x_te, y_te, index, count)
+    pipe = lambda x, y, shuffle: _token_pipeline(
+        x, y, batch_size=host_batch, mask_id=vocab - 1, seed=seed,
+        shuffle=shuffle)
+    return LoaderBundle(
+        make_train_iter=pipe(x_trs, y_trs, True),
+        make_test_iter=pipe(x_te, y_te, False),
+        make_train_eval_iter=pipe(x_trs, y_trs, False),
+        input_shape=(cfg.task.seq_len,), num_train_samples=n_train,
+        num_test_samples=n_test, output_size=n_classes,
+        eval_sharded=shard_eval and count > 1)
+
+
 def get_loader(cfg: Config, *, num_fake_samples: int = 512,
                num_synth_samples: Optional[int] = None,
                shard_eval: bool = False) -> LoaderBundle:
     """Dispatch on ``cfg.task.task``; see module docstring for the contract.
 
-    Tasks: 'fake', 'synth', 'digits', 'cifar10', 'cifar100', 'mnist',
+    Tasks: 'fake', 'synth', 'synth_tokens' (id sequences for a token
+    backbone), 'digits', 'cifar10', 'cifar100', 'mnist',
     'fashion_mnist', 'image_folder' (the reference's
     multi_augment_image_folder default, main.py:38-39).
     """
@@ -314,6 +371,10 @@ def get_loader(cfg: Config, *, num_fake_samples: int = 512,
         raise ValueError(f"global batch {cfg.task.batch_size} not divisible "
                          f"by process count {count}")
     host_batch = cfg.task.batch_size // count
+    if task == "synth_tokens":
+        return _token_loader(cfg, host_batch=host_batch,
+                             num_samples=num_synth_samples, index=index,
+                             count=count, shard_eval=shard_eval)
 
     # Resolve the effective backend and validate the aug spec BEFORE any
     # dataset download/load, so a bad combination fails fast.

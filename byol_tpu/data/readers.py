@@ -176,6 +176,41 @@ def load_synth(num_samples: int = 10_000, image_size: int = 32,
     return (x * 255).astype(np.uint8), y.astype(np.int64)
 
 
+def load_synth_tokens(num_samples: int, seq_len: int, vocab: int,
+                      num_classes: int = 10, seed: int = 0,
+                      train: bool = True) -> Arrays:
+    """Procedural LEARNABLE id sequences: ``(N, S) int32`` below
+    ``vocab - 1`` (the last id is reserved for masking) and labels.
+
+    Each class owns a fixed random quarter of the usable ids; a sample
+    draws three positions in four from its class's ids and the rest from
+    all of them.  Which ids a sequence is made of says its class whatever
+    is masked, so a masked-view BYOL objective has signal and the probe
+    must beat chance.  The class sets depend on ``(num_classes, vocab)``
+    only: train and test share classes, not samples."""
+    usable = vocab - 1
+    if usable < 4 * 2:
+        raise ValueError(f"vocabulary of {vocab} ids is too small")
+    set_rng = np.random.RandomState(123)
+    own = np.stack([set_rng.choice(usable, size=usable // 4, replace=False)
+                    for _ in range(num_classes)])
+    rng = np.random.RandomState(seed + (0 if train else 10_007))
+    y = rng.randint(0, num_classes, size=(num_samples,))
+    from_own = own[y[:, None], rng.randint(0, own.shape[1],
+                                           size=(num_samples, seq_len))]
+    anywhere = rng.randint(0, usable, size=(num_samples, seq_len))
+    x = np.where(rng.rand(num_samples, seq_len) < 0.75, from_own, anywhere)
+    return x.astype(np.int32), y.astype(np.int64)
+
+
+def mask_tokens(ids: np.ndarray, rng: np.random.RandomState, mask_id: int,
+                rate: float = 0.15) -> np.ndarray:
+    """One view of ``ids``: ``rate`` of the positions, drawn independently,
+    replaced by ``mask_id``."""
+    return np.where(rng.rand(*ids.shape) < rate, np.int32(mask_id),
+                    ids).astype(np.int32)
+
+
 def load_digits_img(data_dir: str = "", train: bool = True,
                     download: bool = False) -> Arrays:
     """Real handwritten-digit images (sklearn's bundled UCI digits), no

@@ -23,6 +23,11 @@ class BackboneSpec:
     factory: Callable[..., nn.Module]    # (dtype, small_inputs) -> module
     feature_dim: int
     has_batchnorm: bool = True
+    # what one sample is: 'image' = (H, W, C) float pixels in [0, 1];
+    # 'tokens' = (S,) int32 ids below ``vocab_size / (chips sharing a
+    # layer)`` (models/decoder_trunk.py)
+    input_kind: str = "image"
+    vocab_size: int = 0                  # published vocabulary ('tokens')
 
 
 _REGISTRY: Dict[str, BackboneSpec] = {}
@@ -96,3 +101,38 @@ try:
     _register_vit()
 except ImportError:  # pragma: no cover - vit module lands in a later commit
     pass
+
+
+def _register_decoder_trunks() -> None:
+    from byol_tpu.models import decoder_trunk as trunk_lib
+    # xing4_29b_a4b: huggingface.co/XingChen-AGI/Xing4.0-29B-A4B, config.json
+    for name, sizes in (("xing4_29b_a4b", trunk_lib.XING4_29B_A4B),
+                        ("decoder_trunk_tiny", trunk_lib.TINY)):
+        def factory(dtype=jnp.float32, small_inputs=False, _z=sizes,
+                    layer_share="0/1", trunk_depth="", **kw):
+            del small_inputs
+            if trunk_depth:
+                dense, sparse = (int(t) for t in trunk_depth.split("+"))
+                _z = _z.with_depth(dense, sparse)
+            return trunk_lib.DecoderTrunk(
+                sizes=_z, share=trunk_lib.LayerShare.parse(layer_share),
+                dtype=dtype, **kw)
+        register(name, BackboneSpec(
+            factory=factory, feature_dim=sizes.hidden_size,
+            has_batchnorm=False, input_kind="tokens",
+            vocab_size=sizes.vocab_size))
+
+
+_register_decoder_trunks()
+
+
+def held_vocab_rows(arch: str, layer_share: str) -> int:
+    """Ids a token task may draw for ``arch`` on this chip: the rows of the
+    embedding it holds."""
+    from byol_tpu.models.decoder_trunk import LayerShare
+    spec = get_spec(arch)
+    if spec.input_kind != "tokens":
+        raise ValueError(f"arch {arch!r} takes {spec.input_kind} input, "
+                         "not token ids")
+    return LayerShare.parse(layer_share).held(spec.vocab_size,
+                                              "vocabulary rows")[1]

@@ -50,6 +50,9 @@ BADPUT_PREFIXES = (
     ("checkpoint/", "checkpoint"),
 )
 PRODUCTIVE_PREFIX = "train/"
+# spans of a producer thread that runs BESIDE the phases (the token feed,
+# spans.TOKEN_FEED_SPAN): in span_stats, never in the wall-time partition
+CONCURRENT_PREFIX = "feed/"
 OTHER_BUCKET = "host_other"
 
 # the full bucket vocabulary, for docs/renderers (host_other always last)
@@ -74,7 +77,8 @@ def attribute(records: List[Any], wall: float
     remainder means attributed > wall, and the attributed total is
     reported as wall so the identity stays exact rather than lying by
     clamping."""
-    top = [r for r in records if r.depth == 0]
+    top = [r for r in records if r.depth == 0
+           and not r.name.startswith(CONCURRENT_PREFIX)]
     productive = 0.0
     badput: Dict[str, float] = {b: 0.0 for b in BADPUT_BUCKETS}
     for r in top:

@@ -39,6 +39,11 @@ Signals (one float32 each, ``len(HEALTH_FIELDS)`` total):
   ``--nan-policy {warn,halt}`` keys off this slot.
 - ``loss``: the step loss, so a sampled telemetry record is
   self-contained.
+- ``moe_rows_held`` / ``moe_load_max`` / ``moe_load_mean`` /
+  ``moe_rows_dropped``: rows routed to the experts this chip holds, the
+  largest and the mean load of a held expert, rows the expert product did
+  not cover (always 0: the layer has no capacity) — each summed over the
+  layers that route; 0 for a backbone without sparse experts.
 """
 from __future__ import annotations
 
@@ -64,7 +69,16 @@ HEALTH_FIELDS: Tuple[str, ...] = (
     "collapse_cosine_mean",
     "nonfinite_count",
     "loss",
+    # routing of a backbone with sparse experts, summed over the layers
+    # that route (models/decoder_trunk.py ROUTING_FIELDS); 0 elsewhere
+    "moe_rows_held",
+    "moe_load_max",
+    "moe_load_mean",
+    "moe_rows_dropped",
 )
+
+# fields a packer may leave out (they read 0): what only some backbones have
+OPTIONAL_FIELDS = frozenset(k for k in HEALTH_FIELDS if k.startswith("moe_"))
 
 _EPS = 1e-12
 
@@ -116,13 +130,13 @@ def collapse_stats(proj: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 def pack(values: Dict[str, Any]) -> jnp.ndarray:
     """Pack the named signals into the (len(HEALTH_FIELDS),) fp32 vector."""
-    missing = set(HEALTH_FIELDS) - set(values)
+    missing = set(HEALTH_FIELDS) - set(values) - OPTIONAL_FIELDS
     extra = set(values) - set(HEALTH_FIELDS)
     if missing or extra:
         raise ValueError(
             f"health vector fields mismatch: missing={sorted(missing)} "
             f"extra={sorted(extra)}")
-    return jnp.stack([jnp.asarray(values[k], jnp.float32).reshape(())
+    return jnp.stack([jnp.asarray(values.get(k, 0.0), jnp.float32).reshape(())
                       for k in HEALTH_FIELDS])
 
 
@@ -139,7 +153,8 @@ def unpack(vec: Any) -> Dict[str, float]:
 def health_stats(*, grads: Any, updates: Any, params: Any,
                  target_params: Any, loss: jnp.ndarray,
                  collapse: Tuple[jnp.ndarray, jnp.ndarray],
-                 trust_ratios: jnp.ndarray) -> jnp.ndarray:
+                 trust_ratios: jnp.ndarray,
+                 routing: Optional[Dict[str, Any]] = None) -> jnp.ndarray:
     """Assemble the packed health vector from one optimizer step's tensors.
 
     All inputs are traced values inside the jitted step; the result is a
@@ -152,6 +167,9 @@ def health_stats(*, grads: Any, updates: Any, params: Any,
     live across the accumulation scan, defeating the scan's memory win).
     ``trust_ratios`` is ``optim.lars.trust_ratio_vector(grads, params_pre)``
     — the per-layer-group ratios the LARS transform applies.
+    ``routing`` maps the ``moe_*`` fields to the online forward's routing
+    counters (training/steps.py); a backbone that routes nothing passes
+    none and they read 0.
     """
     param_norm = global_norm(params)
     drift = global_norm(jax.tree_util.tree_map(
@@ -172,4 +190,5 @@ def health_stats(*, grads: Any, updates: Any, params: Any,
         "collapse_cosine_mean": cosine_mean,
         "nonfinite_count": nonfinite_count((grads, loss)),
         "loss": loss,
+        **(routing or {}),
     })

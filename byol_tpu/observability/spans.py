@@ -47,6 +47,13 @@ from typing import Any, Dict, Iterable, List, Optional
 
 from byol_tpu.observability import profiling
 
+# The token feed (data/loader.py ``--task synth_tokens``): one span per host
+# batch of masked id views, opened in the thread that makes the batch — the
+# prefetch producer — on the module default recorder, which the trainer
+# points at its own for the run.  Not under ``input/``: that prefix is the
+# CONSUMER's wait, which goodput counts as badput.
+TOKEN_FEED_SPAN = "feed/tokens"
+
 # default ring capacity: ~3 spans/step x 20k steps; beyond it the OLDEST
 # spans are evicted (``dropped`` counts them) — the recorder must never
 # grow without bound on a week-long run

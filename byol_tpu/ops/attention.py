@@ -19,19 +19,27 @@ module exists because long-context support is first-class in the rebuild.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict
+from typing import Callable, Dict, Optional
 
 import jax.numpy as jnp
 
 
-def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray
+def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+                    scale: Optional[float] = None, causal: bool = False
                     ) -> jnp.ndarray:
-    """Standard softmax attention. (B, H, S, D) -> (B, H, S, D).
+    """Standard softmax attention. (B, H, S, D) -> (B, H, S, Dv).
 
     Softmax statistics in fp32 regardless of compute dtype (bf16-safe),
-    matmuls in the input dtype (MXU-friendly)."""
-    scale = q.shape[-1] ** -0.5
+    matmuls in the input dtype (MXU-friendly).  ``scale`` defaults to
+    ``1/sqrt(D)``; ``causal`` masks key positions after the query's (the
+    decoder trunk, models/decoder_trunk.py, whose value heads are also
+    narrower than its query/key heads)."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if causal:
+        visible = jnp.tril(jnp.ones(scores.shape[-2:], bool))
+        scores = jnp.where(visible, scores, jnp.finfo(scores.dtype).min)
     weights = jnp.exp(
         scores.astype(jnp.float32)
         - jnp.max(scores, axis=-1, keepdims=True).astype(jnp.float32))

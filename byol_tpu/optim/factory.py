@@ -63,13 +63,16 @@ def is_lars_optimizer(opt_name: str) -> bool:
     return opt_name.lower().strip().startswith("lars_")
 
 
-def fused_update_unsupported_reason(opt_name: str,
-                                    clip: float = 0.0) -> Optional[str]:
+def fused_update_unsupported_reason(opt_name: str, clip: float = 0.0,
+                                    adapt_mask: Optional[Any] = None
+                                    ) -> Optional[str]:
     """Why ``--fused-update on`` cannot serve this optimizer config —
     ``None`` when the fused Pallas kernel (ops/fused_update.py) computes
     exactly the chain :func:`build_optimizer` would.  The ONE gating
     predicate, shared by config resolve() (fail fast at the CLI) and the
-    step builder (fail fast for programmatic callers)."""
+    step builder (fail fast for programmatic callers).  ``adapt_mask``
+    (``optim.lars.default_exclusion_mask`` of the parameter tree, once it
+    exists) names a tree the kernel cannot take."""
     full = opt_name.lower().strip()
     if not is_lars_optimizer(full):
         return (f"optimizer {opt_name!r} does not build the LARS wrapper "
@@ -81,6 +84,10 @@ def fused_update_unsupported_reason(opt_name: str,
     if clip > 0.0:
         return ("--clip > 0 value-clips gradients before LARS; the fused "
                 "kernel does not replicate the clip")
+    if adapt_mask is not None and lars_lib.has_expert_axis(adapt_mask):
+        return ("the parameter tree stacks expert kernels on a leading "
+                "axis and LARS adapts every expert alone; the fused kernel "
+                "has one segment, one trust ratio, per leaf")
     return None
 
 
@@ -193,7 +200,7 @@ def build_optimizer(opt_name: str, *,
             chain.append(optax.add_decayed_weights(
                 weight_decay,
                 mask=(adapt_mask if adapt_mask is not None
-                      else lars_lib.default_exclusion_mask)))
+                      else lars_lib.decay_mask)))
         chain.append(base)
 
     return optax.chain(*chain), schedule

@@ -36,13 +36,45 @@ TRUST_COEFFICIENT_DEFAULT = 1e-3
 LARS_EPS_DEFAULT = 0.0
 
 
+# A mask leaf is False (excluded), True (one layer group) or PER_EXPERT:
+# the leaf stacks one kernel per expert on its leading axis and every
+# expert is a layer group of its own.  With ONE ratio for the stack, an
+# expert's update would depend on which other experts share its chip.
+PER_EXPERT = "per_expert"
+# the module whose leaves carry that axis (models/decoder_trunk.py)
+EXPERT_MODULE = "experts"
+# leaves that are gains, biases or buffers whatever their rank: the
+# hyper-connection scalars and static maps (``b_res`` is n x n), the
+# router's selection bias, every norm's scale
+UNADAPTED_LEAVES = frozenset({
+    "scale", "bias", "alpha_pre", "alpha_post", "alpha_res", "b_pre",
+    "b_post", "b_res", "e_score_correction_bias"})
+
+
 def default_exclusion_mask(params) -> Any:
-    """True where LARS adaptation / weight decay applies.
+    """Truthy where LARS adaptation / weight decay applies.
 
     Reproduces the bias/BN exclusion of ``add_weight_decay``: 1-D parameters
-    (biases, BN scale/bias) are excluded; kernels (ndim >= 2) are adapted.
+    (biases, BN scale/bias) are excluded; kernels (ndim >= 2) are adapted —
+    per expert (``PER_EXPERT``) below a module named ``EXPERT_MODULE``, and
+    never a leaf named in ``UNADAPTED_LEAVES``.
     """
-    return jax.tree_util.tree_map(lambda p: p.ndim > 1, params)
+    def rule(path, p):
+        names = [getattr(k, "key", getattr(k, "name", None)) for k in path]
+        if p.ndim <= 1 or names[-1] in UNADAPTED_LEAVES:
+            return False
+        return PER_EXPERT if EXPERT_MODULE in names[:-1] else True
+    return jax.tree_util.tree_map_with_path(rule, params)
+
+
+def has_expert_axis(mask) -> bool:
+    return any(m == PER_EXPERT for m in jax.tree_util.tree_leaves(mask))
+
+
+def decay_mask(params) -> Any:
+    """The default mask as booleans (weight decay is elementwise: an
+    expert axis changes nothing)."""
+    return jax.tree_util.tree_map(bool, default_exclusion_mask(params))
 
 
 def _resolve_mask(mask: Optional[MaskOrFn], params):
@@ -76,8 +108,11 @@ def trust_ratio_from_norms(param_norm: jnp.ndarray, grad_norm: jnp.ndarray,
 
 
 def _leaf_trust_ratio(g: jnp.ndarray, p: jnp.ndarray,
-                      trust_coefficient: float, eps: float) -> jnp.ndarray:
-    """The per-layer-group LARS trust ratio (lars.py:100-108), fp32 scalar.
+                      trust_coefficient: float, eps: float,
+                      per_expert: bool = False) -> jnp.ndarray:
+    """The per-layer-group LARS trust ratio (lars.py:100-108), fp32 scalar
+    — or, ``per_expert``, one ratio per slice of the leading axis, shaped
+    ``(E, 1, ...)`` to broadcast against the leaf.
 
     ONE implementation shared by the optimizer transform below and the
     telemetry stats (:func:`trust_ratio_vector`), so the health vector can
@@ -85,6 +120,12 @@ def _leaf_trust_ratio(g: jnp.ndarray, p: jnp.ndarray,
     """
     g32 = g.astype(jnp.float32)
     p32 = p.astype(jnp.float32)
+    if per_expert:
+        axes = tuple(range(1, p.ndim))
+        norm = lambda x: jnp.sqrt(
+            jnp.sum(jnp.square(x), axis=axes, keepdims=True))
+        return trust_ratio_from_norms(norm(p32), norm(g32),
+                                      trust_coefficient, eps)
     return trust_ratio_from_norms(jnp.linalg.norm(p32),
                                   jnp.linalg.norm(g32),
                                   trust_coefficient, eps)
@@ -111,10 +152,13 @@ def trust_ratio_vector(updates: Any, params: Any,
     g_leaves = jax.tree_util.tree_leaves(updates)
     p_leaves = jax.tree_util.tree_leaves(params)
     m_leaves = jax.tree_util.tree_leaves(m)
-    ratios = [_leaf_trust_ratio(g, p, trust_coefficient, eps)
+    ratios = [_leaf_trust_ratio(g, p, trust_coefficient, eps,
+                                use == PER_EXPERT)
               for g, p, use in zip(g_leaves, p_leaves, m_leaves) if use]
     if not ratios:       # nothing adapted (all-1D tree): ratio is identity
         return jnp.ones((1,), jnp.float32)
+    if has_expert_axis(m):
+        return jnp.concatenate([r.reshape(-1) for r in ratios])
     return jnp.stack(ratios)
 
 
@@ -136,7 +180,8 @@ def scale_by_lars_trust_ratio(trust_coefficient: float = TRUST_COEFFICIENT_DEFAU
         def scale(g, p, use):
             if not use:
                 return g
-            ratio = _leaf_trust_ratio(g, p, trust_coefficient, eps)
+            ratio = _leaf_trust_ratio(g, p, trust_coefficient, eps,
+                                      use == PER_EXPERT)
             return (g.astype(jnp.float32) * ratio).astype(g.dtype)
 
         updates = jax.tree_util.tree_map(scale, updates, params, m)
@@ -152,10 +197,11 @@ def lars_weight_decay(weight_decay: float,
     (lars.py:96-97).  Masked like the adaptation — bias/BN undecayed."""
     if weight_decay <= 0.0:
         return optax.identity()
+    as_bools = lambda m: jax.tree_util.tree_map(bool, m)
     return optax.add_decayed_weights(
         weight_decay,
-        mask=(lambda p: _resolve_mask(mask, p)) if mask is None or callable(mask)
-        else mask)
+        mask=(lambda p: as_bools(_resolve_mask(mask, p)))
+        if mask is None or callable(mask) else as_bools(mask))
 
 
 def lars(inner: optax.GradientTransformation,

@@ -194,6 +194,13 @@ def _assert_drain_transition(server) -> List[str]:
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = build_serve_parser().parse_args(argv)
+    from byol_tpu.models.registry import get_spec
+    if get_spec(args.arch).input_kind != "image":
+        # before any backend starts: requests, buckets and staging are
+        # images through and through
+        print(f"serve: --arch {args.arch} takes token ids; the embedding "
+              "server takes images only", file=sys.stderr)
+        return 2
     import os
     import signal
     import threading

@@ -36,7 +36,17 @@ def build_net(rcfg: ResolvedConfig) -> BYOLNet:
     policy = get_policy(cfg.device.half)
     small = rcfg.input_shape[0] <= 64    # CIFAR-style stem
     from byol_tpu.models.registry import get_spec
-    if get_spec(cfg.model.arch).has_batchnorm:
+    spec = get_spec(cfg.model.arch)
+    if (spec.input_kind == "tokens") != (len(rcfg.input_shape) == 1):
+        raise ValueError(
+            f"arch {cfg.model.arch!r} takes {spec.input_kind} input but the "
+            f"task yields samples of shape {rcfg.input_shape}")
+    if spec.input_kind == "tokens":      # decoder-trunk knobs
+        extra = {"remat": cfg.model.remat,
+                 "remat_policy": cfg.model.remat_policy,
+                 "layer_share": cfg.model.layer_share,
+                 "trunk_depth": cfg.model.trunk_depth}
+    elif spec.has_batchnorm:
         extra = {"zero_init_residual": cfg.parity.zero_init_residual,
                  "remat": cfg.model.remat,
                  "remat_policy": cfg.model.remat_policy,
@@ -64,13 +74,19 @@ def build_net(rcfg: ResolvedConfig) -> BYOLNet:
         **extra)
 
 
+def _dummy_batch(rcfg: ResolvedConfig, batch: int) -> jnp.ndarray:
+    """A batch of zeros of the task's sample shape: pixels, or token ids."""
+    tokens = len(rcfg.input_shape) == 1
+    return jnp.zeros((batch,) + tuple(rcfg.input_shape),
+                     jnp.int32 if tokens else jnp.float32)
+
+
 def init_variables(net: BYOLNet, rcfg: ResolvedConfig, rng: jax.Array,
                    *, batch: int = 2):
     """``batch`` must be divisible by the mesh's data axis when the model
     contains shard_map ops (ring attention) — setup_training sizes it to
     the mesh."""
-    h, w, c = rcfg.input_shape
-    dummy = jnp.zeros((batch, h, w, c), jnp.float32)
+    dummy = _dummy_batch(rcfg, batch)
     axis = getattr(net, "bn_axis_name", None)
     if axis:
         # BN modules pmean over the accumulation axis; init's train-mode
@@ -153,8 +169,7 @@ def _validate_remat_tags(net, rcfg: ResolvedConfig, variables,
                                                 cfg.model.remat_policy)
     if policy_name not in remat_lib.NAMES_BASED_POLICIES:
         return
-    h, w, c = rcfg.input_shape
-    dummy = jnp.zeros((batch, h, w, c), jnp.float32)
+    dummy = _dummy_batch(rcfg, batch)
     axis = getattr(net, "bn_axis_name", None)
 
     def fwd(v, d):
@@ -214,9 +229,22 @@ def setup_training(rcfg: ResolvedConfig, mesh: Mesh, rng: jax.Array,
         # here; the default ndim-derived mask stays for the replicated
         # layout (identical semantics, and bit-identical jit cache keys).
         adapt_mask = None
+        from byol_tpu.optim import lars as lars_lib
+        shaped_mask = lars_lib.default_exclusion_mask(variables["params"])
         if plan.zero1:
-            from byol_tpu.optim.lars import default_exclusion_mask
-            adapt_mask = default_exclusion_mask(variables["params"])
+            if lars_lib.has_expert_axis(shaped_mask):
+                raise ValueError(
+                    "--zero1 on flattens every leaf, and LARS adapts each "
+                    "expert of a stacked expert kernel alone: it needs the "
+                    "expert axis (run such a tree with --zero1 off)")
+            adapt_mask = shaped_mask
+        if cfg.optim.fused_update == "on":
+            from byol_tpu.optim.factory import \
+                fused_update_unsupported_reason
+            reason = fused_update_unsupported_reason(
+                cfg.optim.optimizer, cfg.optim.clip, shaped_mask)
+            if reason is not None:
+                raise ValueError(f"--fused-update on: {reason}")
         tx, schedule = build_tx(rcfg, adapt_mask=adapt_mask)
         state = create_train_state(
             # under ZeRO-1 the plan inits the optimizer state on the FLAT

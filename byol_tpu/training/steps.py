@@ -76,6 +76,7 @@ from jax.experimental.xla_metadata import set_xla_metadata
 from byol_tpu.core import rng as rng_lib
 from byol_tpu.core.precision import Policy, FP32
 from byol_tpu.data import device_augment
+from byol_tpu.models.decoder_trunk import ROUTING, ROUTING_FIELDS
 from byol_tpu.objectives.byol_loss import loss_function
 from byol_tpu.objectives.metrics import cross_entropy, topk_accuracy
 from byol_tpu.observability import health as health_lib
@@ -218,32 +219,38 @@ def _forward_views(net, params, batch_stats, aug1, aug2, *, train: bool,
                    fuse: bool, update_stats: bool):
     """Run both views through encoder+projector+predictor.
 
-    Returns (out1, out2, new_batch_stats); each out is the dict from
-    ``BYOLNet.__call__`` (representation/projection/prediction).
+    Returns (out1, out2, new_batch_stats, routing); each out is the dict
+    from ``BYOLNet.__call__`` (representation/projection/prediction);
+    ``routing`` is the sum over layers and views of what a backbone that
+    routes sowed (``ROUTING_FIELDS``, models/decoder_trunk.py), else None.
     """
     variables = {"params": params, "batch_stats": batch_stats}
     # flax BatchNorm writes running stats whenever train=True, so the
     # collection must be mutable even for the target forward; updates are
     # simply discarded when update_stats=False.
-    mutable = ["batch_stats"] if train else False
+    mutable = ["batch_stats", ROUTING] if train else False
 
     def apply(v, x):
         if mutable:
             out, upd = net.apply(v, x, train=train, mutable=mutable)
             new_bs = upd["batch_stats"] if update_stats else v["batch_stats"]
-            return out, new_bs
+            sown = jax.tree_util.tree_leaves(upd.get(ROUTING, {}))
+            return out, new_bs, sum(sown) if sown else None
         out = net.apply(v, x, train=train, mutable=False)
-        return out, v["batch_stats"]
+        return out, v["batch_stats"], None
 
     if fuse:
         n = aug1.shape[0]
-        out, bs = apply(variables, jnp.concatenate([aug1, aug2], axis=0))
+        out, bs, routing = apply(variables,
+                                 jnp.concatenate([aug1, aug2], axis=0))
         out1 = jax.tree_util.tree_map(lambda x: x[:n], out)
         out2 = jax.tree_util.tree_map(lambda x: x[n:], out)
-        return out1, out2, bs
-    out1, bs = apply(variables, aug1)
-    out2, bs = apply({"params": params, "batch_stats": bs}, aug2)
-    return out1, out2, bs
+        return out1, out2, bs, routing
+    out1, bs, routing = apply(variables, aug1)
+    out2, bs, routing2 = apply({"params": params, "batch_stats": bs}, aug2)
+    if routing is not None:
+        routing = routing + routing2
+    return out1, out2, bs, routing
 
 
 def _microbatch_split(x: jnp.ndarray, k: int) -> jnp.ndarray:
@@ -411,7 +418,7 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
         # Target branch: outside the differentiated function — autodiff never
         # sees it (vs reference building + detaching the graph, Quirk Q10).
         with _phase("target_forward"):
-            tgt1, tgt2, _ = _forward_views(
+            tgt1, tgt2, _, _ = _forward_views(
                 net, target_params, batch_stats, aug1, aug2,
                 train=True, fuse=scfg.fuse_views, update_stats=False)
         target_proj1 = jax.lax.stop_gradient(tgt1["projection"])
@@ -419,7 +426,7 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
 
         def loss_fn(params):
             with _phase("online_forward"):
-                on1, on2, new_bs = _forward_views(
+                on1, on2, new_bs, routing = _forward_views(
                     net, params, batch_stats, aug1, aug2,
                     train=True, fuse=scfg.fuse_views, update_stats=True)
             with _phase("loss"):
@@ -441,6 +448,11 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
                        "linear_loss_mean": cls_loss,
                        "top1_mean": top1,
                        "top5_mean": top5}
+            if routing is not None:
+                # the online forward's routing counters, as scalars like
+                # every metric (the underscore keeps them off the plots)
+                metrics.update({f"_moe_{name}": routing[i]
+                                for i, name in enumerate(ROUTING_FIELDS)})
             return total, (new_bs, metrics)
 
         grads, (new_bs, metrics) = jax.grad(
@@ -776,14 +788,24 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
             metrics["health"] = health_lib.health_stats(
                 grads=grads, updates=updates, params=health_params,
                 target_params=new_target, loss=metrics["loss_mean"],
-                collapse=collapse, trust_ratios=trust)
+                collapse=collapse, trust_ratios=trust,
+                routing={f"moe_{name}": metrics[f"_moe_{name}"]
+                         for name in ROUTING_FIELDS
+                         if f"_moe_{name}" in metrics})
 
         # One real attribute on one scalar add.  The persistent compilation
         # cache keys a program with its debug info stripped, scope names
         # included, so a step whose scopes alone were renamed would be
         # served the executable cached before the rename, stale names and
         # all — and the device trace is read by those names.
-        with set_xla_metadata(phase_scopes=" ".join(PHASE_SCOPES)):
+        # A backbone that names scopes of its own inside the phases (the
+        # decoder trunk's ``mla``, ``moe/...``, ``mhc``) has them stamped
+        # too; one that names none keeps the stamp, and its program, as it
+        # was.
+        layer_scopes = tuple(getattr(getattr(net, "backbone", None),
+                                     "trace_scopes", ()))
+        with set_xla_metadata(
+                phase_scopes=" ".join(PHASE_SCOPES + layer_scopes)):
             next_step = state.step + 1
         new_state = state.replace(
             step=next_step,
@@ -839,10 +861,10 @@ def make_eval_step(net, scfg: StepConfig, policy: Policy = FP32,
             target_params = zero1_ctx.gather(target_params,
                                              zero1_ctx.param_template)
 
-        on1, on2, _ = _forward_views(
+        on1, on2, _, _ = _forward_views(
             net, params, state.batch_stats, aug1, aug2,
             train=False, fuse=scfg.fuse_views, update_stats=False)
-        tgt1, tgt2, _ = _forward_views(
+        tgt1, tgt2, _, _ = _forward_views(
             net, target_params, state.batch_stats, aug1, aug2,
             train=False, fuse=scfg.fuse_views, update_stats=False)
 

@@ -67,11 +67,21 @@ class FitResult:
                                          # SPMD (multi-host) linear-eval path
 
 
-def _range_check(batch: Dict[str, np.ndarray]) -> None:
+def _range_check(batch: Dict[str, np.ndarray], vocab_rows: int = 0) -> None:
     """The reference's startup input contract: augmented pixels must stay in
     [0,1] (main.py:486-490) — hard failure, not a warning.  Step-placement
     batches ship RAW pixels instead of views; their contract is dtype
-    uint8 (the step divides by 255 on device)."""
+    uint8 (the step divides by 255 on device); a token batch's is ids
+    below ``vocab_rows``, the embedding rows this chip holds."""
+    if np.asarray(batch.get("view1", 0.0)).dtype.kind == "i":
+        # token views: ids, whose range the embedding's rows bound
+        for key in ("view1", "view2"):
+            v = np.asarray(batch[key])
+            if v.min() < 0 or v.max() >= vocab_rows:
+                raise ValueError(
+                    f"token batch {key} holds ids outside [0, {vocab_rows})"
+                    f": min={v.min()} max={v.max()}")
+        return
     if "images" in batch:
         v = np.asarray(batch["images"])
         if v.dtype != np.uint8:
@@ -137,6 +147,8 @@ def fit(cfg: Config, *, loader: Optional[LoaderBundle] = None,
     # (records nothing — the hot loop is byte-for-byte the unspanned one).
     recorder = (spans_lib.SpanRecorder() if cfg.device.spans == "on"
                 else spans_lib.NULL)
+    # what records on the module default (the token feed) lands here too
+    spans_lib.set_default(recorder)
     # The meter's first window opens HERE, before the model build, so
     # startup (build + first-step compile) is attributed, not lost.
     goodput_meter = goodput_lib.GoodputMeter(recorder)
@@ -216,12 +228,17 @@ def fit(cfg: Config, *, loader: Optional[LoaderBundle] = None,
     # Eval batches are padded to the fixed per-host batch so all of them
     # share one compiled executable and shard cleanly on the data axis.
     host_eval_batch = rcfg.global_batch_size // jax.process_count()
+    tokens = len(rcfg.input_shape) == 1
+    vocab_rows = 0
+    if tokens:
+        from byol_tpu.models.registry import held_vocab_rows
+        vocab_rows = held_vocab_rows(cfg.model.arch, cfg.model.layer_share)
 
     def _all_pad_batch():
         """Zero-row batch for a host that drained its eval shard early;
         pad_batch fills it to the static shape with an all-zero mask."""
-        h, w, c = rcfg.input_shape
-        z = np.zeros((0, h, w, c), np.float32)
+        z = np.zeros((0,) + tuple(rcfg.input_shape),
+                     np.int32 if tokens else np.float32)
         return {"view1": z, "view2": z, "label": np.zeros((0,), np.int32)}
 
     def run_eval(state, batches=None) -> MetricAccumulator:
@@ -423,9 +440,9 @@ def fit(cfg: Config, *, loader: Optional[LoaderBundle] = None,
                 if i < skip:
                     continue
                 if not first_batch_checked:
-                    _range_check(batch)
+                    _range_check(batch, vocab_rows)
                     first_batch_checked = True
-                if sample_batch is None and "view1" in batch:
+                if sample_batch is None and "view1" in batch and not tokens:
                     # step placement ships raw pixels — no host-side views
                     # to grid; the eval path still plots resized images
                     sample_batch = {k: np.asarray(batch[k][:64])

@@ -194,6 +194,31 @@ def test_flash_attention(no_persistent_cache, one_chip, shape, dtype):
 
 
 # ---------------------------------------------------------------------------
+# the decoder trunk's expert layer at the published widths: sort, ragged
+# products over the held experts (the compiler's own ragged-dot kernel),
+# forward and backward (models/decoder_trunk.py)
+# ---------------------------------------------------------------------------
+
+def test_expert_layer_at_published_widths(no_persistent_cache, one_chip):
+    from byol_tpu.models import decoder_trunk as trunk_lib
+    z = trunk_lib.XING4_29B_A4B
+    layer = trunk_lib.ExpertLayer(z, 0, 8, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2, 1024, z.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    params = _with(jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0),
+                           jnp.zeros(x.shape, x.dtype)))["params"], one_chip)
+    assert params["experts"]["gate"].shape == (8, 3584, 1024)
+    assert params["router"].shape == (3584, 64)      # all 64 are scored
+
+    def loss(p, x):
+        return jnp.sum(layer.apply({"params": p}, x).astype(jnp.float32))
+    compiled = _compile(jax.grad(loss), params, x)
+    # a quarter of the copies is the usual product, all of them the fallback
+    assert "conditional" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
 # one whole ResNet-50 train step at batch 256 fits the chip
 # ---------------------------------------------------------------------------
 
