@@ -13,9 +13,15 @@ TPU-native choices:
 - patch embedding as a strided Conv (one big MXU matmul per image);
 - pre-LN blocks, LayerNorm/softmax statistics in fp32 under bf16 compute;
 - attention behind :func:`byol_tpu.ops.attention.get_attention_fn`:
-  ``dense`` for 224px ViT-B (197 tokens — no sequence parallelism
-  warranted, SURVEY.md §5.7), ``flash`` (Pallas) or ``ring``
-  (sequence-parallel over the mesh) for long-sequence configs;
+  ``dense`` (exact softmax attention over the whole sequence) for 224px
+  ViT-B — 197 tokens, no sequence parallelism warranted (SURVEY.md §5.7) —
+  ``flash`` (Pallas) or ``ring`` (sequence-parallel over the mesh) for
+  long-sequence configs.  ``dense`` as two XLA einsums is NOT the right
+  answer at 197 tokens: the scores cross HBM and every head layout is a
+  copy, half of the step's bytes (PERF.md §5, PR 28).  So where
+  :func:`byol_tpu.ops.attention.packed_kernel_applies` (a TPU, a sequence
+  that fits VMEM, heads that tile the lanes) it runs as one fused kernel
+  over the packed ``qkv``, forward and backward; elsewhere as the einsums;
 - optional ``remat`` per block (jax.checkpoint) to trade FLOPs for HBM.
 """
 from __future__ import annotations
@@ -28,7 +34,9 @@ import jax
 import jax.numpy as jnp
 
 from byol_tpu.core import remat as remat_lib
-from byol_tpu.ops.attention import get_attention_fn
+from byol_tpu.ops.attention import get_attention_fn, packed_kernel_applies
+from byol_tpu.ops.packed_attention import packed_self_attention
+from byol_tpu.parallel.mesh import ambient_mesh
 
 
 class MlpBlock(nn.Module):
@@ -55,10 +63,15 @@ class SelfAttention(nn.Module):
         assert d % self.num_heads == 0, (d, self.num_heads)
         head_dim = d // self.num_heads
         qkv = nn.Dense(3 * d, dtype=self.dtype, name="qkv")(x)
-        qkv = qkv.reshape(b, s, 3, self.num_heads, head_dim)
-        q, k, v = (qkv[:, :, i].transpose(0, 2, 1, 3) for i in range(3))
-        out = get_attention_fn(self.attn_impl)(q, k, v)
-        out = out.transpose(0, 2, 1, 3).reshape(b, s, d)
+        mesh = ambient_mesh()
+        if self.attn_impl == "dense" and packed_kernel_applies(
+                b, s, self.num_heads, head_dim, mesh=mesh):
+            out = packed_self_attention(qkv, self.num_heads, mesh=mesh)
+        else:
+            qkv = qkv.reshape(b, s, 3, self.num_heads, head_dim)
+            q, k, v = (qkv[:, :, i].transpose(0, 2, 1, 3) for i in range(3))
+            out = get_attention_fn(self.attn_impl)(q, k, v)
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, d)
         return nn.Dense(d, dtype=self.dtype, name="proj")(out)
 
 

@@ -7,6 +7,9 @@ tier-1 runs the real kernel code) plus the shared plumbing in
 call sites name the capability, not the file:
 
 - :func:`flash_attention` — tiled online-softmax attention (ViT backend).
+- :func:`packed_self_attention` — whole-sequence softmax attention over the
+  packed ``qkv``, forward and backward, for sequences that fit VMEM (what
+  ``attn_impl='dense'`` runs on a TPU at ViT-B/16's 197 tokens).
 - :func:`fused_lars_ema_update` / :func:`fused_lars_ema_update_zero1` —
   the fused LARS+EMA weight update over the flat segmented buffer
   (``--fused-update on``), replicated and ZeRO-1 layouts.
@@ -18,6 +21,7 @@ from byol_tpu.ops.common import (LANES, TPU_BLOCK_ROWS, fat_tile,
                                  resolve_block_rows, resolve_interpret)
 from byol_tpu.ops.flash_attention import flash_attention
 from byol_tpu.ops.fused_augment import crop_weight_mats, fused_two_view
+from byol_tpu.ops.packed_attention import packed_self_attention
 from byol_tpu.ops.fused_update import (SegmentMap, build_segment_map,
                                        fused_lars_ema_update,
                                        fused_lars_ema_update_zero1,
@@ -25,7 +29,8 @@ from byol_tpu.ops.fused_update import (SegmentMap, build_segment_map,
 
 __all__ = [
     "LANES", "TPU_BLOCK_ROWS", "fat_tile", "resolve_block_rows",
-    "resolve_interpret", "flash_attention", "crop_weight_mats",
+    "resolve_interpret", "flash_attention", "packed_self_attention",
+    "crop_weight_mats",
     "fused_two_view", "SegmentMap", "build_segment_map",
     "fused_lars_ema_update", "fused_lars_ema_update_zero1", "pack_flat",
     "unpack_flat",

@@ -5,8 +5,16 @@ All implementations share one signature::
     fn(q, k, v) -> out      # (B, H, S, D) x3 -> (B, H, S, D)
 
 so the model swaps between them by name without re-plumbing:
-  ``dense``   — straightforward XLA softmax attention (fused by the compiler;
-                right answer for ViT-B's 197 tokens, SURVEY.md §5.7);
+  ``dense``   — exact softmax attention over the whole sequence, written as
+                two einsums.  XLA does NOT fuse them: at ViT-B/16's 197
+                tokens the ``[B,H,S,S]`` scores and weights cross HBM and
+                q, k, v and the output are relaid out by copies — half of
+                the train step's bytes (compiler and trace, PERF.md §5,
+                PR 28).  Where the shapes allow and the program lowers for
+                a TPU, ``models/vit.SelfAttention`` therefore hands the
+                packed ``qkv`` to ``ops/packed_attention.py`` instead
+                (:func:`packed_kernel_applies`): same arithmetic, one
+                kernel forward and one backward;
   ``flash``   — Pallas blockwise-softmax kernel (ops/flash_attention.py),
                 for long sequences where the S x S score matrix shouldn't hit
                 HBM;
@@ -19,9 +27,13 @@ module exists because long-context support is first-class in the rebuild.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional
+from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
+
+from byol_tpu.ops import packed_attention
+from byol_tpu.parallel.mesh import DATA_AXIS
 
 
 def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -45,6 +57,26 @@ def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         - jnp.max(scores, axis=-1, keepdims=True).astype(jnp.float32))
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
+
+
+def packed_kernel_applies(batch: int, seq_len: int, num_heads: int,
+                          head_dim: int, *, causal: bool = False,
+                          masked: bool = False, mesh=None,
+                          backend: Optional[str] = None) -> bool:
+    """Whether ``dense`` self-attention runs as the fused kernel over the
+    packed ``qkv`` (ops/packed_attention.py) — decided from what the code
+    can see, never by a flag: the program lowers for a TPU, nothing is
+    masked, the padded sequence's ``[S,S]`` float32 tiles and row blocks fit
+    VMEM, the head width tiles the 128 lanes, and the mesh in scope (if any)
+    shards nothing but the batch."""
+    backend = jax.default_backend() if backend is None else backend
+    if backend != "tpu" or causal or masked:
+        return False
+    if mesh is not None and (
+            batch % mesh.shape.get(DATA_AXIS, 1)
+            or mesh.size != mesh.shape.get(DATA_AXIS, 1)):
+        return False
+    return packed_attention.supported(seq_len, num_heads, head_dim)
 
 
 def get_attention_fn(impl: str) -> Callable:

@@ -219,3 +219,12 @@ def shard_batch_to_mesh(batch, mesh: Mesh):
 def local_device_count(mesh: Mesh) -> int:
     return len([d for d in mesh.devices.flat
                 if d.process_index == jax.process_index()])
+
+
+def ambient_mesh() -> Optional[Mesh]:
+    """The mesh entered via ``with mesh:`` (the physical mesh thread-local),
+    or ``None``: what a module traced inside the jitted step can see of the
+    devices it will run on."""
+    from jax._src.mesh import thread_resources
+    mesh = thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh

@@ -29,7 +29,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from byol_tpu.ops import common as ops_common
-from byol_tpu.parallel.mesh import DATA_AXIS, SEQUENCE_AXIS
+from byol_tpu.parallel.mesh import (DATA_AXIS, SEQUENCE_AXIS,
+                                    ambient_mesh)
 
 NEG_INF = -1e30
 
@@ -81,7 +82,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     re-plumbing.  S must divide evenly by the sequence-axis size.
     """
     if mesh is None:
-        mesh = _ambient_mesh()
+        mesh = ambient_mesh()
     if mesh is None or SEQUENCE_AXIS not in mesh.axis_names:
         raise ValueError(
             "ring_attention needs a mesh with a 'sequence' axis in scope "
@@ -96,13 +97,3 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     fn = ops_common.shard_map_unchecked(body, mesh, in_specs=(spec, spec, spec),
                                      out_specs=spec)
     return fn(q, k, v)
-
-
-def _ambient_mesh():
-    """The mesh entered via ``with mesh:`` (physical mesh thread-local)."""
-    try:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
-        return None

@@ -636,9 +636,12 @@ def _flash_phase(s: dict, seed: int) -> bool:
     diff = float(np.max(np.abs(out["flash"] - out["dense"])))
     tol = EMBED_RTOL * float(np.max(np.abs(out["dense"])))
     has_kernel = "tpu_custom_call" in text["flash"]
+    # on a TPU ``dense`` at this length is ops/packed_attention.py's kernel
+    packed = "packed_attention_fwd" in text["dense"]
     ok = bool(np.isfinite(out["flash"]).all()) and diff <= tol
     say(f"flash-attention: {s['vit']} forward through the serve builder, "
-        f"batch {s['vit_batch']}; compiled dense {secs['dense']:.1f}s / "
+        f"batch {s['vit_batch']}; compiled dense {secs['dense']:.1f}s "
+        f"({'the packed-qkv kernel' if packed else 'einsums'}) / "
         f"flash {secs['flash']:.1f}s; tpu_custom_call "
         f"{'present' if has_kernel else 'ABSENT'}; max |flash - dense| "
         f"{diff:.3g} (tolerance {tol:.3g} = {EMBED_RTOL} of the largest "

@@ -64,16 +64,17 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _flagship_rcfg():
-    """ResNet-50 BYOL as byol_tpu/cli.py builds it by default, batch 256."""
+def _flagship_rcfg(arch="resnet50", batch=BATCH):
+    """ResNet-50 BYOL as byol_tpu/cli.py builds it by default, batch 256
+    (or another backbone under the same heads, loss and optimizer)."""
     from byol_tpu.core import config as config_lib
     c = config_lib.Config()
     c = c.replace(
-        task=dataclasses.replace(c.task, batch_size=BATCH, epochs=2),
-        model=dataclasses.replace(c.model, arch="resnet50", fuse_views=True),
+        task=dataclasses.replace(c.task, batch_size=batch, epochs=2),
+        model=dataclasses.replace(c.model, arch=arch, fuse_views=True),
         device=dataclasses.replace(c.device, num_replicas=1, half=True))
-    return config_lib.resolve(c, num_train_samples=4 * BATCH,
-                              num_test_samples=BATCH, output_size=10,
+    return config_lib.resolve(c, num_train_samples=4 * batch,
+                              num_test_samples=batch, output_size=10,
                               input_shape=(IMAGE, IMAGE, 3))
 
 
@@ -222,17 +223,16 @@ def test_expert_layer_at_published_widths(no_persistent_cache, one_chip):
 # one whole ResNet-50 train step at batch 256 fits the chip
 # ---------------------------------------------------------------------------
 
-def test_resnet50_train_step_fits_16gb(no_persistent_cache, topo):
-    """The flagship's jitted step (``--fuse-views``, bf16, LARS), built
-    from the compile plan exactly as setup_training wires it, compiles for
-    one described chip and its ``memory_analysis()`` fits 16 GB."""
+def _compile_train_step(topo, rcfg, batch):
+    """The jitted step (``--fuse-views``, bf16, LARS), built from the
+    compile plan exactly as setup_training wires it, compiled for one
+    described chip."""
     from byol_tpu.core.precision import get_policy
     from byol_tpu.parallel.compile_plan import build_plan
     from byol_tpu.training.build import (build_net, build_tx,
                                          init_variables, step_config)
     from byol_tpu.training.state import create_train_state
     from byol_tpu.training.steps import make_train_step
-    rcfg = _flagship_rcfg()
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1, 1),
                 (DATA_AXIS, SEQUENCE_AXIS, MODEL_AXIS))
     net = build_net(rcfg)
@@ -245,12 +245,66 @@ def test_resnet50_train_step_fits_16gb(no_persistent_cache, topo):
         make_train_step(net, tx, step_config(rcfg), get_policy(True),
                         lr_schedule=schedule, mesh=mesh),
         plan.state_sharding(state))
-    view = jax.ShapeDtypeStruct((BATCH, IMAGE, IMAGE, 3), jnp.float32)
-    batch = {"view1": view, "view2": view,
-             "label": jax.ShapeDtypeStruct((BATCH,), jnp.int32)}
+    view = jax.ShapeDtypeStruct((batch, IMAGE, IMAGE, 3), jnp.float32)
+    views = {"view1": view, "view2": view,
+             "label": jax.ShapeDtypeStruct((batch,), jnp.int32)}
     with mesh:
-        compiled = step.lower(state, batch).compile()
+        return step.lower(state, views).compile()
+
+
+def _program_bytes(compiled):
     ma = compiled.memory_analysis()
-    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def test_resnet50_train_step_fits_16gb(no_persistent_cache, topo):
+    """The flagship's step compiles for one described chip and its
+    ``memory_analysis()`` fits 16 GB."""
+    total = _program_bytes(_compile_train_step(topo, _flagship_rcfg(),
+                                               BATCH))
     assert 0 < total < V5E_HBM_BYTES, f"{total / 2 ** 30:.2f} GiB"
+
+
+# ---------------------------------------------------------------------------
+# the fused attention kernels over the packed qkv (ops/packed_attention.py)
+# at ViT-B/16's shapes, and the whole ViT-B/16 step that calls them
+# ---------------------------------------------------------------------------
+
+VIT_TOKENS, VIT_HEADS = 197, 12
+
+
+@pytest.mark.parametrize("batch,tokens,heads,dtype", [
+    (128, VIT_TOKENS, VIT_HEADS, "bfloat16"),   # vitb16_train_b64, two views
+    (128, VIT_TOKENS, VIT_HEADS, "float32"),
+    (32, 512, 16, "bfloat16"),                  # the longest it takes, ViT-L
+])
+def test_packed_attention_forward_and_backward(no_persistent_cache, one_chip,
+                                               batch, tokens, heads, dtype):
+    from byol_tpu.ops.packed_attention import packed_self_attention
+    qkv = jax.ShapeDtypeStruct((batch, tokens, 3 * heads * 64),
+                               jnp.dtype(dtype), sharding=one_chip)
+    _compile(lambda x: packed_self_attention(x, heads, interpret=False), qkv)
+    compiled = _compile(jax.grad(lambda x: jnp.sum(packed_self_attention(
+        x, heads, interpret=False).astype(jnp.float32))), qkv)
+    assert "packed_attention_bwd" in compiled.as_text()
+
+
+def test_vitb16_train_step_keeps_attention_on_chip(no_persistent_cache, topo,
+                                                   monkeypatch):
+    """``vitb16_train_b64``'s step, lowered as on a TPU (the rule and the
+    kernels' ``interpret`` default both ask ``jax.default_backend()``, which
+    is the CPU here): 36 kernel calls, no ``[..., 197, 197]`` array, and
+    next to none of the 148 relayout copies the einsum form compiled to."""
+    import re
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _compile_train_step(topo, _flagship_rcfg("vit_b16", 64), 64)
+    text = compiled.as_text()
+    assert not re.findall(r"\[[\d,]*197,197\]", text)
+    entry = text[text.index("ENTRY"):]
+    assert entry.count('custom_call_target="tpu_custom_call"') == 36
+    assert len(re.findall(r" copy\(", entry)) <= 8
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert cost["bytes accessed"] < 115e9, cost["bytes accessed"]
+    assert 0 < _program_bytes(compiled) < 7 * 2 ** 30
