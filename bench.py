@@ -95,17 +95,6 @@ Usage:
                                    #   must scale ~1/N with mesh size).
                                    #   --cpu-devices N sizes the virtual
                                    #   CPU mesh for off-hardware captures
-  python bench.py --resident-ab    # resident flat update-state A/B
-                                   #   (--zero1 on --fused-update on both
-                                   #   arms): transient per-step
-                                   #   pack/unpack + per-leaf gather
-                                   #   (--flat-resident off) vs resident
-                                   #   buffers aliased in place + bucketed
-                                   #   all-gather (on); wall rate +
-                                   #   dispatch-span p50 per arm, plus an
-                                   #   in-process microbench of the bare
-                                   #   pack+kernel+unpack vs the resident
-                                   #   kernel call
   python bench.py --augment-ab     # fused-augmentation A/B: the step-
                                    #   placement config with the XLA op
                                    #   chain (--fused-augment off) vs the
@@ -234,8 +223,7 @@ def _build(batch_size: int, image_size: int, arch: str, *, half: bool,
            accum_steps: int = 1, accum_bn_mode: str = "average",
            remat_policy: str = "none", augment_placement: str = "loader",
            telemetry: str = "off", zero1: str = "off",
-           fused_update: str = "off", fused_augment: str = "off",
-           flat_resident: str = "off", materialize_batch: bool = True):
+           fused_augment: str = "off", materialize_batch: bool = True):
     from byol_tpu.core.config import (Config, DeviceConfig, ModelConfig,
                                       OptimConfig, ParityConfig, TaskConfig,
                                       resolve)
@@ -253,11 +241,9 @@ def _build(batch_size: int, image_size: int, arch: str, *, half: bool,
                           remat_policy=remat_policy,
                           stem=stem, attn_impl=attn_impl),
         optim=OptimConfig(accum_steps=accum_steps,
-                          accum_bn_mode=accum_bn_mode,
-                          fused_update=fused_update),
+                          accum_bn_mode=accum_bn_mode),
         device=DeviceConfig(num_replicas=n_dev, half=half, seed=0,
-                            telemetry=telemetry, zero1=zero1,
-                            flat_resident=flat_resident),
+                            telemetry=telemetry, zero1=zero1),
         parity=ParityConfig(ema_update_mode=ema_update_mode),
     )
     rcfg = resolve(cfg, num_train_samples=1_281_167, num_test_samples=50_000,
@@ -668,12 +654,6 @@ def main():
         return
     if "--zero1-ab" in sys.argv[1:]:
         _zero1_ab(arch, image_size, on_tpu, attn_impl)
-        return
-    if "--fused-ab" in sys.argv[1:]:
-        _fused_ab(arch, image_size, on_tpu, attn_impl)
-        return
-    if "--resident-ab" in sys.argv[1:]:
-        _resident_ab(arch, image_size, on_tpu, attn_impl)
         return
     if "--augment-ab" in sys.argv[1:]:
         _augment_ab(arch, image_size, on_tpu, attn_impl)
@@ -1637,280 +1617,6 @@ def _zero1_ab(arch, image_size, on_tpu, attn_impl):
         "arch": arch, "image_size": image_size,
         "effective_batch_per_chip": eff, "microbatch_per_chip": mb,
         "accum_steps": accum, "remat_policy": policy,
-        "device_kind": jax.devices()[0].device_kind,
-    }))
-
-
-def _fused_ab(arch, image_size, on_tpu, attn_impl):
-    """Fused-update A/B (``--fused-ab``): the SAME config AOT-compiled
-    with the optax chain (``--fused-update off``, the exact pre-fused
-    graph — pinned by the HLO-identity test) and with the fused Pallas
-    LARS+EMA kernel (``on``; ops/fused_update.py), each arm timed with a
-    live :class:`spans.SpanRecorder` wrapping every step dispatch plus
-    the closing readback — so the win is attributed in the same
-    flight-recorder currency the trainer logs (wall rate + per-step
-    dispatch-span stats into ``bench_events.jsonl``).
-
-    Also records an IN-PROCESS kernel microbenchmark row: the bare weight
-    update (optax chain + apply_updates + EMA tick vs the fused kernel)
-    on a synthetic multi-leaf tree, timed on its own executable — the
-    number that isolates the update from the forward/backward around it.
-    NB on CPU the fused arm runs the kernel under the Pallas INTERPRETER
-    (correctness-grade, not speed-grade — interpret mode dispatches one
-    XLA op per kernel instruction): the CPU capture documents the
-    mechanism and the event plumbing; the TPU row is where the HBM-sweep
-    arithmetic pays.
-    """
-    import jax.numpy as jnp
-
-    from byol_tpu.observability import goodput as goodput_lib
-    from byol_tpu.observability import spans as spans_lib
-    from byol_tpu.optim.factory import (MOMENTUM_DECAY, build_optimizer,
-                                        extract_sgdm_state)
-    from byol_tpu.ops import fused_update as fused_lib
-    bs = 256 if on_tpu else 16
-    steps = 60 if on_tpu else 30
-    rates, span_p50 = {}, {}
-    for mode in ("off", "on"):
-        state, train_step, batch, mesh = _build(
-            bs, image_size, arch, half=on_tpu, fuse_views=True,
-            ema_update_mode="post", attn_impl=attn_impl, fused_update=mode)
-        compiled, stats = _aot_compile(train_step, state, batch, mesh)
-        recorder = spans_lib.SpanRecorder()
-        for _ in range(3):                       # warm; sync via readback
-            state, metrics = compiled(state, batch)
-        float(metrics["loss_mean"])
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            with recorder.span("train/dispatch"):
-                state, metrics = compiled(state, batch)
-        with recorder.span("train/epoch_readback"):
-            float(metrics["loss_mean"])
-        dt = time.perf_counter() - t0
-        n_dev = len(jax.devices())
-        rates[mode] = batch["label"].shape[0] * steps / dt / n_dev
-        sstats = goodput_lib.span_stats(recorder.records())
-        span_p50[mode] = sstats.get("train/dispatch", {}).get("p50_ms")
-        if _events is not None:
-            _events.emit("span_stats", scope="epoch",
-                         label=f"fused_{mode}", spans=sstats)
-        _record(f"fused_{mode}", fit=True, batch_per_chip=bs,
-                fused_update=mode,
-                images_per_sec_per_chip=round(rates[mode], 2),
-                dispatch_span_p50_ms=span_p50[mode], **stats)
-        print(f"bench: fused_{mode}: {rates[mode]:.2f} img/s/chip "
-              f"(dispatch p50 {span_p50[mode]}ms)", file=sys.stderr)
-
-    # ---- in-process kernel microbenchmark ------------------------------
-    # synthetic tree: a few conv-shaped kernels + 1-D bias/BN leaves, big
-    # enough that per-dispatch overhead is not the whole measurement
-    rng = np.random.default_rng(0)
-    leaf_shapes = ([(3, 3, 256, 256)] * 4 + [(1024, 512), (512,), (256,)]
-                   if on_tpu else
-                   [(3, 3, 32, 64), (3, 3, 64, 64), (128, 256), (64,),
-                    (256,)])
-    params = {f"l{i}": jnp.asarray(rng.standard_normal(s) * 0.05,
-                                   jnp.float32)
-              for i, s in enumerate(leaf_shapes)}
-    n_elems = sum(int(np.prod(s)) for s in leaf_shapes)
-    grads = jax.tree_util.tree_map(
-        lambda p: jnp.asarray(rng.standard_normal(p.shape) * 0.01,
-                              jnp.float32), params)
-    target = jax.tree_util.tree_map(lambda p: p * 0.9, params)
-    wd = 1e-6
-    tau = 0.99
-    tx, sched = build_optimizer(
-        "lars_momentum", base_lr=0.2, global_batch_size=4096,
-        weight_decay=wd, total_units=100, warmup_units=10)
-    opt_state = tx.init(params)
-    trace, count = extract_sgdm_state(opt_state)
-    lr = sched(count)
-
-    @jax.jit
-    def optax_update(g, st, p, t):
-        u, st2 = tx.update(g, st, p)
-        import optax as _optax
-        p2 = _optax.apply_updates(p, u)
-        t2 = jax.tree_util.tree_map(
-            lambda tt, pp: tau * tt + (1 - tau) * pp, t, p2)
-        return p2, st2, t2
-
-    @jax.jit
-    def fused(g, m, p, t):
-        return fused_lib.fused_lars_ema_update(
-            p, g, m, t, lr=lr, tau=tau, weight_decay=wd,
-            momentum_decay=MOMENTUM_DECAY)
-
-    def bench_fn(fn, args, reps=5, inner=3):
-        out = fn(*args)                       # compile + warm
-        jax.block_until_ready(out)
-        times = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            for _ in range(inner):
-                jax.block_until_ready(fn(*args))
-            times.append((time.perf_counter() - t0) / inner)
-        return float(np.median(times))
-
-    t_optax = bench_fn(optax_update, (grads, opt_state, params, target))
-    t_fused = bench_fn(fused, (grads, trace, params, target))
-    row = {
-        "params": n_elems,
-        "optax_chain_us": round(t_optax * 1e6, 1),
-        "fused_kernel_us": round(t_fused * 1e6, 1),
-        "fused_speedup": round(t_optax / t_fused, 3),
-        "interpret_mode": not on_tpu,
-    }
-    _record("fused_microbench", fit=True, **row)
-    overhead = 1.0 - rates["on"] / rates["off"]
-    print(_json_line({
-        "metric": "fused_update_ab",
-        "value": round(rates["on"], 2),
-        "unit": "images/sec/chip",
-        "vs_baseline": round(rates["on"] / rates["off"], 4),
-        "off_images_per_sec_per_chip": round(rates["off"], 2),
-        "on_images_per_sec_per_chip": round(rates["on"], 2),
-        "step_overhead_pct": round(100.0 * overhead, 2),
-        "dispatch_span_p50_ms": span_p50,
-        "microbench": row,
-        "batch_per_chip": bs, "arch": arch, "image_size": image_size,
-        "timing_steps": steps,
-        "device_kind": jax.devices()[0].device_kind,
-    }))
-
-
-def _resident_ab(arch, image_size, on_tpu, attn_impl):
-    """Resident flat update-state A/B (``--resident-ab``): the ZeRO-1 +
-    fused-update config AOT-compiled with the transient layout
-    (``--flat-resident off`` — momentum/target packed and unpacked every
-    step, EMA target gathered leaf-by-leaf) and with resident flat
-    buffers (``on`` — packed once at setup, aliased in place step over
-    step, bucketed all-gather), each arm timed with a live
-    :class:`spans.SpanRecorder` wrapping every step dispatch plus the
-    closing readback (wall rate + dispatch-span p50 ->
-    ``bench_events.jsonl``).
-
-    Also records an IN-PROCESS microbenchmark row isolating what
-    residency deletes: the transient entry (pack params/grads/momentum/
-    target + kernel + unpack all four) vs the resident entry (pack
-    params/grads only, kernel consumes the resident buffers in place) on
-    the same synthetic multi-leaf tree.  NB on CPU both arms run the
-    kernel under the Pallas INTERPRETER — correctness-grade plumbing
-    capture, not speed-grade; the TPU row is where the deleted VMEM
-    round trips pay.
-    """
-    import jax.numpy as jnp
-
-    from byol_tpu.observability import goodput as goodput_lib
-    from byol_tpu.observability import spans as spans_lib
-    from byol_tpu.optim.factory import (MOMENTUM_DECAY, build_optimizer,
-                                        extract_sgdm_state)
-    from byol_tpu.ops import fused_update as fused_lib
-    from byol_tpu.parallel import flat_state as flat_lib
-    bs = 256 if on_tpu else 16
-    steps = 60 if on_tpu else 30
-    rates, span_p50 = {}, {}
-    for mode in ("off", "on"):
-        state, train_step, batch, mesh = _build(
-            bs, image_size, arch, half=on_tpu, fuse_views=True,
-            ema_update_mode="post", attn_impl=attn_impl,
-            zero1="on", fused_update="on", flat_resident=mode)
-        compiled, stats = _aot_compile(train_step, state, batch, mesh)
-        recorder = spans_lib.SpanRecorder()
-        for _ in range(3):                       # warm; sync via readback
-            state, metrics = compiled(state, batch)
-        float(metrics["loss_mean"])
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            with recorder.span("train/dispatch"):
-                state, metrics = compiled(state, batch)
-        with recorder.span("train/epoch_readback"):
-            float(metrics["loss_mean"])
-        dt = time.perf_counter() - t0
-        n_dev = len(jax.devices())
-        rates[mode] = batch["label"].shape[0] * steps / dt / n_dev
-        sstats = goodput_lib.span_stats(recorder.records())
-        span_p50[mode] = sstats.get("train/dispatch", {}).get("p50_ms")
-        if _events is not None:
-            _events.emit("span_stats", scope="epoch",
-                         label=f"resident_{mode}", spans=sstats)
-        _record(f"resident_{mode}", fit=True, batch_per_chip=bs,
-                flat_resident=mode, zero1="on", fused_update="on",
-                images_per_sec_per_chip=round(rates[mode], 2),
-                dispatch_span_p50_ms=span_p50[mode], **stats)
-        print(f"bench: resident_{mode}: {rates[mode]:.2f} img/s/chip "
-              f"(dispatch p50 {span_p50[mode]}ms)", file=sys.stderr)
-
-    # ---- in-process microbenchmark: transient entry vs resident entry --
-    rng = np.random.default_rng(0)
-    leaf_shapes = ([(3, 3, 256, 256)] * 4 + [(1024, 512), (512,), (256,)]
-                   if on_tpu else
-                   [(3, 3, 32, 64), (3, 3, 64, 64), (128, 256), (64,),
-                    (256,)])
-    params = {f"l{i}": jnp.asarray(rng.standard_normal(s) * 0.05,
-                                   jnp.float32)
-              for i, s in enumerate(leaf_shapes)}
-    n_elems = sum(int(np.prod(s)) for s in leaf_shapes)
-    grads = jax.tree_util.tree_map(
-        lambda p: jnp.asarray(rng.standard_normal(p.shape) * 0.01,
-                              jnp.float32), params)
-    target = jax.tree_util.tree_map(lambda p: p * 0.9, params)
-    wd = 1e-6
-    tau = 0.99
-    tx, sched = build_optimizer(
-        "lars_momentum", base_lr=0.2, global_batch_size=4096,
-        weight_decay=wd, total_units=100, warmup_units=10)
-    opt_state = tx.init(params)
-    trace, count = extract_sgdm_state(opt_state)
-    lr = sched(count)
-    layout = flat_lib.build_layout(params, 1)
-    m_buf = jax.jit(lambda t: flat_lib.pack_tree(t, layout))(trace)
-    t_buf = jax.jit(lambda t: flat_lib.pack_tree(t, layout))(target)
-
-    @jax.jit
-    def transient(g, m, p, t):
-        return fused_lib.fused_lars_ema_update(
-            p, g, m, t, lr=lr, tau=tau, weight_decay=wd,
-            momentum_decay=MOMENTUM_DECAY)
-
-    @jax.jit
-    def resident(g, mb, p, tb):
-        return fused_lib.fused_lars_ema_update_resident(
-            p, g, mb, tb, layout=layout, lr=lr, tau=tau, weight_decay=wd,
-            momentum_decay=MOMENTUM_DECAY)
-
-    def bench_fn(fn, args, reps=5, inner=3):
-        out = fn(*args)                       # compile + warm
-        jax.block_until_ready(out)
-        times = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            for _ in range(inner):
-                jax.block_until_ready(fn(*args))
-            times.append((time.perf_counter() - t0) / inner)
-        return float(np.median(times))
-
-    t_transient = bench_fn(transient, (grads, trace, params, target))
-    t_resident = bench_fn(resident, (grads, m_buf, params, t_buf))
-    row = {
-        "params": n_elems,
-        "transient_entry_us": round(t_transient * 1e6, 1),
-        "resident_entry_us": round(t_resident * 1e6, 1),
-        "resident_speedup": round(t_transient / t_resident, 3),
-        "interpret_mode": not on_tpu,
-    }
-    _record("resident_microbench", fit=True, **row)
-    print(_json_line({
-        "metric": "flat_resident_ab",
-        "value": round(rates["on"], 2),
-        "unit": "images/sec/chip",
-        "vs_baseline": round(rates["on"] / rates["off"], 4),
-        "off_images_per_sec_per_chip": round(rates["off"], 2),
-        "on_images_per_sec_per_chip": round(rates["on"], 2),
-        "dispatch_span_p50_ms": span_p50,
-        "microbench": row,
-        "batch_per_chip": bs, "arch": arch, "image_size": image_size,
-        "timing_steps": steps,
         "device_kind": jax.devices()[0].device_kind,
     }))
 

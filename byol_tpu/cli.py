@@ -195,32 +195,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "(parallel/compile_plan.py)")
     x.add_argument("--fsdp", action="store_true",
                    help=argparse.SUPPRESS)  # deprecated alias: --zero1 on
-    x.add_argument("--flat-resident", type=str, default="off",
-                   choices=("off", "on"),
-                   help="resident flat update state (parallel/flat_state"
-                        ".py): 'on' keeps LARS momentum, the EMA target, "
-                        "and (under --zero1 on) the param shadow as ONE "
-                        "resident flat fp32 buffer each across steps — "
-                        "packed once at setup, consumed in place by the "
-                        "fused kernel (zero per-step pack/unpack), with "
-                        "bucketed all-gathers replacing the per-leaf "
-                        "ones.  Requires --fused-update on; 'off' lowers "
-                        "the transient graph unchanged")
-    x.add_argument("--flat-bucket-mb", type=int, default=64,
-                   help="bucket budget in MiB of gathered bytes for the "
-                        "resident layout's coalesced all-gathers "
-                        "(--flat-resident on)")
-    x.add_argument("--fused-update", type=str, default="off",
-                   choices=("off", "on"),
-                   help="fused LARS+EMA weight update (ops/fused_update.py "
-                        "Pallas kernel): 'on' computes per-layer trust "
-                        "ratios from a flat segment-norm pass and applies "
-                        "weight decay + trust scaling + momentum tick + "
-                        "param write + EMA target tick in ONE pass over "
-                        "the flat parameter buffer (~3 elementwise HBM "
-                        "sweeps -> ~1; shard-local under --zero1 on).  "
-                        "Requires --optimizer lars_momentum with --clip 0; "
-                        "'off' lowers the exact unfused graph")
     x.add_argument("--fused-augment", type=str, default="off",
                    choices=("off", "on"),
                    help="fused in-step augmentation (ops/fused_augment.py "
@@ -385,8 +359,7 @@ def config_from_args(args: argparse.Namespace) -> Config:
             warmup=args.warmup, optimizer=args.optimizer,
             early_stop=args.early_stop,
             accum_steps=args.accum_steps,
-            accum_bn_mode=args.accum_bn_mode,
-            fused_update=args.fused_update),
+            accum_bn_mode=args.accum_bn_mode),
         device=DeviceConfig(
             num_replicas=n_rep,
             workers_per_replica=args.workers_per_replica,
@@ -406,9 +379,7 @@ def config_from_args(args: argparse.Namespace) -> Config:
             model_parallel=args.model_parallel,
             sequence_parallel=args.sequence_parallel,
             dcn_data_parallel=args.dcn_data_parallel,
-            zero1=zero1,
-            flat_resident=args.flat_resident,
-            flat_bucket_mb=args.flat_bucket_mb),
+            zero1=zero1),
         parity=ParityConfig(
             loss_norm_mode=args.loss_norm_mode,
             ema_init_mode=args.ema_init_mode,

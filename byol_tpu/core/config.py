@@ -174,14 +174,6 @@ class OptimConfig:
     #                big-batch memory back (all microbatches in flight):
     #                a semantics oracle for parity tests, not an HBM saver.
     accum_bn_mode: str = "average"
-    # Fused LARS+EMA weight update (ops/fused_update.py): 'on' replaces the
-    # optax chain + EMA tick — ~3 full-parameter elementwise HBM sweeps per
-    # optimizer step — with one Pallas kernel pass over a flat segmented
-    # buffer (segment norms -> trust ratios -> wd/momentum/param/EMA in one
-    # read-modify-write), shard-local under --zero1 on.  Requires the
-    # lars_momentum chain with --clip 0 (validated at resolve()); 'off'
-    # lowers the exact unfused graph (HLO identity pinned by test).
-    fused_update: str = "off"
 
 
 @_frozen
@@ -249,20 +241,6 @@ class DeviceConfig:
                                         # state HBM per chip); 'off' lowers
                                         # the replicated graph unchanged.
                                         # parallel/{compile_plan,zero1}.py
-    flat_resident: str = "off"          # resident flat update state
-                                        # (parallel/flat_state.py): 'on'
-                                        # keeps LARS momentum, the EMA
-                                        # target, and (under zero1) the
-                                        # param shadow as ONE flat fp32
-                                        # buffer each across steps — packed
-                                        # once at setup, zero per-step
-                                        # pack/unpack, gathers bucketed.
-                                        # Requires --fused-update on;
-                                        # 'off' lowers the transient graph
-                                        # unchanged.
-    flat_bucket_mb: int = 64            # bucket budget (MiB of gathered
-                                        # bytes) for the resident layout's
-                                        # coalesced all-gathers
 
 
 @_frozen
@@ -408,46 +386,6 @@ def resolve(cfg: Config, *, num_train_samples: int, num_test_samples: int,
             "--zero1 on does not compose with --model-parallel > 1 "
             "(tensor parallelism already shards those optimizer-state "
             "leaves over the 'model' axis)")
-    if cfg.optim.fused_update not in ("off", "on"):
-        raise ValueError(
-            f"unknown fused_update mode {cfg.optim.fused_update!r}; "
-            "'off' | 'on'")
-    if cfg.optim.fused_update == "on":
-        # the kernel implements exactly the lars_momentum chain; any other
-        # optimizer config would silently train with different math
-        from byol_tpu.optim.factory import fused_update_unsupported_reason
-        reason = fused_update_unsupported_reason(cfg.optim.optimizer,
-                                                 cfg.optim.clip)
-        if reason is not None:
-            raise ValueError(f"--fused-update on: {reason}")
-        if cfg.device.model_parallel > 1:
-            # the replicated-layout kernel runs under a shard_map with
-            # fully-replicated specs — it would silently all-gather the
-            # TP-sharded head params/opt-state leaves every step (the
-            # same non-composition --zero1 on rejects above)
-            raise ValueError(
-                "--fused-update on does not compose with "
-                "--model-parallel > 1 (tensor parallelism shards head "
-                "opt-state leaves over 'model'; the fused kernel's flat "
-                "buffer would un-shard them every step)")
-    if cfg.device.flat_resident not in ("off", "on"):
-        raise ValueError(
-            f"unknown flat_resident mode {cfg.device.flat_resident!r}; "
-            "'off' | 'on'")
-    if cfg.device.flat_resident == "on":
-        if cfg.optim.fused_update != "on":
-            raise ValueError(
-                "--flat-resident on requires --fused-update on: the "
-                "resident buffers are laid out for (and consumed by) the "
-                "fused kernel — the optax chain has no flat entry point")
-        if cfg.device.model_parallel > 1:
-            raise ValueError(
-                "--flat-resident on lays the update state out over the "
-                "data axis; it does not compose with --model-parallel > 1")
-        if cfg.device.flat_bucket_mb < 1:
-            raise ValueError(
-                "--flat-bucket-mb must be >= 1, got "
-                f"{cfg.device.flat_bucket_mb}")
     if cfg.task.fused_augment not in ("off", "on"):
         raise ValueError(
             f"unknown fused_augment mode {cfg.task.fused_augment!r}; "

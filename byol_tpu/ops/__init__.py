@@ -10,28 +10,16 @@ call sites name the capability, not the file:
 - :func:`packed_self_attention` — whole-sequence softmax attention over the
   packed ``qkv``, forward and backward, for sequences that fit VMEM (what
   ``attn_impl='dense'`` runs on a TPU at ViT-B/16's 197 tokens).
-- :func:`fused_lars_ema_update` / :func:`fused_lars_ema_update_zero1` —
-  the fused LARS+EMA weight update over the flat segmented buffer
-  (``--fused-update on``), replicated and ZeRO-1 layouts.
 - :func:`fused_two_view` — the fused uint8→two-view augmentation
   (``--fused-augment on``): one VMEM pass per image for
   convert/crop/flip/jitter/grayscale, blur as an MXU conv on the output.
 """
-from byol_tpu.ops.common import (LANES, TPU_BLOCK_ROWS, fat_tile,
-                                 resolve_block_rows, resolve_interpret)
+from byol_tpu.ops.common import LANES, resolve_interpret
 from byol_tpu.ops.flash_attention import flash_attention
 from byol_tpu.ops.fused_augment import crop_weight_mats, fused_two_view
 from byol_tpu.ops.packed_attention import packed_self_attention
-from byol_tpu.ops.fused_update import (SegmentMap, build_segment_map,
-                                       fused_lars_ema_update,
-                                       fused_lars_ema_update_zero1,
-                                       pack_flat, unpack_flat)
 
 __all__ = [
-    "LANES", "TPU_BLOCK_ROWS", "fat_tile", "resolve_block_rows",
-    "resolve_interpret", "flash_attention", "packed_self_attention",
-    "crop_weight_mats",
-    "fused_two_view", "SegmentMap", "build_segment_map",
-    "fused_lars_ema_update", "fused_lars_ema_update_zero1", "pack_flat",
-    "unpack_flat",
+    "LANES", "resolve_interpret", "flash_attention",
+    "packed_self_attention", "crop_weight_mats", "fused_two_view",
 ]

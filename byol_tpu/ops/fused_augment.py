@@ -48,8 +48,8 @@ so the step's input tax stops scaling with its length:
    rounding under ``--half``.
 
 Layout/meshes: on a multi-device mesh the ``pallas_call`` runs inside a
-``shard_map`` over the data axis (GSPMD cannot partition a pallas_call —
-the fused_update.py lesson); every chip augments only its batch shard, and
+``shard_map`` over the data axis (GSPMD cannot partition a pallas_call);
+every chip augments only its batch shard, and
 the per-image parameter/weight construction before it and the blur after
 it are ordinary GSPMD ops.
 

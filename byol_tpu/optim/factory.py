@@ -25,9 +25,7 @@ import optax
 from byol_tpu.optim import lars as lars_lib
 from byol_tpu.optim import schedules as sched_lib
 
-# the 'momentum' registry entry's decay (reference main.py:311) — also the
-# momentum the fused update kernel ticks (training/steps.py), so the
-# number has exactly one home
+# the 'momentum' registry entry's decay (reference main.py:311)
 MOMENTUM_DECAY = 0.9
 
 
@@ -63,43 +61,13 @@ def is_lars_optimizer(opt_name: str) -> bool:
     return opt_name.lower().strip().startswith("lars_")
 
 
-def fused_update_unsupported_reason(opt_name: str, clip: float = 0.0,
-                                    adapt_mask: Optional[Any] = None
-                                    ) -> Optional[str]:
-    """Why ``--fused-update on`` cannot serve this optimizer config —
-    ``None`` when the fused Pallas kernel (ops/fused_update.py) computes
-    exactly the chain :func:`build_optimizer` would.  The ONE gating
-    predicate, shared by config resolve() (fail fast at the CLI) and the
-    step builder (fail fast for programmatic callers).  ``adapt_mask``
-    (``optim.lars.default_exclusion_mask`` of the parameter tree, once it
-    exists) names a tree the kernel cannot take."""
-    full = opt_name.lower().strip()
-    if not is_lars_optimizer(full):
-        return (f"optimizer {opt_name!r} does not build the LARS wrapper "
-                "chain; the fused kernel implements wd fold-in + trust "
-                "ratio + momentum (use lars_momentum)")
-    if full.split("_")[-1] != "momentum":
-        return (f"inner optimizer {full.split('_')[-1]!r} is not the sgd-"
-                "momentum trace the fused kernel ticks (use lars_momentum)")
-    if clip > 0.0:
-        return ("--clip > 0 value-clips gradients before LARS; the fused "
-                "kernel does not replicate the clip")
-    if adapt_mask is not None and lars_lib.has_expert_axis(adapt_mask):
-        return ("the parameter tree stacks expert kernels on a leading "
-                "axis and LARS adapts every expert alone; the fused kernel "
-                "has one segment, one trust ratio, per leaf")
-    return None
-
-
 def extract_sgdm_state(opt_state: Any) -> Tuple[Any, Any]:
     """``(momentum_trace_tree, schedule_count)`` out of the lars_momentum
     chain state — located by node TYPE (TraceState / ScaleByScheduleState),
     not by tuple position, so an optax version reshuffling the chain
     nesting fails loudly here instead of silently reading the wrong slot.
-    The fused update reads these, ticks them in-kernel, and writes them
-    back via :func:`replace_sgdm_state`; the opt_state PYTREE STRUCTURE is
-    never changed (checkpoints, shardings, and the zero1 codec all key on
-    it)."""
+    Read by what compares a step's momentum with a reference
+    (benchmarks/drivers/train_loop.py, chip_smoke.py, tests)."""
     traces, counts = [], []
 
     def walk(node):
@@ -114,31 +82,10 @@ def extract_sgdm_state(opt_state: Any) -> Tuple[Any, Any]:
     walk(opt_state)
     if len(traces) != 1 or len(counts) != 1:
         raise ValueError(
-            f"opt_state is not the lars_momentum chain the fused update "
-            f"expects: found {len(traces)} TraceState / {len(counts)} "
-            "ScaleByScheduleState nodes (fused_update_unsupported_reason "
-            "should have rejected this config)")
+            "opt_state is not the lars_momentum chain: found "
+            f"{len(traces)} TraceState / {len(counts)} "
+            "ScaleByScheduleState nodes")
     return traces[0], counts[0]
-
-
-def replace_sgdm_state(opt_state: Any, new_trace: Any,
-                       new_count: Any) -> Any:
-    """Rebuild the chain state with a fresh momentum trace + schedule
-    count — the exact inverse of :func:`extract_sgdm_state` (every other
-    node, including the empty wd/LARS states, passes through untouched)."""
-
-    def rebuild(node):
-        if isinstance(node, optax.TraceState):
-            return optax.TraceState(trace=new_trace)
-        if isinstance(node, optax.ScaleByScheduleState):
-            return optax.ScaleByScheduleState(count=new_count)
-        if isinstance(node, tuple) and hasattr(node, "_fields"):
-            return type(node)(*[rebuild(c) for c in node])
-        if isinstance(node, tuple):
-            return tuple(rebuild(c) for c in node)
-        return node
-
-    return rebuild(opt_state)
 
 
 def build_optimizer(opt_name: str, *,

@@ -29,9 +29,7 @@ import optax
 MaskOrFn = Union[Any, Callable[[Any], Any]]
 
 # Factory defaults (reference main.py:339-340) — the ONE home for these
-# numbers: optim/factory.py's signature and the fused update kernel
-# (ops/fused_update.py) both read them here, so the fused path can never
-# apply a ratio computed with drifted hyperparameters.
+# numbers: optim/factory.py's signature reads them here.
 TRUST_COEFFICIENT_DEFAULT = 1e-3
 LARS_EPS_DEFAULT = 0.0
 
@@ -95,11 +93,9 @@ def trust_ratio_from_norms(param_norm: jnp.ndarray, grad_norm: jnp.ndarray,
     """Steps 2-3 on PRECOMPUTED norms (lars.py:100-108), elementwise.
 
     The ONE trust-ratio formula: :func:`_leaf_trust_ratio` (the optax
-    transform + per-leaf telemetry) applies it to scalar norms, and the
-    fused Pallas kernel (ops/fused_update.py) applies it to its
-    segment-norm vectors — so a norm source can change without the ratio
-    semantics ever forking.  ``grad_norm`` must be of the POST-weight-decay
-    gradient (step 1 folds wd in first).
+    transform + per-leaf telemetry) applies it to a leaf's scalar norms and
+    to a stacked expert kernel's per-expert norms.  ``grad_norm`` must be of
+    the POST-weight-decay gradient (step 1 folds wd in first).
     """
     return jnp.where(
         (param_norm > 0.0) & (grad_norm > 0.0),

@@ -25,13 +25,6 @@ answer is declared data in ONE place:
   in_shardings=...)`` outside this module, or a PartitionSpec naming an
   axis the parallel/ modules never declared, is a lint failure.
 
-The fused weight-update kernel (``--fused-update on``,
-ops/fused_update.py) consumes this plan's layouts unchanged: same state
-shardings, same donation, same ``Zero1Context`` — it swaps WHAT computes
-the update (one Pallas pass instead of the optax chain), never where
-anything lives, which is why ``--fused-update off`` lowers byte-identical
-HLO (tests/test_fused_update.py).
-
 ``--zero1 off`` must lower the exact pre-plan graph: the plan then passes
 the same partitioning.py shardings and the same donation the per-site jit
 calls passed, pinned by an HLO-identity test (tests/test_zero1.py).
@@ -44,8 +37,7 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from byol_tpu.parallel import flat_state, zero1 as zero1_lib
-from byol_tpu.parallel.flat_state import FlatResidentContext
+from byol_tpu.parallel import zero1 as zero1_lib
 from byol_tpu.parallel.mesh import DATA_AXIS
 from byol_tpu.parallel.partitioning import _path_names, state_shardings
 from byol_tpu.parallel.zero1 import ZERO1_STATE_FIELDS, Zero1Context
@@ -77,19 +69,12 @@ class CompilePlan:
 
     mesh: Mesh
     zero1: bool = False
-    # --flat-resident on: momentum / EMA target / (zero1) param shadow live
-    # as resident flat fp32 buffers (parallel/flat_state.py) packed once in
-    # prepare_state; bucket_mb sizes the coalesced gather's all-gathers.
-    flat_resident: bool = False
-    bucket_mb: int = flat_state.DEFAULT_BUCKET_MB
-    # Templates derived by prepare_state (zero1/flat_resident): the
-    # canonical (replicated, shaped) and flat (padded 1-D) skeletons of the
-    # converted state fields, used by the in-graph gather and the
-    # checkpoint codec.
+    # Templates derived by prepare_state (zero1): the canonical
+    # (replicated, shaped) and flat (padded 1-D) skeletons of the converted
+    # state fields, used by the in-graph gather and the checkpoint codec.
     _param_template: Any = None
     _canon_templates: Any = None     # {field: canonical template tree}
     _flat_templates: Any = None      # {field: flat template tree}
-    _flat_layout: Any = None         # FlatLayout (flat_resident only)
     # jitted layout conversions of the checkpoint codec, built on first use
     _codec: Any = dataclasses.field(default_factory=dict)
 
@@ -119,15 +104,10 @@ class CompilePlan:
             return base
         n = self.num_shards
         sharded = NamedSharding(self.mesh, P(DATA_AXIS))
-        # the resident param shadow is a sharded flat buffer like the
-        # zero1 opt_state/target leaves (it only exists under zero1 +
-        # flat_resident; the replicated-resident buffers stay replicated)
-        fields = ZERO1_STATE_FIELDS + (
-            ("flat_shadow",) if self.flat_resident else ())
 
         def spec_for(path, leaf, cur):
             names = _path_names(path)
-            if (names and names[0] in fields
+            if (names and names[0] in ZERO1_STATE_FIELDS
                     and getattr(leaf, "ndim", 0) == 1
                     and leaf.shape[0] % n == 0):
                 return sharded
@@ -169,40 +149,9 @@ class CompilePlan:
             # train step donates the state (training/state._dedupe_buffers)
             from byol_tpu.training.state import _dedupe_buffers
             state = _dedupe_buffers(state)
-        if self.flat_resident:
-            if self._param_template is None:
-                # replicated resident plan: derive the canonical templates
-                # the zero1 branch would have (the codec + gather need them)
-                self._param_template = jax.tree_util.tree_map(
-                    _struct_of, state.params)
-                self._canon_templates = {
-                    "opt_state": jax.eval_shape(tx.init,
-                                                self._param_template),
-                    "target_params": self._param_template,
-                }
-            self._flat_layout = flat_state.build_layout(
-                self._param_template,
-                self.num_shards if self.zero1 else 1)
-            state = self._pack_resident(state)
         sharding = self.state_sharding(state)
         state = jax.device_put(state, sharding)
         return state, sharding
-
-    def _pack_resident(self, state: Any) -> Any:
-        """The ONE pack: momentum trace, EMA target, and (zero1) the param
-        shadow become resident flat buffers.  pack_tree is idempotent over
-        the zero1 global flat leaves, so this runs identically after either
-        layout branch above."""
-        from byol_tpu.optim.factory import (extract_sgdm_state,
-                                            replace_sgdm_state)
-        lay = self._flat_layout
-        trace, count = extract_sgdm_state(state.opt_state)
-        return state.replace(
-            opt_state=replace_sgdm_state(
-                state.opt_state, flat_state.pack_tree(trace, lay), count),
-            target_params=flat_state.pack_tree(state.target_params, lay),
-            flat_shadow=(flat_state.pack_tree(state.params, lay)
-                         if self.zero1 else None))
 
     def _require_prepared(self, what: str) -> None:
         if self._param_template is None:
@@ -219,16 +168,6 @@ class CompilePlan:
         self._require_prepared("zero1_context()")
         return Zero1Context(mesh=self.mesh, num_shards=self.num_shards,
                             param_template=self._param_template)
-
-    def flat_context(self) -> Optional[FlatResidentContext]:
-        """The in-graph resident-buffer helper (bucketed gather + layout)
-        for the step builders; ``None`` when ``--flat-resident off`` — the
-        builders then trace the transient graph byte-identically."""
-        if not self.flat_resident:
-            return None
-        self._require_prepared("flat_context()")
-        return FlatResidentContext(mesh=self.mesh, layout=self._flat_layout,
-                                   bucket_mb=self.bucket_mb)
 
     # -- jit wiring: the six entry points ----------------------------------
     def jit_train_step(self, fn: Callable, state_sharding: Any):
@@ -288,16 +227,15 @@ class CompilePlan:
         """Plan layout -> the mesh-size-portable checkpoint layout
         (unflattened, replicated).  Identity when the plan is replicated,
         so ``--zero1 off`` checkpoints exactly as before — and a ckpt
-        written either way restores under either flag, any device count,
-        and either ``--flat-resident`` setting."""
-        if not (self.zero1 or self.flat_resident):
+        written either way restores under either flag and any device
+        count."""
+        if not self.zero1:
             return state
         self._require_prepared("to_canonical()")
         return self._run_codec(
             "to_canonical", state,
-            self._unpack_resident if self.flat_resident
-            else lambda s: self._convert(s, self._canon_templates,
-                                         self.num_shards),
+            lambda s: self._convert(s, self._canon_templates,
+                                    self.num_shards),
             lambda out: jax.tree_util.tree_map(lambda _: self.replicated,
                                                out))
 
@@ -317,29 +255,15 @@ class CompilePlan:
             self._codec[name] = fn
         return fn(state)
 
-    def _unpack_resident(self, state: Any) -> Any:
-        """Resident buffers -> shaped canonical trees (the shadow is
-        dropped: canonical ``params`` already carries those values)."""
-        from byol_tpu.optim.factory import (extract_sgdm_state,
-                                            replace_sgdm_state)
-        lay = self._flat_layout
-        trace, count = extract_sgdm_state(state.opt_state)
-        return state.replace(
-            opt_state=replace_sgdm_state(
-                state.opt_state, flat_state.unpack_tree(trace, lay), count),
-            target_params=flat_state.unpack_tree(state.target_params, lay),
-            flat_shadow=None)
-
     def from_canonical(self, state: Any) -> Any:
         """Canonical (restored) layout -> plan layout, placed on the mesh."""
-        if not (self.zero1 or self.flat_resident):
+        if not self.zero1:
             return state
         self._require_prepared("from_canonical()")
         return self._run_codec(
             "from_canonical", state,
-            self._pack_resident if self.flat_resident
-            else lambda s: self._convert(s, self._flat_templates,
-                                         self.num_shards),
+            lambda s: self._convert(s, self._flat_templates,
+                                    self.num_shards),
             self.state_sharding)
 
     def canonical_template(self, state: Any) -> Any:
@@ -347,7 +271,7 @@ class CompilePlan:
         from the canonical templates, everything placed replicated.  Pure
         metadata — the stored templates already carry the canonical shapes,
         so no concrete flat->canonical conversion of the live state runs."""
-        if not (self.zero1 or self.flat_resident):
+        if not self.zero1:
             return state
         self._require_prepared("canonical_template()")
         rep = self.replicated
@@ -357,11 +281,6 @@ class CompilePlan:
                                         sharding=rep)
         canon = state.replace(
             **{f: self._canon_templates[f] for f in ZERO1_STATE_FIELDS})
-        if self.flat_resident:
-            # the live opt_state holds the resident buffer in TraceState;
-            # restore targets the canonical shaped chain, shadow excluded
-            # (checkpoints are layout-agnostic: None fields have no leaves)
-            canon = canon.replace(flat_shadow=None)
         return jax.tree_util.tree_map(abstract, canon)
 
     # -- provenance --------------------------------------------------------
@@ -376,37 +295,23 @@ class CompilePlan:
             "axis_names": [str(a) for a in self.mesh.axis_names],
             "zero1": "on" if self.zero1 else "off",
             "donate_argnums": {k: list(v) for k, v in DONATE.items()},
-            "flat_resident": "on" if self.flat_resident else "off",
-            "flat_bucket_mb": int(self.bucket_mb),
         }
 
 
-def build_plan(mesh: Mesh, *, zero1: bool = False,
-               flat_resident: bool = False,
-               bucket_mb: int = flat_state.DEFAULT_BUCKET_MB) -> CompilePlan:
-    """The one constructor: cfg.device.zero1 == 'on' -> a ZeRO-1 plan,
-    cfg.device.flat_resident == 'on' -> resident flat update-state buffers.
+def build_plan(mesh: Mesh, *, zero1: bool = False) -> CompilePlan:
+    """The one constructor: cfg.device.zero1 == 'on' -> a ZeRO-1 plan.
 
     ZeRO-1 shards over the ``data`` axis only; combining it with tensor
     parallelism would need TP-aware flat layouts (the opt-state leaves of
     a TP-sharded kernel live sharded over ``model`` already) — rejected at
-    config resolve(), re-checked here for programmatic callers.  The
-    resident layout inherits the same restriction (its buffers are laid
-    out by the same data-axis segment maps).
+    config resolve(), re-checked here for programmatic callers.
     """
     if zero1 and mesh.shape.get("model", 1) > 1:
         raise ValueError(
             "zero1='on' is data-parallel weight-update sharding; it does "
             "not compose with model_parallel > 1 (the TP rules in "
             "partitioning.py already shard those opt-state leaves)")
-    if flat_resident and mesh.shape.get("model", 1) > 1:
-        raise ValueError(
-            "flat_resident='on' lays the update state out over the data "
-            "axis; it does not compose with model_parallel > 1")
-    if bucket_mb < 1:
-        raise ValueError(f"bucket_mb must be >= 1, got {bucket_mb}")
-    return CompilePlan(mesh=mesh, zero1=zero1, flat_resident=flat_resident,
-                       bucket_mb=bucket_mb)
+    return CompilePlan(mesh=mesh, zero1=zero1)
 
 
 def jit_encoder_extractor(fn: Callable):

@@ -86,17 +86,6 @@ def flat_struct(template: Any, n: int) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct((padded_size(size, n),), template.dtype)
 
 
-def local_flat_size(template: Any, n: int) -> int:
-    """Per-shard length of a leaf's flat-padded form under ``n`` shards —
-    the shard-local SEGMENT size the fused update kernel
-    (ops/fused_update.py) lays its flat buffer out with.  Exact by the
-    padding invariant: every shard holds the same contiguous element
-    count, and the global zero-pad tail (which lives entirely inside the
-    last shard) is inert under every norm and every elementwise update
-    step."""
-    return flat_struct(template, n).shape[0] // n
-
-
 def flatten_tree(tree: Any, n: int) -> Any:
     return jax.tree_util.tree_map(lambda x: flatten_leaf(x, n), tree)
 
@@ -181,11 +170,7 @@ class Zero1Context:
         just-in-time for a forward pass (params, EMA target).
 
         One small all-gather PER LEAF (~leaf-count latency-bound
-        collectives per tree).  ``--flat-resident on`` replaces this with
-        the bucketed gather over ONE resident buffer —
-        :meth:`byol_tpu.parallel.flat_state.FlatResidentContext.
-        gather_tree`, a handful of <= bucket_mb MiB all-gathers with the
-        leaves carved out by slice+reshape."""
+        collectives per tree; bucketing them is ROADMAP S8)."""
         rep = self._replicated()
         return jax.tree_util.tree_map(
             lambda f, t: unflatten_leaf(

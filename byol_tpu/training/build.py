@@ -145,8 +145,6 @@ def step_config(rcfg: ResolvedConfig) -> StepConfig:
         accum_steps=cfg.optim.accum_steps,
         accum_bn_mode=cfg.optim.accum_bn_mode,
         normalize_inputs=cfg.parity.normalize_inputs,
-        clip=cfg.optim.clip,
-        fused_update=cfg.optim.fused_update == "on",
         augment_in_step=cfg.task.augment_placement == "step",
         fused_augment=cfg.task.fused_augment == "on",
         image_size=rcfg.input_shape[0],
@@ -154,8 +152,7 @@ def step_config(rcfg: ResolvedConfig) -> StepConfig:
         aug_seed=cfg.device.seed,
         telemetry=cfg.device.telemetry,
         weight_decay=cfg.regularizer.weight_decay,
-        lars_in_chain=is_lars_optimizer(cfg.optim.optimizer),
-        flat_resident=cfg.device.flat_resident == "on")
+        lars_in_chain=is_lars_optimizer(cfg.optim.optimizer))
 
 
 def _validate_remat_tags(net, rcfg: ResolvedConfig, variables,
@@ -206,9 +203,7 @@ def setup_training(rcfg: ResolvedConfig, mesh: Mesh, rng: jax.Array,
     scfg = step_config(rcfg)
     from byol_tpu.parallel.compile_plan import build_plan
     if plan is None:
-        plan = build_plan(mesh, zero1=cfg.device.zero1 == "on",
-                          flat_resident=cfg.device.flat_resident == "on",
-                          bucket_mb=cfg.device.flat_bucket_mb)
+        plan = build_plan(mesh, zero1=cfg.device.zero1 == "on")
 
     from byol_tpu.core.rng import split_named
     keys = split_named(rng, ("params", "weight_init"))
@@ -229,22 +224,15 @@ def setup_training(rcfg: ResolvedConfig, mesh: Mesh, rng: jax.Array,
         # here; the default ndim-derived mask stays for the replicated
         # layout (identical semantics, and bit-identical jit cache keys).
         adapt_mask = None
-        from byol_tpu.optim import lars as lars_lib
-        shaped_mask = lars_lib.default_exclusion_mask(variables["params"])
         if plan.zero1:
-            if lars_lib.has_expert_axis(shaped_mask):
+            from byol_tpu.optim import lars as lars_lib
+            adapt_mask = lars_lib.default_exclusion_mask(
+                variables["params"])
+            if lars_lib.has_expert_axis(adapt_mask):
                 raise ValueError(
                     "--zero1 on flattens every leaf, and LARS adapts each "
                     "expert of a stacked expert kernel alone: it needs the "
                     "expert axis (run such a tree with --zero1 off)")
-            adapt_mask = shaped_mask
-        if cfg.optim.fused_update == "on":
-            from byol_tpu.optim.factory import \
-                fused_update_unsupported_reason
-            reason = fused_update_unsupported_reason(
-                cfg.optim.optimizer, cfg.optim.clip, shaped_mask)
-            if reason is not None:
-                raise ValueError(f"--fused-update on: {reason}")
         tx, schedule = build_tx(rcfg, adapt_mask=adapt_mask)
         state = create_train_state(
             # under ZeRO-1 the plan inits the optimizer state on the FLAT
@@ -258,19 +246,14 @@ def setup_training(rcfg: ResolvedConfig, mesh: Mesh, rng: jax.Array,
     # momentum/EMA), places it, and owns the jit wiring of both steps.
     state, state_sh = plan.prepare_state(state, tx)
     z1 = plan.zero1_context()
-    fctx = plan.flat_context()
 
-    # lr_schedule + mesh feed ONLY the fused-kernel paths (fused_update
-    # needs the bare lr value; both fused kernels need a mesh for their
-    # shard_maps); with both fused flags off they are inert and the traced
-    # graph is unchanged.
+    # mesh feeds only the fused augmentation's shard_map; without
+    # --fused-augment it is inert and the traced graph is unchanged.
     train_step = plan.jit_train_step(
-        make_train_step(net, tx, scfg, policy, zero1_ctx=z1,
-                        lr_schedule=schedule, mesh=mesh, flat_ctx=fctx),
+        make_train_step(net, tx, scfg, policy, zero1_ctx=z1, mesh=mesh),
         state_sh)
     eval_step = plan.jit_eval_step(
-        make_eval_step(net, scfg, policy, zero1_ctx=z1, flat_ctx=fctx),
-        state_sh)
+        make_eval_step(net, scfg, policy, zero1_ctx=z1), state_sh)
 
     def _with_mesh(fn):
         # keep the mesh in thread-local scope at call (=trace) time so

@@ -34,10 +34,6 @@ class TrainState:
     ema_step: jnp.ndarray                # persisted tau-schedule counter (Q6 fix)
     opt_state: Any
     polyak_params: Optional[Any] = None  # --polyak-ema tree (main.py:76,625-626)
-    # --flat-resident + --zero1: the sharded resident param shadow, one 1-D
-    # fp32 buffer laid out by parallel/flat_state.py (None otherwise; None
-    # fields contribute no leaves, so checkpoints stay layout-agnostic).
-    flat_shadow: Optional[Any] = None
 
 
 def create_train_state(variables: Any,

@@ -164,35 +164,12 @@ class StepConfig:
                                          # metrics['health'].  'off' traces
                                          # the exact pre-telemetry graph
                                          # (pinned by an HLO-identity test).
-    weight_decay: float = 0.0            # telemetry + fused update: LARS
-                                         # folds wd into the gradient
-                                         # BEFORE the trust ratio
-                                         # (optim/lars.py step 1), so the
-                                         # health vector's trust stats
-                                         # must see g + wd*p too or they
-                                         # drift from what was applied;
-                                         # the fused kernel folds the same
-                                         # wd in its norm + apply passes
-    clip: float = 0.0                    # fused-update gating only: the
-                                         # --clip value the optimizer
-                                         # chain was built with.  The
-                                         # fused kernel does not replicate
-                                         # value clipping, so clip > 0
-                                         # with fused_update=True is
-                                         # rejected at build — config
-                                         # resolve() catches the CLI, this
-                                         # field catches programmatic
-                                         # callers handing a clip-bearing
-                                         # tx to make_train_step
-    fused_update: bool = False           # --fused-update on: replace the
-                                         # optax chain + EMA tick with the
-                                         # fused Pallas kernel
-                                         # (ops/fused_update.py) — one pass
-                                         # over the flat parameter buffer,
-                                         # shard-local under ZeRO-1.  False
-                                         # traces the exact unfused graph
-                                         # (HLO identity pinned by
-                                         # tests/test_fused_update.py)
+    weight_decay: float = 0.0            # telemetry only: LARS folds wd
+                                         # into the gradient BEFORE the
+                                         # trust ratio (optim/lars.py step
+                                         # 1), so the health vector's trust
+                                         # stats must see g + wd*p too or
+                                         # they drift from what was applied
     lars_in_chain: bool = True           # telemetry only: the optimizer
                                          # chain contains the LARS wrapper
                                          # (build.py: 'lars_' prefix).
@@ -202,17 +179,6 @@ class StepConfig:
                                          # one as "applied" would be
                                          # fiction (LAMB's internal ratio
                                          # is not surfaced here)
-    flat_resident: bool = False          # --flat-resident on: momentum /
-                                         # EMA target / (zero1) the param
-                                         # shadow live as resident flat
-                                         # buffers (parallel/flat_state
-                                         # .py); the step consumes and
-                                         # produces them in place and the
-                                         # gathers run bucketed.  Requires
-                                         # fused_update and a flat_ctx.
-                                         # False traces the exact transient
-                                         # graph (HLO identity pinned by
-                                         # tests/test_flat_state.py)
 
 
 def _forward_views(net, params, batch_stats, aug1, aug2, *, train: bool,
@@ -284,9 +250,113 @@ def augment_keys(seed: int, step, k: int) -> jnp.ndarray:
         jnp.arange(k, dtype=jnp.uint32))
 
 
+def apply_update(state: TrainState, grads, new_bs, metrics, *,
+                 tx: optax.GradientTransformation, scfg: StepConfig,
+                 zero1_ctx=None, layer_scopes: Tuple[str, ...] = ()
+                 ) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
+    """Everything of the step after the gradients exist — the optimizer
+    chain, the EMA tick, the telemetry vector, the new state — traced by
+    :func:`make_train_step` under the ``update`` scope.
+
+    One path for both layouts.  Replicated (``zero1_ctx`` None) the update
+    runs on the shaped trees.  Under ZeRO-1 (arXiv 2004.13336)
+    ``state.opt_state`` and ``state.target_params`` arrive flat, leaf-
+    partitioned over the data axis: the reduced gradient and the params
+    scatter to their flat 1/N shards (free: both are replicated, each chip
+    keeps a slice), the optax chain runs shard-local — LARS norms are
+    unchanged by the zero padding — the EMA ticks on the shards (it is
+    elementwise; the target STAYS sharded and is re-gathered at the top of
+    the next step), and ONE all-gather rebuilds the fresh params for the
+    next forward.
+    """
+    if zero1_ctx is None:
+        to_update_layout = from_update_layout = lambda tree: tree
+    else:
+        to_update_layout = zero1_ctx.shard
+        from_update_layout = functools.partial(
+            zero1_ctx.gather, template=zero1_ctx.param_template)
+    old_params = to_update_layout(state.params)
+    updates, new_opt_state = tx.update(to_update_layout(grads),
+                                       state.opt_state, old_params)
+    fresh_params = optax.apply_updates(old_params, updates)
+    new_params = from_update_layout(fresh_params)
+
+    # Cosine-annealed EMA of the full tree (main.py:156-162,255).
+    tau = cosine_ema_decay(state.ema_step, scfg.total_train_steps,
+                           scfg.base_decay)
+    ema_src = (old_params if scfg.ema_update_mode == "reference_pre"
+               else fresh_params)
+    new_target = jax.tree_util.tree_map(
+        lambda t, p: tau * t + (1.0 - tau) * p,
+        state.target_params, ema_src)
+
+    new_polyak = state.polyak_params
+    if scfg.polyak_ema > 0.0 and state.polyak_params is not None:
+        d = scfg.polyak_ema
+        new_polyak = jax.tree_util.tree_map(
+            lambda m, p: d * m + (1.0 - d) * p,
+            state.polyak_params, new_params)
+
+    if scfg.telemetry != "off":
+        # Pack the step's health diagnostics (observability/health.py)
+        # into ONE fp32 vector under metrics['health'] — a step OUTPUT
+        # (replicated out_sharding like every metric), read back
+        # asynchronously by the TelemetrySink with >= interval-step
+        # lag, so telemetry adds reductions to the graph but zero host
+        # syncs to the dispatch loop.  Trust ratios use the PRE-update
+        # params — what the LARS transform saw this step.
+        metrics = dict(metrics)
+        collapse = (metrics.pop("_collapse_feature_std"),
+                    metrics.pop("_collapse_cosine_mean"))
+        # The ratio LARS APPLIES is computed on the post-wd gradient:
+        # run the SAME fold-in transform the optimizer chain runs
+        # (lars_weight_decay — shared code, so the reported spread
+        # can never drift from the applied one).  Non-LARS chains
+        # applied no ratio: pack identity rather than a fictitious
+        # "applied" value.  Residual caveat: --clip > 0 clips before
+        # LARS and is not replicated (value clipping is off in every
+        # recipe this telemetry targets).
+        if scfg.lars_in_chain:
+            wd_tx = lars_lib.lars_weight_decay(scfg.weight_decay)
+            trust_grads, _ = wd_tx.update(
+                grads, wd_tx.init(state.params), state.params)
+            trust = lars_lib.trust_ratio_vector(trust_grads, state.params)
+        else:
+            trust = jnp.ones((1,), jnp.float32)
+        # The drift subtraction needs the params in the target's layout
+        # (flat shards under ZeRO-1); zero padding contributes nothing to
+        # any norm, so every reported value equals the replicated step's.
+        metrics["health"] = health_lib.health_stats(
+            grads=grads, updates=updates, params=fresh_params,
+            target_params=new_target, loss=metrics["loss_mean"],
+            collapse=collapse, trust_ratios=trust,
+            routing={f"moe_{name}": metrics[f"_moe_{name}"]
+                     for name in ROUTING_FIELDS
+                     if f"_moe_{name}" in metrics})
+
+    # One real attribute on one scalar add.  The persistent compilation
+    # cache keys a program with its debug info stripped, scope names
+    # included, so a step whose scopes alone were renamed would be
+    # served the executable cached before the rename, stale names and
+    # all — and the device trace is read by those names.
+    with set_xla_metadata(
+            phase_scopes=" ".join(PHASE_SCOPES + tuple(layer_scopes))):
+        next_step = state.step + 1
+    new_state = state.replace(
+        step=next_step,
+        params=new_params,
+        batch_stats=new_bs,
+        target_params=new_target,
+        ema_step=state.ema_step + 1,
+        opt_state=new_opt_state,
+        polyak_params=new_polyak,
+    )
+    return new_state, metrics
+
+
 def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
                     policy: Policy = FP32, zero1_ctx=None,
-                    lr_schedule=None, mesh=None, flat_ctx=None
+                    lr_schedule=None, mesh=None
                     ) -> Callable[[TrainState, Dict[str, jnp.ndarray]],
                                   Tuple[TrainState, Dict[str, jnp.ndarray]]]:
     """Build the jittable train step: (state, batch) -> (state, metrics).
@@ -307,32 +377,6 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
     ``None`` traces the replicated graph unchanged (``--zero1 off`` HLO
     identity, tests/test_zero1.py).
 
-    ``scfg.fused_update`` replaces the whole tail of the step — the optax
-    chain, ``apply_updates``, and the EMA tick (~3 full-parameter
-    elementwise HBM sweeps) — with the fused Pallas kernel
-    (ops/fused_update.py): a flat segment-norm pass feeding one fused
-    apply pass, shard-local on the ZeRO-1 layout when ``zero1_ctx`` is
-    set.  It reads/ticks the SAME opt_state pytree (momentum trace +
-    schedule count, located by node type in optim/factory.py), so
-    checkpoints, shardings, and telemetry are layout-identical either
-    way.  Requires ``lr_schedule`` (the schedule ``tx`` closes over — the
-    kernel needs the bare lr value) and, on a multi-device mesh,
-    ``mesh`` (the kernel runs under shard_map; GSPMD cannot partition a
-    pallas_call).  False leaves the traced graph byte-identical to the
-    pre-fused-update step.
-
-    ``flat_ctx`` (parallel.flat_state.FlatResidentContext, from the compile
-    plan): ``--flat-resident on``.  The LARS momentum, the EMA target, and
-    (with ``zero1_ctx``) the param shadow arrive as RESIDENT flat fp32
-    buffers packed once at setup; the step reshapes them straight into the
-    fused kernel (no per-step pack/unpack — only fresh gradients still
-    pack), writes them back shape- and sharding-identical (the jit state
-    donation aliases them step over step), and every target/param gather
-    runs BUCKETED (one all-gather per <= bucket_mb MiB contiguous bucket
-    instead of one per leaf).  ``None`` traces the transient graph
-    unchanged (``--flat-resident off`` HLO identity,
-    tests/test_flat_state.py).
-
     ``scfg.fused_augment`` swaps the in-step two-view augmentation
     (``augment_in_step``) for the fused Pallas kernel
     (ops/fused_augment.py) inside the same accumulation scan — identical
@@ -340,7 +384,11 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
     to fp32 tolerance, shard-local over ``mesh``'s data axis when it
     spans several devices.  False traces the unfused augmentation graph
     byte-identically.
+
+    ``lr_schedule`` is accepted and unused: the schedule lives in ``tx``.
+    ``benchmarks/rehearse_v5e*.py`` still pass it; it goes once they stop.
     """
+    del lr_schedule
     if scfg.accum_steps < 1:
         raise ValueError(f"accum_steps must be >= 1, got {scfg.accum_steps}")
     if scfg.accum_bn_mode not in ("average", "microbatch", "global"):
@@ -355,40 +403,6 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
         raise ValueError(
             f"unknown telemetry mode {scfg.telemetry!r}; "
             "'off' | 'epoch' | 'step'")
-    if scfg.fused_update:
-        # config resolve() rejects unsupported optimizer configs at the
-        # CLI; re-checked here for programmatic callers, plus the builder
-        # input the fused path cannot run without
-        if not scfg.lars_in_chain:
-            raise ValueError(
-                "fused_update=True with lars_in_chain=False: the fused "
-                "kernel implements the lars_momentum chain (see "
-                "optim.factory.fused_update_unsupported_reason)")
-        if scfg.clip > 0.0:
-            raise ValueError(
-                "fused_update=True with clip > 0: the optimizer chain "
-                "value-clips gradients before LARS and the fused kernel "
-                "does not replicate the clip — the two paths would "
-                "silently apply different updates")
-        if lr_schedule is None:
-            raise ValueError(
-                "fused_update=True requires lr_schedule (the schedule tx "
-                "closes over; the fused kernel needs the bare lr value)")
-    if scfg.flat_resident:
-        if not scfg.fused_update:
-            raise ValueError(
-                "flat_resident=True requires fused_update=True: the "
-                "resident buffers are laid out for (and consumed by) the "
-                "fused kernel — the optax chain has no flat entry point")
-        if flat_ctx is None:
-            raise ValueError(
-                "flat_resident=True requires flat_ctx (the compile plan's "
-                "FlatResidentContext — build the plan with "
-                "flat_resident=True)")
-    elif flat_ctx is not None:
-        raise ValueError(
-            "flat_ctx passed but scfg.flat_resident is False: the plan "
-            "and the step config disagree about the state layout")
     if scfg.fused_augment:
         # config resolve() rejects these at the CLI; re-checked for
         # programmatic callers handing a StepConfig straight to the builder
@@ -403,6 +417,13 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
                 "global oracle vmaps microbatches, and a pallas_call/"
                 "shard_map cannot run under that vmap — use 'average' or "
                 "'microbatch'")
+
+    # A backbone that names scopes of its own inside the phases (the decoder
+    # trunk's ``mla``, ``moe/...``, ``mhc``) has them stamped beside the
+    # phases' (apply_update); one that names none keeps the stamp, and its
+    # program, as it was.
+    layer_scopes = tuple(getattr(getattr(net, "backbone", None),
+                                 "trace_scopes", ()))
 
     def micro_grads(params, target_params, batch_stats, view1, view2,
                     labels):
@@ -564,21 +585,12 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
     def train_step(state: TrainState, batch):
         labels = batch["label"]
         k = scfg.accum_steps
-        if flat_ctx is not None:
-            # Resident layout: the EMA target is ONE flat buffer (sharded
-            # under zero1, replicated otherwise); rebuild the shaped tree
-            # just-in-time with the bucketed gather — a handful of
-            # coalesced all-gathers instead of one per leaf (and with one
-            # shard, a pure carve with no collective at all).  Scoped as
-            # ``update``: it is the update's layout cost, paid early.
-            with _phase("update"):
-                micro_state = state.replace(
-                    target_params=flat_ctx.gather_tree(state.target_params))
-        elif zero1_ctx is not None:
+        if zero1_ctx is not None:
             # ZeRO-1: the EMA target arrives flat-sharded; gather it
             # just-in-time for the target forwards.  The microbatch paths
             # read the target off the state they are handed, so hand them
-            # a view with the gathered tree in place.
+            # a view with the gathered tree in place.  Scoped as
+            # ``update``: it is the update's layout cost, paid early.
             with _phase("update"):
                 micro_state = state.replace(
                     target_params=zero1_ctx.gather(
@@ -606,237 +618,22 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
                           else accumulate_scan)
             grads, new_bs, metrics = accumulate(micro_state, xs)
         with _phase("update"):
-            return apply_update(state, grads, new_bs, metrics)
-
-    def apply_update(state: TrainState, grads, new_bs, metrics):
-        """Everything of the step after the gradients exist, traced under
-        the ``update`` scope: the optimizer (optax chain or a fused kernel
-        entry), the EMA tick, the telemetry vector, the new state."""
-        if scfg.fused_update:
-            # Fused LARS+EMA update (ops/fused_update.py): trust ratios
-            # from the kernel's segment-norm pass, then wd fold-in +
-            # trust scaling + momentum tick + param write + EMA tick in
-            # ONE pass over the flat buffer — replacing the optax chain,
-            # apply_updates, AND the EMA tree_map below (~3 elementwise
-            # HBM sweeps -> ~1).  The momentum trace and schedule count
-            # are read from / written back into the SAME opt_state pytree
-            # the unfused chain uses (optim/factory.py locates them by
-            # node type), so checkpoints and shardings are identical.
-            from byol_tpu.optim import factory as factory_lib
-            from byol_tpu.ops import fused_update as fused_lib
-            trace, count = factory_lib.extract_sgdm_state(state.opt_state)
-            fused_lr = lr_schedule(count)
-            tau = cosine_ema_decay(state.ema_step, scfg.total_train_steps,
-                                   scfg.base_decay)
-            ema_pre = scfg.ema_update_mode == "reference_pre"
-            if flat_ctx is not None and zero1_ctx is None:
-                # resident replicated: momentum + target stay flat buffers
-                # end to end; params/grads (shaped forward inputs / fresh
-                # autodiff outputs) pack inside the kernel entry — the one
-                # remaining per-step pack.  new_shadow is the kernel's own
-                # packed view of the fresh params, kept for telemetry.
-                new_params, new_shadow, new_trace, new_target, \
-                    fused_trust = fused_lib.fused_lars_ema_update_resident(
-                        state.params, grads, trace, state.target_params,
-                        layout=flat_ctx.layout, lr=fused_lr, tau=tau,
-                        weight_decay=scfg.weight_decay,
-                        momentum_decay=factory_lib.MOMENTUM_DECAY,
-                        ema_pre=ema_pre, mesh=mesh)
-            elif flat_ctx is not None:
-                # resident ZeRO-1: the param shadow, momentum, and target
-                # are all resident sharded buffers — each chip reshapes
-                # its chunk straight into the kernel (zero pack/unpack);
-                # only the fresh gradients scatter+pack, and the fresh
-                # params come back via the bucketed gather.
-                flat_grads = zero1_ctx.shard(grads)
-                new_shadow, new_trace, new_target, fused_trust = \
-                    fused_lib.fused_lars_ema_update_resident_zero1(
-                        state.flat_shadow, flat_grads, trace,
-                        state.target_params, layout=flat_ctx.layout,
-                        mesh=zero1_ctx.mesh, lr=fused_lr, tau=tau,
-                        weight_decay=scfg.weight_decay,
-                        momentum_decay=factory_lib.MOMENTUM_DECAY,
-                        ema_pre=ema_pre)
-                new_params = flat_ctx.gather_tree(new_shadow)
-            elif zero1_ctx is None:
-                new_params, new_trace, new_target, fused_trust = \
-                    fused_lib.fused_lars_ema_update(
-                        state.params, grads, trace, state.target_params,
-                        lr=fused_lr, tau=tau,
-                        weight_decay=scfg.weight_decay,
-                        momentum_decay=factory_lib.MOMENTUM_DECAY,
-                        ema_pre=ema_pre, mesh=mesh)
-            else:
-                # shard-local kernel on the ZeRO-1 flat layout: each chip
-                # updates its 1/N of the buffer, segment norms psum over
-                # the data axis, and the one just-in-time all-gather of
-                # fresh params below is unchanged from the unfused path
-                flat_params = zero1_ctx.shard(state.params)
-                flat_grads = zero1_ctx.shard(grads)
-                new_params_flat, new_trace, new_target, fused_trust = \
-                    fused_lib.fused_lars_ema_update_zero1(
-                        flat_params, flat_grads, trace,
-                        state.target_params,
-                        param_template=zero1_ctx.param_template,
-                        mesh=zero1_ctx.mesh,
-                        num_shards=zero1_ctx.num_shards,
-                        lr=fused_lr, tau=tau,
-                        weight_decay=scfg.weight_decay,
-                        momentum_decay=factory_lib.MOMENTUM_DECAY,
-                        ema_pre=ema_pre)
-                new_params = zero1_ctx.gather(new_params_flat,
-                                              zero1_ctx.param_template)
-            new_opt_state = factory_lib.replace_sgdm_state(
-                state.opt_state, new_trace,
-                optax.safe_int32_increment(count))
-        else:
-            if zero1_ctx is None:
-                updates, new_opt_state = tx.update(grads, state.opt_state,
-                                                   state.params)
-                new_params = optax.apply_updates(state.params, updates)
-            else:
-                # Per-shard weight update (arXiv 2004.13336): the reduced
-                # gradient and the params scatter to their flat 1/N shards
-                # (free: both are replicated, each chip keeps a slice), the
-                # optax chain runs shard-local — LARS norms are unchanged by
-                # the zero padding — and ONE all-gather rebuilds the fresh
-                # params just-in-time for the next forward.
-                flat_params = zero1_ctx.shard(state.params)
-                flat_grads = zero1_ctx.shard(grads)
-                updates, new_opt_state = tx.update(flat_grads,
-                                                   state.opt_state,
-                                                   flat_params)
-                new_params_flat = optax.apply_updates(flat_params, updates)
-                new_params = zero1_ctx.gather(new_params_flat,
-                                              zero1_ctx.param_template)
-
-            # Cosine-annealed EMA of the full tree (main.py:156-162,255).
-            tau = cosine_ema_decay(state.ema_step, scfg.total_train_steps,
-                                   scfg.base_decay)
-            if zero1_ctx is None:
-                ema_src = (state.params
-                           if scfg.ema_update_mode == "reference_pre"
-                           else new_params)
-            else:
-                # the tick is elementwise, so it runs on the flat shards
-                # and the target STAYS sharded — it is re-gathered at the
-                # top of the next step, just-in-time for the target
-                # forward
-                ema_src = (flat_params
-                           if scfg.ema_update_mode == "reference_pre"
-                           else new_params_flat)
-            new_target = jax.tree_util.tree_map(
-                lambda t, p: tau * t + (1.0 - tau) * p,
-                state.target_params, ema_src)
-
-        new_polyak = state.polyak_params
-        if scfg.polyak_ema > 0.0 and state.polyak_params is not None:
-            d = scfg.polyak_ema
-            new_polyak = jax.tree_util.tree_map(
-                lambda m, p: d * m + (1.0 - d) * p,
-                state.polyak_params, new_params)
-
-        if scfg.telemetry != "off":
-            # Pack the step's health diagnostics (observability/health.py)
-            # into ONE fp32 vector under metrics['health'] — a step OUTPUT
-            # (replicated out_sharding like every metric), read back
-            # asynchronously by the TelemetrySink with >= interval-step
-            # lag, so telemetry adds reductions to the graph but zero host
-            # syncs to the dispatch loop.  Trust ratios use the PRE-update
-            # params — what the LARS transform saw this step.
-            metrics = dict(metrics)
-            collapse = (metrics.pop("_collapse_feature_std"),
-                        metrics.pop("_collapse_cosine_mean"))
-            # The ratio LARS APPLIES is computed on the post-wd gradient:
-            # run the SAME fold-in transform the optimizer chain runs
-            # (lars_weight_decay — shared code, so the reported spread
-            # can never drift from the applied one).  Non-LARS chains
-            # applied no ratio: pack identity rather than a fictitious
-            # "applied" value.  Residual caveat: --clip > 0 clips before
-            # LARS and is not replicated (value clipping is off in every
-            # recipe this telemetry targets).
-            if scfg.fused_update:
-                # the kernel's OWN segment norms produced these ratios —
-                # reported == applied by construction, no recompute (and
-                # no second set of norm reductions in the graph).  The
-                # update the kernel wrote is -lr * m_new; rebuilding it
-                # from the fresh trace costs one telemetry-only sweep,
-                # exactly like the unfused trust recompute above.
-                trust = fused_trust
-                updates = jax.tree_util.tree_map(
-                    lambda m: -fused_lr * m, new_trace)
-            elif scfg.lars_in_chain:
-                wd_tx = lars_lib.lars_weight_decay(scfg.weight_decay)
-                trust_grads, _ = wd_tx.update(
-                    grads, wd_tx.init(state.params), state.params)
-                trust = lars_lib.trust_ratio_vector(trust_grads,
-                                                    state.params)
-            else:
-                trust = jnp.ones((1,), jnp.float32)
-            # Under ZeRO-1 the target tree is flat-sharded, so the drift
-            # subtraction needs the params in the SAME layout; zero
-            # padding contributes nothing to any norm, so every reported
-            # value is identical to the replicated step's.  Under the
-            # resident layout the target is ONE flat buffer, so the health
-            # vector reads the kernel's own packed params buffer — the
-            # resident layout's segment norms, no shaped recompute.
-            if flat_ctx is not None:
-                health_params = new_shadow
-            else:
-                health_params = (new_params if zero1_ctx is None
-                                 else new_params_flat)
-            metrics["health"] = health_lib.health_stats(
-                grads=grads, updates=updates, params=health_params,
-                target_params=new_target, loss=metrics["loss_mean"],
-                collapse=collapse, trust_ratios=trust,
-                routing={f"moe_{name}": metrics[f"_moe_{name}"]
-                         for name in ROUTING_FIELDS
-                         if f"_moe_{name}" in metrics})
-
-        # One real attribute on one scalar add.  The persistent compilation
-        # cache keys a program with its debug info stripped, scope names
-        # included, so a step whose scopes alone were renamed would be
-        # served the executable cached before the rename, stale names and
-        # all — and the device trace is read by those names.
-        # A backbone that names scopes of its own inside the phases (the
-        # decoder trunk's ``mla``, ``moe/...``, ``mhc``) has them stamped
-        # too; one that names none keeps the stamp, and its program, as it
-        # was.
-        layer_scopes = tuple(getattr(getattr(net, "backbone", None),
-                                     "trace_scopes", ()))
-        with set_xla_metadata(
-                phase_scopes=" ".join(PHASE_SCOPES + layer_scopes)):
-            next_step = state.step + 1
-        new_state = state.replace(
-            step=next_step,
-            params=new_params,
-            batch_stats=new_bs,
-            target_params=new_target,
-            ema_step=state.ema_step + 1,
-            opt_state=new_opt_state,
-            polyak_params=new_polyak,
-        )
-        if flat_ctx is not None and zero1_ctx is not None:
-            # the fresh shadow buffer rides the state (same shape, same
-            # sharding as the one donated in) — next step reshapes it
-            # straight into the kernel again
-            new_state = new_state.replace(flat_shadow=new_shadow)
-        return new_state, metrics
+            return apply_update(state, grads, new_bs, metrics, tx=tx,
+                                scfg=scfg, zero1_ctx=zero1_ctx,
+                                layer_scopes=layer_scopes)
 
     return train_step
 
 
 def make_eval_step(net, scfg: StepConfig, policy: Policy = FP32,
-                   zero1_ctx=None, flat_ctx=None):
+                   zero1_ctx=None):
     """Eval step per reference semantics (main.py:574-606, §3.3): full BYOL
     loss computed in eval too; probe sees only view-1 representations with
     un-doubled labels (main.py:250-251); EMA frozen; BN uses running stats;
     Polyak params used for prediction when enabled (main.py:585-587).
 
     ``zero1_ctx``: as in :func:`make_train_step` — the flat-sharded EMA
-    target is all-gathered just-in-time for the target forward.
-    ``flat_ctx``: the resident layout's bucketed gather takes over that
-    rebuild (eval and linear-eval share the train step's coalescing)."""
+    target is all-gathered just-in-time for the target forward."""
 
     def eval_step(state: TrainState, batch):
         aug1 = policy.cast_to_compute(batch["view1"])
@@ -855,9 +652,7 @@ def make_eval_step(net, scfg: StepConfig, policy: Policy = FP32,
             params = state.polyak_params
 
         target_params = state.target_params
-        if flat_ctx is not None:
-            target_params = flat_ctx.gather_tree(target_params)
-        elif zero1_ctx is not None:
+        if zero1_ctx is not None:
             target_params = zero1_ctx.gather(target_params,
                                              zero1_ctx.param_template)
 

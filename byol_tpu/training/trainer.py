@@ -137,9 +137,7 @@ def fit(cfg: Config, *, loader: Optional[LoaderBundle] = None,
     # checkpoint codec (ZeRO-1 state is canonicalized at the save/restore
     # boundary so checkpoints stay mesh-size portable).
     from byol_tpu.parallel.compile_plan import build_plan
-    plan = build_plan(mesh, zero1=cfg.device.zero1 == "on",
-                      flat_resident=cfg.device.flat_resident == "on",
-                      bucket_mb=cfg.device.flat_bucket_mb)
+    plan = build_plan(mesh, zero1=cfg.device.zero1 == "on")
 
     # Flight recorder (observability/spans.py): every hot-loop phase below
     # runs under a named span; goodput.py folds them into the wall-time

@@ -28,10 +28,10 @@ What runs (one chip), through the entry points a user calls:
 3. ``--phase probe`` (this file, in a child): step time ending in
    ``block_until_ready`` and ending in a scalar readback, peak device
    memory, served-vs-offline embedding difference, and the kernel phases —
-   ``--fused-update on``, ``--flat-resident on``, ``--fused-augment on``
-   (raw uint8 256->224), ViT-B/16 ``--attn-impl flash`` through the serve
-   builder — each next to its un-fused arm on the same seed and batch, each
-   asserting ``tpu_custom_call`` in the compiled program.  No ``interpret=``
+   ``--fused-augment on`` (raw uint8 256->224), ViT-B/16 ``--attn-impl
+   flash`` through the serve builder — each next to its un-fused arm on
+   the same seed and batch, each asserting ``tpu_custom_call`` in the
+   compiled program.  No ``interpret=``
    is passed anywhere: on a TPU backend the kernels compile for the chip.
 
 PROCESS RULE.  A chip belongs to one process at a time.  The parent (this
@@ -45,7 +45,7 @@ trainer's ``run_header``, the probe's result line).
 Tolerances (stated here because the CPU parity tests run fp32 on an exact
 backend, and the chip runs bf16 convolutions): fused-vs-unfused and
 one-vs-four-device losses must agree per step within
-``LOSS_RTOL``/``LOSS_ATOL`` below — tests/test_fused_update.py (1e-5),
+``LOSS_RTOL``/``LOSS_ATOL`` below —
 tests/test_fused_augment.py (2e-4), tests/test_zero1.py and
 tests/test_train_step.py (1e-5 .. 1e-4) widened to bf16's 2^-8 relative
 rounding; flash-vs-dense and served-vs-offline embeddings within
@@ -381,10 +381,7 @@ class Arm:
         rcfg = resolve(cfg, num_train_samples=4 * s["batch"],
                        num_test_samples=s["batch"], output_size=10,
                        input_shape=(s["image"], s["image"], 3))
-        self.plan = build_plan(
-            self.mesh, zero1=cfg.device.zero1 == "on",
-            flat_resident=cfg.device.flat_resident == "on",
-            bucket_mb=cfg.device.flat_bucket_mb)
+        self.plan = build_plan(self.mesh, zero1=cfg.device.zero1 == "on")
         _, self.state, step, _, _ = setup_training(
             rcfg, self.mesh, root_key(seed), plan=self.plan)
         self._shard = lambda b: shard_batch_to_mesh(dict(b), self.mesh)
@@ -423,18 +420,17 @@ def _release(*objs) -> None:
     gc.collect()
 
 
-def _kernel_arm(s, seed, name, extra, ref_extra, *, raw, ref=None):
+def _kernel_arm(s, seed, name, extra, ref_extra, *, raw):
     """``name``: flags ``extra`` next to the un-fused arm ``ref_extra`` on
-    the same seed and batches (``ref``: that arm's losses, when it has
-    already run).  A compiler refusal is reported as ``refused`` with its
-    message — never passed, never swapped for the un-fused program."""
+    the same seed and batches.  A compiler refusal is reported as
+    ``refused`` with its message — never passed, never swapped for the
+    un-fused program."""
     batches = _host_batches(s, seed, s["steps"], raw=raw)
-    if ref is None:
-        arm = Arm(s, seed, ref_extra, batches[0])
-        say(f"{name}: un-fused arm {' '.join(ref_extra) or '(defaults)'} "
-            f"compiled in {arm.compile_seconds:.1f}s")
-        ref = arm.losses(batches)
-        _release(arm)
+    arm = Arm(s, seed, ref_extra, batches[0])
+    say(f"{name}: un-fused arm {' '.join(ref_extra) or '(defaults)'} "
+        f"compiled in {arm.compile_seconds:.1f}s")
+    ref = arm.losses(batches)
+    _release(arm)
     try:
         arm = Arm(s, seed, extra, batches[0])
     except Exception as e:            # the chip's compiler said no
@@ -495,13 +491,6 @@ def child_probe(s: dict, seed: int, rehearsal: bool, checkpoint: str) -> int:
     # ---- kernel phases, each next to its un-fused arm ------------------
     step_aug = ["--augment-placement", "step"]
     phases = (
-        ("fused-update", lambda: _kernel_arm(
-            s, seed, "fused-update", ["--fused-update", "on"], [],
-            raw=False, ref=base_losses)),
-        ("flat-resident", lambda: _kernel_arm(
-            s, seed, "flat-resident",
-            ["--fused-update", "on", "--flat-resident", "on"], [],
-            raw=False, ref=base_losses)),
         ("fused-augment", lambda: _kernel_arm(
             s, seed, "fused-augment", step_aug + ["--fused-augment", "on"],
             step_aug, raw=True)),

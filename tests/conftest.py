@@ -45,7 +45,7 @@ def guard_steps(fn):
 
 def tree_maxdiff(a, b):
     """Max abs elementwise difference over two pytrees' paired leaves (fp32
-    compare) — the parity comparator test_zero1.py and test_fused_update.py
+    compare) — the parity comparator test_zero1.py and test_checkpoint.py
     share."""
     import numpy as np
 

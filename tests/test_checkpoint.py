@@ -355,92 +355,6 @@ def test_zero1_reshard_on_restore_different_device_count(mesh8, tmp_path):
     store.close()
 
 
-def _resident_setup(mesh, *, resident="on", zero1="on", data=8):
-    """--flat-resident training on ``mesh``; reuses test_flat_state's
-    config so the tier-1 run compiles each program once."""
-    import dataclasses as _dc
-    from tests.test_flat_state import _plan_for, _rcfg
-    rcfg = _rcfg(resident=resident, zero1=zero1)
-    if data != 8:
-        rcfg = resolve(
-            rcfg.cfg.replace(device=_dc.replace(rcfg.cfg.device,
-                                                num_replicas=data)),
-            num_train_samples=64, num_test_samples=16, output_size=10,
-            input_shape=(16, 16, 3), representation_size=512)
-    plan = _plan_for(mesh, rcfg)
-    return plan, setup_training(rcfg, mesh, jax.random.PRNGKey(0),
-                                plan=plan)
-
-
-def test_resident_roundtrip_via_canonical_codec(mesh8, tmp_path):
-    """ISSUE 18 checkpoint satellite (1/2): resident flat buffers never
-    reach disk — ``to_canonical`` unpacks them to the shaped replicated
-    trees (``flat_shadow`` drops to None, contributing no leaves), and
-    ``from_canonical`` re-packs on restore.  The round trip is exact and
-    the restored state is steppable with the resident step."""
-    from tests.test_flat_state import _batch as fs_batch
-    plan, (net, state, train_step, _, _) = _resident_setup(mesh8)
-    batch = shard_batch_to_mesh(fs_batch(seed=0), mesh8)
-    state, _ = train_step(state, batch)
-
-    canon = plan.to_canonical(state)
-    assert canon.flat_shadow is None
-    # canonical view is layout-free: shaped leaves, nothing data-sharded
-    for leaf in jax.tree_util.tree_leaves(
-            (canon.opt_state, canon.target_params)):
-        assert "data" not in str(leaf.sharding.spec)
-    store = CheckpointStore(str(tmp_path / "res"))
-    store.save(0, canon)
-    restored, epoch = store.restore(plan.canonical_template(state))
-    assert epoch == 0
-    _canon_equal(canon, restored)
-
-    live = plan.from_canonical(restored)
-    assert live.flat_shadow is not None and live.flat_shadow.ndim == 1
-    _canon_equal(canon, plan.to_canonical(live))
-    live, metrics = train_step(live, batch)
-    assert np.isfinite(float(metrics["loss_mean"]))
-    assert int(live.step) == 2 and int(live.ema_step) == 2
-    store.close()
-
-
-def test_resident_ckpt_portable_across_flag_and_mesh(mesh8, tmp_path):
-    """ISSUE 18 checkpoint satellite (2/2): because checkpoints store the
-    canonical layout, a ckpt written under ``--flat-resident on`` (8-way)
-    restores into a transient ``off`` plan AND into a 4-way resident
-    plan — flag and shard count are both restore-time choices."""
-    from tests.test_flat_state import _batch as fs_batch
-    plan_on, (_, state_on, step_on, _, _) = _resident_setup(mesh8)
-    batch8 = shard_batch_to_mesh(fs_batch(seed=0), mesh8)
-    state_on, _ = step_on(state_on, batch8)
-    store = CheckpointStore(str(tmp_path / "resport"))
-    canon_on = plan_on.to_canonical(state_on)
-    store.save(0, canon_on)
-    store._ckptr.wait_until_finished()
-
-    # on -> off: the transient fused plan consumes the same checkpoint
-    plan_off, (_, state_off, step_off, _, _) = _resident_setup(
-        mesh8, resident="off")
-    restored, _ = store.restore(plan_off.canonical_template(state_off))
-    live_off = plan_off.from_canonical(restored)
-    assert live_off.flat_shadow is None
-    _canon_equal(canon_on, plan_off.to_canonical(live_off))
-    live_off, metrics = step_off(live_off, batch8)
-    assert np.isfinite(float(metrics["loss_mean"]))
-
-    # 8-way -> 4-way resident: different layout padding, same canonical
-    mesh4 = build_mesh(MeshSpec(data=4), jax.devices()[:4])
-    plan4, (_, state4, step4, _, _) = _resident_setup(mesh4, data=4)
-    restored4, _ = store.restore(plan4.canonical_template(state4))
-    live4 = plan4.from_canonical(restored4)
-    _canon_equal(canon_on, plan4.to_canonical(live4))
-    batch4 = shard_batch_to_mesh(fs_batch(seed=1), mesh4)
-    live4, metrics4 = step4(live4, batch4)
-    assert np.isfinite(float(metrics4["loss_mean"]))
-    assert int(live4.step) == 2
-    store.close()
-
-
 def test_saver_state_survives_restart(tmp_path):
     """Patience/best metric persist across ModelSaver re-construction
     (the reference forgets both on restart)."""

@@ -181,73 +181,3 @@ class TestCompiledShardingsMatchPlan:
         plan = build_plan(mesh8)
         assert plan.batch_sharding.spec == P("data")
         assert plan.replicated.spec == P()
-
-
-class TestResidentDonationContract:
-    """ISSUE 18 satellite: under ``--flat-resident on`` the resident flat
-    buffers ride the donated state argument of the REAL train step —
-    their inputs carry aliasing attributes and the compiled executable
-    keeps an input_output_alias table — and the per-step pack
-    concatenates of the transient layout are gone from the hot path."""
-
-    @pytest.fixture(scope="class")
-    def resident_arms(self, mesh8):
-        """Lowered + compiled real train steps, zero1+fused, resident
-        off/on — built once for the class (the compiles are the expensive
-        part)."""
-        from tests.test_flat_state import _batch, _plan_for, _rcfg
-        from byol_tpu.parallel.mesh import shard_batch_to_mesh
-        from byol_tpu.training.build import setup_training
-        arms = {}
-        for resident in ("off", "on"):
-            rcfg = _rcfg(resident=resident, zero1="on")
-            plan = _plan_for(mesh8, rcfg)
-            _, state, train_step, _, _ = setup_training(
-                rcfg, mesh8, jax.random.PRNGKey(0), plan=plan)
-            batch = shard_batch_to_mesh(_batch(), mesh8)
-            with mesh8:
-                lowered = train_step.__wrapped__.lower(state, batch)
-                arms[resident] = {
-                    "lowered": lowered.as_text(),
-                    "compiled": lowered.compile().as_text(),
-                    "global_size": (plan._flat_layout.global_size
-                                    if resident == "on" else None),
-                }
-        return arms
-
-    def test_resident_buffers_are_aliased_inputs(self, resident_arms):
-        """Every resident buffer input (the flat_shadow, the momentum
-        trace inside opt_state, the target buffer — the three 1-D fp32
-        args of the layout's distinctive global size) must carry a
-        tf.aliasing_output attribute in the lowered step — donated
-        step-over-step, never copied — and the compiled executable keeps
-        an input_output_alias table."""
-        import re
-        arm = resident_arms["on"]
-        sig = next(line for line in arm["lowered"].splitlines()
-                   if "func public @main" in line)
-        # split on argument boundaries rather than parsing the attribute
-        # dicts: attrs like mhlo.sharding carry nested braces inside
-        # quoted strings, so each chunk is everything up to the next %arg
-        params = sig.split("@main(", 1)[1].rsplit(") -> ", 1)[0]
-        args = re.split(r",\s+(?=%arg\d+: )", params)
-        buf_ty = f"tensor<{arm['global_size']}xf32>"
-        buffers = [a for a in args if buf_ty in a]
-        assert len(buffers) == 3, (buf_ty, args)
-        for a in buffers:
-            assert "tf.aliasing_output" in a, a
-        assert "input_output_alias" in arm["compiled"]
-
-    def test_resident_step_drops_the_pack_concatenates(self, resident_arms):
-        """The transient fused step packs params/grads/momentum/target
-        every step (pack_flat's concatenate feeding the kernel); resident
-        keeps only the gradient pack.  Three of the four concatenates
-        must be gone from the compiled hot path."""
-        concat = lambda text: len(
-            [1 for line in text.splitlines()
-             if " concatenate(" in line or " concatenate.(" in line])
-        n_off = concat(resident_arms["off"]["compiled"])
-        n_on = concat(resident_arms["on"]["compiled"])
-        assert n_on <= n_off - 3, (
-            f"resident step still packs: {n_on} concatenates vs "
-            f"{n_off} transient")

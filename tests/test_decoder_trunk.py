@@ -107,7 +107,7 @@ def test_every_gradient_leaf_matches_the_reference():
             (jax.tree_util.keystr(path), gap, float(jnp.linalg.norm(w)))
 
 
-def _training(share="1/2", telemetry="off"):
+def _training(share="1/2", telemetry="off", zero1=False):
     """The normal path: Config -> resolve -> mesh -> plan ->
     setup_training, at the tiny preset."""
     from byol_tpu.training.build import setup_training
@@ -127,7 +127,8 @@ def _training(share="1/2", telemetry="off"):
                               input_shape=(SEQ,))
     mesh = build_mesh(MeshSpec(data=1), jax.devices()[:1])
     _, state, step, _, _ = setup_training(
-        rcfg, mesh, jax.random.PRNGKey(0), plan=build_plan(mesh))
+        rcfg, mesh, jax.random.PRNGKey(0),
+        plan=build_plan(mesh, zero1=zero1))
     return rcfg, mesh, state, step
 
 
@@ -308,12 +309,14 @@ def test_lars_leaves_gains_biases_and_the_hyper_connection_values_alone():
         "down"] is True
 
 
-def test_the_fused_kernel_and_zero1_name_a_tree_with_an_expert_axis():
-    from byol_tpu.optim.factory import fused_update_unsupported_reason
-    mask = {"experts": {"gate": lars_lib.PER_EXPERT}, "kernel": True}
-    assert fused_update_unsupported_reason("lars_momentum", 0.0) is None
-    assert "expert" in fused_update_unsupported_reason("lars_momentum", 0.0,
-                                                       mask)
+def test_zero1_names_a_tree_with_an_expert_axis():
+    """``--zero1 on`` flattens every leaf; LARS adapts each expert of a
+    stacked kernel alone, so the build refuses the trunk by that name."""
+    assert lars_lib.has_expert_axis({"experts": {"gate": lars_lib.PER_EXPERT},
+                                     "kernel": True})
+    assert not lars_lib.has_expert_axis({"kernel": True, "bias": False})
+    with pytest.raises(ValueError, match="expert axis"):
+        _training(zero1=True)
 
 
 def test_the_step_stamps_the_trunks_scopes_and_counts_its_routing():

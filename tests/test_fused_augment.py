@@ -325,25 +325,6 @@ class TestTrainStepParity:
 # ---------------------------------------------------------------------------
 
 class TestOpsCommonHoist:
-    def test_fused_update_reexports_the_shared_helpers(self):
-        """The hoist must be a move, not a fork: fused_update's public
-        grid-sizing names ARE the ops/common.py objects (one
-        implementation for every kernel)."""
-        from byol_tpu.ops import common
-        from byol_tpu.ops import fused_update as fu
-        assert fu.resolve_block_rows is common.resolve_block_rows
-        assert fu.TPU_BLOCK_ROWS == common.TPU_BLOCK_ROWS == 256
-
-    def test_fat_tile_backs_the_interpreter_grid(self):
-        """resolve_block_rows' interpreter arm == fat_tile(align=8): the
-        fat-tile heuristic the fused_update tests pin is the shared one."""
-        from byol_tpu.ops import common
-        for n in (3, 100, 4096, 10_000):
-            assert (common.resolve_block_rows(n, True)
-                    == common.fat_tile(n, align=8))
-        assert common.fat_tile(5, align=1) == 1           # unit grids
-        assert common.fat_tile(170, align=1) == 11        # ceil(170/16)
-
     def test_resolve_interpret_explicit_wins(self):
         from byol_tpu.ops import common
         assert common.resolve_interpret(True) is True

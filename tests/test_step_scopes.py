@@ -185,20 +185,17 @@ def _two_view():
                                   interpret=True), images)
 
 
-def _update():
-    from byol_tpu.ops import fused_lars_ema_update
-    tree = {"kernel": jnp.ones((8, 130)), "bias": jnp.ones((10,))}
-    return _pallas_names(
-        lambda p: fused_lars_ema_update(
-            p, p, p, p, lr=0.1, tau=0.99, weight_decay=1e-6,
-            momentum_decay=0.9, ema_pre=False, interpret=True), tree)
+def _packed_attention():
+    from byol_tpu.ops import packed_self_attention
+    qkv = jnp.ones((2, 8, 3 * 128), jnp.float32)
+    return _pallas_names(jax.grad(lambda x: jnp.sum(
+        packed_self_attention(x, 2, interpret=True))), qkv)
 
 
 @pytest.mark.parametrize("entry,expected", [
     (_flash, ["flash_attention"]),
     (_two_view, ["fused_two_view"]),
-    (_update, ["fused_lars_ema_update_norms",
-               "fused_lars_ema_update_apply"]),
-], ids=["flash_attention", "fused_two_view", "fused_lars_ema_update"])
+    (_packed_attention, ["packed_attention_fwd", "packed_attention_bwd"]),
+], ids=["flash_attention", "fused_two_view", "packed_self_attention"])
 def test_each_pallas_call_carries_its_name(entry, expected):
     assert entry() == expected

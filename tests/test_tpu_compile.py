@@ -24,14 +24,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 from jax.sharding import SingleDeviceSharding
 
 from byol_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, SEQUENCE_AXIS
 
 V5E_HBM_BYTES = 16 * 2 ** 30
 BATCH, IMAGE, RAW = 256, 224, 256       # the flagship's per-chip batch
-LARS = dict(weight_decay=1e-6, momentum_decay=0.9)
 
 
 @pytest.fixture(scope="module")
@@ -47,11 +46,6 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(scope="module")
-def mesh4(topo):
-    return Mesh(np.array(topo.devices), (DATA_AXIS,))
 
 
 @pytest.fixture(scope="module")
@@ -78,17 +72,6 @@ def _flagship_rcfg(arch="resnet50", batch=BATCH):
                               input_shape=(IMAGE, IMAGE, 3))
 
 
-@pytest.fixture(scope="module")
-def rn50_params():
-    """The flagship's parameter tree as shapes (no array is ever made)."""
-    from byol_tpu.training.build import build_net, init_variables
-    rcfg = _flagship_rcfg()
-    net = build_net(rcfg)
-    variables = jax.eval_shape(lambda k: init_variables(net, rcfg, k),
-                               jax.random.PRNGKey(0))
-    return variables["params"]
-
-
 def _with(tree, sharding):
     return jax.tree_util.tree_map(
         lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=sharding),
@@ -99,67 +82,6 @@ def _compile(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
-
-
-# ---------------------------------------------------------------------------
-# the fused LARS+EMA update: four entries (ops/fused_update.py)
-# ---------------------------------------------------------------------------
-
-def test_fused_update_transient(no_persistent_cache, one_chip, rn50_params):
-    from byol_tpu.ops import fused_update as fu
-    p = _with(rn50_params, one_chip)
-    s = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
-    _compile(lambda p, g, m, t, lr, tau: fu.fused_lars_ema_update(
-        p, g, m, t, lr=lr, tau=tau, interpret=False, **LARS),
-        p, p, p, p, s, s)
-
-
-def test_fused_update_resident(no_persistent_cache, one_chip, rn50_params):
-    from byol_tpu.ops import fused_update as fu
-    from byol_tpu.parallel import flat_state
-    layout = flat_state.build_layout(rn50_params, 1, interpret=False)
-    p = _with(rn50_params, one_chip)
-    buf = jax.ShapeDtypeStruct((layout.global_size,), jnp.float32,
-                               sharding=one_chip)
-    s = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
-    _compile(lambda p, g, m, t, lr, tau: fu.fused_lars_ema_update_resident(
-        p, g, m, t, layout=layout, lr=lr, tau=tau, interpret=False, **LARS),
-        p, p, buf, buf, s, s)
-
-
-def test_fused_update_zero1(no_persistent_cache, mesh4, rn50_params):
-    from byol_tpu.ops import fused_update as fu
-    from byol_tpu.parallel import zero1
-    sharded = NamedSharding(mesh4, P(DATA_AXIS))
-    flat = _with(jax.tree_util.tree_map(
-        lambda t: zero1.flat_struct(t, 4), rn50_params), sharded)
-    s = jax.ShapeDtypeStruct((), jnp.float32,
-                             sharding=NamedSharding(mesh4, P()))
-    compiled = _compile(
-        lambda p, g, m, t, lr, tau: fu.fused_lars_ema_update_zero1(
-            p, g, m, t, param_template=rn50_params, mesh=mesh4,
-            num_shards=4, lr=lr, tau=tau, interpret=False, **LARS),
-        flat, flat, flat, flat, s, s)
-    assert "all-reduce" in compiled.as_text()    # the segment-norm psum
-
-
-def test_fused_update_resident_zero1(no_persistent_cache, mesh4,
-                                     rn50_params):
-    from byol_tpu.ops import fused_update as fu
-    from byol_tpu.parallel import flat_state, zero1
-    layout = flat_state.build_layout(rn50_params, 4, interpret=False)
-    sharded = NamedSharding(mesh4, P(DATA_AXIS))
-    grads = _with(jax.tree_util.tree_map(
-        lambda t: zero1.flat_struct(t, 4), rn50_params), sharded)
-    buf = jax.ShapeDtypeStruct((layout.global_size,), jnp.float32,
-                               sharding=sharded)
-    s = jax.ShapeDtypeStruct((), jnp.float32,
-                             sharding=NamedSharding(mesh4, P()))
-    _compile(
-        lambda p, g, m, t, lr, tau: fu.fused_lars_ema_update_resident_zero1(
-            p, g, m, t, layout=layout, mesh=mesh4, lr=lr, tau=tau,
-            interpret=False, **LARS),
-        buf, grads, buf, buf, s, s)
 
 
 # ---------------------------------------------------------------------------
@@ -236,14 +158,14 @@ def _compile_train_step(topo, rcfg, batch):
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1, 1),
                 (DATA_AXIS, SEQUENCE_AXIS, MODEL_AXIS))
     net = build_net(rcfg)
-    tx, schedule = build_tx(rcfg)
+    tx, _ = build_tx(rcfg)
     state = jax.eval_shape(
         lambda k: create_train_state(init_variables(net, rcfg, k), tx),
         jax.random.PRNGKey(0))
     plan = build_plan(mesh)
     step = plan.jit_train_step(
         make_train_step(net, tx, step_config(rcfg), get_policy(True),
-                        lr_schedule=schedule, mesh=mesh),
+                        mesh=mesh),
         plan.state_sharding(state))
     view = jax.ShapeDtypeStruct((batch, IMAGE, IMAGE, 3), jnp.float32)
     views = {"view1": view, "view2": view,
