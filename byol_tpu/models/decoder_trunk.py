@@ -28,10 +28,11 @@ registered instance (models/registry.py).  Three mechanisms nothing else in
 this one is; heads, experts and vocabulary rows held follow from it.
 
 Device-trace scopes (``TRACE_SCOPES``): ``mla``, ``moe/route``,
-``moe/experts``, ``moe/shared``, ``mhc`` (and ``ffn`` for a leading dense
-layer) inside every layer; the train step stamps them beside its phases so
-the compile cache keys them (training/steps.py).  Each routing layer sows
-``[rows held, largest load, mean load, rows dropped]`` into the
+``moe/experts`` (and in it ``combine``: the sum of a token's copies, forward
+and as the dispatch's backward), ``moe/shared``, ``mhc`` (and ``ffn`` for a
+leading dense layer) inside every layer; the train step stamps them beside
+its phases so the compile cache keys them (training/steps.py).  Each routing
+layer sows ``[rows held, largest load, mean load, rows dropped]`` into the
 ``ROUTING`` collection; the train step sums them over layers.
 """
 from __future__ import annotations
@@ -48,7 +49,8 @@ import jax.numpy as jnp
 from byol_tpu.core import remat as remat_lib
 from byol_tpu.ops.attention import dense_attention
 
-TRACE_SCOPES = ("mla", "moe/route", "moe/experts", "moe/shared", "mhc", "ffn")
+TRACE_SCOPES = ("mla", "moe/route", "moe/experts", "moe/experts/combine",
+                "moe/shared", "mhc", "ffn")
 ROUTING = "routing"                  # flax collection of the routing counters
 ROUTING_FIELDS = ("rows_held", "load_max", "load_mean", "rows_dropped")
 
@@ -258,9 +260,19 @@ class GatedMLP(nn.Module):
 
 def _sum_copies(rows, pos, ok):
     """``out[t] = sum_j rows[pos[t, j]]`` over the copies ``ok`` marks: a
-    token's row back from the (up to k) sorted rows that are its copies."""
-    picked = jnp.where(ok[..., None], rows[pos], 0)
-    return jnp.sum(picked.astype(jnp.float32), axis=1).astype(rows.dtype)
+    token's row back from the (up to k) sorted rows that are its copies,
+    added in float32 in slot order and rounded once.  One gather of
+    ``(tokens, D)`` a slot, the k of them added in one pass: gathered as one
+    ``(tokens, k, D)`` array, k lands on the tiled minor dimensions and the
+    TPU compiler relays out all k copies of the hidden states before it
+    sums them (PERF.md section 6, PR 30)."""
+    with jax.named_scope("combine"):
+        total = None
+        for j in range(pos.shape[1]):
+            copy = jnp.where(ok[:, j, None], rows[pos[:, j]],
+                             0).astype(jnp.float32)
+            total = copy if total is None else total + copy
+        return total.astype(rows.dtype)
 
 
 # Dispatch and combine are each other's transpose: ``rows[p] = x[idx[p]]``

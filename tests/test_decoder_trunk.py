@@ -251,6 +251,92 @@ def test_no_row_is_dropped_when_the_router_sends_everything_here(
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
+# Hand-made routing for the combine: 8 tokens x top-4, tokens with 0, 1, 2,
+# 3 and k copies on the held experts, sorted as the layer sorts them.
+COPIES = (0, 1, 2, 4, 0, 3, 1, 4)               # held copies of each token
+CAPS = {"every": 32, "usual": 20, "short": 12}  # 15 held: 'short' cuts 3
+
+
+def _routing(cap, experts=3, seed=11):
+    """``idx, pos, ok, valid`` as ``ExpertLayer.product(cap)`` builds them."""
+    rng = np.random.default_rng(seed)
+    tokens, k = len(COPIES), 4
+    here = np.zeros((tokens, k), bool)
+    for t, n in enumerate(COPIES):
+        here[t, rng.permutation(k)[:n]] = True
+    bucket = np.where(here, rng.integers(0, experts, (tokens, k)),
+                      experts).reshape(-1)
+    order = np.argsort(bucket, kind="stable")
+    place = np.argsort(order, kind="stable").reshape(tokens, k)
+    held = int(here.sum())
+    idx = (order // k)[:cap]
+    ok = here & (place < cap)
+    pos = np.minimum(place, cap - 1)
+    assert (place >= cap).any() or cap == tokens * k    # something to clamp
+    return idx, pos, ok, (np.arange(cap) < held)[:, None]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("cap", sorted(CAPS))
+def test_the_combine_adds_each_marked_copy_once(cap, dtype):
+    """``_sum_copies`` / ``_put_rows`` against a float64 sum rounded once
+    (bf16: to the bit) and a float32 sum in slot order (to the bit):
+    tokens with 0, 1, 2, 3 and k copies, every copy in reach
+    (``every``), the usual cut (``usual``: only copies held elsewhere lie
+    past it) and a cut that loses held copies (``short``: clamped, masked)."""
+    idx, pos, ok, _ = _routing(CAPS[cap])
+    counts = ok.sum(1)
+    assert {0, 1, 2, 4} <= set(counts.tolist())
+    assert (counts.sum() < sum(COPIES)) == (cap == "short")
+    rows = jnp.asarray(np.random.default_rng(12).normal(
+        size=(CAPS[cap], 24)), jnp.dtype(dtype))
+    want = np.zeros((len(COPIES), 24))
+    in_order = np.zeros((len(COPIES), 24), np.float32)   # slot by slot
+    for t, j in zip(*np.nonzero(ok)):
+        want[t] += np.asarray(rows, np.float64)[pos[t, j]]
+        in_order[t] += np.asarray(rows, np.float32)[pos[t, j]]
+    for fn in (trunk_lib._sum_copies,
+               lambda *a: trunk_lib._put_rows(a[0], idx, *a[1:])):
+        got = jax.jit(fn)(rows, pos, ok)
+        assert got.dtype == rows.dtype
+        np.testing.assert_array_equal(np.asarray(got),
+                                      in_order.astype(rows.dtype))
+        if dtype == "bfloat16":      # four bf16 add exactly in float32
+            np.testing.assert_array_equal(np.asarray(got),
+                                          want.astype(rows.dtype))
+        else:
+            np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+    x = jnp.asarray(np.random.default_rng(13).normal(
+        size=(len(COPIES), 24)), rows.dtype)
+    np.testing.assert_array_equal(
+        np.asarray(trunk_lib._take_rows(x, idx, pos, ok)),
+        np.asarray(x)[idx])
+
+
+@pytest.mark.parametrize("cap", ["every", "usual"])
+def test_dispatch_and_combine_are_each_others_transpose(cap):
+    """``<take(x), r> = <x, put(r)>`` for rows ``r`` masked as the layer
+    masks them, and each ``custom_vjp`` hands back the other's forward."""
+    idx, pos, ok, valid = _routing(CAPS[cap])
+    rng = np.random.default_rng(14)
+    x = jnp.asarray(rng.normal(size=(len(COPIES), 24)), jnp.float32)
+    r = jnp.asarray(np.where(valid, rng.normal(size=(CAPS[cap], 24)), 0),
+                    jnp.float32)
+    rows, take_vjp = jax.vjp(
+        lambda x: trunk_lib._take_rows(x, idx, pos, ok), x)
+    out, put_vjp = jax.vjp(
+        lambda r: trunk_lib._put_rows(r, idx, pos, ok), r)
+    np.testing.assert_allclose(jnp.vdot(rows, r), jnp.vdot(x, out),
+                               rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(take_vjp(r)[0]),
+                                  np.asarray(out))
+    np.testing.assert_array_equal(np.asarray(put_vjp(x)[0]),
+                                  np.asarray(rows))
+    # autodiff's own transpose of the gather (a scatter-add) agrees
+    np.testing.assert_allclose(
+        jax.vjp(lambda x: x[idx], x)[1](r)[0], out, rtol=1e-6, atol=1e-6)
+
+
 def test_h_res_has_unit_row_and_column_sums():
     streams = tuple(jnp.asarray(np.random.default_rng(7 + j).normal(
         size=(2, SEQ, 64)), jnp.float32) for j in range(TINY.hc_mult))

@@ -141,6 +141,45 @@ def test_expert_layer_at_published_widths(no_persistent_cache, one_chip):
     assert "conditional" in compiled.as_text()
 
 
+def test_expert_layer_combine_moves_no_relaid_out_copies(no_persistent_cache,
+                                                         one_chip):
+    """At ``xing4_train_b8_s1024``'s shapes (16 x 1,024 rows, top-4, 8 of 64
+    experts held) the combine and the dispatch's backward gather one
+    ``[16384,3584]`` array a slot and add the four in one fusion: no array
+    of 65,536 x 3,584 elements under the ``combine`` scope, so none to relay
+    out before the sum (PERF.md section 6, PR 30: 0.94 GB a call)."""
+    from byol_tpu.models import decoder_trunk as trunk_lib
+    z = trunk_lib.XING4_29B_A4B
+    layer = trunk_lib.ExpertLayer(z, 0, 8, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((16, 1024, z.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    params = _with(jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8, z.hidden_size), x.dtype))
+    )["params"], one_chip)
+
+    def loss(p, x):           # not linear: the forward's combine stays
+        return jnp.sum(jnp.square(
+            layer.apply({"params": p}, x).astype(jnp.float32)))
+    text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
+    tokens, k, d = 16 * 1024, z.num_experts_per_tok, z.hidden_size
+    assert f"[{tokens},{k},{d}]" not in text
+    assert f"[{k},{tokens},{d}]" not in text
+    # the ops that read and write HBM on their own, under the scope the
+    # trace reads: (name, result bytes, opcode, operands, path, called)
+    from scripts import hlo_bytes_by_scope
+    combine = [row for name, rows in hlo_bytes_by_scope.parse(text).items()
+               if name and not name.startswith("%fused_computation")
+               for row in rows if "/combine/" in row[4]]
+    one = tokens * d * 2                         # a bf16[16384,3584]
+    assert max(row[1] for row in combine) == one
+    assert not [row for row in combine if row[2] in ("copy", "reshape")]
+    # forward combine and dispatch backward, each in both branches of the
+    # cond: four sums of four gathers
+    assert len([row for row in combine
+                if row[2] == "fusion" and row[1] == one]) == 4 * (k + 1)
+
+
 # ---------------------------------------------------------------------------
 # one whole ResNet-50 train step at batch 256 fits the chip
 # ---------------------------------------------------------------------------
