@@ -254,9 +254,12 @@ def build_parser() -> argparse.ArgumentParser:
                    help="decoder trunk: 'i/n' = this chip is chip i of the "
                         "n that share every layer (expert- and head-"
                         "parallel); the heads, routed experts and "
-                        "vocabulary rows it holds follow from it.  The "
-                        "layer runs without its exchange: what the other "
-                        "chips would add is left out")
+                        "vocabulary rows it holds follow from it.  A part "
+                        "that divides over fewer chips is named after it: "
+                        "'0/16,vocab=8,heads=1' = 16 expert-parallel chips, "
+                        "the vocabulary over 8 of them, the heads whole on "
+                        "each.  The layer runs without its exchange: what "
+                        "the other chips would add is left out")
     x.add_argument("--trunk-depth", type=str, default="",
                    help="decoder trunk: 'D+S' builds D leading dense and S "
                         "expert layers instead of the published depth")

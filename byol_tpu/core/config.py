@@ -120,6 +120,8 @@ class ModelConfig:
                                         # chip i of the n that share every
                                         # layer; heads, routed experts and
                                         # vocabulary rows held follow from it
+                                        # (',vocab=m,heads=m': a part that
+                                        # divides over m of the n)
     trunk_depth: str = ""               # decoder trunk: 'D+S' builds D
                                         # leading dense and S expert layers;
                                         # '' = the published depth

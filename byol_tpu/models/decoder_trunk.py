@@ -3,8 +3,10 @@
 A causal decoder stack read as a BYOL encoder: ``(B, S) int32 -> (B,
 hidden)``, the mean over positions of the final-norm hidden states.  Named
 by its mechanisms, sized by :class:`TrunkSizes`; a published model is one
-registered instance (models/registry.py).  Three mechanisms nothing else in
-``models/`` has:
+registered instance (models/registry.py).  The shell (embedding, layers
+under remat, final norm, mean pooling) and the expert layer are one; a
+trunk's sizes say which token mixer each layer has and how its residual
+travels.  Mechanisms nothing else in ``models/`` has:
 
 - **latent attention** (MLA, as the DeepSeek-V3 modelling code writes it):
   low-rank query and key/value paths with an RMSNorm on each latent, a
@@ -22,12 +24,27 @@ registered instance (models/registry.py).  Three mechanisms nothing else in
 - **hyper-connected residual streams** (manifold-constrained, arXiv
   2512.24880): ``n`` streams per token, mixed round every sub-layer by maps
   computed from the streams themselves; the stream-to-stream map is made
-  doubly stochastic by Sinkhorn-Knopp iterations.
+  doubly stochastic by Sinkhorn-Knopp iterations.  A trunk with ONE stream
+  has a plain residual instead (``x + F(norm(x))``);
+- **a layer pattern of two mixers** (``full_attention_interval = n``): layer
+  ``i`` is gated grouped-query softmax attention (:class:`GatedAttention`:
+  an output gate beside every query head, RMSNorm on query and key heads,
+  rotary on the first part of the head, the core blockwise over the keys —
+  ops/attention.py) where ``(i + 1) % n == 0``, and Gated DeltaNet
+  (models/gated_delta.py: a recurrence over the sequence, run in chunks)
+  otherwise; with it come zero-centred norm gains (``x^ (1 + w)``), a
+  softmax router without selection bias, and a shared expert behind a
+  sigmoid gate.
 
 :class:`LayerShare` states ONCE which of the ``of`` chips that share a layer
-this one is; heads, experts and vocabulary rows held follow from it.
+this one is; heads, experts and vocabulary rows held follow from it — and
+where a part divides over fewer chips than the experts do (a vocabulary
+over 8 of 16, heads over none), it says so there too.
 
-Device-trace scopes (``TRACE_SCOPES``): ``mla``, ``moe/route``,
+Device-trace scopes (``DecoderTrunk.trace_scopes``; ``TRACE_SCOPES`` for a
+latent-attention trunk, ``HYBRID_SCOPES`` for a patterned one: ``gdn`` with
+``proj``, ``conv``, ``core``, ``gate_norm``; ``gqa`` with ``core``; the
+``moe`` scopes): ``mla``, ``moe/route``,
 ``moe/experts`` (and in it ``combine``: the sum of a token's copies, forward
 and as the dispatch's backward), ``moe/shared``, ``mhc`` (and ``ffn`` for a
 leading dense layer) inside every layer; the train step stamps them beside
@@ -40,46 +57,76 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from byol_tpu.core import remat as remat_lib
-from byol_tpu.ops.attention import dense_attention
+from byol_tpu.models.gated_delta import GatedDeltaNet, GatedDeltaSizes
+from byol_tpu.ops.attention import (blockwise_causal_attention,
+                                    dense_attention)
 
-TRACE_SCOPES = ("mla", "moe/route", "moe/experts", "moe/experts/combine",
-                "moe/shared", "mhc", "ffn")
+_MOE_SCOPES = ("moe/route", "moe/experts", "moe/experts/combine",
+               "moe/shared")
+TRACE_SCOPES = ("mla",) + _MOE_SCOPES + ("mhc", "ffn")
+HYBRID_SCOPES = ("gdn", "gdn/proj", "gdn/conv", "gdn/core", "gdn/gate_norm",
+                 "gqa", "gqa/core") + _MOE_SCOPES
+# the expert layer's fallback (a step whose load passes twice the nominal
+# one) forms its rows whole up to this size and in slabs beyond it
+WHOLE_FALLBACK_BYTES = 1 << 30
 ROUTING = "routing"                  # flax collection of the routing counters
 ROUTING_FIELDS = ("rows_held", "load_max", "load_mean", "rows_dropped")
 
 
 @dataclasses.dataclass(frozen=True)
+class GatedAttentionSizes:
+    """One gated grouped-query attention layer."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rotary_dim: int                  # leading dims of a head that rotate
+    rope_theta: float
+    block: int = 512                 # the program's own: keys a block
+
+
+@dataclasses.dataclass(frozen=True, kw_only=True)
 class TrunkSizes:
-    """The sizes of one decoder trunk, as its published config names them."""
+    """The sizes of one decoder trunk, as its published config names them.
+    A token mixer the trunk does not have keeps its zeros."""
 
     hidden_size: int
     num_hidden_layers: int
     first_k_dense_replace: int       # leading layers with a dense FFN
-    num_attention_heads: int
-    q_lora_rank: int
-    kv_lora_rank: int
-    qk_nope_head_dim: int
-    qk_rope_head_dim: int
-    v_head_dim: int
+    # latent attention (every layer, unless a pattern is given below)
+    num_attention_heads: int = 0
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     intermediate_size: int           # dense FFN width
     n_routed_experts: int
     moe_intermediate_size: int
     num_experts_per_tok: int
     n_shared_experts: int
-    routed_scaling_factor: float
+    routed_scaling_factor: float = 1.0
     norm_topk_prob: bool
     vocab_size: int
-    hc_mult: int                     # residual streams
-    hc_sinkhorn_iters: int
-    hc_eps: float
-    hc_clamp: float                  # |logit| bound before the exp
+    hc_mult: int = 1                 # residual streams; 1 = plain residual
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 0.0
+    hc_clamp: float = 0.0            # |logit| bound before the exp
+    # the pattern: layer i is gated attention where (i + 1) % interval == 0
+    # and Gated DeltaNet otherwise; 0 = latent attention everywhere
+    full_attention_interval: int = 0
+    gated_attention: Optional[GatedAttentionSizes] = None
+    gated_delta: Optional[GatedDeltaSizes] = None
+    scoring_func: str = "sigmoid"    # 'sigmoid' (noaux_tc bias) | 'softmax'
+    shared_expert_gate: bool = False     # sigmoid(x w_s) on the shared expert
+    zero_centred_norm: bool = False      # gains are 1 + w, w from zeros
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     rope_factor: float = 1.0         # YaRN; 1 = plain rotary
@@ -93,6 +140,13 @@ class TrunkSizes:
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+    def mixer(self, layer: int) -> str:
+        """The token mixer of layer ``layer``: its scope's name."""
+        if not self.full_attention_interval:
+            return "mla"
+        return "gqa" if (layer + 1) % self.full_attention_interval == 0 \
+            else "gdn"
+
     def with_depth(self, dense: int, sparse: int) -> "TrunkSizes":
         """The same trunk cut to ``dense`` leading dense layers and
         ``sparse`` expert layers."""
@@ -103,30 +157,43 @@ class TrunkSizes:
 @dataclasses.dataclass(frozen=True)
 class LayerShare:
     """Chip ``index`` of the ``of`` chips that share every layer (expert- and
-    head-parallel, vocabulary rows split the same way)."""
+    head-parallel, vocabulary rows split the same way).  A part that
+    divides over FEWER chips is named in ``over``: ``0/16,vocab=8,heads=1``
+    is chip 0 of 16 expert-parallel chips whose vocabulary is split over 8
+    of them (chip ``i`` holds slice ``i % 8``) and whose heads are whole on
+    every chip."""
 
     index: int = 0
     of: int = 1
+    over: Tuple[Tuple[str, int], ...] = ()     # (part, chips it divides over)
+
+    PARTS = {"vocab": "vocabulary rows", "heads": "attention heads"}
 
     @classmethod
     def parse(cls, text: str) -> "LayerShare":
+        chip, *parts = text.split(",")
         try:
-            index, of = (int(t) for t in text.split("/"))
-        except ValueError:
+            index, of = (int(t) for t in chip.split("/"))
+            over = tuple((cls.PARTS[name], int(n)) for name, n in
+                         (part.split("=") for part in parts))
+        except (ValueError, KeyError):
             raise ValueError(
                 f"layer share {text!r} is not 'i/n' (chip i of the n that "
-                "share a layer)") from None
-        if not 0 <= index < of:
-            raise ValueError(f"layer share {text!r}: need 0 <= i < n")
-        return cls(index, of)
+                "share a layer), optionally ',vocab=m' and ',heads=m' for "
+                "a part that divides over m of them") from None
+        if not 0 <= index < of or any(n < 1 or of % n for _, n in over):
+            raise ValueError(f"layer share {text!r}: need 0 <= i < n, and "
+                             "every m a divisor of n")
+        return cls(index, of, over)
 
     def held(self, total: int, what: str) -> Tuple[int, int]:
         """``(first, count)`` of ``total`` heads / experts / rows held."""
-        if total % self.of:
+        of = dict(self.over).get(what, self.of)
+        if total % of:
             raise ValueError(
-                f"{total} {what} do not divide over {self.of} chips")
-        count = total // self.of
-        return self.index * count, count
+                f"{total} {what} do not divide over {of} chips")
+        count = total // of
+        return self.index % of * count, count
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -190,13 +257,20 @@ def apply_rotary(x, cos, sin):
 
 
 class RMSNorm(nn.Module):
+    """``x / rms(x) * scale``; ``zero_centred``: ``x / rms(x) * (1 +
+    scale)`` with ``scale`` from zeros."""
+
     eps: float = 1e-6
     dtype: jnp.dtype = jnp.float32
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
+        scale = self.param(
+            "scale", nn.initializers.zeros if self.zero_centred
+            else nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        if self.zero_centred:
+            scale = 1.0 + scale
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(
             jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
@@ -243,6 +317,74 @@ class LatentAttention(nn.Module):
                               scale=scale, causal=True)
         out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * dv)
         return _dense(z.hidden_size, self.dtype, "o")(out)
+
+
+@functools.lru_cache(maxsize=8)
+def _half_rotary_values(theta: float, dim: int, seq_len: int):
+    """``cos, sin`` as rows of Python floats (angles in double precision),
+    as :func:`_rotary_values`: plain rotary at base ``theta``."""
+    freqs = [theta ** (-2.0 * i / dim) for i in range(dim // 2)]
+    rows = lambda fn: tuple(tuple(fn(p * f) for f in freqs)
+                            for p in range(seq_len))
+    return rows(math.cos), rows(math.sin)
+
+
+def half_rotary_tables(theta: float, dim: int, seq_len: int):
+    """``cos, sin`` of shape ``(S, dim / 2)``, float32 constants."""
+    cos, sin = _half_rotary_values(theta, dim, seq_len)
+    return jnp.asarray(cos, jnp.float32), jnp.asarray(sin, jnp.float32)
+
+
+def apply_half_rotary(x, cos, sin):
+    """Rotate the pairs ``(x[i], x[i + r/2])`` of the FIRST ``r = 2 x
+    cos.shape[-1]`` dims of the last axis by the position's angle (the
+    rotate-half layout), and leave the rest of the head alone.  ``x`` is
+    ``(B, S, H, D)``."""
+    half = cos.shape[-1]
+    x32 = x.astype(jnp.float32)
+    a, b, rest = x32[..., :half], x32[..., half:2 * half], x32[..., 2 * half:]
+    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest],
+                           axis=-1).astype(x.dtype)
+
+
+class GatedAttention(nn.Module):
+    """Grouped-query softmax attention with an output gate (the public
+    ``qwen3_next`` modelling code): ``q`` comes with a gate of its own width
+    per head, ``q`` and ``k`` heads are RMS-normalised (zero-centred gain),
+    the first ``rotary_dim`` dims of a head rotate, ``kv_heads`` key/value
+    heads serve ``heads`` query heads, and ``out = softmax(.) v *
+    sigmoid(gate)`` goes through ``o``."""
+
+    sizes: GatedAttentionSizes
+    heads: int
+    kv_heads: int
+    eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        z, dt = self.sizes, self.dtype
+        b, s, d = h.shape
+        dh = z.head_dim
+        q = _dense(self.heads * dh * 2, dt, "q")(h).reshape(
+            b, s, self.heads, 2 * dh)
+        q, gate = q[..., :dh], q[..., dh:]
+        k = _dense(self.kv_heads * dh, dt, "k")(h).reshape(
+            b, s, self.kv_heads, dh)
+        v = _dense(self.kv_heads * dh, dt, "v")(h).reshape(
+            b, s, self.kv_heads, dh)
+        norm = lambda name: RMSNorm(self.eps, dt, True, name=name)
+        cos, sin = half_rotary_tables(z.rope_theta, z.rotary_dim, s)
+        q = apply_half_rotary(norm("q_norm")(q), cos, sin)
+        k = apply_half_rotary(norm("k_norm")(k), cos, sin)
+        with jax.named_scope("core"):
+            out = blockwise_causal_attention(
+                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3), scale=dh ** -0.5, block=z.block)
+        out = out.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
+            gate.astype(jnp.float32)).astype(dt)
+        return _dense(d, dt, "o")(out.reshape(b, s, self.heads * dh))
 
 
 class GatedMLP(nn.Module):
@@ -335,7 +477,10 @@ class ExpertWeights(nn.Module):
 
 class ExpertLayer(nn.Module):
     """Routed experts ``[lo, lo + held)`` of ``n_routed_experts`` plus the
-    shared expert.  Every row routed to a held expert is computed."""
+    shared expert.  Every row routed to a held expert is computed.  The
+    sizes name the scoring rule (sigmoid scores with a selection bias, or a
+    softmax over all experts); from the chosen experts down there is one
+    path."""
 
     sizes: TrunkSizes
     lo: int
@@ -353,16 +498,21 @@ class ExpertLayer(nn.Module):
             router = self.param(
                 "router", nn.initializers.lecun_normal(),
                 (d, z.n_routed_experts), jnp.float32)
-            # noaux_tc: a selection bias that takes no gradient (it moves
-            # which experts are chosen, never their weights)
-            bias = self.param("e_score_correction_bias",
-                              nn.initializers.zeros,
-                              (z.n_routed_experts,), jnp.float32)
-            scores = jax.nn.sigmoid(jnp.dot(
-                x.astype(jnp.float32), router,
-                precision=jax.lax.Precision.HIGHEST))
-            _, chosen = jax.lax.top_k(scores + bias, k)
-            weight = jnp.take_along_axis(scores, chosen, axis=-1)
+            logits = jnp.dot(x.astype(jnp.float32), router,
+                             precision=jax.lax.Precision.HIGHEST)
+            if z.scoring_func == "softmax":
+                # the k largest ARE the weights: no gather of them
+                weight, chosen = jax.lax.top_k(
+                    jax.nn.softmax(logits, axis=-1), k)
+            else:
+                # noaux_tc: a selection bias that takes no gradient (it
+                # moves which experts are chosen, never their weights)
+                bias = self.param("e_score_correction_bias",
+                                  nn.initializers.zeros,
+                                  (z.n_routed_experts,), jnp.float32)
+                scores = jax.nn.sigmoid(logits)
+                _, chosen = jax.lax.top_k(scores + bias, k)
+                weight = jnp.take_along_axis(scores, chosen, axis=-1)
             if z.norm_topk_prob and k > 1:
                 weight = weight / (jnp.sum(weight, -1, keepdims=True) + 1e-20)
             weight = weight * z.routed_scaling_factor
@@ -382,25 +532,63 @@ class ExpertLayer(nn.Module):
         with jax.named_scope("experts"):
             w_gate, w_up, w_down = ExpertWeights(
                 self.held, d, f, name="experts")()
-            ragged = lambda lhs, w: jax.lax.ragged_dot(
-                lhs, w.astype(dt), group_sizes)
+            every = tokens * k
 
-            def product(cap):
-                """The held experts over the first ``cap`` sorted copies.
-                A ragged product writes no row beyond its groups: rows past
-                ``rows_held`` are masked on the way in AND out, so neither
+            def held_experts(idx, ok, pos, valid, sizes, weights):
+                """The held experts over a window of the sorted copies:
+                ``idx`` their tokens, ``ok`` / ``pos`` which copies of a
+                token lie in it and where, ``sizes`` the experts' rows in
+                it, ``weights()`` the copies' routing weights.  A ragged
+                product writes no row beyond its groups: rows ``valid``
+                leaves out are masked on the way in AND out, so neither
                 they nor their cotangents reach a token."""
-                idx = token_of[:cap]
-                ok = here_2d & (place < cap)
-                pos = jnp.minimum(place, cap - 1)
-                valid = (jnp.arange(cap) < rows_held)[:, None]
+                ragged = lambda lhs, w: jax.lax.ragged_dot(
+                    lhs, w.astype(dt), sizes)
                 rows = jnp.where(valid, _take_rows(x, idx, pos, ok), 0)
                 act = nn.silu(ragged(rows, w_gate)) * ragged(rows, w_up)
                 # the copy's routing weight goes on BEFORE the last product
                 # (it is linear): (cap, f) to scale, not (cap, d)
-                act = (act * weight_of[:cap, None]).astype(dt)
+                act = (act * weights()).astype(dt)
                 out = jnp.where(valid, ragged(act, w_down), 0)
                 return _put_rows(out, idx, pos, ok)
+
+            def product(cap):
+                """The first ``cap`` sorted copies."""
+                return held_experts(
+                    idx=token_of[:cap], ok=here_2d & (place < cap),
+                    pos=jnp.minimum(place, cap - 1),
+                    valid=(jnp.arange(cap) < rows_held)[:, None],
+                    sizes=group_sizes, weights=lambda: weight_of[:cap, None])
+
+            def slab(cap, start):
+                """``cap`` sorted copies from ``start`` (traced) on.  The
+                last slab ends with the copies: it starts early, and the
+                rows it shares with the one before are masked."""
+                first = jnp.minimum(start, every - cap)
+                window = lambda a: jax.lax.dynamic_slice_in_dim(a, first, cap)
+                ends = jnp.cumsum(group_sizes)
+                row = first + jnp.arange(cap)
+                return held_experts(
+                    idx=window(token_of),
+                    ok=here_2d & (place >= start) & (place < first + cap),
+                    pos=jnp.clip(place - first, 0, cap - 1),
+                    valid=((row >= start) & (row < rows_held))[:, None],
+                    sizes=jnp.clip(
+                        jnp.minimum(ends, first + cap)
+                        - jnp.maximum(ends - group_sizes, first), 0, None),
+                    weights=lambda: window(weight_of)[:, None])
+
+            def in_slabs(cap):
+                """Every copy, ``cap`` at a time, each slab under
+                ``jax.checkpoint``: the memory of one slab, whatever the
+                load."""
+                one = jax.checkpoint(lambda start: slab(cap, start))
+                total, _ = jax.lax.scan(
+                    lambda total, start: (
+                        total + one(start).astype(jnp.float32), None),
+                    jnp.zeros((tokens, d), jnp.float32),
+                    jnp.arange(0, every, cap))
+                return total.astype(dt)
 
             # Shapes are static, loads are not.  At the nominal load a chip
             # gets ``k x held / published`` copies per token; gathers and
@@ -408,18 +596,25 @@ class ExpertLayer(nn.Module):
             # load stays under it, and a step whose load does not takes
             # the same product over ALL ``tokens x k`` copies: no capacity,
             # no dropped row, and the common step does not pay for the
-            # worst one.
-            every = tokens * k
+            # worst one.  Where one ``(every, D)`` array of that fallback
+            # would pass ``WHOLE_FALLBACK_BYTES`` (a 512-way router's 327,680
+            # copies of 2,048: the branch not taken would hold 4.5 GB of
+            # the step's memory) it runs in slabs of the usual size.
             usual = min(every, -(-2 * every * self.held
                                  // z.n_routed_experts))
+            whole = every * d * jnp.dtype(dt).itemsize <= WHOLE_FALLBACK_BYTES
             if usual == every:
                 routed = product(every)
             else:
-                routed = jax.lax.cond(rows_held <= usual,
-                                      lambda: product(usual),
-                                      lambda: product(every))
+                routed = jax.lax.cond(
+                    rows_held <= usual, lambda: product(usual),
+                    (lambda: product(every)) if whole
+                    else (lambda: in_slabs(usual)))
         with jax.named_scope("shared"):
             shared = GatedMLP(f * z.n_shared_experts, dt, name="shared")(x)
+            if z.shared_expert_gate:
+                shared = shared * jax.nn.sigmoid(_dense(
+                    1, dt, "shared_gate")(x).astype(jnp.float32)).astype(dt)
         load = group_sizes.astype(jnp.float32)
         self.sow(ROUTING, "stats", jnp.stack([
             rows_held.astype(jnp.float32), jnp.max(load), jnp.mean(load),
@@ -499,31 +694,50 @@ def _write_streams(streams, h_res, h_post, y):
 
 
 class TrunkLayer(nn.Module):
-    """Attention, then a dense FFN or the expert layer, each read from and
-    written to the residual streams through its own hyper-connection."""
+    """A token mixer, then a dense FFN or the expert layer, each read from
+    and written to the residual streams through its own hyper-connection —
+    or, with one stream, added to it."""
 
     sizes: TrunkSizes
     share: LayerShare
     dense: bool
     dtype: jnp.dtype = jnp.float32
+    mixer: str = "mla"               # TrunkSizes.mixer(i)
 
     @nn.compact
     def __call__(self, streams):
         z, dt = self.sizes, self.dtype
-        _, heads = self.share.held(z.num_attention_heads, "attention heads")
+        heads = lambda n: self.share.held(n, "attention heads")[1]
 
         def sublayer(streams, name, fn):
+            norm = RMSNorm(z.rms_norm_eps, dt, z.zero_centred_norm,
+                           name=f"{name}_norm")
+            if len(streams) == 1:                   # plain residual
+                return (streams[0] + fn(norm(streams[0])),)
             with jax.named_scope("mhc"):
                 h_pre, h_post, h_res = HyperConnection(
                     z, dt, name=f"{name}_hc")(streams)
                 x = _read_streams(streams, h_pre)
-            y = fn(RMSNorm(z.rms_norm_eps, dt, name=f"{name}_norm")(x))
+            y = fn(norm(x))
             with jax.named_scope("mhc"):
                 return _write_streams(streams, h_res, h_post, y)
 
         def attention(x):
+            # ``gdn`` and ``gqa`` are modules named after their scope, as
+            # ``moe`` is
+            if self.mixer == "gdn":
+                d = z.gated_delta
+                return GatedDeltaNet(
+                    d, heads(d.num_key_heads), heads(d.num_value_heads),
+                    z.rms_norm_eps, dt, name="gdn")(x)
+            if self.mixer == "gqa":
+                a = z.gated_attention
+                return GatedAttention(
+                    a, heads(a.num_heads), heads(a.num_kv_heads),
+                    z.rms_norm_eps, dt, name="gqa")(x)
             with jax.named_scope("mla"):
-                return LatentAttention(z, heads, dt, name="attn")(x)
+                return LatentAttention(z, heads(z.num_attention_heads), dt,
+                                       name="attn")(x)
 
         def feed_forward(x):
             # flax names a module's scope after it: ``ffn/...``, and
@@ -547,7 +761,10 @@ class DecoderTrunk(nn.Module):
     remat: bool = False
     remat_policy: str = "none"
 
-    trace_scopes = TRACE_SCOPES
+    @property
+    def trace_scopes(self) -> Tuple[str, ...]:
+        return HYBRID_SCOPES if self.sizes.full_attention_interval \
+            else TRACE_SCOPES
 
     @property
     def feature_dim(self) -> int:
@@ -568,19 +785,21 @@ class DecoderTrunk(nn.Module):
                      embedding_init=nn.initializers.normal(stddev=0.02),
                      name="embed")(tokens)
         # entry: every stream starts as the embedding.  The streams travel
-        # as a tuple of (B, S, D) arrays: a stream axis of 4 beside D
-        # would sit on the tiled minor dimensions, padded fourfold
+        # as a tuple of (B, S, D) arrays (of one, under a plain residual):
+        # a stream axis of 4 beside D would sit on the tiled minor
+        # dimensions, padded fourfold
         streams = (x,) * z.hc_mult
         layer = remat_lib.wrap_block(
             TrunkLayer,
             remat_lib.resolve_policy_name(self.remat, self.remat_policy))
         for i in range(z.num_hidden_layers):
             streams = layer(z, self.share, i < z.first_k_dense_replace,
-                            self.dtype, name=f"layer{i}")(streams)
+                            self.dtype, z.mixer(i),
+                            name=f"layer{i}")(streams)
         # exit: the streams are summed
         hidden = sum(x.astype(jnp.float32) for x in streams).astype(
             self.dtype)
-        hidden = RMSNorm(z.rms_norm_eps, self.dtype,
+        hidden = RMSNorm(z.rms_norm_eps, self.dtype, z.zero_centred_norm,
                          name="final_norm")(hidden)
         return jnp.mean(hidden.astype(jnp.float32), axis=1).astype(self.dtype)
 
@@ -610,3 +829,39 @@ TINY = TrunkSizes(
     hc_eps=1e-6, hc_clamp=30.0, rms_norm_eps=1e-6, rope_theta=10000.0,
     rope_factor=64.0, rope_original_max_position=16, rope_beta_fast=32.0,
     rope_beta_slow=1.0, rope_mscale=1.0, rope_mscale_all_dim=1.0)
+
+# Qwen3-Next-80B-A3B-Instruct, from its public config.json: 48 layers, every
+# fourth gated attention and the others Gated DeltaNet, every layer sparse
+# (512 experts of width 512, top-10, softmax scores, one gated shared
+# expert).  ``intermediate_size`` is the config's; no layer is dense.
+QWEN3_NEXT_80B_A3B = TrunkSizes(
+    hidden_size=2048, num_hidden_layers=48, first_k_dense_replace=0,
+    intermediate_size=5120, n_routed_experts=512, moe_intermediate_size=512,
+    num_experts_per_tok=10, n_shared_experts=1, norm_topk_prob=True,
+    vocab_size=151936, full_attention_interval=4,
+    gated_attention=GatedAttentionSizes(
+        num_heads=16, num_kv_heads=2, head_dim=256, rotary_dim=64,
+        rope_theta=1e7),
+    # chunk 128, not the published code's 64: a C x C float32 array with
+    # C = 64 pads to the 128 lanes anyway, and the scan is half as deep
+    # (compiler, PR 31: 496 -> 437 GB a step under gdn/core, unpadded)
+    gated_delta=GatedDeltaSizes(
+        num_key_heads=16, num_value_heads=32, key_head_dim=128,
+        value_head_dim=128, conv_kernel=4, chunk=128),
+    scoring_func="softmax", shared_expert_gate=True, zero_centred_norm=True,
+    rms_norm_eps=1e-6)
+
+# The patterned trunk at test size (tests/test_hybrid_trunk.py): period 2.
+HYBRID_TINY = TrunkSizes(
+    hidden_size=32, num_hidden_layers=4, first_k_dense_replace=0,
+    intermediate_size=64, n_routed_experts=8, moe_intermediate_size=16,
+    num_experts_per_tok=3, n_shared_experts=1, norm_topk_prob=True,
+    vocab_size=128, full_attention_interval=2,
+    gated_attention=GatedAttentionSizes(
+        num_heads=4, num_kv_heads=2, head_dim=16, rotary_dim=8,
+        rope_theta=1e7, block=8),
+    gated_delta=GatedDeltaSizes(
+        num_key_heads=2, num_value_heads=4, key_head_dim=8,
+        value_head_dim=8, conv_kernel=4, chunk=8, group=2),
+    scoring_func="softmax", shared_expert_gate=True, zero_centred_norm=True,
+    rms_norm_eps=1e-6)

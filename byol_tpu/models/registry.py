@@ -106,8 +106,12 @@ except ImportError:  # pragma: no cover - vit module lands in a later commit
 def _register_decoder_trunks() -> None:
     from byol_tpu.models import decoder_trunk as trunk_lib
     # xing4_29b_a4b: huggingface.co/XingChen-AGI/Xing4.0-29B-A4B, config.json
+    # qwen3_next_80b_a3b:
+    # huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct, config.json
     for name, sizes in (("xing4_29b_a4b", trunk_lib.XING4_29B_A4B),
-                        ("decoder_trunk_tiny", trunk_lib.TINY)):
+                        ("decoder_trunk_tiny", trunk_lib.TINY),
+                        ("qwen3_next_80b_a3b", trunk_lib.QWEN3_NEXT_80B_A3B),
+                        ("hybrid_trunk_tiny", trunk_lib.HYBRID_TINY)):
         def factory(dtype=jnp.float32, small_inputs=False, _z=sizes,
                     layer_share="0/1", trunk_depth="", **kw):
             del small_inputs

@@ -733,6 +733,11 @@ def main() -> int:
     if a.phase == "multichip":
         return child_multichip(s, a.seed, a.cpu_rehearsal)
 
+    # the four-chip run keeps a directory of its own: tier-1 rehearses both
+    # side by side (two xdist workers), and each clears its directory first
+    global OUT
+    if a.chips == 4:
+        OUT += "_4"
     shutil.rmtree(OUT, ignore_errors=True)
     os.makedirs(OUT)
     t0 = time.monotonic()

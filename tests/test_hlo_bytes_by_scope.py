@@ -64,3 +64,58 @@ def test_traffic_goes_to_scopes_and_the_cheaper_branch():
     assert total == sum(into.values())
     # a fused computation's inside, a bitcast and a parameter move nothing
     assert {op[3] for op in ops} == {"%slice-done", "%mix", "%step"}
+
+
+LOOP = '''HloModule jit_scan
+
+%fused_slice (p0: bf16[64,8,128], p1: s32[]) -> bf16[8,128] {
+  %p0 = bf16[64,8,128]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %p1 = s32[]{:T(128)} parameter(1)
+  %zero = s32[]{:T(128)} constant(0)
+  %ds = bf16[1,8,128]{2,1,0:T(8,128)(2,1)} dynamic-slice(%p0, %p1, %zero, %zero), dynamic_slice_sizes={1,8,128}
+  ROOT %row = bf16[8,128]{1,0:T(8,128)(2,1)} bitcast(%ds)
+}
+
+%fused_update (p0: bf16[64,8,128], p1: bf16[8,128], p2: s32[]) -> bf16[64,8,128] {
+  %p0 = bf16[64,8,128]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %p1 = bf16[8,128]{1,0:T(8,128)(2,1)} parameter(1)
+  %p2 = s32[]{:T(128)} parameter(2)
+  %zero = s32[]{:T(128)} constant(0)
+  %one = bf16[1,8,128]{2,1,0:T(8,128)(2,1)} bitcast(%p1)
+  ROOT %dus = bf16[64,8,128]{2,1,0:T(8,128)(2,1)} dynamic-update-slice(%p0, %one, %p2, %zero, %zero)
+}
+
+%body (t: (s32[], bf16[64,8,128], bf16[64,8,128])) -> (s32[], bf16[64,8,128], bf16[64,8,128]) {
+  %t = (s32[]{:T(128)}, bf16[64,8,128]{2,1,0:T(8,128)(2,1)}, bf16[64,8,128]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%t), index=0
+  %xs = bf16[64,8,128]{2,1,0:T(8,128)(2,1)} get-tuple-element(%t), index=1
+  %ys = bf16[64,8,128]{2,1,0:T(8,128)(2,1)} get-tuple-element(%t), index=2
+  %x = bf16[8,128]{1,0:T(8,128)(2,1)} fusion(%xs, %i), kind=kLoop, calls=%fused_slice, metadata={op_name="jit(step)/layer0/gdn/core/while/body/dynamic_slice"}
+  %new = bf16[64,8,128]{2,1,0:T(8,128)(2,1)} fusion(%ys, %x, %i), kind=kLoop, calls=%fused_update, metadata={op_name="jit(step)/layer0/gdn/core/while/body/dynamic_update_slice"}
+  ROOT %out = (s32[]{:T(128)}, bf16[64,8,128]{2,1,0:T(8,128)(2,1)}, bf16[64,8,128]{2,1,0:T(8,128)(2,1)}) tuple(%i, %xs, %new)
+}
+
+%cond (t: (s32[], bf16[64,8,128], bf16[64,8,128])) -> pred[] {
+  %t = (s32[]{:T(128)}, bf16[64,8,128]{2,1,0:T(8,128)(2,1)}, bf16[64,8,128]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%t), index=0
+  %n = s32[]{:T(128)} constant(64)
+  ROOT %lt = pred[]{:T(512)} compare(%i, %n), direction=LT
+}
+
+ENTRY %main (a: (s32[], bf16[64,8,128], bf16[64,8,128])) -> (s32[], bf16[64,8,128], bf16[64,8,128]) {
+  %a = (s32[]{:T(128)}, bf16[64,8,128]{2,1,0:T(8,128)(2,1)}, bf16[64,8,128]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  ROOT %while.1 = (s32[]{:T(128)}, bf16[64,8,128]{2,1,0:T(8,128)(2,1)}, bf16[64,8,128]{2,1,0:T(8,128)(2,1)}) while(%a), condition=%cond, body=%body, metadata={op_name="jit(step)/layer0/gdn/core/while"}
+}
+'''
+
+
+def test_a_scan_counts_its_trips_and_its_slices_not_its_buffers():
+    """64 trips; each reads one row of the stacked input (and the index)
+    and writes one row of the stacked output in place: 64 x (row + 4 + row
+    read, row + row written), not 64 x the two 64-row buffers."""
+    comps = hlo_bytes.parse(LOOP)
+    into = collections.Counter()
+    total = hlo_bytes.count(comps, comps[None], None, into, [])
+    a_slice = ROW + 4 + ROW              # row and index read, row written
+    an_update = ROW + 4 + ROW            # row and index read, row written
+    assert total == into["gdn/core"] == 64 * (a_slice + an_update)

@@ -184,6 +184,53 @@ def test_expert_layer_combine_moves_no_relaid_out_copies(no_persistent_cache,
 # one whole ResNet-50 train step at batch 256 fits the chip
 # ---------------------------------------------------------------------------
 
+# ---------------------------------------------------------------------------
+# the patterned trunk's two mixers at the published head sizes and 4,096
+# tokens, forward and backward: attention that never writes [S, S]
+# (ops/attention.blockwise_causal_attention) and the delta rule in chunks
+# (models/gated_delta.py)
+# ---------------------------------------------------------------------------
+
+def test_blockwise_causal_attention_writes_no_square_at_4096(
+        no_persistent_cache, one_chip):
+    from byol_tpu.ops.attention import blockwise_causal_attention
+    seq = 4096
+    q = jax.ShapeDtypeStruct((1, 16, seq, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 2, seq, 256), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(jnp.square(blockwise_causal_attention(
+            q, k, v, block=512).astype(jnp.float32)))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()            # plain jax.numpy: no kernel
+    assert f"{seq},{seq}]" not in text            # no [.., S, S] anywhere
+    assert ",512,512]" in text                    # tiles of one block pair
+
+
+def test_chunked_delta_rule_scans_chunks_not_tokens(no_persistent_cache,
+                                                    one_chip):
+    from byol_tpu.models.gated_delta import chunked_delta_rule
+    seq, heads = 4096, 32
+    wide = jax.ShapeDtypeStruct((2, seq, heads, 128), jnp.bfloat16,
+                                sharding=one_chip)
+    gate = jax.ShapeDtypeStruct((2, seq, heads), jnp.float32,
+                                sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(jnp.square(chunked_delta_rule(
+            q, k, v, g, beta, chunk=64, dtype=jnp.bfloat16,
+            group=1).astype(jnp.float32)))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        wide, wide, wide, gate, gate).compile().as_text()
+    from scripts import hlo_bytes_by_scope
+    bounds = hlo_bytes_by_scope.loop_bounds(hlo_bytes_by_scope.parse(text))
+    # the chunk scan (64 trips, forward and backward) and the map over the
+    # two sequences; nothing counts to 4,096
+    assert bounds and max(bounds) == seq // 64
+
+
 def _compile_train_step(topo, rcfg, batch):
     """The jitted step (``--fuse-views``, bf16, LARS), built from the
     compile plan exactly as setup_training wires it, compiled for one
