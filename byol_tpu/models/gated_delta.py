@@ -18,12 +18,22 @@ value heads; the output RMS-normalised per head, gained, and gated by
 A chip never runs it token by token.  :func:`chunked_delta_rule` is the
 chunked (WY) form: inside a chunk of ``C`` tokens the rule is a unit lower
 triangular system — ``(I + L) [U | W] = [beta V | beta K e^gamma]`` with ``L
-= strict_tril(beta K K^T . decay)`` — whose inverse
-:func:`unit_lower_inverse` builds exactly (forward substitution on small
-diagonal blocks, then block elimination: nothing cancels where keys
-repeat, as a power series of ``L`` would); between chunks a ``lax.scan``
-carries the state, ``S / C`` trips of four products.  Everything is plain
-``jax.numpy``; the backward is autodiff's but for the inverse's.
+= strict_tril(beta K K^T . decay)`` — whose inverse is built exactly
+(forward substitution on small diagonal blocks, then block elimination:
+nothing cancels where keys repeat, as a power series of ``L`` would);
+between chunks a ``lax.scan`` carries the state, ``S / C`` trips of four
+products.
+
+One algorithm, two lowerings of its WITHIN-CHUNK stage (everything before
+the scan), chosen from what the code can see (``ops/delta_rule.applies``):
+where the program lowers for a TPU and ``chunk``, ``d_k``, ``d_v`` are
+multiples of 128 that fit VMEM, the kernels ``delta_wy_fwd`` /
+``delta_wy_bwd`` of ``ops/delta_rule.py`` — a chunk's ``C x C`` matrices
+live and die in VMEM, the keys are read per KEY head and never repeated;
+everywhere else (``HYBRID_TINY``, every CPU run) plain ``jax.numpy``,
+:func:`_chunked_rule` with :func:`unit_lower_inverse`, which is also the
+tests' oracle for the kernels.  The scan, the projections, the convolution
+and the gated norm are plain ``jax.numpy`` on both.
 
 Device-trace scopes, inside the layer's ``gdn``: ``proj``, ``conv``,
 ``core``, ``gate_norm``.
@@ -35,6 +45,8 @@ import dataclasses
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from byol_tpu.ops import delta_rule
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,11 +65,14 @@ class GatedDeltaSizes:
 
 
 HIGHEST = jax.lax.Precision.HIGHEST
-# Side of the diagonal blocks that forward substitution inverts.  The TPU
-# compiler's ``triangular_solve`` substitutes ROW BY ROW over every matrix
-# at once, a pass over all of them a row: at 128 x 128 it took 41.6 ms a
-# call, 999 ms of a 2,793 ms step (PERF.md section 6, PR 31); its cost
-# falls with the square of the side, and the matrix unit takes over above.
+# Side of the diagonal blocks that forward substitution inverts on the
+# ``jax.numpy`` path (the kernels have their own: ops/delta_rule.py).  The
+# TPU compiler's ``triangular_solve`` substitutes ROW BY ROW over every
+# matrix at once, a pass over all of them a row: at 128 x 128 it took 41.6
+# ms a call, 999 ms of a 2,793 ms step, at 32 x 32 8.3 ms, 200 ms of 2,334
+# (PERF.md section 6, PR 31) — which is why a TPU takes the kernels since
+# PR 32; its cost falls with the square of the side, and the matrix unit
+# takes over above.
 SUBSTITUTED = 32
 
 
@@ -130,9 +145,10 @@ def chunked_delta_rule(q, k, v, g, beta, *, chunk: int, dtype=jnp.float32,
                        group: int = 0):
     """The gated delta rule over whole sequences, in chunks.
 
-    ``q, k``: ``(B, S, H, d_k)`` (normalised and scaled by the caller, one
-    per VALUE head); ``v``: ``(B, S, H, d_v)``; ``g`` (log decay, <= 0) and
-    ``beta``: ``(B, S, H)`` float32.  Returns ``o (B, S, H, d_v)`` in
+    ``q, k``: ``(B, S, Hk, d_k)`` (normalised and scaled by the caller;
+    ``Hk`` divides ``H``: key head ``h // (H / Hk)`` serves value head
+    ``h``); ``v``: ``(B, S, H, d_v)``; ``g`` (log decay, <= 0) and ``beta``:
+    ``(B, S, H)`` float32.  Returns ``o (B, S, H, d_v)`` in
     ``dtype``.  Matrix products take operands in ``dtype`` and accumulate
     in float32; gates, the triangular inverse and the carried state are
     float32.  A sequence that ``chunk`` does not divide is padded with
@@ -140,29 +156,77 @@ def chunked_delta_rule(q, k, v, g, beta, *, chunk: int, dtype=jnp.float32,
 
     ``group`` > 0 works on that many sequences at a time (where it divides
     ``B``), each group under ``jax.checkpoint``: the rule's intermediates —
-    several ``(B, H, S / C, C, C)`` and ``(B, H, S, d)`` float32 arrays —
-    then exist for one group, in the forward and in the backward alike,
-    at the price of one more forward of the rule."""
-    b = q.shape[0]
+    on the ``jax.numpy`` path several ``(B, H, S / C, C, C)`` and ``(B, H,
+    S, d)`` float32 arrays, with the kernels the scan's operands and one
+    kept inverse — then exist for one group, in the forward and in the
+    backward alike, at the price of one more forward of the rule."""
+    b, s, hk, dk = q.shape
+    if delta_rule.applies(min(chunk, s), dk, v.shape[-1], dtype):
+        rule = _chunked_rule_kernels
+    else:
+        rule = _chunked_rule          # one q, k per VALUE head, repeated
+        if hk != v.shape[2]:          # out here, before the groups
+            q, k = (jnp.repeat(x, v.shape[2] // hk, axis=2) for x in (q, k))
     if not group or group >= b or b % group:
-        return _chunked_rule(q, k, v, g, beta, chunk, dtype)
+        return rule(q, k, v, g, beta, chunk, dtype)
     grouped = lambda x: x.reshape((b // group, group) + x.shape[1:])
     out = jax.lax.map(
-        jax.checkpoint(lambda xs: _chunked_rule(*xs, chunk, dtype)),
+        jax.checkpoint(lambda xs: rule(*xs, chunk, dtype)),
         tuple(grouped(x) for x in (q, k, v, g, beta)))
     return out.reshape((b,) + out.shape[2:])
+
+
+def _pad_to_chunks(arrays, s, c):
+    """Tokens that write nothing (``beta = 0, g = 0``) up to a whole chunk."""
+    pad = -s % c
+    if not pad:
+        return arrays
+    return tuple(jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                 for x in arrays)
+
+
+def _chunk_step(dtype):
+    """One trip of the scan over chunk states: ``(state (B, H, d_k, d_v)
+    float32, one chunk's (u, w, within, q_in, k_out, carry_decay)) ->
+    (state, out (B, H, C, d_v))``; the products' operands in ``dtype``."""
+    mm = lambda spec, x, y: jnp.einsum(
+        spec, x.astype(dtype), y.astype(dtype),
+        preferred_element_type=jnp.float32)
+
+    def step(state, chunk_inputs):
+        u_i, w_i, within_i, q_i, k_i, decay_i = chunk_inputs
+        held = state.astype(dtype)
+        delta = u_i - mm("bhcd,bhde->bhce", w_i, held)
+        out = mm("bhcd,bhde->bhce", q_i, held) \
+            + mm("bhij,bhje->bhie", within_i, delta)
+        state = state * decay_i + mm("bhcd,bhce->bhde", k_i, delta)
+        return state, out.astype(dtype)
+
+    return step
+
+
+def _chunked_rule_kernels(q, k, v, g, beta, chunk, dtype):
+    """The rule with its within-chunk stage as the kernels of
+    ``ops/delta_rule.py``: ``q, k`` one per KEY head, no ``(.., C, C)``
+    float32 array outside them; the scan is ``_chunked_rule``'s."""
+    b, s, h, dv = v.shape
+    c = min(chunk, s)
+    q, k, v, g, beta = _pad_to_chunks((q, k, v, g, beta), s, c)
+    u, w, within, q_in, k_out, gamma = delta_rule.within_chunk(
+        q, k, v, g, beta, chunk=c, dtype=dtype)
+    _, out = jax.lax.scan(
+        _chunk_step(dtype), jnp.zeros((b, h, k.shape[-1], dv), jnp.float32),
+        (u, w, within, q_in, k_out, jnp.exp(gamma[..., -1:])))
+    # (N, B, H, C, d_v) -> (B, S, H, d_v)
+    return jnp.moveaxis(out, (0, 3), (1, 2)).reshape(b, -1, h, dv)[:, :s]
 
 
 def _chunked_rule(q, k, v, g, beta, chunk, dtype):
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     c = min(chunk, s)
-    pad = -s % c
-    if pad:
-        tail = lambda x: jnp.pad(
-            x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        q, k, v, g, beta = (tail(x) for x in (q, k, v, g, beta))
-    n = (s + pad) // c
+    q, k, v, g, beta = _pad_to_chunks((q, k, v, g, beta), s, c)
+    n = q.shape[1] // c
     # (B, H, N, C, d): a chunk's tokens and a head's width on the minor axes
     split = lambda x: x.reshape((b, n, c) + x.shape[2:])
     heads = lambda x: jnp.moveaxis(split(x), 3, 1)
@@ -197,19 +261,10 @@ def _chunked_rule(q, k, v, g, beta, chunk, dtype):
     k_out = k * jnp.exp(total - gamma)[..., None]        # writes the outgoing state
     carry_decay = jnp.exp(total)[..., None]              # (B, H, N, 1, 1)
 
-    def step(state, chunk_inputs):
-        u_i, w_i, within_i, q_i, k_i, decay_i = chunk_inputs
-        held = state.astype(dtype)
-        delta = u_i - mm("bhcd,bhde->bhce", w_i, held)
-        out = mm("bhcd,bhde->bhce", q_i, held) \
-            + mm("bhij,bhje->bhie", within_i, delta)
-        state = state * decay_i + mm("bhcd,bhce->bhde", k_i, delta)
-        return state, out.astype(dtype)
-
     # scan over N; the products' operands are rounded once, out here
     per_chunk = lambda x, kind: jnp.moveaxis(x.astype(kind), 2, 0)
     _, out = jax.lax.scan(
-        step, jnp.zeros((b, h, dk, dv), jnp.float32),
+        _chunk_step(dtype), jnp.zeros((b, h, dk, dv), jnp.float32),
         (per_chunk(u, jnp.float32),) + tuple(
             per_chunk(x, dtype) for x in (w, within, q_in, k_out))
         + (per_chunk(carry_decay, jnp.float32),))
@@ -273,9 +328,8 @@ class GatedDeltaNet(nn.Module):
             beta = jax.nn.sigmoid(b_.astype(jnp.float32))
             g = -jnp.exp(a_log) * jax.nn.softplus(
                 a_.astype(jnp.float32) + dt_bias)
-            out = chunked_delta_rule(
-                jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), v, g,
-                beta, chunk=z.chunk, dtype=dt, group=z.group)
+            out = chunked_delta_rule(q, k, v, g, beta, chunk=z.chunk,
+                                     dtype=dt, group=z.group)
         with jax.named_scope("gate_norm"):
             gain = self.param("scale", nn.initializers.ones, (dv,),
                               jnp.float32)

@@ -10,6 +10,10 @@ call sites name the capability, not the file:
 - :func:`packed_self_attention` — whole-sequence softmax attention over the
   packed ``qkv``, forward and backward, for sequences that fit VMEM (what
   ``attn_impl='dense'`` runs on a TPU at ViT-B/16's 197 tokens).
+- :mod:`byol_tpu.ops.delta_rule` (``within_chunk``, ``applies``) — the
+  chunked gated delta rule's within-chunk stage (a ``C x C`` unit
+  triangular system a chunk, solved in VMEM), forward and backward: what
+  ``models/gated_delta.py`` runs on a TPU at chunks and heads of 128.
 - :func:`fused_two_view` — the fused uint8→two-view augmentation
   (``--fused-augment on``): one VMEM pass per image for
   convert/crop/flip/jitter/grayscale, blur as an MXU conv on the output.

@@ -1,0 +1,134 @@
+"""The within-chunk kernels of the gated delta rule (ops/delta_rule.py)
+against the ``jax.numpy`` path of ``models/gated_delta.py``, on the CPU under
+the Pallas interpreter: ``chunked_delta_rule`` chooses the kernels from the
+backend and the shapes, so the tests answer ``delta_rule.applies`` for it
+and run the same kernel bodies at sizes the interpreter is quick at.
+
+Tolerances.  In float32 the two paths are the same equations in another
+order of sums (the inverse by doubling from blocks of 2 against
+substitution on blocks of 32; cumulative sums as products): a few float32
+roundings.  In bfloat16 both round the same operands of the same products;
+what differs is where a cotangent is rounded (the kernel adds the parts of
+``dq``, ``dk`` in float32 and rounds once): a bfloat16 rounding of the
+gradient's norm.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from byol_tpu.models import gated_delta
+from byol_tpu.ops import delta_rule
+
+NAMES = "q k v g beta".split()
+
+
+@pytest.fixture(autouse=True)
+def _float32_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _inputs(seq, *, batch=2, key_heads=2, shared=1, dk=8, dv=16, seed=0,
+            dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    k = f(batch, seq, key_heads, dk)
+    heads = key_heads * shared
+    return ((f(batch, seq, key_heads, dk) * dk ** -0.5).astype(dtype),
+            (k / jnp.linalg.norm(k, axis=-1, keepdims=True)).astype(dtype),
+            f(batch, seq, heads, dv).astype(dtype),
+            -jnp.asarray(rng.uniform(0, 2, (batch, seq, heads)), jnp.float32),
+            jnp.asarray(rng.uniform(0, 1, (batch, seq, heads)), jnp.float32))
+
+
+def _value_and_grads(monkeypatch, kernels, inputs, **kw):
+    monkeypatch.setattr(delta_rule, "applies", lambda *a, **k: kernels)
+    rule = lambda *a: gated_delta.chunked_delta_rule(*a, **kw)
+    loss = lambda *a: jnp.sum(jnp.sin(rule(*a).astype(jnp.float32)))
+    return rule(*inputs), jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*inputs)
+
+
+# relative to the norm: of the output, of each gradient
+TOLERANCE = {"float32": (1e-5, 1e-5), "bfloat16": (4e-3, 1.5e-2)}
+
+
+def _assert_both_paths_agree(monkeypatch, inputs, **kw):
+    want, want_grads = _value_and_grads(monkeypatch, False, inputs, **kw)
+    got, grads = _value_and_grads(monkeypatch, True, inputs, **kw)
+    out_tol, grad_tol = TOLERANCE[jnp.dtype(kw.get("dtype", "float32")).name]
+    f32 = lambda x: x.astype(jnp.float32)
+    gap = lambda a, b: float(jnp.linalg.norm(f32(a) - f32(b)))
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert gap(got, want) <= out_tol * float(jnp.linalg.norm(f32(want)))
+    for name, g, w in zip(NAMES, grads, want_grads):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert gap(g, w) <= grad_tol * float(jnp.linalg.norm(f32(w))), name
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("group", [0, 2])
+@pytest.mark.parametrize("shared", [1, 2])
+@pytest.mark.parametrize("seq,chunk", [
+    (16, 16),       # one chunk a sequence
+    (64, 16),       # four
+    (40, 16)])      # the last chunk is padded
+def test_the_kernels_are_the_jnp_path(monkeypatch, seq, chunk, shared, group,
+                                      dtype):
+    inputs = _inputs(seq, batch=4, shared=shared, seed=seq + shared + group,
+                     dtype=jnp.dtype(dtype))
+    _assert_both_paths_agree(monkeypatch, inputs, chunk=chunk, group=group,
+                             dtype=jnp.dtype(dtype))
+
+
+def test_the_kernels_at_the_published_tile(monkeypatch):
+    """Chunk 128, heads of 128, two value heads a key head, bfloat16: the
+    shapes ``supported`` asks for, through the interpreter once."""
+    inputs = _inputs(256, batch=1, key_heads=1, shared=2, dk=128, dv=128,
+                     seed=5, dtype=jnp.bfloat16)
+    assert delta_rule.supported(128, 128, 128)
+    _assert_both_paths_agree(monkeypatch, inputs, chunk=128,
+                             dtype=jnp.bfloat16)
+
+
+def test_the_float32_parts_leave_the_kernel_in_float32():
+    """``u`` and ``gamma`` float32, the scan's other operands in ``dtype``,
+    chunk-major; the keys are read per KEY head, never repeated."""
+    q, k, v, g, beta = _inputs(32, shared=2, dtype=jnp.bfloat16)
+    u, w, within, q_in, k_out, gamma = delta_rule.within_chunk(
+        q, k, v, g, beta, chunk=16, dtype=jnp.bfloat16)
+    assert u.shape == (2, 2, 4, 16, 16) and u.dtype == jnp.float32
+    assert gamma.shape == (2, 2, 4, 1, 16) and gamma.dtype == jnp.float32
+    assert within.shape == (2, 2, 4, 16, 16)
+    for x in (w, within, q_in, k_out):
+        assert x.dtype == jnp.bfloat16
+    want = jnp.cumsum(g.reshape(2, 2, 16, 4), axis=2)     # (B, N, C, H)
+    np.testing.assert_allclose(gamma[:, :, :, 0], jnp.moveaxis(
+        want, (1, 3), (0, 2)), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("chunk,dk,dv,dtype,backend,taken", [
+    (128, 128, 128, "bfloat16", "tpu", True),     # the published sizes
+    (128, 128, 256, "float32", "tpu", True),
+    (128, 128, 128, "bfloat16", "cpu", False),    # not lowered for a TPU
+    (8, 8, 8, "float32", "tpu", False),           # HYBRID_TINY
+    (64, 128, 128, "bfloat16", "tpu", False),     # half a lane tile of tokens
+    (128, 96, 128, "bfloat16", "tpu", False),
+    (256, 128, 128, "bfloat16", "tpu", True),
+    (512, 128, 128, "bfloat16", "tpu", False),    # the squares outgrow VMEM
+])
+def test_the_kernels_are_chosen_from_backend_and_shapes(chunk, dk, dv, dtype,
+                                                        backend, taken):
+    assert delta_rule.applies(chunk, dk, dv, jnp.dtype(dtype),
+                              backend=backend) is taken
+
+
+def test_on_the_cpu_the_rule_lowers_to_no_kernel():
+    """What tier-1 and every CPU run of ``train.py`` take: today's
+    ``jax.numpy`` program, whatever the shapes."""
+    inputs = _inputs(256, batch=1, key_heads=1, dk=128, dv=128,
+                     dtype=jnp.bfloat16)
+    text = jax.jit(lambda *a: gated_delta.chunked_delta_rule(
+        *a, chunk=128, dtype=jnp.bfloat16)).lower(*inputs).as_text()
+    assert "delta_wy" not in text
+    assert "lapack_strsm" in text             # solve_triangular, on the CPU

@@ -29,6 +29,7 @@ from benchmarks.lib import weights_hybrid_trunk
 from byol_tpu.core import config as config_lib
 from byol_tpu.models import decoder_trunk as trunk_lib
 from byol_tpu.models import gated_delta
+from byol_tpu.ops import delta_rule
 from byol_tpu.ops.attention import (blockwise_causal_attention,
                                     dense_attention)
 from byol_tpu.optim import lars as lars_lib
@@ -107,6 +108,17 @@ def _rule_inputs(seq, seed=0, batch=2, heads=3, dk=8, dv=6):
             jnp.asarray(rng.uniform(0, 1, (batch, seq, heads)), jnp.float32))
 
 
+@pytest.fixture(params=["jnp", "kernels"])
+def rule_path(request, monkeypatch):
+    """The rule's two lowerings: the ``jax.numpy`` path (what a CPU takes)
+    and the within-chunk kernels of ops/delta_rule.py under the Pallas
+    interpreter (``chunked_delta_rule`` chooses them from backend and
+    shapes: the test answers for it)."""
+    monkeypatch.setattr(delta_rule, "applies",
+                        lambda *a, **k: request.param == "kernels")
+    return request.param
+
+
 def _per_token(*inputs):
     return jnp.stack([reference.delta_recurrence(*(x[i] for x in inputs),
                                                  "float32")
@@ -117,7 +129,7 @@ def _per_token(*inputs):
     (24, 8), (24, 4), (20, 8),      # 20: the last chunk is padded
     (24, 24), (24, 64),             # the whole sequence is one chunk
     (96, 32)])
-def test_the_chunked_rule_is_the_per_token_recurrence(seq, chunk):
+def test_the_chunked_rule_is_the_per_token_recurrence(seq, chunk, rule_path):
     inputs = _rule_inputs(seq, seed=seq + chunk)
     got = gated_delta.chunked_delta_rule(*inputs, chunk=chunk)
     want = _per_token(*inputs)
@@ -131,7 +143,7 @@ def test_the_chunked_rule_is_the_per_token_recurrence(seq, chunk):
             jnp.linalg.norm(w)), name
 
 
-def test_the_rule_in_groups_of_sequences_is_the_rule():
+def test_the_rule_in_groups_of_sequences_is_the_rule(rule_path):
     inputs = _rule_inputs(24, seed=7, batch=4)
     loss = lambda **kw: lambda *a: jnp.sum(jnp.sin(
         gated_delta.chunked_delta_rule(*a, chunk=8, **kw)))
@@ -172,7 +184,8 @@ def test_the_triangular_inverse_and_its_backward(side):
     np.testing.assert_allclose(grad, want, rtol=1e-4, atol=1e-4)
 
 
-def test_the_rule_is_exact_where_a_power_series_of_the_system_cancels():
+def test_the_rule_is_exact_where_a_power_series_of_the_system_cancels(
+        rule_path):
     """One key repeated through the chunk, no decay, beta = 1: the system's
     strict lower triangle is all ones, whose powers grow like binomials
     (to 1e18 at 64) while its inverse is bidiagonal; forward substitution

@@ -231,6 +231,69 @@ def test_chunked_delta_rule_scans_chunks_not_tokens(no_persistent_cache,
     assert bounds and max(bounds) == seq // 64
 
 
+def _rule_text(one_chip, monkeypatch, *, backend, chunk=128, heads=32,
+               key_heads=16, dk=128, dv=128, seq=4096):
+    """The rule, forward and backward, compiled for the described v5e as
+    ``backend`` would lower it (``chunked_delta_rule`` and the kernels'
+    ``interpret`` default both ask ``jax.default_backend()``)."""
+    from byol_tpu.models.gated_delta import chunked_delta_rule
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    like = lambda *shape, kind=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, kind, sharding=one_chip)
+    gate = like(2, seq, heads, kind=jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(jnp.square(chunked_delta_rule(
+            q, k, v, g, beta, chunk=chunk, dtype=jnp.bfloat16,
+            group=1).astype(jnp.float32)))
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        like(2, seq, key_heads, dk), like(2, seq, key_heads, dk),
+        like(2, seq, heads, dv), gate, gate).compile().as_text()
+
+
+def test_delta_rule_kernels_at_the_published_sizes(no_persistent_cache,
+                                                   one_chip, monkeypatch):
+    """``[2, 4096, 32, 128]`` values on 16 key heads, chunk 128, bf16: the
+    within-chunk stage is ``delta_wy_fwd`` (the forward, and again under the
+    rule's own checkpoint) and ``delta_wy_bwd``; no triangular solve."""
+    text = _rule_text(one_chip, monkeypatch, backend="tpu")
+    assert text.count("delta_wy_fwd") >= 2 and "delta_wy_bwd" in text
+    assert "InvertDiagBlocks" not in text
+    assert "triangular" not in text.lower()
+
+
+def test_delta_rule_keeps_its_squares_in_the_kernels(no_persistent_cache,
+                                                     one_chip, monkeypatch):
+    """Value heads of 256, so that a float32 ``[.., 128, 128]`` can only be
+    a chunk's ``C x C`` matrix: whole ``[N, B, H, C, C]`` float32 arrays
+    are the kernels' own (the inverse, kept from the forward for the
+    backward) and nothing else computes on one."""
+    import re
+    text = _rule_text(one_chip, monkeypatch, backend="tpu", heads=8,
+                      key_heads=4, dv=256)
+    square = re.compile(r"f32\[\d+,\d+,\d+,128,128\]")
+    plumbing = re.compile(
+        r" (get-tuple-element|tuple|parameter|bitcast|while|conditional)\(")
+    held = [line for line in text.splitlines()
+            if " = " in line and square.search(line)
+            and "delta_wy" not in line and not plumbing.search(line)]
+    assert not held, held[:3]
+    assert "delta_wy_bwd" in text
+
+
+@pytest.mark.parametrize("backend,sizes", [
+    ("cpu", {}),                                  # not lowered for a TPU
+    ("tpu", dict(chunk=8, heads=4, key_heads=2, dk=8, dv=8, seq=64)),
+    ("tpu", dict(chunk=64)),                      # half a tile of tokens
+])
+def test_delta_rule_falls_back_to_jax_numpy(no_persistent_cache, one_chip,
+                                            monkeypatch, backend, sizes):
+    """``HYBRID_TINY``'s sizes (chunk 8, heads of 8) and a lowering for
+    another backend take the ``jax.numpy`` path: no kernel in the text."""
+    text = _rule_text(one_chip, monkeypatch, backend=backend, **sizes)
+    assert "delta_wy" not in text and "tpu_custom_call" not in text
+
+
 def _compile_train_step(topo, rcfg, batch):
     """The jitted step (``--fuse-views``, bf16, LARS), built from the
     compile plan exactly as setup_training wires it, compiled for one
