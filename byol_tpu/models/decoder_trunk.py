@@ -34,7 +34,15 @@ travels.  Mechanisms nothing else in ``models/`` has:
   (models/gated_delta.py: a recurrence over the sequence, run in chunks)
   otherwise; with it come zero-centred norm gains (``x^ (1 + w)``), a
   softmax router without selection bias, and a shared expert behind a
-  sigmoid gate.
+  sigmoid gate;
+- **sparse attention behind a learned indexer** (:class:`SparseAttention`,
+  every layer of a trunk whose sizes carry ``sparse_attention``):
+  grouped-query softmax attention whose softmax runs over a per-query SET
+  of keys — the ``topk`` causal keys a second, small scorer ranks highest
+  (ops/key_selection.py) — and which sows a loss of its own, the KL that
+  teaches the scorer the core's attention (``LAYER_LOSS``; the train step
+  adds whatever a layer sows there); with it an expert layer WITHOUT a
+  shared expert.
 
 :class:`LayerShare` states ONCE which of the ``of`` chips that share a layer
 this one is; heads, experts and vocabulary rows held follow from it — and
@@ -44,13 +52,16 @@ over 8 of 16, heads over none), it says so there too.
 Device-trace scopes (``DecoderTrunk.trace_scopes``; ``TRACE_SCOPES`` for a
 latent-attention trunk, ``HYBRID_SCOPES`` for a patterned one: ``gdn`` with
 ``proj``, ``conv``, ``core``, ``gate_norm``; ``gqa`` with ``core``; the
-``moe`` scopes): ``mla``, ``moe/route``,
+``moe`` scopes; ``SPARSE_SCOPES`` for a sparse-attention one: ``dsa`` with
+``index``, ``select``, ``core``, ``index_loss``): ``mla``, ``moe/route``,
 ``moe/experts`` (and in it ``combine``: the sum of a token's copies, forward
 and as the dispatch's backward), ``moe/shared``, ``mhc`` (and ``ffn`` for a
 leading dense layer) inside every layer; the train step stamps them beside
 its phases so the compile cache keys them (training/steps.py).  Each routing
 layer sows ``[rows held, largest load, mean load, rows dropped]`` into the
-``ROUTING`` collection; the train step sums them over layers.
+``ROUTING`` collection; the train step sums them over layers.  A
+sparse-attention layer sows ``[causal pairs, selected pairs]`` into
+``SELECTION`` beside them.
 """
 from __future__ import annotations
 
@@ -65,19 +76,26 @@ import jax.numpy as jnp
 
 from byol_tpu.core import remat as remat_lib
 from byol_tpu.models.gated_delta import GatedDeltaNet, GatedDeltaSizes
+from byol_tpu.ops import key_selection
 from byol_tpu.ops.attention import (blockwise_causal_attention,
-                                    dense_attention)
+                                    dense_attention, kept_probabilities,
+                                    selected_attention)
 
 _MOE_SCOPES = ("moe/route", "moe/experts", "moe/experts/combine",
                "moe/shared")
 TRACE_SCOPES = ("mla",) + _MOE_SCOPES + ("mhc", "ffn")
 HYBRID_SCOPES = ("gdn", "gdn/proj", "gdn/conv", "gdn/core", "gdn/gate_norm",
                  "gqa", "gqa/core") + _MOE_SCOPES
+SPARSE_SCOPES = ("dsa", "dsa/index", "dsa/select", "dsa/core",
+                 "dsa/index_loss") + _MOE_SCOPES
 # the expert layer's fallback (a step whose load passes twice the nominal
-# one) forms its rows whole up to this size and in slabs beyond it
+# one) forms its rows whole under this size and in slabs from it on
 WHOLE_FALLBACK_BYTES = 1 << 30
 ROUTING = "routing"                  # flax collection of the routing counters
 ROUTING_FIELDS = ("rows_held", "load_max", "load_mean", "rows_dropped")
+SELECTION = "selection"              # ... of the key-selection counters
+SELECTION_FIELDS = ("causal_pairs", "selected_pairs")
+LAYER_LOSS = "layer_loss"            # ... of the scalar losses layers add
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,6 +108,20 @@ class GatedAttentionSizes:
     rotary_dim: int                  # leading dims of a head that rotate
     rope_theta: float
     block: int = 512                 # the program's own: keys a block
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseAttentionSizes:
+    """One grouped-query attention layer behind an indexer."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_theta: float
+    index_heads: int
+    index_head_dim: int              # of the indexer's ONE key head too
+    topk: int                        # keys a query keeps
+    block: int = 512                 # q_chunk_size = kv_chunk_size
 
 
 @dataclasses.dataclass(frozen=True, kw_only=True)
@@ -124,6 +156,8 @@ class TrunkSizes:
     full_attention_interval: int = 0
     gated_attention: Optional[GatedAttentionSizes] = None
     gated_delta: Optional[GatedDeltaSizes] = None
+    # sparse attention behind an indexer, every layer (no pattern)
+    sparse_attention: Optional[SparseAttentionSizes] = None
     scoring_func: str = "sigmoid"    # 'sigmoid' (noaux_tc bias) | 'softmax'
     shared_expert_gate: bool = False     # sigmoid(x w_s) on the shared expert
     zero_centred_norm: bool = False      # gains are 1 + w, w from zeros
@@ -142,6 +176,8 @@ class TrunkSizes:
 
     def mixer(self, layer: int) -> str:
         """The token mixer of layer ``layer``: its scope's name."""
+        if self.sparse_attention is not None:
+            return "dsa"
         if not self.full_attention_interval:
             return "mla"
         return "gqa" if (layer + 1) % self.full_attention_interval == 0 \
@@ -387,6 +423,70 @@ class GatedAttention(nn.Module):
         return _dense(d, dt, "o")(out.reshape(b, s, self.heads * dh))
 
 
+class SparseAttention(nn.Module):
+    """Grouped-query softmax attention over the keys an indexer picks
+    (Keye-VL-2.0's ``sa_config``; the indexer as the DeepSeek-V3.2-Exp
+    report describes it).  ``q, k`` heads RMS-normalised with a gain, rotary
+    over the whole head.  The indexer reads ``stop_gradient(h)``: ``J`` small
+    query heads and a weight each against ONE key head, ``I[t, s] = sum_j
+    w[t, j] relu(qI[t, j] . kI[s]) / sqrt(d_I J)``, rotary over its whole
+    head too.  Query ``t`` attends the ``min(t + 1, topk)`` causal keys of
+    largest ``I[t, .]``, all heads the same set, and the layer sows ``mean_t
+    KL(p_t || softmax_{S_t} I[t, .])`` with ``p_t`` the core's head-mean
+    probabilities under stop-gradient — so the trunk takes no gradient from
+    that loss and the indexer none from any other."""
+
+    sizes: SparseAttentionSizes
+    eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        z, dt = self.sizes, self.dtype
+        b, s, d = h.shape
+        dh, di, blk = z.head_dim, z.index_head_dim, z.block
+        heads = lambda x, n: x.reshape(b, s, n, x.shape[-1] // n)
+        norm = lambda name: RMSNorm(self.eps, dt, name=name)
+        # the block passes below take whole blocks: a last, short one is
+        # filled with rows after every real one, which no real query sees
+        whole = lambda x: jnp.pad(
+            x, [(0, 0), (0, -s % blk)] + [(0, 0)] * (x.ndim - 2))
+        cos, sin = half_rotary_tables(z.rope_theta, dh, s)
+        q = heads(_dense(z.num_heads * dh, dt, "q")(h), z.num_heads)
+        k = heads(_dense(z.num_kv_heads * dh, dt, "k")(h), z.num_kv_heads)
+        v = heads(_dense(z.num_kv_heads * dh, dt, "v")(h), z.num_kv_heads)
+        q, k, v = (whole(x).transpose(0, 2, 1, 3) for x in (
+            apply_half_rotary(norm("q_norm")(q), cos, sin),
+            apply_half_rotary(norm("k_norm")(k), cos, sin), v))
+        with jax.named_scope("index"):
+            seen = jax.lax.stop_gradient(h)
+            cos_i, sin_i = half_rotary_tables(z.rope_theta, di, s)
+            q_i = apply_half_rotary(heads(_dense(
+                z.index_heads * di, dt, "index_q")(seen), z.index_heads),
+                cos_i, sin_i)
+            k_i = apply_half_rotary(heads(_dense(
+                di, dt, "index_k")(seen), 1), cos_i, sin_i)[:, :, 0]
+            w = _dense(z.index_heads, dt, "index_w")(seen)
+            scores = key_selection.index_scores(
+                whole(q_i), whole(k_i), whole(w), block=blk,
+                scale=(di * z.index_heads) ** -0.5)
+        with jax.named_scope("select"):
+            selected = key_selection.select_top_keys(scores, z.topk,
+                                                     block=blk)
+            self.sow(SELECTION, "pairs",
+                     key_selection.pair_counts(selected, s))
+        with jax.named_scope("core"):
+            out, lse = selected_attention(q, k, v, selected,
+                                          scale=dh ** -0.5, block=blk)
+        with jax.named_scope("index_loss"):
+            self.sow(LAYER_LOSS, "index", key_selection.index_loss(
+                scores, kept_probabilities(
+                    *jax.lax.stop_gradient((q, k, lse)), selected,
+                    scale=dh ** -0.5, block=blk), selected, s))
+        out = out[:, :, :s].transpose(0, 2, 1, 3)
+        return _dense(d, dt, "o")(out.reshape(b, s, z.num_heads * dh))
+
+
 class GatedMLP(nn.Module):
     """SwiGLU: ``down(silu(gate x) * up x)``."""
 
@@ -597,12 +697,13 @@ class ExpertLayer(nn.Module):
             # the same product over ALL ``tokens x k`` copies: no capacity,
             # no dropped row, and the common step does not pay for the
             # worst one.  Where one ``(every, D)`` array of that fallback
-            # would pass ``WHOLE_FALLBACK_BYTES`` (a 512-way router's 327,680
-            # copies of 2,048: the branch not taken would hold 4.5 GB of
-            # the step's memory) it runs in slabs of the usual size.
+            # would reach ``WHOLE_FALLBACK_BYTES`` (a 128-way router's 262,144
+            # copies of 2,048, a 512-way router's 327,680: the branch not
+            # taken would hold 4 to 4.5 GB of the step's memory) it runs in
+            # slabs of the usual size.
             usual = min(every, -(-2 * every * self.held
                                  // z.n_routed_experts))
-            whole = every * d * jnp.dtype(dt).itemsize <= WHOLE_FALLBACK_BYTES
+            whole = every * d * jnp.dtype(dt).itemsize < WHOLE_FALLBACK_BYTES
             if usual == every:
                 routed = product(every)
             else:
@@ -610,16 +711,22 @@ class ExpertLayer(nn.Module):
                     rows_held <= usual, lambda: product(usual),
                     (lambda: product(every)) if whole
                     else (lambda: in_slabs(usual)))
-        with jax.named_scope("shared"):
-            shared = GatedMLP(f * z.n_shared_experts, dt, name="shared")(x)
-            if z.shared_expert_gate:
-                shared = shared * jax.nn.sigmoid(_dense(
-                    1, dt, "shared_gate")(x).astype(jnp.float32)).astype(dt)
+        shared = None
+        if z.n_shared_experts:                  # a trunk may have none
+            with jax.named_scope("shared"):
+                shared = GatedMLP(f * z.n_shared_experts, dt,
+                                  name="shared")(x)
+                if z.shared_expert_gate:
+                    shared = shared * jax.nn.sigmoid(_dense(
+                        1, dt, "shared_gate")(x).astype(
+                            jnp.float32)).astype(dt)
         load = group_sizes.astype(jnp.float32)
         self.sow(ROUTING, "stats", jnp.stack([
             rows_held.astype(jnp.float32), jnp.max(load), jnp.mean(load),
             (jnp.sum(here) - rows_held).astype(jnp.float32)]))
-        return (routed + shared).reshape(b, s, d)
+        if shared is not None:
+            routed = routed + shared
+        return routed.reshape(b, s, d)
 
 
 class HyperConnection(nn.Module):
@@ -723,8 +830,8 @@ class TrunkLayer(nn.Module):
                 return _write_streams(streams, h_res, h_post, y)
 
         def attention(x):
-            # ``gdn`` and ``gqa`` are modules named after their scope, as
-            # ``moe`` is
+            # ``gdn``, ``gqa`` and ``dsa`` are modules named after their
+            # scope, as ``moe`` is
             if self.mixer == "gdn":
                 d = z.gated_delta
                 return GatedDeltaNet(
@@ -735,6 +842,9 @@ class TrunkLayer(nn.Module):
                 return GatedAttention(
                     a, heads(a.num_heads), heads(a.num_kv_heads),
                     z.rms_norm_eps, dt, name="gqa")(x)
+            if self.mixer == "dsa":
+                return SparseAttention(z.sparse_attention, z.rms_norm_eps,
+                                       dt, name="dsa")(x)
             with jax.named_scope("mla"):
                 return LatentAttention(z, heads(z.num_attention_heads), dt,
                                        name="attn")(x)
@@ -763,6 +873,8 @@ class DecoderTrunk(nn.Module):
 
     @property
     def trace_scopes(self) -> Tuple[str, ...]:
+        if self.sizes.sparse_attention is not None:
+            return SPARSE_SCOPES
         return HYBRID_SCOPES if self.sizes.full_attention_interval \
             else TRACE_SCOPES
 
@@ -865,3 +977,32 @@ HYBRID_TINY = TrunkSizes(
         value_head_dim=8, conv_kernel=4, chunk=8, group=2),
     scoring_func="softmax", shared_expert_gate=True, zero_centred_norm=True,
     rms_norm_eps=1e-6)
+
+# Keye-VL-2.0-30B-A3B's language model, from its public config.json
+# (``text_config`` and ``sa_config``): 48 layers, every one grouped-query
+# attention (32 query on 4 key/value heads of 128, rotary theta 1e7) behind
+# an indexer (16 heads of 64 on one key head, 2,048 keys a query, blocks of
+# 512) and sparse (128 experts of width 768, top-8, softmax scores, NO
+# shared expert).  The vision tower belongs to image input and is not built.
+KEYE_VL2_30B_A3B = TrunkSizes(
+    hidden_size=2048, num_hidden_layers=48, first_k_dense_replace=0,
+    intermediate_size=6144, n_routed_experts=128, moe_intermediate_size=768,
+    num_experts_per_tok=8, n_shared_experts=0, norm_topk_prob=True,
+    vocab_size=151936,
+    sparse_attention=SparseAttentionSizes(
+        num_heads=32, num_kv_heads=4, head_dim=128, rope_theta=1e7,
+        index_heads=16, index_head_dim=64, topk=2048, block=512),
+    scoring_func="softmax", rms_norm_eps=1e-6)
+
+# The sparse-attention trunk at test size (tests/test_sparse_trunk.py): at
+# 20 tokens three blocks a row, the last one short, and ``topk`` inside the
+# first block, so that a block's rows keep different numbers of keys.
+SPARSE_TINY = TrunkSizes(
+    hidden_size=32, num_hidden_layers=2, first_k_dense_replace=0,
+    intermediate_size=64, n_routed_experts=8, moe_intermediate_size=16,
+    num_experts_per_tok=3, n_shared_experts=0, norm_topk_prob=True,
+    vocab_size=128,
+    sparse_attention=SparseAttentionSizes(
+        num_heads=4, num_kv_heads=2, head_dim=16, rope_theta=1e7,
+        index_heads=2, index_head_dim=8, topk=6, block=8),
+    scoring_func="softmax", rms_norm_eps=1e-6)

@@ -67,7 +67,9 @@ class MetricAccumulator:
 def epoch_log_line(prefix: str, epoch: int, num_samples: int,
                    elapsed_s: float, metrics: Dict[str, Any]) -> str:
     """The reference's one-line epoch summary (main.py:638-643):
-    prefix, epoch, samples, seconds, loss, top1/top5."""
+    prefix, epoch, samples, seconds, loss, top1/top5; and, where a
+    backbone's layers add a loss of their own to the step's
+    (training/steps.py ``LAYER_LOSS``), that sum too."""
     def get(k):
         v = metrics.get(k)
         return float(np.asarray(v)) if v is not None else float("nan")
@@ -75,7 +77,9 @@ def epoch_log_line(prefix: str, epoch: int, num_samples: int,
             f"[{elapsed_s:.2f} sec]: loss: {get('loss_mean'):.4f}\t"
             f"byol: {get('byol_loss_mean'):.4f}\t"
             f"linear: {get('linear_loss_mean'):.4f}\t"
-            f"top1: {get('top1_mean'):.4f}\ttop5: {get('top5_mean'):.4f}")
+            + (f"layers: {get('layer_loss_mean'):.4f}\t"
+               if "layer_loss_mean" in metrics else "")
+            + f"top1: {get('top1_mean'):.4f}\ttop5: {get('top5_mean'):.4f}")
 
 
 class InputPipelineMeter:

@@ -19,6 +19,11 @@ so the model swaps between them by name without re-plumbing:
                 gated grouped-query layers) — the same arithmetic, causal,
                 over blocks of keys with a running max and sum, forward and
                 backward, so that no ``[S, S]`` array exists at any length;
+  ``selected`` (:func:`selected_attention`, the decoder trunk's
+                sparse-attention layers) — the same again with each query's
+                softmax over a SET of its causal keys
+                (ops/key_selection.py finds it), the block pairs walked by
+                loops the compiler keeps rolled;
   ``flash``   — Pallas blockwise-softmax kernel (ops/flash_attention.py),
                 for long sequences where the S x S score matrix shouldn't hit
                 HBM;
@@ -36,6 +41,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from byol_tpu.ops import packed_attention
 from byol_tpu.parallel.mesh import DATA_AXIS
@@ -189,6 +195,195 @@ def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
     out = _blockwise_causal(q.reshape(b, hkv, hq // hkv, s, d), k, v,
                             float(scale), int(block))
     return out.reshape(b, hq, s, v.shape[-1])
+
+
+# ---- attention over a per-query set of keys --------------------------------
+#
+# The TILE layout.  Everything ``[S, S]``-shaped round this core is held as the
+# block pairs on and under the diagonal and nothing else: ``(P, B, block,
+# block)``, ``P = n (n + 1) / 2`` pairs of ``n = S / block`` blocks, query
+# block by query block and key block by key block inside (:func:`causal_pairs`;
+# pair ``(i, j)`` is tile ``i (i + 1) / 2 + j``).  No head axis.  Every loop
+# over pairs is a ``lax`` loop whose body is traced ONCE: the program's size
+# does not grow with ``S``.
+
+def causal_pairs(blocks: int):
+    """``(query block, key block)`` of every tile, as two int32 arrays."""
+    q_of = np.repeat(np.arange(blocks), np.arange(1, blocks + 1))
+    k_of = np.concatenate([np.arange(i + 1) for i in range(blocks)])
+    return q_of.astype(np.int32), k_of.astype(np.int32)
+
+
+def _slab(x, i, block: int, axis: int = -2):
+    """Block ``i`` (traced) of ``x`` along ``axis``."""
+    return jax.lax.dynamic_slice_in_dim(x, i * block, block, axis=axis)
+
+
+def _kept_scores(q_blk, k_blk, scale, keep):
+    """``(B, Hkv, G, bq, bk)`` float32 scores of one tile; ``keep`` (``(B,
+    bq, bk)`` bool, the same for every head) says which keys each query
+    attends."""
+    scores = jnp.einsum("bhgqd,bhkd->bhgqk", q_blk, k_blk,
+                        preferred_element_type=jnp.float32) * scale
+    return jnp.where(keep[:, None, None], scores, _MASKED)
+
+
+def _tile(selected, i, j):
+    return jax.lax.dynamic_index_in_dim(selected, i * (i + 1) // 2 + j,
+                                        keepdims=False)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _selected(q, k, v, selected, scale, block):
+    """``(out, log-sum-exp)``; the second takes no cotangent."""
+    return _selected_fwd(q, k, v, selected, scale, block)[0]
+
+
+def _selected_fwd(q, k, v, selected, scale, block):
+    """``q``: ``(B, Hkv, G, S, D)``; ``k, v``: ``(B, Hkv, S, D)``;
+    ``selected``: ``(P, B, block, block)`` bool.  A query block at a time
+    over the key blocks it can see, with a running max and sum.  A row none
+    of whose keys in a tile is kept carries ``_MASKED`` as its max until a
+    kept key comes, whose ``keep`` factor then zeroes what went before:
+    every row keeps a key somewhere."""
+    b, hkv, g, s, _ = q.shape
+    rows = (b, hkv, g, block)
+
+    def query_block(i):
+        q_blk = _slab(q, i, block)
+
+        def key_block(j, carry):
+            top, total, acc = carry
+            scores = _kept_scores(q_blk, _slab(k, j, block), scale,
+                                  _tile(selected, i, j))
+            new_top = jnp.maximum(top, jnp.max(scores, axis=-1))
+            weights = jnp.exp(scores - new_top[..., None])
+            keep = jnp.exp(top - new_top)
+            part = jnp.einsum("bhgqk,bhkd->bhgqd", weights.astype(v.dtype),
+                              _slab(v, j, block),
+                              preferred_element_type=jnp.float32)
+            return (new_top, total * keep + jnp.sum(weights, axis=-1),
+                    acc * keep[..., None] + part)
+
+        top, total, acc = jax.lax.fori_loop(
+            0, i + 1, key_block,
+            (jnp.full(rows, _MASKED, jnp.float32),
+             jnp.zeros(rows, jnp.float32),
+             jnp.zeros(rows + v.shape[-1:], jnp.float32)))
+        return (acc / total[..., None]).astype(q.dtype), top + jnp.log(total)
+
+    outs, lses = jax.lax.map(query_block, jnp.arange(s // block))
+    out = jnp.moveaxis(outs, 0, 3).reshape(b, hkv, g, s, v.shape[-1])
+    lse = jnp.moveaxis(lses, 0, 3).reshape(b, hkv, g, s)
+    return (out, lse), (q, k, v, selected, out, lse)
+
+
+def _selected_bwd(scale, block, residuals, cotangents):
+    """The same tiles again: scores recomputed from ``q, k`` and the saved
+    log-sum-exp, five products a tile; ``d_k, d_v`` grow in place, block by
+    block, in float32."""
+    q, k, v, selected, out, lse = residuals
+    d_out, _ = cotangents
+    # sum_k w (dw) of the softmax's backward is rowsum(dO . O)
+    delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)
+
+    def grown(total, j, part):
+        return jax.lax.dynamic_update_slice_in_dim(
+            total, _slab(total, j, block) + part, j * block, axis=-2)
+
+    def query_block(carry, i):
+        q_blk, do_blk = _slab(q, i, block), _slab(d_out, i, block)
+        lse_blk = _slab(lse, i, block, -1)[..., None]
+        delta_blk = _slab(delta, i, block, -1)[..., None]
+
+        def key_block(j, carry):
+            dq_blk, d_k, d_v = carry
+            k_blk, v_blk = _slab(k, j, block), _slab(v, j, block)
+            weights = jnp.exp(_kept_scores(q_blk, k_blk, scale,
+                                           _tile(selected, i, j)) - lse_blk)
+            d_v = grown(d_v, j, jnp.einsum(
+                "bhgqk,bhgqd->bhkd", weights.astype(v.dtype), do_blk,
+                preferred_element_type=jnp.float32))
+            d_weights = jnp.einsum("bhgqd,bhkd->bhgqk", do_blk, v_blk,
+                                   preferred_element_type=jnp.float32)
+            d_scores = (weights * (d_weights - delta_blk)
+                        * scale).astype(q.dtype)
+            dq_blk = dq_blk + jnp.einsum(
+                "bhgqk,bhkd->bhgqd", d_scores, k_blk,
+                preferred_element_type=jnp.float32)
+            d_k = grown(d_k, j, jnp.einsum(
+                "bhgqk,bhgqd->bhkd", d_scores, q_blk,
+                preferred_element_type=jnp.float32))
+            return dq_blk, d_k, d_v
+
+        dq_blk, d_k, d_v = jax.lax.fori_loop(
+            0, i + 1, key_block,
+            (jnp.zeros(q_blk.shape, jnp.float32),) + carry)
+        return (d_k, d_v), dq_blk.astype(q.dtype)
+
+    (d_k, d_v), d_q = jax.lax.scan(
+        query_block, (jnp.zeros(k.shape, jnp.float32),
+                      jnp.zeros(v.shape, jnp.float32)),
+        jnp.arange(q.shape[-2] // block))
+    d_q = jnp.moveaxis(d_q, 0, 3).reshape(q.shape)
+    return d_q, d_k.astype(k.dtype), d_v.astype(v.dtype), None
+
+
+_selected.defvjp(_selected_fwd, _selected_bwd)
+
+
+def selected_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                       selected: jnp.ndarray, *,
+                       scale: Optional[float] = None, block: int = 512):
+    """:func:`blockwise_causal_attention` with each query's softmax over a
+    SET of its causal keys, one set for all heads: ``selected`` is the set
+    in the tile layout above (``(P, B, block, block)`` bool;
+    ops/key_selection.py finds it), ``S`` a multiple of ``block``.  Returns
+    the output and each row's log-sum-exp over its keys (``(B, Hq, S)``
+    float32, for :func:`kept_probabilities`).  Masked-dense: a tile none of
+    whose keys is kept is still formed; a tile above the diagonal never.
+    Plain ``jax.numpy`` under ``lax`` loops — a query block at a time
+    (``lax.map``), its key blocks under a ``fori_loop`` — forward and
+    backward (``jax.custom_vjp``, scores recomputed from the saved
+    log-sum-exp)."""
+    b, hq, s, d = q.shape
+    hkv = k.shape[1]
+    if hq % hkv or s % block:
+        raise ValueError(f"{hq} query heads on {hkv} key heads, {s} tokens "
+                         f"in blocks of {block}")
+    if scale is None:
+        scale = d ** -0.5
+    out, lse = _selected(q.reshape(b, hkv, hq // hkv, s, d), k, v, selected,
+                         float(scale), int(block))
+    return out.reshape(b, hq, s, v.shape[-1]), lse.reshape(b, hq, s)
+
+
+def kept_probabilities(q: jnp.ndarray, k: jnp.ndarray, lse: jnp.ndarray,
+                       selected: jnp.ndarray, *,
+                       scale: Optional[float] = None, block: int = 512):
+    """The MEAN over the query heads of the attention probabilities
+    :func:`selected_attention` used, in the tile layout (``(P, B, block,
+    block)`` float32, zero where a key is not kept): the scores once more
+    from ``q, k`` and the rows' ``lse``, summed over the heads a tile at a
+    time, so that no ``[B, H, S, S]`` array exists.  Not differentiated (the
+    indexer's target: ops/key_selection.index_loss)."""
+    b, hq, s, d = q.shape
+    hkv = k.shape[1]
+    if scale is None:
+        scale = d ** -0.5
+    q = q.reshape(b, hkv, hq // hkv, s, d)
+    lse = lse.reshape(b, hkv, hq // hkv, s)
+
+    def tile(pair):
+        i, j, keep = pair
+        weights = jnp.exp(
+            _kept_scores(_slab(q, i, block), _slab(k, j, block),
+                         float(scale), keep)
+            - _slab(lse, i, block, -1)[..., None])
+        return jnp.sum(weights, axis=(1, 2)) / hq
+
+    return jax.lax.map(tile, causal_pairs(s // block) + (selected,))
 
 
 def packed_kernel_applies(batch: int, seq_len: int, num_heads: int,

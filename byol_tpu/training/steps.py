@@ -76,7 +76,9 @@ from jax.experimental.xla_metadata import set_xla_metadata
 from byol_tpu.core import rng as rng_lib
 from byol_tpu.core.precision import Policy, FP32
 from byol_tpu.data import device_augment
-from byol_tpu.models.decoder_trunk import ROUTING, ROUTING_FIELDS
+from byol_tpu.models.decoder_trunk import (LAYER_LOSS, ROUTING,
+                                           ROUTING_FIELDS, SELECTION,
+                                           SELECTION_FIELDS)
 from byol_tpu.objectives.byol_loss import loss_function
 from byol_tpu.objectives.metrics import cross_entropy, topk_accuracy
 from byol_tpu.observability import health as health_lib
@@ -181,42 +183,54 @@ class StepConfig:
                                          # is not surfaced here)
 
 
+# The collections a backbone's layers may sow into during a training forward.
+SOWN = (ROUTING, SELECTION, LAYER_LOSS)
+
+
 def _forward_views(net, params, batch_stats, aug1, aug2, *, train: bool,
                    fuse: bool, update_stats: bool):
     """Run both views through encoder+projector+predictor.
 
-    Returns (out1, out2, new_batch_stats, routing); each out is the dict
+    Returns (out1, out2, new_batch_stats, sown); each out is the dict
     from ``BYOLNet.__call__`` (representation/projection/prediction);
-    ``routing`` is the sum over layers and views of what a backbone that
-    routes sowed (``ROUTING_FIELDS``, models/decoder_trunk.py), else None.
+    ``sown`` holds, for each collection of ``SOWN`` a layer of the backbone
+    wrote to (models/decoder_trunk.py), the sum over layers and views of
+    what it wrote: the routing counters (``ROUTING_FIELDS``), the
+    key-selection counters (``SELECTION_FIELDS``), and ``LAYER_LOSS``, the
+    scalar losses layers add to the step's — each a mean over the rows of
+    its forward, so two unfused views' are averaged.  A backbone that sows
+    nothing leaves it empty.
     """
     variables = {"params": params, "batch_stats": batch_stats}
     # flax BatchNorm writes running stats whenever train=True, so the
     # collection must be mutable even for the target forward; updates are
     # simply discarded when update_stats=False.
-    mutable = ["batch_stats", ROUTING] if train else False
+    mutable = ["batch_stats", *SOWN] if train else False
 
     def apply(v, x):
         if mutable:
             out, upd = net.apply(v, x, train=train, mutable=mutable)
             new_bs = upd["batch_stats"] if update_stats else v["batch_stats"]
-            sown = jax.tree_util.tree_leaves(upd.get(ROUTING, {}))
-            return out, new_bs, sum(sown) if sown else None
+            leaves = {name: jax.tree_util.tree_leaves(upd.get(name, {}))
+                      for name in SOWN}
+            return out, new_bs, {name: sum(found)
+                                 for name, found in leaves.items() if found}
         out = net.apply(v, x, train=train, mutable=False)
-        return out, v["batch_stats"], None
+        return out, v["batch_stats"], {}
 
     if fuse:
         n = aug1.shape[0]
-        out, bs, routing = apply(variables,
-                                 jnp.concatenate([aug1, aug2], axis=0))
+        out, bs, sown = apply(variables,
+                              jnp.concatenate([aug1, aug2], axis=0))
         out1 = jax.tree_util.tree_map(lambda x: x[:n], out)
         out2 = jax.tree_util.tree_map(lambda x: x[n:], out)
-        return out1, out2, bs, routing
-    out1, bs, routing = apply(variables, aug1)
-    out2, bs, routing2 = apply({"params": params, "batch_stats": bs}, aug2)
-    if routing is not None:
-        routing = routing + routing2
-    return out1, out2, bs, routing
+        return out1, out2, bs, sown
+    out1, bs, sown = apply(variables, aug1)
+    out2, bs, sown2 = apply({"params": params, "batch_stats": bs}, aug2)
+    sown = {name: sown[name] + sown2[name] for name in sown}
+    if LAYER_LOSS in sown:
+        sown[LAYER_LOSS] = sown[LAYER_LOSS] / 2
+    return out1, out2, bs, sown
 
 
 def _microbatch_split(x: jnp.ndarray, k: int) -> jnp.ndarray:
@@ -447,7 +461,7 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
 
         def loss_fn(params):
             with _phase("online_forward"):
-                on1, on2, new_bs, routing = _forward_views(
+                on1, on2, new_bs, sown = _forward_views(
                     net, params, batch_stats, aug1, aug2,
                     train=True, fuse=scfg.fuse_views, update_stats=True)
             with _phase("loss"):
@@ -463,17 +477,27 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
                 cls_labels = jnp.concatenate([labels, labels], axis=0)
                 cls_loss = cross_entropy(logits, cls_labels)
                 total = byol_loss + cls_loss
+                if LAYER_LOSS in sown:
+                    # what the backbone's layers add of their own (each
+                    # with its own stop-gradients: models/decoder_trunk.py)
+                    total = total + sown[LAYER_LOSS]
                 top1, top5 = topk_accuracy(logits, cls_labels)
             metrics = {"loss_mean": total,
                        "byol_loss_mean": byol_loss,
                        "linear_loss_mean": cls_loss,
                        "top1_mean": top1,
                        "top5_mean": top5}
-            if routing is not None:
-                # the online forward's routing counters, as scalars like
-                # every metric (the underscore keeps them off the plots)
-                metrics.update({f"_moe_{name}": routing[i]
-                                for i, name in enumerate(ROUTING_FIELDS)})
+            if LAYER_LOSS in sown:
+                metrics["layer_loss_mean"] = sown[LAYER_LOSS]
+            # the online forward's routing and key-selection counters, as
+            # scalars like every metric (the underscore keeps them off the
+            # plots)
+            for prefix, collection, fields in (
+                    ("_moe_", ROUTING, ROUTING_FIELDS),
+                    ("_sel_", SELECTION, SELECTION_FIELDS)):
+                if collection in sown:
+                    metrics.update({prefix + name: sown[collection][i]
+                                    for i, name in enumerate(fields)})
             return total, (new_bs, metrics)
 
         grads, (new_bs, metrics) = jax.grad(
