@@ -14,6 +14,12 @@ call sites name the capability, not the file:
   chunked gated delta rule's within-chunk stage (a ``C x C`` unit
   triangular system a chunk, solved in VMEM), forward and backward: what
   ``models/gated_delta.py`` runs on a TPU at chunks and heads of 128.
+- :mod:`byol_tpu.ops.selected_attention` (``attend``, ``applies``) — sparse
+  attention's core, grouped-query softmax over each query's SELECTED causal
+  keys a ``block x block`` tile at a time with the tile's squares in VMEM,
+  forward and backward: what ``ops/attention.selected_attention`` runs on a
+  TPU at blocks and heads of 128 lanes (``models/decoder_trunk.
+  SparseAttention``).
 - :func:`fused_two_view` — the fused uint8→two-view augmentation
   (``--fused-augment on``): one VMEM pass per image for
   convert/crop/flip/jitter/grayscale, blur as an MXU conv on the output.

@@ -22,8 +22,13 @@ so the model swaps between them by name without re-plumbing:
   ``selected`` (:func:`selected_attention`, the decoder trunk's
                 sparse-attention layers) — the same again with each query's
                 softmax over a SET of its causal keys
-                (ops/key_selection.py finds it), the block pairs walked by
-                loops the compiler keeps rolled;
+                (ops/key_selection.py finds it).  Where the program lowers
+                for a TPU and the shapes allow, the Pallas kernels of
+                ops/selected_attention.py (``selected_attention.applies``):
+                a tile's scores and weights never leave VMEM; elsewhere
+                plain ``jax.numpy``, the block pairs walked by loops the
+                compiler keeps rolled — ``[B,Hkv,G,block,block]`` float32
+                tiles through HBM;
   ``flash``   — Pallas blockwise-softmax kernel (ops/flash_attention.py),
                 for long sequences where the S x S score matrix shouldn't hit
                 HBM;
@@ -343,10 +348,18 @@ def selected_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     the output and each row's log-sum-exp over its keys (``(B, Hq, S)``
     float32, for :func:`kept_probabilities`).  Masked-dense: a tile none of
     whose keys is kept is still formed; a tile above the diagonal never.
-    Plain ``jax.numpy`` under ``lax`` loops — a query block at a time
-    (``lax.map``), its key blocks under a ``fori_loop`` — forward and
-    backward (``jax.custom_vjp``, scores recomputed from the saved
-    log-sum-exp)."""
+    Two lowerings of one arithmetic, chosen from what the code can see
+    (``ops/selected_attention.applies``): where the program lowers for a TPU
+    and ``block`` and the head width are multiples of 128 whose working set
+    fits VMEM, the Pallas kernels ``selected_attention_fwd`` /
+    ``selected_attention_bwd`` of ops/selected_attention.py — a tile's
+    scores, weights and their cotangents live and die in VMEM; everywhere
+    else (the CPU, the tiny presets, odd shapes) plain ``jax.numpy`` under
+    ``lax`` loops — a query block at a time (``lax.map``), its key blocks
+    under a ``fori_loop`` — which is also the tests' oracle for the
+    kernels.  Forward and backward on both (``jax.custom_vjp``, scores
+    recomputed from the saved log-sum-exp)."""
+    from byol_tpu.ops import selected_attention as kernels   # imports this
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     if hq % hkv or s % block:
@@ -354,8 +367,13 @@ def selected_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          f"in blocks of {block}")
     if scale is None:
         scale = d ** -0.5
-    out, lse = _selected(q.reshape(b, hkv, hq // hkv, s, d), k, v, selected,
-                         float(scale), int(block))
+    grouped = q.reshape(b, hkv, hq // hkv, s, d)
+    if d == v.shape[-1] and kernels.applies(block, d, s, hq, hkv, q.dtype):
+        out, lse = kernels.attend(grouped, k, v, selected, scale=scale,
+                                  block=block)
+    else:
+        out, lse = _selected(grouped, k, v, selected, float(scale),
+                             int(block))
     return out.reshape(b, hq, s, v.shape[-1]), lse.reshape(b, hq, s)
 
 

@@ -294,6 +294,60 @@ def test_delta_rule_falls_back_to_jax_numpy(no_persistent_cache, one_chip,
     assert "delta_wy" not in text and "tpu_custom_call" not in text
 
 
+def _core_text(one_chip, monkeypatch, *, backend, block=512, dim=128,
+               seq=4096):
+    """Sparse attention's core, forward and backward, compiled for the
+    described v5e as ``backend`` would lower it: 8 sequences, 32 query heads
+    on 4 key heads — the published sizes of ``keye_train_b4_s4096``."""
+    from byol_tpu.ops.attention import selected_attention
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    like = lambda *shape, kind=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, kind, sharding=one_chip)
+    blocks = seq // block
+
+    def loss(q, k, v, selected):
+        out, lse = selected_attention(q, k, v, selected, block=block)
+        return jnp.sum(jnp.square(out.astype(jnp.float32))), lse
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True)).lower(
+        like(8, 32, seq, dim), like(8, 4, seq, dim), like(8, 4, seq, dim),
+        like(blocks * (blocks + 1) // 2, 8, block, block, kind=jnp.bool_)
+    ).compile().as_text()
+
+
+def _core_kernel_calls(text):
+    """Custom calls of the forward and of the backward kernel (a frame of
+    the text's metadata may hold either name too)."""
+    import re
+    return [len(re.findall(rf"custom-call\([^\n]*selected_attention_{way}",
+                           text)) for way in ("fwd", "bwd")]
+
+
+def test_selected_attention_kernels_at_the_published_sizes(
+        no_persistent_cache, one_chip, monkeypatch):
+    """The core is ``selected_attention_fwd`` and ``selected_attention_bwd``;
+    no float32 ``(8, 4, 8, 512, 512)`` tile, nor any ``(.., 512, 512)``
+    float32 array, is left in the program."""
+    import re
+    text = _core_text(one_chip, monkeypatch, backend="tpu")
+    assert _core_kernel_calls(text) == [1, 1]
+    assert not re.search(r"f32\[[\d,]*512,512\]", text)
+    assert " while(" not in text
+
+
+@pytest.mark.parametrize("backend,sizes", [
+    ("cpu", {}),                                  # not lowered for a TPU
+    ("tpu", dict(block=96, seq=4032)),            # 3/4 of a lane tile
+    ("tpu", dict(dim=64)),                        # half a lane tile a head
+])
+def test_selected_attention_falls_back_to_jax_numpy(
+        no_persistent_cache, one_chip, monkeypatch, backend, sizes):
+    """Another backend and shapes the kernels do not take run the
+    ``jax.numpy`` body under its ``lax`` loops: no kernel in the text."""
+    text = _core_text(one_chip, monkeypatch, backend=backend, **sizes)
+    assert _core_kernel_calls(text) == [0, 0]
+    assert "tpu_custom_call" not in text and " while(" in text
+
+
 def _compile_train_step(topo, rcfg, batch):
     """The jitted step (``--fuse-views``, bf16, LARS), built from the
     compile plan exactly as setup_training wires it, compiled for one
