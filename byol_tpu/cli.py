@@ -23,6 +23,7 @@ from typing import List, Optional
 from byol_tpu.core.config import (Config, DeviceConfig, ModelConfig,
                                   OptimConfig, ParityConfig,
                                   RegularizerConfig, TaskConfig)
+from byol_tpu.observability import spans
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -143,8 +144,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "hot-loop phase (input wait, dispatch, readback, "
                         "eval, checkpoint, compile), emits goodput/"
                         "span_stats events into run.jsonl and writes a "
-                        "Chrome-trace trace.json per run (< 2% overhead, "
-                        "bench --spans-ab); 'off' records nothing")
+                        "Chrome-trace trace.json per run, set-up "
+                        "(startup/*) and JAX's compiles (compile/*) on the "
+                        "same timeline; 'off' keeps the hot loop free of "
+                        "spans and their TraceAnnotation regions (an "
+                        "on-demand start_server capture then shows no host "
+                        "phase marker) and writes neither events nor "
+                        "trace.json — set-up and compiles are still "
+                        "recorded in memory, as in every process")
     d.add_argument("--fault-at-step", type=int, default=0,
                    help="fault injection: kill the process at step N "
                         "(tests checkpoint/resume)")
@@ -309,6 +316,7 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+@spans.spanned("startup/config")
 def config_from_args(args: argparse.Namespace) -> Config:
     import jax
     n_rep = args.num_replicas or jax.device_count() // (

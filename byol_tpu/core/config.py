@@ -15,6 +15,8 @@ import hashlib
 import json
 from typing import Any, Optional, Tuple
 
+from byol_tpu.observability import spans
+
 
 def _frozen(cls):
     return dataclasses.dataclass(frozen=True)(cls)
@@ -325,6 +327,7 @@ class ResolvedConfig:
         return self.cfg.task.batch_size // self.cfg.optim.accum_steps
 
 
+@spans.spanned("startup/resolve")
 def resolve(cfg: Config, *, num_train_samples: int, num_test_samples: int,
             output_size: int, input_shape: Tuple[int, ...],
             representation_size: Optional[int] = None,

@@ -9,7 +9,9 @@ initialises the XLA backend:
 - :func:`force_cpu_devices` — the ``--cpu-devices N`` semantics: pin the
   CPU platform and size an N-device virtual mesh.
 - :func:`place_compile_cache` — JAX's persistent compilation cache at a
-  place that can be chosen from outside.
+  place that can be chosen from outside; and, because every entry point
+  makes this call first, JAX's compile events onto the process's span
+  recorder (``observability/spans.install_compile_listeners``).
 - :func:`require_tpu` — the program runs on the CPU only when it was asked
   to; otherwise a default backend that is not ``tpu`` is an error at
   start-up, never a silent CPU run.
@@ -19,6 +21,8 @@ from __future__ import annotations
 import os
 
 import jax
+
+from byol_tpu.observability import spans
 
 # <checkout>/.jax_cache — fixed, so the next process started from this
 # checkout finds it again: never a temporary name, a pid or a time.
@@ -43,7 +47,10 @@ def place_compile_cache() -> str:
     directory is set in code; otherwise the cache lives in the one fixed
     directory ``<checkout>/.jax_cache`` (git-ignored), so a second process
     started from the same checkout finds what the first one compiled.
+    From here on every trace, lowering and backend compile (or cache load)
+    is a ``compile/*`` span on ``spans.PROCESS``.
     """
+    spans.install_compile_listeners()
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = _DEFAULT_CACHE_DIR

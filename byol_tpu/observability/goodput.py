@@ -113,6 +113,32 @@ def span_stats(records: List[Any]) -> Dict[str, Dict[str, float]]:
     return out
 
 
+def covered_seconds(intervals: List[Tuple[float, float]]) -> float:
+    """Length of the union of ``(t0, t1)`` intervals."""
+    total, end = 0.0, float("-inf")
+    for t0, t1 in sorted(intervals):
+        if t1 > end:
+            total += t1 - max(t0, end)
+            end = t1
+    return total
+
+
+def self_seconds(records: List[Any]) -> Dict[int, float]:
+    """Self time of every span of a window, by ``seq``: its duration less
+    the part of it that its children (the spans naming it as ``parent``)
+    cover — the union of their intervals, clipped to the span's own, so
+    children that overlap are not taken off twice."""
+    children: Dict[int, List[Tuple[float, float]]] = {}
+    for r in records:
+        children.setdefault(r.parent, []).append((r.t0, r.t1))
+    return {
+        r.seq: r.seconds - covered_seconds(
+            [(max(t0, r.t0), min(t1, r.t1))
+             for t0, t1 in children.get(r.seq, ())
+             if t1 > r.t0 and t0 < r.t1])
+        for r in records}
+
+
 class GoodputMeter:
     """Folds a SpanRecorder's ring into contiguous goodput windows.
 
@@ -126,7 +152,10 @@ class GoodputMeter:
 
     def __init__(self, recorder: Any) -> None:
         self._rec = recorder
-        self._since = -1
+        # the ring may hold spans from before this meter (the process-wide
+        # recorder's set-up spans): the first window takes only what
+        # closes from here on
+        self._since = recorder.last_seq()
         self._t_window = time.perf_counter()
         self._windows = 0
         self._run_wall = 0.0
@@ -145,7 +174,7 @@ class GoodputMeter:
         self._t_window = now
         records = self._rec.records(since_seq=self._since)
         if records:
-            self._since = max(r.seq for r in records)
+            self._since = records[-1].seq    # the ring is in closing order
         wall, productive, badput = attribute(records, wall)
         self._windows += 1
         self._run_wall += wall

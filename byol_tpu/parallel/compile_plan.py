@@ -37,6 +37,7 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from byol_tpu.observability import spans
 from byol_tpu.parallel import zero1 as zero1_lib
 from byol_tpu.parallel.mesh import DATA_AXIS
 from byol_tpu.parallel.partitioning import _path_names, state_shardings
@@ -298,6 +299,7 @@ class CompilePlan:
         }
 
 
+@spans.spanned("startup/plan")
 def build_plan(mesh: Mesh, *, zero1: bool = False) -> CompilePlan:
     """The one constructor: cfg.device.zero1 == 'on' -> a ZeRO-1 plan.
 

@@ -30,6 +30,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from byol_tpu.observability import spans
+
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
 SEQUENCE_AXIS = "sequence"
@@ -86,6 +88,7 @@ def _slice_granules(devices: Sequence[jax.Device]) -> list:
     return [by_key[k] for k in keys]
 
 
+@spans.spanned("startup/mesh")
 def build_mesh(spec: MeshSpec = MeshSpec(),
                devices: Optional[Sequence[jax.Device]] = None,
                dcn_granules: Optional[Sequence[Sequence[jax.Device]]] = None

@@ -103,9 +103,11 @@ def build_serve_parser():
                         "serving flight recorder (per-batch spans with "
                         "request trace ids + engine stage/dispatch/"
                         "readback + wire http/read|parse|wait|write; "
-                        "observability/spans.py); default "
-                        "<log_dir>/serve_trace.json, 'off' disables "
-                        "recording entirely")
+                        "observability/spans.py) on one timeline with "
+                        "set-up (startup/*) and JAX's compiles "
+                        "(compile/*, under each bucket's startup/compile); "
+                        "default <log_dir>/serve_trace.json, 'off' keeps "
+                        "the serving path free of spans and writes no file")
     s.add_argument("--smoke", type=int, default=0,
                    help="drive N synthetic requests through the service "
                         "(over the wire when --http is given), print "
@@ -242,7 +244,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     trace_path = args.serve_trace or os.path.join(cfg.task.log_dir,
                                                   "serve_trace.json")
     recorder = (spans_lib.NULL if args.serve_trace == "off"
-                else spans_lib.SpanRecorder())
+                else spans_lib.PROCESS)
 
     def _export_trace() -> None:
         if not recorder.enabled:

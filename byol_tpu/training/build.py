@@ -25,6 +25,7 @@ from jax.sharding import Mesh
 from byol_tpu.core.config import Config, ResolvedConfig
 from byol_tpu.core.precision import get_policy
 from byol_tpu.models.byol_net import BYOLNet, build_byol_net
+from byol_tpu.observability import spans
 from byol_tpu.optim.factory import build_optimizer, is_lars_optimizer
 from byol_tpu.parallel.mesh import DATA_AXIS
 from byol_tpu.training.state import TrainState, create_train_state
@@ -184,6 +185,7 @@ def _validate_remat_tags(net, rcfg: ResolvedConfig, variables,
                                    policy_name=policy_name)
 
 
+@spans.spanned("startup/build")
 def setup_training(rcfg: ResolvedConfig, mesh: Mesh, rng: jax.Array,
                    plan: Optional[Any] = None
                    ) -> Tuple[BYOLNet, TrainState, Callable, Callable, Any]:
@@ -196,11 +198,18 @@ def setup_training(rcfg: ResolvedConfig, mesh: Mesh, rng: jax.Array,
     need the plan afterwards (the trainer: run-log provenance + the
     checkpoint canonicalization codec) build it themselves and pass it in;
     ``None`` builds the config-implied plan internally.
+
+    The whole call is the span ``startup/build`` on the process's recorder
+    (observability/spans.py), its seven parts ``startup/build/net``,
+    ``/init`` (the eager flax init; attrs ``leaves``, ``parameters``),
+    ``/remat_tags``, ``/optimizer``, ``/state``, ``/place``, ``/jit``.
     """
     cfg = rcfg.cfg
     policy = get_policy(cfg.device.half)
-    net = build_net(rcfg)
-    scfg = step_config(rcfg)
+    span = spans.PROCESS.span       # host code: set-up is never traced
+    with span("startup/build/net"):
+        net = build_net(rcfg)
+        scfg = step_config(rcfg)
     from byol_tpu.parallel.compile_plan import build_plan
     if plan is None:
         plan = build_plan(mesh, zero1=cfg.device.zero1 == "on")
@@ -208,52 +217,64 @@ def setup_training(rcfg: ResolvedConfig, mesh: Mesh, rng: jax.Array,
     from byol_tpu.core.rng import split_named
     keys = split_named(rng, ("params", "weight_init"))
     with mesh:
-        variables = init_variables(
-            net, rcfg, keys["params"], batch=max(2, mesh.shape[DATA_AXIS]))
-        _validate_remat_tags(net, rcfg, variables,
-                             batch=max(2, mesh.shape[DATA_AXIS]))
-        if cfg.model.weight_initialization:
-            # --weight-initialization scheme re-draw (main.py:436 analog)
-            from byol_tpu.models.init import apply_weight_init
-            variables = dict(variables)
-            variables["params"] = apply_weight_init(
-                variables["params"], keys["weight_init"],
-                cfg.model.weight_initialization)
-        # Under ZeRO-1 the optax chain sees FLAT leaves (every leaf 1-D),
-        # so the bias/BN exclusion mask must be fixed from the REAL shapes
-        # here; the default ndim-derived mask stays for the replicated
-        # layout (identical semantics, and bit-identical jit cache keys).
-        adapt_mask = None
-        if plan.zero1:
-            from byol_tpu.optim import lars as lars_lib
-            adapt_mask = lars_lib.default_exclusion_mask(
-                variables["params"])
-            if lars_lib.has_expert_axis(adapt_mask):
-                raise ValueError(
-                    "--zero1 on flattens every leaf, and LARS adapts each "
-                    "expert of a stacked expert kernel alone: it needs the "
-                    "expert axis (run such a tree with --zero1 off)")
-        tx, schedule = build_tx(rcfg, adapt_mask=adapt_mask)
-        state = create_train_state(
-            # under ZeRO-1 the plan inits the optimizer state on the FLAT
-            # params in prepare_state; initializing the replicated tree
-            # here too would double the setup-time momentum footprint
-            variables, None if plan.zero1 else tx,
-            ema_init_mode=cfg.parity.ema_init_mode,
-            polyak_ema=cfg.regularizer.polyak_ema)
+        with span("startup/build/init") as init_span:
+            variables = init_variables(
+                net, rcfg, keys["params"],
+                batch=max(2, mesh.shape[DATA_AXIS]))
+            if cfg.model.weight_initialization:
+                # --weight-initialization scheme re-draw (main.py:436 analog)
+                from byol_tpu.models.init import apply_weight_init
+                variables = dict(variables)
+                variables["params"] = apply_weight_init(
+                    variables["params"], keys["weight_init"],
+                    cfg.model.weight_initialization)
+            leaves = jax.tree_util.tree_leaves(variables["params"])
+            init_span.note(leaves=len(leaves),
+                           parameters=sum(x.size for x in leaves))
+        with span("startup/build/remat_tags"):
+            _validate_remat_tags(net, rcfg, variables,
+                                 batch=max(2, mesh.shape[DATA_AXIS]))
+        with span("startup/build/optimizer"):
+            # Under ZeRO-1 the optax chain sees FLAT leaves (every leaf
+            # 1-D), so the bias/BN exclusion mask must be fixed from the
+            # REAL shapes here; the default ndim-derived mask stays for the
+            # replicated layout (identical semantics, and bit-identical jit
+            # cache keys).
+            adapt_mask = None
+            if plan.zero1:
+                from byol_tpu.optim import lars as lars_lib
+                adapt_mask = lars_lib.default_exclusion_mask(
+                    variables["params"])
+                if lars_lib.has_expert_axis(adapt_mask):
+                    raise ValueError(
+                        "--zero1 on flattens every leaf, and LARS adapts "
+                        "each expert of a stacked expert kernel alone: it "
+                        "needs the expert axis (run such a tree with "
+                        "--zero1 off)")
+            tx, schedule = build_tx(rcfg, adapt_mask=adapt_mask)
+        with span("startup/build/state"):
+            state = create_train_state(
+                # under ZeRO-1 the plan inits the optimizer state on the
+                # FLAT params in prepare_state; initializing the replicated
+                # tree here too would double the setup-time momentum
+                # footprint
+                variables, None if plan.zero1 else tx,
+                ema_init_mode=cfg.parity.ema_init_mode,
+                polyak_ema=cfg.regularizer.polyak_ema)
 
     # The plan converts the state to its layout (ZeRO-1: flat-sharded
     # momentum/EMA), places it, and owns the jit wiring of both steps.
-    state, state_sh = plan.prepare_state(state, tx)
-    z1 = plan.zero1_context()
-
-    # mesh feeds only the fused augmentation's shard_map; without
-    # --fused-augment it is inert and the traced graph is unchanged.
-    train_step = plan.jit_train_step(
-        make_train_step(net, tx, scfg, policy, zero1_ctx=z1, mesh=mesh),
-        state_sh)
-    eval_step = plan.jit_eval_step(
-        make_eval_step(net, scfg, policy, zero1_ctx=z1), state_sh)
+    with span("startup/build/place"):
+        state, state_sh = plan.prepare_state(state, tx)
+    with span("startup/build/jit"):
+        z1 = plan.zero1_context()
+        # mesh feeds only the fused augmentation's shard_map; without
+        # --fused-augment it is inert and the traced graph is unchanged.
+        train_step = plan.jit_train_step(
+            make_train_step(net, tx, scfg, policy, zero1_ctx=z1, mesh=mesh),
+            state_sh)
+        eval_step = plan.jit_eval_step(
+            make_eval_step(net, scfg, policy, zero1_ctx=z1), state_sh)
 
     def _with_mesh(fn):
         # keep the mesh in thread-local scope at call (=trace) time so

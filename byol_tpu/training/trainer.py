@@ -40,8 +40,7 @@ from byol_tpu.data.loader import LoaderBundle, get_loader, pad_batch
 from byol_tpu.data.prefetch import prefetch_to_mesh
 from byol_tpu.observability import (Grapher, InputPipelineMeter,
                                     MetricAccumulator, StepTimer,
-                                    epoch_log_line, input_log_line,
-                                    profiling)
+                                    epoch_log_line, input_log_line)
 from byol_tpu.observability import goodput as goodput_lib
 from byol_tpu.observability import spans as spans_lib
 from byol_tpu.observability.events import RunLog
@@ -141,9 +140,12 @@ def fit(cfg: Config, *, loader: Optional[LoaderBundle] = None,
 
     # Flight recorder (observability/spans.py): every hot-loop phase below
     # runs under a named span; goodput.py folds them into the wall-time
-    # partition per epoch.  --spans off hands every `with` a shared no-op
-    # (records nothing — the hot loop is byte-for-byte the unspanned one).
-    recorder = (spans_lib.SpanRecorder() if cfg.device.spans == "on"
+    # partition per epoch.  Under --spans on it is the PROCESS's recorder,
+    # which holds the set-up and JAX's compile spans whatever this flag
+    # says: start-up, compiles and the hot loop are one timeline and one
+    # export.  --spans off hands every `with` below a shared no-op (records
+    # nothing — the hot loop is byte-for-byte the unspanned one).
+    recorder = (spans_lib.PROCESS if cfg.device.spans == "on"
                 else spans_lib.NULL)
     # what records on the module default (the token feed) lands here too
     spans_lib.set_default(recorder)
@@ -152,9 +154,9 @@ def fit(cfg: Config, *, loader: Optional[LoaderBundle] = None,
     goodput_meter = goodput_lib.GoodputMeter(recorder)
 
     from byol_tpu.core.rng import root_key
-    with recorder.span("startup/build"):
-        net, state, train_step, eval_step, schedule = setup_training(
-            rcfg, mesh, root_key(cfg.device.seed), plan=plan)
+    # setup_training opens ``startup/build`` itself (training/build.py)
+    net, state, train_step, eval_step, schedule = setup_training(
+        rcfg, mesh, root_key(cfg.device.seed), plan=plan)
     if verbose:
         from byol_tpu.utils import number_of_parameters
         print(f"model: {cfg.model.arch}, "
@@ -253,13 +255,12 @@ def fit(cfg: Config, *, loader: Optional[LoaderBundle] = None,
             # deadlocks in eval_step's collectives
             from byol_tpu.parallel.lockstep import lockstep_iter
             src = lockstep_iter(src, _all_pad_batch)
-        with profiling.annotate("byol/eval_dispatch"):
-            for batch in src:
-                dev_batch = shard_batch_to_mesh(
-                    pad_batch(batch, host_eval_batch), mesh)
-                acc.update(eval_step(state, dev_batch))
-                if cfg.device.debug_step:
-                    break
+        for batch in src:
+            dev_batch = shard_batch_to_mesh(
+                pad_batch(batch, host_eval_batch), mesh)
+            acc.update(eval_step(state, dev_batch))
+            if cfg.device.debug_step:
+                break
         return acc
 
     # Checkpoints always store the CANONICAL state layout (replicated,
@@ -452,66 +453,61 @@ def fit(cfg: Config, *, loader: Optional[LoaderBundle] = None,
         # the throughput numbers
         input_meter = InputPipelineMeter()
         timer.reset_ticks()
-        with profiling.annotate("byol/train_dispatch"):
-            for dev_batch in prefetch_to_mesh(tapped_batches(), mesh,
-                                              meter=input_meter,
-                                              recorder=recorder):
-                if not flops_resolved:
-                    # Once per fit: FLOPs of the real train step via XLA
-                    # cost analysis (observability/flops.py) -> MFU next to
-                    # every throughput number.  Lowering only traces; must
-                    # precede the first call because the step donates its
-                    # input state.
-                    flops_resolved = True
-                    from byol_tpu.observability import flops as flops_lib
-                    with recorder.span("startup/cost_analysis"), mesh:
-                        step_flops = flops_lib.cost_analysis_flops(
-                            train_step, state, dev_batch)
-                    if step_flops:
-                        timer.set_flops(step_flops / rcfg.global_batch_size,
-                                        flops_lib.chip_peak_tflops())
-                # The FIRST dispatch of a fit pays trace + XLA compile
-                # before the async dispatch returns: attribute it to the
-                # startup_compile bucket, not to productive step time.
-                with recorder.span("startup/compile" if first_dispatch
-                                   else "train/dispatch"):
-                    state, metrics = train_step(state, dev_batch)
-                first_dispatch = False
-                global_step += 1
-                timer.tick()
-                if sink is not None:
-                    # 'health' is the packed in-graph diagnostics vector —
-                    # popped so the scalar accumulator (and the epoch
-                    # float() conversions) only ever see scalars.  'step'
-                    # mode: lagged async readback; 'epoch' mode: hold the
-                    # newest, drained for free after the epoch readback.
-                    health_vec = metrics.pop("health")
-                    try:
-                        with recorder.span("telemetry/readback"):
-                            if telemetry_mode == "step":
-                                sink.offer(global_step, health_vec)
-                            else:
-                                sink.hold(global_step, health_vec)
-                    except NanHaltError as e:
-                        _halt_dump(e, epoch)
-                        raise
-                acc.update(metrics)  # device-side running sum; no host sync
-                _maybe_preempt_save()
-                if cfg.device.fault_at_step and \
-                        int(state.step) == cfg.device.fault_at_step:
-                    # fault injection (§5.3): die mid-epoch like a
-                    # preempted pod worker; a relaunch must resume from
-                    # the last checkpoint.
-                    raise SystemExit(
-                        f"fault injected at step {int(state.step)} "
-                        f"(--fault-at-step)")
-                if cfg.device.debug_step:  # single-minibatch smoke
-                    break                  # (main.py:630)
-        # the annotate region stays UNCONDITIONAL (pre-PR-9 contract: XLA
-        # captures carry the host phase markers even under --spans off);
-        # the span nests inside it when recording is on
-        with profiling.annotate("byol/epoch_readback"), \
-                recorder.span("train/epoch_readback"):
+        for dev_batch in prefetch_to_mesh(tapped_batches(), mesh,
+                                          meter=input_meter,
+                                          recorder=recorder):
+            if not flops_resolved:
+                # Once per fit: FLOPs of the real train step via XLA
+                # cost analysis (observability/flops.py) -> MFU next to
+                # every throughput number.  Lowering only traces; must
+                # precede the first call because the step donates its
+                # input state.
+                flops_resolved = True
+                from byol_tpu.observability import flops as flops_lib
+                with recorder.span("startup/cost_analysis"), mesh:
+                    step_flops = flops_lib.cost_analysis_flops(
+                        train_step, state, dev_batch)
+                if step_flops:
+                    timer.set_flops(step_flops / rcfg.global_batch_size,
+                                    flops_lib.chip_peak_tflops())
+            # The FIRST dispatch of a fit pays trace + XLA compile
+            # before the async dispatch returns: attribute it to the
+            # startup_compile bucket, not to productive step time.
+            with recorder.span("startup/compile" if first_dispatch
+                               else "train/dispatch"):
+                state, metrics = train_step(state, dev_batch)
+            first_dispatch = False
+            global_step += 1
+            timer.tick()
+            if sink is not None:
+                # 'health' is the packed in-graph diagnostics vector —
+                # popped so the scalar accumulator (and the epoch
+                # float() conversions) only ever see scalars.  'step'
+                # mode: lagged async readback; 'epoch' mode: hold the
+                # newest, drained for free after the epoch readback.
+                health_vec = metrics.pop("health")
+                try:
+                    with recorder.span("telemetry/readback"):
+                        if telemetry_mode == "step":
+                            sink.offer(global_step, health_vec)
+                        else:
+                            sink.hold(global_step, health_vec)
+                except NanHaltError as e:
+                    _halt_dump(e, epoch)
+                    raise
+            acc.update(metrics)  # device-side running sum; no host sync
+            _maybe_preempt_save()
+            if cfg.device.fault_at_step and \
+                    int(state.step) == cfg.device.fault_at_step:
+                # fault injection (§5.3): die mid-epoch like a
+                # preempted pod worker; a relaunch must resume from
+                # the last checkpoint.
+                raise SystemExit(
+                    f"fault injected at step {int(state.step)} "
+                    f"(--fault-at-step)")
+            if cfg.device.debug_step:  # single-minibatch smoke
+                break                  # (main.py:630)
+        with recorder.span("train/epoch_readback"):
             train_metrics = {k: float(v) for k, v in acc.result().items()}
         # acc.result() is a D2H readback of sums depending on every step —
         # the only sync this platform can't fake, so the elapsed time (and
@@ -625,8 +621,7 @@ def fit(cfg: Config, *, loader: Optional[LoaderBundle] = None,
         # The save serializes device state (a D2H readback window on pods):
         # pet around it so a wedged collective during the flush is caught.
         watchdog.pet()
-        with profiling.annotate("byol/checkpoint"), \
-                recorder.span("checkpoint/save", epoch=epoch):
+        with recorder.span("checkpoint/save", epoch=epoch):
             stop_now = saver(test_metrics.get("loss_mean", float("inf")),
                              epoch, _save_state(state))
         watchdog.pet()

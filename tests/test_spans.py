@@ -257,7 +257,10 @@ class TestChromeTraceExport:
             assert e["dur"] >= 0.0
         # sorted by start time: the nested span starts after its parent
         assert xs[0]["name"] == "train/dispatch"
-        assert xs[1]["args"] == {"trace_ids": [1, 2]}
+        # attrs beside the span's seq and its parent's (the span that
+        # caused it)
+        assert xs[1]["args"] == {"seq": 1, "parent": 0,
+                                 "trace_ids": [1, 2]}
         # process metadata present (multi-file Perfetto sessions)
         assert any(e.get("ph") == "M" for e in events)
 
