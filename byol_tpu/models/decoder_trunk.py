@@ -76,7 +76,7 @@ import jax.numpy as jnp
 
 from byol_tpu.core import remat as remat_lib
 from byol_tpu.models.gated_delta import GatedDeltaNet, GatedDeltaSizes
-from byol_tpu.ops import key_selection
+from byol_tpu.ops import key_selection, sum_copies
 from byol_tpu.ops.attention import (blockwise_causal_attention,
                                     dense_attention, kept_probabilities,
                                     selected_attention)
@@ -500,15 +500,20 @@ class GatedMLP(nn.Module):
         return _dense(x.shape[-1], self.dtype, "down")(nn.silu(gate) * up)
 
 
-def _sum_copies(rows, pos, ok):
+def _sum_copies(rows, pos, ok, by_token=None):
     """``out[t] = sum_j rows[pos[t, j]]`` over the copies ``ok`` marks: a
     token's row back from the (up to k) sorted rows that are its copies,
-    added in float32 in slot order and rounded once.  One gather of
-    ``(tokens, D)`` a slot, the k of them added in one pass: gathered as one
-    ``(tokens, k, D)`` array, k lands on the tiled minor dimensions and the
-    TPU compiler relays out all k copies of the hidden states before it
-    sums them (PERF.md section 6, PR 30)."""
+    added in float32 and rounded once.  Two lowerings of the one sum.  With
+    the window's rows ``by_token`` (``ops/sum_copies.py``): one gather of the
+    ``cap`` rows into token order and a segment sum over them on the matrix
+    unit, so the cost follows the rows held.  Without: one gather of
+    ``(tokens, D)`` a slot, the k of them added in slot order in one pass
+    (gathered as one ``(tokens, k, D)`` array, k lands on the tiled minor
+    dimensions and the TPU compiler relays out all k copies of the hidden
+    states before it sums them: PERF.md section 6, PR 30)."""
     with jax.named_scope("combine"):
+        if by_token is not None:
+            return sum_copies.sum_copies(rows, by_token, pos.shape[0])
         total = None
         for j in range(pos.shape[1]):
             copy = jnp.where(ok[:, j, None], rows[pos[:, j]],
@@ -523,33 +528,32 @@ def _sum_copies(rows, pos, ok):
 # on the chip), which autodiff cannot know: it would transpose either gather
 # into a scatter-add.
 @jax.custom_vjp
-def _take_rows(x, idx, pos, ok):
+def _take_rows(x, idx, pos, ok, by_token=None):
     return x[idx]
 
 
-def _take_fwd(x, idx, pos, ok):
-    return x[idx], (idx, pos, ok)
+def _take_fwd(x, idx, pos, ok, by_token):
+    return x[idx], (pos, ok, by_token)
 
 
 def _take_bwd(res, g):
-    _, pos, ok = res
-    return _sum_copies(g, pos, ok), None, None, None
+    return _sum_copies(g, *res), None, None, None, None
 
 
 _take_rows.defvjp(_take_fwd, _take_bwd)
 
 
 @jax.custom_vjp
-def _put_rows(rows, idx, pos, ok):
-    return _sum_copies(rows, pos, ok)
+def _put_rows(rows, idx, pos, ok, by_token=None):
+    return _sum_copies(rows, pos, ok, by_token)
 
 
-def _put_fwd(rows, idx, pos, ok):
-    return _sum_copies(rows, pos, ok), (idx, pos, ok)
+def _put_fwd(rows, idx, pos, ok, by_token):
+    return _sum_copies(rows, pos, ok, by_token), idx
 
 
-def _put_bwd(res, g):
-    return g[res[0]], None, None, None
+def _put_bwd(idx, g):
+    return g[idx], None, None, None, None
 
 
 _put_rows.defvjp(_put_fwd, _put_bwd)
@@ -644,13 +648,21 @@ class ExpertLayer(nn.Module):
                 they nor their cotangents reach a token."""
                 ragged = lambda lhs, w: jax.lax.ragged_dot(
                     lhs, w.astype(dt), sizes)
-                rows = jnp.where(valid, _take_rows(x, idx, pos, ok), 0)
+                # which lowering the two sums over a token's copies take
+                # (the combine, the dispatch's backward): from the shapes
+                by_token = None
+                if sum_copies.applies(tokens, k, idx.shape[0], d, dt):
+                    with jax.named_scope("combine"):
+                        by_token = sum_copies.by_token(idx, valid[:, 0],
+                                                       tokens)
+                rows = jnp.where(
+                    valid, _take_rows(x, idx, pos, ok, by_token), 0)
                 act = nn.silu(ragged(rows, w_gate)) * ragged(rows, w_up)
                 # the copy's routing weight goes on BEFORE the last product
                 # (it is linear): (cap, f) to scale, not (cap, d)
                 act = (act * weights()).astype(dt)
                 out = jnp.where(valid, ragged(act, w_down), 0)
-                return _put_rows(out, idx, pos, ok)
+                return _put_rows(out, idx, pos, ok, by_token)
 
             def product(cap):
                 """The first ``cap`` sorted copies."""

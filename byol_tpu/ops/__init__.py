@@ -20,6 +20,11 @@ call sites name the capability, not the file:
   forward and backward: what ``ops/attention.selected_attention`` runs on a
   TPU at blocks and heads of 128 lanes (``models/decoder_trunk.
   SparseAttention``).
+- :mod:`byol_tpu.ops.sum_copies` (``by_token``, ``sum_copies``, ``applies``)
+  — the expert layer's combine (and its dispatch's backward) as ONE gather
+  of the held rows into token order and a one-hot segment-sum kernel on the
+  matrix unit: what ``models/decoder_trunk._sum_copies`` runs on a TPU where
+  the sorted window is shorter than every copy.
 - :func:`fused_two_view` — the fused uint8→two-view augmentation
   (``--fused-augment on``): one VMEM pass per image for
   convert/crop/flip/jitter/grayscale, blur as an MXU conv on the output.

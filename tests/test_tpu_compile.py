@@ -18,6 +18,7 @@ read back without a chip).
 
 A compile that passes is not a chip run: ``python chip_smoke.py`` is.
 """
+import collections
 import dataclasses
 
 import jax
@@ -141,17 +142,33 @@ def test_expert_layer_at_published_widths(no_persistent_cache, one_chip):
     assert "conditional" in compiled.as_text()
 
 
-def test_expert_layer_combine_moves_no_relaid_out_copies(no_persistent_cache,
-                                                         one_chip):
-    """At ``xing4_train_b8_s1024``'s shapes (16 x 1,024 rows, top-4, 8 of 64
-    experts held) the combine and the dispatch's backward gather one
-    ``[16384,3584]`` array a slot and add the four in one fusion: no array
-    of 65,536 x 3,584 elements under the ``combine`` scope, so none to relay
-    out before the sum (PERF.md section 6, PR 30: 0.94 GB a call)."""
+# the cells' layer calls: sizes, experts held, (sequences, tokens), and
+# whether the fallback over every copy is one product (its window IS every
+# copy: the ``jax.numpy`` body by shape) or slabs of the usual size
+_EXPERT_LAYERS = {
+    "xing4_train_b8_s1024": ("XING4_29B_A4B", 8, (16, 1024), True),
+    "qwen3next_train_b4_s4096": ("QWEN3_NEXT_80B_A3B", 32, (8, 4096), False),
+    "keye_train_b4_s4096": ("KEYE_VL2_30B_A3B", 16, (8, 4096), False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_EXPERT_LAYERS))
+def test_expert_layer_combine_moves_no_relaid_out_copies(
+        no_persistent_cache, one_chip, monkeypatch, cell):
+    """At the three trunk cells' shapes, lowered as on a TPU
+    (``sum_copies.applies`` asks ``jax.default_backend()``): the combine and
+    the dispatch's backward of a window of ``cap`` rows are each ONE gather
+    of ``[cap, D]`` into token order and one ``sum_copies`` kernel — no
+    gather a slot of ``[tokens, D]``, no array of ``tokens x k x D`` elements
+    under the ``combine`` scope, no ``copy`` / ``reshape`` of the hidden
+    states (PERF.md section 6, PR 30 and 37).  Where the fallback is one
+    product over every copy, that branch keeps the k gathers a sum."""
     from byol_tpu.models import decoder_trunk as trunk_lib
-    z = trunk_lib.XING4_29B_A4B
-    layer = trunk_lib.ExpertLayer(z, 0, 8, jnp.bfloat16)
-    x = jax.ShapeDtypeStruct((16, 1024, z.hidden_size), jnp.bfloat16,
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sizes, held, (batch, seq), whole_fallback = _EXPERT_LAYERS[cell]
+    z = getattr(trunk_lib, sizes)
+    layer = trunk_lib.ExpertLayer(z, 0, held, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((batch, seq, z.hidden_size), jnp.bfloat16,
                              sharding=one_chip)
     params = _with(jax.eval_shape(
         lambda: layer.init(jax.random.PRNGKey(0),
@@ -162,7 +179,8 @@ def test_expert_layer_combine_moves_no_relaid_out_copies(no_persistent_cache,
         return jnp.sum(jnp.square(
             layer.apply({"params": p}, x).astype(jnp.float32)))
     text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
-    tokens, k, d = 16 * 1024, z.num_experts_per_tok, z.hidden_size
+    tokens, k, d = batch * seq, z.num_experts_per_tok, z.hidden_size
+    cap = 2 * tokens * k * held // z.n_routed_experts
     assert f"[{tokens},{k},{d}]" not in text
     assert f"[{k},{tokens},{d}]" not in text
     # the ops that read and write HBM on their own, under the scope the
@@ -171,13 +189,21 @@ def test_expert_layer_combine_moves_no_relaid_out_copies(no_persistent_cache,
     combine = [row for name, rows in hlo_bytes_by_scope.parse(text).items()
                if name and not name.startswith("%fused_computation")
                for row in rows if "/combine/" in row[4]]
-    one = tokens * d * 2                         # a bf16[16384,3584]
-    assert max(row[1] for row in combine) == one
+    assert max(row[1] for row in combine) == max(cap, tokens) * d * 2
     assert not [row for row in combine if row[2] in ("copy", "reshape")]
-    # forward combine and dispatch backward, each in both branches of the
-    # cond: four sums of four gathers
-    assert len([row for row in combine
-                if row[2] == "fusion" and row[1] == one]) == 4 * (k + 1)
+    # forward combine and dispatch backward of the usual window, and of a
+    # slab where the fallback runs in slabs: a kernel and a gather each
+    windows = 2 if whole_fallback else 4
+    assert len([row for row in combine if row[2] == "custom-call"
+                and "sum_copies" in row[4]]) == windows
+    gathers = collections.Counter(
+        row[1] // (d * 2) for row in combine
+        if row[2] == "fusion" and row[4].endswith("/gather")
+        and row[1] % (d * 2) == 0 and row[1] >= tokens * d * 2)
+    want = collections.Counter({cap: windows})
+    if whole_fallback:        # (xing4's window is as long as its tokens)
+        want[tokens] += 2 * k
+    assert gathers == want
 
 
 # ---------------------------------------------------------------------------
