@@ -1,0 +1,16 @@
+"""Device time per step in ops traced under ``gqa`` in a short-convolution
+trunk's cell — the plain grouped-query attention layer: projections, head
+norms, rotary, the blockwise core, the output projection — every pass
+together (benchmarks/lib/trace_shortconv_trunk.py).  Absent off the chip,
+for another architecture, and for a program that names no such scope."""
+from benchmarks.lib import trace_shortconv_trunk
+
+NAME = "train_step.shortconv_gqa_ms"
+LAYER = "train step"
+UNIT = "ms"
+MOVES = "train_images_per_s_per_chip"
+SOURCE = "device_trace"
+
+
+def read(sources):
+    return trace_shortconv_trunk.scope_ms(sources, "gqa")

@@ -64,7 +64,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "on-disk valid/ root, which wins")
     # Model (main.py:56-70)
     m = p.add_argument_group("model")
-    m.add_argument("--arch", type=str, default="resnet50")
+    m.add_argument("--arch", type=str, default="resnet50",
+                   help="a backbone of models/registry.py: resnet*, vit_*, "
+                        "or a decoder trunk over token ids (--task "
+                        "synth_tokens): xing4_29b_a4b, qwen3_next_80b_a3b, "
+                        "keye_vl2_30b_a3b, lfm2_24b_a2b and their test-size "
+                        "twins decoder_trunk_tiny, hybrid_trunk_tiny, "
+                        "sparse_trunk_tiny, shortconv_trunk_tiny")
     m.add_argument("--representation-size", type=int, default=None,
                    help="derived from the arch registry unless overridden")
     m.add_argument("--projection-size", type=int, default=256)

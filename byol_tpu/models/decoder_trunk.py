@@ -53,7 +53,9 @@ Device-trace scopes (``DecoderTrunk.trace_scopes``; ``TRACE_SCOPES`` for a
 latent-attention trunk, ``HYBRID_SCOPES`` for a patterned one: ``gdn`` with
 ``proj``, ``conv``, ``core``, ``gate_norm``; ``gqa`` with ``core``; the
 ``moe`` scopes; ``SPARSE_SCOPES`` for a sparse-attention one: ``dsa`` with
-``index``, ``select``, ``core``, ``index_loss``): ``mla``, ``moe/route``,
+``index``, ``select``, ``core``, ``index_loss``; ``SHORTCONV_SCOPES`` for one
+with short convolutions: ``shortconv`` with ``proj``, ``core``; ``gqa`` with
+``core``; ``ffn``): ``mla``, ``moe/route``,
 ``moe/experts`` (and in it ``combine``: the sum of a token's copies, forward
 and as the dispatch's backward), ``moe/shared``, ``mhc`` (and ``ffn`` for a
 leading dense layer) inside every layer; the train step stamps them beside
@@ -75,7 +77,8 @@ import jax
 import jax.numpy as jnp
 
 from byol_tpu.core import remat as remat_lib
-from byol_tpu.models.gated_delta import GatedDeltaNet, GatedDeltaSizes
+from byol_tpu.models.gated_delta import (GatedDeltaNet, GatedDeltaSizes,
+                                          causal_conv)
 from byol_tpu.ops import key_selection, sum_copies
 from byol_tpu.ops.attention import (blockwise_causal_attention,
                                     dense_attention, kept_probabilities,
@@ -88,9 +91,11 @@ HYBRID_SCOPES = ("gdn", "gdn/proj", "gdn/conv", "gdn/core", "gdn/gate_norm",
                  "gqa", "gqa/core") + _MOE_SCOPES
 SPARSE_SCOPES = ("dsa", "dsa/index", "dsa/select", "dsa/core",
                  "dsa/index_loss") + _MOE_SCOPES
+SHORTCONV_SCOPES = ("shortconv", "shortconv/proj", "shortconv/core", "gqa",
+                    "gqa/core") + _MOE_SCOPES + ("ffn",)
 # the expert layer's fallback (a step whose load passes twice the nominal
 # one) forms its rows whole under this size and in slabs from it on
-WHOLE_FALLBACK_BYTES = 1 << 30
+WHOLE_FALLBACK_BYTES = 1 << 29
 ROUTING = "routing"                  # flax collection of the routing counters
 ROUTING_FIELDS = ("rows_held", "load_max", "load_mean", "rows_dropped")
 SELECTION = "selection"              # ... of the key-selection counters
@@ -100,7 +105,7 @@ LAYER_LOSS = "layer_loss"            # ... of the scalar losses layers add
 
 @dataclasses.dataclass(frozen=True)
 class GatedAttentionSizes:
-    """One gated grouped-query attention layer."""
+    """One grouped-query attention layer, with an output gate or plain."""
 
     num_heads: int
     num_kv_heads: int
@@ -108,6 +113,11 @@ class GatedAttentionSizes:
     rotary_dim: int                  # leading dims of a head that rotate
     rope_theta: float
     block: int = 512                 # the program's own: keys a block
+    output_gate: bool = True         # sigmoid(gate) on the core's output
+    # the program's own: sequences the core takes at a time (0 = all).  A
+    # score tile is ``(group, H, block, block)`` float32 and the compiler
+    # keeps some twenty of them alive: at 32 heads and 8 sequences 5 GB
+    group: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,6 +156,7 @@ class TrunkSizes:
     n_shared_experts: int
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool
+    norm_topk_eps: float = 1e-20     # top-k weights / (their sum + this)
     vocab_size: int
     hc_mult: int = 1                 # residual streams; 1 = plain residual
     hc_sinkhorn_iters: int = 0
@@ -158,6 +169,10 @@ class TrunkSizes:
     gated_delta: Optional[GatedDeltaSizes] = None
     # sparse attention behind an indexer, every layer (no pattern)
     sparse_attention: Optional[SparseAttentionSizes] = None
+    # the pattern as a list: the mixer ('shortconv' | 'gqa') of every layer
+    # BUILT, in order; () = one of the rules above
+    layer_mixers: Tuple[str, ...] = ()
+    conv_taps: int = 0               # of a 'shortconv' layer's convolution
     scoring_func: str = "sigmoid"    # 'sigmoid' (noaux_tc bias) | 'softmax'
     shared_expert_gate: bool = False     # sigmoid(x w_s) on the shared expert
     zero_centred_norm: bool = False      # gains are 1 + w, w from zeros
@@ -176,6 +191,8 @@ class TrunkSizes:
 
     def mixer(self, layer: int) -> str:
         """The token mixer of layer ``layer``: its scope's name."""
+        if self.layer_mixers:
+            return self.layer_mixers[layer]
         if self.sparse_attention is not None:
             return "dsa"
         if not self.full_attention_interval:
@@ -184,10 +201,16 @@ class TrunkSizes:
             else "gdn"
 
     def with_depth(self, dense: int, sparse: int) -> "TrunkSizes":
-        """The same trunk cut to ``dense`` leading dense layers and
-        ``sparse`` expert layers."""
-        return dataclasses.replace(self, num_hidden_layers=dense + sparse,
-                                   first_k_dense_replace=dense)
+        """The same trunk cut to its first ``dense`` dense layers and its
+        first ``sparse`` expert layers.  A listed pattern keeps the mixer of
+        each layer kept, by its PUBLISHED index."""
+        first = self.first_k_dense_replace
+        kept = tuple(range(dense)) + tuple(range(first, first + sparse))
+        return dataclasses.replace(
+            self, num_hidden_layers=dense + sparse,
+            first_k_dense_replace=dense,
+            layer_mixers=tuple(self.layer_mixers[i] for i in kept)
+            if self.layer_mixers else ())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -385,42 +408,82 @@ def apply_half_rotary(x, cos, sin):
 
 
 class GatedAttention(nn.Module):
-    """Grouped-query softmax attention with an output gate (the public
-    ``qwen3_next`` modelling code): ``q`` comes with a gate of its own width
-    per head, ``q`` and ``k`` heads are RMS-normalised (zero-centred gain),
-    the first ``rotary_dim`` dims of a head rotate, ``kv_heads`` key/value
-    heads serve ``heads`` query heads, and ``out = softmax(.) v *
-    sigmoid(gate)`` goes through ``o``."""
+    """Grouped-query softmax attention (the public ``qwen3_next`` modelling
+    code; ``lfm2_moe``'s without the gate): ``q`` and ``k`` heads are
+    RMS-normalised (``zero_centred``: the gain is ``1 + w``), the first
+    ``rotary_dim`` dims of a head rotate, ``kv_heads`` key/value heads serve
+    ``heads`` query heads, and the core's output goes through ``o``.  With
+    ``sizes.output_gate`` ``q`` comes with a gate of its own width per head
+    and ``out = softmax(.) v * sigmoid(gate)``."""
 
     sizes: GatedAttentionSizes
     heads: int
     kv_heads: int
     eps: float = 1e-6
     dtype: jnp.dtype = jnp.float32
+    zero_centred: bool = True
 
     @nn.compact
     def __call__(self, h):
         z, dt = self.sizes, self.dtype
         b, s, d = h.shape
         dh = z.head_dim
-        q = _dense(self.heads * dh * 2, dt, "q")(h).reshape(
-            b, s, self.heads, 2 * dh)
-        q, gate = q[..., :dh], q[..., dh:]
+        gate = None
+        if z.output_gate:
+            q = _dense(self.heads * dh * 2, dt, "q")(h).reshape(
+                b, s, self.heads, 2 * dh)
+            q, gate = q[..., :dh], q[..., dh:]
+        else:
+            q = _dense(self.heads * dh, dt, "q")(h).reshape(
+                b, s, self.heads, dh)
         k = _dense(self.kv_heads * dh, dt, "k")(h).reshape(
             b, s, self.kv_heads, dh)
         v = _dense(self.kv_heads * dh, dt, "v")(h).reshape(
             b, s, self.kv_heads, dh)
-        norm = lambda name: RMSNorm(self.eps, dt, True, name=name)
+        norm = lambda name: RMSNorm(self.eps, dt, self.zero_centred,
+                                    name=name)
         cos, sin = half_rotary_tables(z.rope_theta, z.rotary_dim, s)
         q = apply_half_rotary(norm("q_norm")(q), cos, sin)
         k = apply_half_rotary(norm("k_norm")(k), cos, sin)
+        core = functools.partial(blockwise_causal_attention,
+                                 scale=dh ** -0.5, block=z.block)
         with jax.named_scope("core"):
-            out = blockwise_causal_attention(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3), scale=dh ** -0.5, block=z.block)
-        out = out.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
-            gate.astype(jnp.float32)).astype(dt)
+            q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+            if z.group and b > z.group and b % z.group == 0:
+                grouped = lambda x: x.reshape(
+                    (b // z.group, z.group) + x.shape[1:])
+                out = jax.lax.map(lambda xs: core(*xs),
+                                  (grouped(q), grouped(k), grouped(v)))
+                out = out.reshape((b,) + out.shape[2:])
+            else:
+                out = core(q, k, v)
+        out = out.transpose(0, 2, 1, 3)
+        if gate is not None:
+            out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
         return _dense(d, dt, "o")(out.reshape(b, s, self.heads * dh))
+
+
+class ShortConv(nn.Module):
+    """A gated short convolution (the public ``lfm2`` modelling code):
+    ``[B, C, u] = split3(x W_in)``, ``z[t] = sum_j taps[j] (B * u)[t - (K-1)
+    + j]`` (depthwise, causal, nothing before the sequence's start, no
+    bias), ``(C * z) W_out``.  No activation anywhere in it."""
+
+    taps: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        d, dt = h.shape[-1], self.dtype
+        with jax.named_scope("proj"):
+            mixed = _dense(3 * d, dt, "in_proj")(h)
+        with jax.named_scope("core"):
+            taps = self.param("conv", nn.initializers.lecun_normal(),
+                              (self.taps, d), jnp.float32)
+            gate_in, gate_out, u = jnp.split(mixed, 3, axis=-1)
+            out = gate_out * causal_conv(gate_in * u, taps.astype(dt))
+        with jax.named_scope("proj"):
+            return _dense(d, dt, "out_proj")(out)
 
 
 class SparseAttention(nn.Module):
@@ -618,7 +681,8 @@ class ExpertLayer(nn.Module):
                 _, chosen = jax.lax.top_k(scores + bias, k)
                 weight = jnp.take_along_axis(scores, chosen, axis=-1)
             if z.norm_topk_prob and k > 1:
-                weight = weight / (jnp.sum(weight, -1, keepdims=True) + 1e-20)
+                weight = weight / (jnp.sum(weight, -1, keepdims=True)
+                                   + z.norm_topk_eps)
             weight = weight * z.routed_scaling_factor
             local = chosen.reshape(-1) - self.lo
             here = (local >= 0) & (local < self.held)
@@ -709,10 +773,11 @@ class ExpertLayer(nn.Module):
             # the same product over ALL ``tokens x k`` copies: no capacity,
             # no dropped row, and the common step does not pay for the
             # worst one.  Where one ``(every, D)`` array of that fallback
-            # would reach ``WHOLE_FALLBACK_BYTES`` (a 128-way router's 262,144
-            # copies of 2,048, a 512-way router's 327,680: the branch not
-            # taken would hold 4 to 4.5 GB of the step's memory) it runs in
-            # slabs of the usual size.
+            # would reach ``WHOLE_FALLBACK_BYTES`` (a 64-way router's 131,072
+            # copies of 2,048 — beside them experts 1,536 wide — a 128-way
+            # router's 262,144, a 512-way router's 327,680: the branch not
+            # taken would hold 3.5 to 4.5 GB of the step's memory) it runs
+            # in slabs of the usual size.
             usual = min(every, -(-2 * every * self.held
                                  // z.n_routed_experts))
             whole = every * d * jnp.dtype(dt).itemsize < WHOLE_FALLBACK_BYTES
@@ -842,8 +907,8 @@ class TrunkLayer(nn.Module):
                 return _write_streams(streams, h_res, h_post, y)
 
         def attention(x):
-            # ``gdn``, ``gqa`` and ``dsa`` are modules named after their
-            # scope, as ``moe`` is
+            # ``gdn``, ``gqa``, ``dsa`` and ``shortconv`` are modules named
+            # after their scope, as ``moe`` is
             if self.mixer == "gdn":
                 d = z.gated_delta
                 return GatedDeltaNet(
@@ -853,7 +918,9 @@ class TrunkLayer(nn.Module):
                 a = z.gated_attention
                 return GatedAttention(
                     a, heads(a.num_heads), heads(a.num_kv_heads),
-                    z.rms_norm_eps, dt, name="gqa")(x)
+                    z.rms_norm_eps, dt, z.zero_centred_norm, name="gqa")(x)
+            if self.mixer == "shortconv":
+                return ShortConv(z.conv_taps, dt, name="shortconv")(x)
             if self.mixer == "dsa":
                 return SparseAttention(z.sparse_attention, z.rms_norm_eps,
                                        dt, name="dsa")(x)
@@ -885,6 +952,8 @@ class DecoderTrunk(nn.Module):
 
     @property
     def trace_scopes(self) -> Tuple[str, ...]:
+        if "shortconv" in self.sizes.layer_mixers:
+            return SHORTCONV_SCOPES
         if self.sizes.sparse_attention is not None:
             return SPARSE_SCOPES
         return HYBRID_SCOPES if self.sizes.full_attention_interval \
@@ -1018,3 +1087,39 @@ SPARSE_TINY = TrunkSizes(
         num_heads=4, num_kv_heads=2, head_dim=16, rope_theta=1e7,
         index_heads=2, index_head_dim=8, topk=6, block=8),
     scoring_func="softmax", rms_norm_eps=1e-6)
+
+# LFM2-24B-A2B, from its public config.json (``model_type: lfm2_moe``): 40
+# layers, ``layer_types`` lists 30 gated short convolutions (3 taps, no bias)
+# and 10 grouped-query attention layers (32 query on 8 key/value heads of
+# 64 = hidden / heads, rotary theta 1e6, at ``i % 4 == 2``); the first 2
+# dense (SwiGLU of 11,776), the others sparse (64 experts of width 1,536,
+# top-4, sigmoid scores with a selection bias, ``norm_topk_prob`` over
+# ``sum + 1e-6``, NO shared expert).
+LFM2_24B_A2B = TrunkSizes(
+    hidden_size=2048, num_hidden_layers=40, first_k_dense_replace=2,
+    intermediate_size=11776, n_routed_experts=64, moe_intermediate_size=1536,
+    num_experts_per_tok=4, n_shared_experts=0, routed_scaling_factor=1.0,
+    norm_topk_prob=True, norm_topk_eps=1e-6, vocab_size=65536,
+    layer_mixers=tuple("gqa" if i % 4 == 2 else "shortconv"
+                       for i in range(40)),
+    conv_taps=3,
+    gated_attention=GatedAttentionSizes(
+        num_heads=32, num_kv_heads=8, head_dim=64, rotary_dim=64,
+        rope_theta=1e6, output_gate=False, group=2),
+    scoring_func="sigmoid", rms_norm_eps=1e-5)
+
+# The short-convolution trunk at test size (tests/test_shortconv_trunk.py):
+# 7 published layers, 2 dense, attention at ``i % 4 == 2``; cut ``1+4`` it
+# is published layers 0, 2, 3, 4, 5: conv | attention, conv, conv, conv.
+SHORTCONV_TINY = TrunkSizes(
+    hidden_size=32, num_hidden_layers=7, first_k_dense_replace=2,
+    intermediate_size=64, n_routed_experts=8, moe_intermediate_size=16,
+    num_experts_per_tok=2, n_shared_experts=0, routed_scaling_factor=1.0,
+    norm_topk_prob=True, norm_topk_eps=1e-6, vocab_size=128,
+    layer_mixers=tuple("gqa" if i % 4 == 2 else "shortconv"
+                       for i in range(7)),
+    conv_taps=3,
+    gated_attention=GatedAttentionSizes(
+        num_heads=4, num_kv_heads=2, head_dim=8, rotary_dim=8,
+        rope_theta=1e6, block=8, output_gate=False, group=2),
+    scoring_func="sigmoid", rms_norm_eps=1e-5)

@@ -110,12 +110,15 @@ def _register_decoder_trunks() -> None:
     # huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct, config.json
     # keye_vl2_30b_a3b (the language model):
     # huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B, config.json
+    # lfm2_24b_a2b: huggingface.co/LiquidAI/LFM2-24B-A2B, config.json
     for name, sizes in (("xing4_29b_a4b", trunk_lib.XING4_29B_A4B),
                         ("decoder_trunk_tiny", trunk_lib.TINY),
                         ("qwen3_next_80b_a3b", trunk_lib.QWEN3_NEXT_80B_A3B),
                         ("hybrid_trunk_tiny", trunk_lib.HYBRID_TINY),
                         ("keye_vl2_30b_a3b", trunk_lib.KEYE_VL2_30B_A3B),
-                        ("sparse_trunk_tiny", trunk_lib.SPARSE_TINY)):
+                        ("sparse_trunk_tiny", trunk_lib.SPARSE_TINY),
+                        ("lfm2_24b_a2b", trunk_lib.LFM2_24B_A2B),
+                        ("shortconv_trunk_tiny", trunk_lib.SHORTCONV_TINY)):
         def factory(dtype=jnp.float32, small_inputs=False, _z=sizes,
                     layer_share="0/1", trunk_depth="", **kw):
             del small_inputs

@@ -72,6 +72,37 @@ def run_steps(rcfg, mesh, n=3):
     return state, {k: float(v) for k, v in metrics.items()}
 
 
+def _snapshot(tree):
+    """Host copies: device_get is zero-copy on CPU and the jitted step
+    DONATES the state, so a buffer read later would have been overwritten
+    in place."""
+    return jax.tree_util.tree_map(lambda x: np.array(x, copy=True),
+                                  jax.device_get(tree))
+
+
+_RUNS = {}
+
+
+def followed_steps(mesh, n=3, **overrides):
+    """``run_steps`` of ``tiny_config(**overrides)`` ONCE for all the tests
+    that compare against it: ``{k: (state, metrics)}`` after each of ``n``
+    steps (host copies), and ``"batch_stats0"``, the statistics before the
+    first.  A set-up and a compile are most of these tests' time."""
+    key = tuple(sorted((k, str(v)) for k, v in overrides.items())) + (n,)
+    if key not in _RUNS:
+        net, state, train_step, _, _ = setup_training(
+            tiny_config(**overrides), mesh, jax.random.PRNGKey(0))
+        train_step = guard_steps(train_step)
+        out = {"batch_stats0": _snapshot(state.batch_stats)}
+        for i in range(n):
+            state, metrics = train_step(
+                state, shard_batch_to_mesh(make_batch(seed=i), mesh))
+            out[i + 1] = (_snapshot(state),
+                          {k: float(v) for k, v in metrics.items()})
+        _RUNS[key] = out
+    return _RUNS[key]
+
+
 def tree_maxdiff(a, b):
     la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
     assert len(la) == len(lb)
@@ -86,7 +117,7 @@ class TestAccumulationParity:
         step for accum_bn_mode='global' — params bitwise-close after 3 real
         LARS updates, BN running stats in sync, step counter advanced once
         per effective batch (3, not 3*k)."""
-        big, big_m = run_steps(tiny_config(), mesh8)
+        big, big_m = followed_steps(mesh8)[3]
         acc, acc_m = run_steps(
             tiny_config(accum_steps=4, accum_bn_mode="global"), mesh8)
         assert int(acc.step) == int(big.step) == 3
@@ -106,19 +137,9 @@ class TestAccumulationParity:
         batch, finite metrics, moving params and running stats.  (They
         deliberately differ from the big batch in BN granularity, so no
         equality assertion — that is what 'global' is for.)"""
-        rcfg = tiny_config(accum_steps=4, accum_bn_mode=bn_mode)
-        net, state, train_step, _, _ = setup_training(
-            rcfg, mesh8, jax.random.PRNGKey(0))
-        train_step = guard_steps(train_step)
-        # device_get is zero-copy on CPU and the jitted step DONATES the
-        # state, so the buffer is overwritten in place — snapshot by copy.
-        bs_before = jax.tree_util.tree_map(
-            lambda x: np.array(x, copy=True),
-            jax.device_get(state.batch_stats))
-        state, m1 = train_step(state, shard_batch_to_mesh(make_batch(0),
-                                                          mesh8))
-        state, m2 = train_step(state, shard_batch_to_mesh(make_batch(1),
-                                                          mesh8))
+        run = followed_steps(mesh8, n=2, accum_steps=4,
+                             accum_bn_mode=bn_mode)
+        bs_before, (_, m1), (state, m2) = run["batch_stats0"], run[1], run[2]
         assert int(state.step) == 2          # optimizer steps, not k*2
         assert int(state.ema_step) == 2
         for k, v in {**m1, **m2}.items():
@@ -130,12 +151,10 @@ class TestAccumulationParity:
         microbatch); from identical init their FIRST step must produce
         identical losses/gradients — they diverge only through the
         running-stat tick, which the first forward does not read."""
-        _, m_avg = run_steps(tiny_config(accum_steps=4,
-                                         accum_bn_mode="average"),
-                             mesh8, n=1)
-        _, m_mb = run_steps(tiny_config(accum_steps=4,
-                                        accum_bn_mode="microbatch"),
-                            mesh8, n=1)
+        _, m_avg = followed_steps(mesh8, n=2, accum_steps=4,
+                                  accum_bn_mode="average")[1]
+        _, m_mb = followed_steps(mesh8, n=2, accum_steps=4,
+                                 accum_bn_mode="microbatch")[1]
         for k in m_avg:
             np.testing.assert_allclose(m_mb[k], m_avg[k], rtol=1e-5,
                                        err_msg=k)
@@ -224,7 +243,7 @@ class TestRematPolicies:
     def test_policy_is_numerically_inert(self, mesh8, policy):
         """Remat trades FLOPs for memory; the math must not move: same
         metrics and same post-step params as the un-rematted graph."""
-        plain, plain_m = run_steps(tiny_config(), mesh8, n=2)
+        plain, plain_m = followed_steps(mesh8)[2]
         remat, remat_m = run_steps(
             tiny_config(model={"remat_policy": policy}), mesh8, n=2)
         for k in plain_m:

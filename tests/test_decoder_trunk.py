@@ -82,8 +82,9 @@ def test_features_match_the_reference(share, remat_policy):
     tokens = _tokens(0, vocab=32)
     module = _trunk(share, remat_policy=remat_policy)
     params = _seeded(module, tokens)
-    got = module.apply({"params": params}, tokens)
-    want = _reference_features(params, tokens, share)
+    # each side ONE compiled program (op by op, the first case took minutes)
+    got = jax.jit(lambda p: module.apply({"params": p}, tokens))(params)
+    want = jax.jit(lambda p: _reference_features(p, tokens, share))(params)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
@@ -93,10 +94,10 @@ def test_every_gradient_leaf_matches_the_reference():
     params = _seeded(module, tokens)
     ct = jnp.asarray(np.random.default_rng(2).normal(size=(BATCH, 64)),
                      jnp.float32)
-    got = jax.grad(lambda p: jnp.sum(
-        module.apply({"params": p}, tokens) * ct))(params)
-    want = jax.grad(lambda p: jnp.sum(
-        _reference_features(p, tokens) * ct))(params)
+    got = jax.jit(jax.grad(lambda p: jnp.sum(
+        module.apply({"params": p}, tokens) * ct)))(params)
+    want = jax.jit(jax.grad(lambda p: jnp.sum(
+        _reference_features(p, tokens) * ct)))(params)
     flat_got = jax.tree_util.tree_flatten_with_path(got)[0]
     flat_want = jax.tree_util.tree_leaves(want)
     largest = max(float(jnp.linalg.norm(w)) for w in flat_want)
@@ -105,6 +106,18 @@ def test_every_gradient_leaf_matches_the_reference():
         gap = float(jnp.linalg.norm(g - w))
         assert gap <= 1e-3 * float(jnp.linalg.norm(w)) + 1e-6 * largest, \
             (jax.tree_util.keystr(path), gap, float(jnp.linalg.norm(w)))
+
+
+@pytest.fixture(scope="module")
+def training():
+    """ONE set-up and ONE compiled step for the tests that drive it (the
+    step donates its state: a test steps a copy)."""
+    with jax.default_matmul_precision("highest"):
+        return _training(telemetry="step")
+
+
+def _copy(state):
+    return jax.tree_util.tree_map(jnp.array, state)
 
 
 def _training(share="1/2", telemetry="off", zero1=False):
@@ -140,17 +153,17 @@ def _batches(n, seed=3):
             for _ in range(n)]
 
 
-def test_three_optimizer_steps_match_the_reference():
+def test_three_optimizer_steps_match_the_reference(training):
     from byol_tpu.optim.factory import extract_sgdm_state
-    rcfg, mesh, state, step = _training()
+    rcfg, mesh, state, step = training
     like = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
         (state.params, state.batch_stats))
     params, target, stats = weights_decoder_trunk.make_weights(
         *like, 11, copies=2)
     params0 = jax.device_get(params)
-    state = state.replace(params=params, target_params=target,
-                          batch_stats=stats)
+    state = _copy(state).replace(params=params, target_params=target,
+                                 batch_stats=stats)
     batches = _batches(3)
     losses, first = [], None
     for i, b in enumerate(batches):
@@ -405,14 +418,14 @@ def test_zero1_names_a_tree_with_an_expert_axis():
         _training(zero1=True)
 
 
-def test_the_step_stamps_the_trunks_scopes_and_counts_its_routing():
-    rcfg, mesh, state, step = _training(telemetry="step")
+def test_the_step_stamps_the_trunks_scopes_and_counts_its_routing(training):
+    rcfg, mesh, state, step = training
     batch = shard_batch_to_mesh(dict(_batches(1)[0]), mesh)
     with mesh:
         text = step.__wrapped__.lower(state, batch).as_text()
     for scope in trunk_lib.TRACE_SCOPES:
         assert scope in text.split('phase_scopes = "')[1].split('"')[0]
-    _, metrics = step(state, batch)
+    _, metrics = step(_copy(state), batch)
     from byol_tpu.observability import health
     record = health.unpack(metrics["health"])
     # two routing layers x (2 views x 4 sequences x 16 positions) x top-2,

@@ -44,9 +44,15 @@ def _inputs(seq, *, batch=2, key_heads=2, shared=1, dk=8, dv=16, seed=0,
 
 def _value_and_grads(monkeypatch, kernels, inputs, **kw):
     monkeypatch.setattr(delta_rule, "applies", lambda *a, **k: kernels)
-    rule = lambda *a: gated_delta.chunked_delta_rule(*a, **kw)
-    loss = lambda *a: jnp.sum(jnp.sin(rule(*a).astype(jnp.float32)))
-    return rule(*inputs), jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*inputs)
+
+    def loss(*a):
+        out = gated_delta.chunked_delta_rule(*a, **kw)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+    # value and gradients as ONE compiled program a path: op by op, the
+    # interpreter's every step was a dispatch of its own
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*inputs)
+    return out, grads
 
 
 # relative to the norm: of the output, of each gradient

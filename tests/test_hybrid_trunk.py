@@ -18,6 +18,7 @@ comparisons FAIL by three orders of magnitude when the rule's products run
 in bfloat16 (the last test of the rule): a lower precision does not pass.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +82,18 @@ def _sizes(share=SHARE):
 def _reference_features(params, tokens, share=SHARE):
     return jnp.stack([reference.trunk(params, t, _sizes(share))
                       for t in tokens])
+
+
+@functools.lru_cache(maxsize=None)
+def _features_case(share):
+    """Tokens, seeded weights and the reference's features of one share:
+    the same for every remat policy (ONE compiled program a share)."""
+    tokens = _tokens(0)
+    params = _seeded(_trunk(share), tokens)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda p: _reference_features(p, tokens, share))(
+            params)
+    return tokens, params, want
 
 
 def _leafwise_close(got, want, rtol=1e-3):
@@ -260,26 +273,45 @@ def test_half_rotary_turns_the_pairs_i_and_i_plus_half_and_leaves_the_rest():
 @pytest.mark.parametrize("share,remat_policy", [
     ("0/1", "none"), ("0/1", "full"), (SHARE, "none"), (SHARE, "full")])
 def test_features_match_the_reference(share, remat_policy):
-    tokens = _tokens(0)
+    tokens, params, want = _features_case(share)
     module = _trunk(share, remat_policy=remat_policy)
-    params = _seeded(module, tokens)
-    got = module.apply({"params": params}, tokens)
-    want = _reference_features(params, tokens, share)
+    got = jax.jit(lambda p: module.apply({"params": p}, tokens))(params)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@functools.lru_cache(maxsize=None)
+def _gradient_case():
+    """Tokens, seeded weights, a cotangent and the reference's gradient: ONE
+    compiled program, whatever the remat policy it is compared with."""
+    tokens = _tokens(1)
+    params = _seeded(_trunk(), tokens)
+    ct = jnp.asarray(np.random.default_rng(2).normal(size=(BATCH, 32)),
+                     jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.grad(lambda p: jnp.sum(
+            _reference_features(p, tokens) * ct)))(params)
+    return tokens, params, ct, want
 
 
 @pytest.mark.parametrize("remat_policy", ["none", "full"])
 def test_every_gradient_leaf_matches_the_reference(remat_policy):
-    tokens = _tokens(1)
+    tokens, params, ct, want = _gradient_case()
     module = _trunk(remat_policy=remat_policy)
-    params = _seeded(module, tokens)
-    ct = jnp.asarray(np.random.default_rng(2).normal(size=(BATCH, 32)),
-                     jnp.float32)
-    got = jax.grad(lambda p: jnp.sum(
-        module.apply({"params": p}, tokens) * ct))(params)
-    want = jax.grad(lambda p: jnp.sum(
-        _reference_features(p, tokens) * ct))(params)
+    got = jax.jit(jax.grad(lambda p: jnp.sum(
+        module.apply({"params": p}, tokens) * ct)))(params)
     assert _leafwise_close(got, want) > 60
+
+
+@pytest.fixture(scope="module")
+def training():
+    """ONE set-up and ONE compiled step for the tests that drive it (the
+    step donates its state: a test steps a copy)."""
+    with jax.default_matmul_precision("highest"):
+        return _training(telemetry="step")
+
+
+def _copy(state):
+    return jax.tree_util.tree_map(jnp.array, state)
 
 
 def _training(share=SHARE, telemetry="off"):
@@ -314,17 +346,17 @@ def _batches(n, seed=3):
             for _ in range(n)]
 
 
-def test_three_optimizer_steps_match_the_reference():
+def test_three_optimizer_steps_match_the_reference(training):
     from byol_tpu.optim.factory import extract_sgdm_state
-    rcfg, mesh, state, step = _training()
+    rcfg, mesh, state, step = training
     like = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
         (state.params, state.batch_stats))
     params, target, stats = weights_hybrid_trunk.make_weights(
         *like, 11, copies=2)
     params0 = jax.device_get(params)
-    state = state.replace(params=params, target_params=target,
-                          batch_stats=stats)
+    state = _copy(state).replace(params=params, target_params=target,
+                                 batch_stats=stats)
     batches = _batches(3)
     losses, first = [], None
     for i, b in enumerate(batches):
@@ -505,8 +537,8 @@ def test_lars_leaves_the_gates_and_gains_alone_and_adapts_each_expert():
             rtol=1e-5)
 
 
-def test_the_step_stamps_gdn_and_gqa_and_counts_a_512_free_routing():
-    rcfg, mesh, state, step = _training(telemetry="step")
+def test_the_step_stamps_gdn_and_gqa_and_counts_a_512_free_routing(training):
+    rcfg, mesh, state, step = training
     batch = shard_batch_to_mesh(dict(_batches(1)[0]), mesh)
     with mesh:
         text = step.__wrapped__.lower(state, batch).as_text()
@@ -514,7 +546,7 @@ def test_the_step_stamps_gdn_and_gqa_and_counts_a_512_free_routing():
     for scope in trunk_lib.HYBRID_SCOPES:
         assert scope in stamped
     assert "mla" not in stamped and "mhc" not in stamped
-    _, metrics = step(state, batch)
+    _, metrics = step(_copy(state), batch)
     # four routing layers x (2 views x 4 sequences x 20 positions) x top-3,
     # a quarter of the experts held: 480 copies expected, none dropped
     assert 200 < float(metrics["_moe_rows_held"]) < 900

@@ -61,15 +61,17 @@ class TestResNet:
         variables = conv_net.init(jax.random.PRNGKey(0), x, train=False)
         k_shape = variables["params"]["stem_conv"]["kernel"].shape
         assert k_shape == (7, 7, 3, 64)
-        out_conv = conv_net.apply(variables, x, train=False, mutable=False)
-        out_s2d = s2d_net.apply(variables, x, train=False, mutable=False)
+        # each net ONE compiled program: output and gradient together
+        def out_and_grad(net):
+            def loss(v):
+                out = net.apply(v, x, train=False, mutable=False)
+                return jnp.sum(out ** 2), out
+            (_, out), grad = jax.jit(jax.value_and_grad(
+                loss, has_aux=True))(variables)
+            return out, grad
+        out_conv, g_conv = out_and_grad(conv_net)
+        out_s2d, g_s2d = out_and_grad(s2d_net)
         assert jnp.max(jnp.abs(out_conv - out_s2d)) < 1e-4
-
-        def loss(net):
-            return lambda v: jnp.sum(
-                net.apply(v, x, train=False, mutable=False) ** 2)
-        g_conv = jax.grad(loss(conv_net))(variables)
-        g_s2d = jax.grad(loss(s2d_net))(variables)
         gk_conv = g_conv["params"]["stem_conv"]["kernel"]
         gk_s2d = g_s2d["params"]["stem_conv"]["kernel"]
         assert jnp.max(jnp.abs(gk_conv - gk_s2d)) < 1e-3

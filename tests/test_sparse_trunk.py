@@ -313,6 +313,18 @@ def test_the_trunks_features_loss_and_gradients_match_the_reference():
     assert "shared" not in like["layer0"]["moe"]
 
 
+@pytest.fixture(scope="module")
+def training():
+    """ONE set-up and ONE compiled step for the tests that drive it (the
+    step donates its state: a test steps a copy)."""
+    with jax.default_matmul_precision("highest"):
+        return _training(telemetry="step")
+
+
+def _copy(state):
+    return jax.tree_util.tree_map(jnp.array, state)
+
+
 def _training(telemetry="off"):
     """The normal path: Config -> resolve -> mesh -> plan ->
     setup_training, at the tiny preset."""
@@ -354,29 +366,31 @@ def test_the_references_expert_loads_are_the_programs_routing():
         jax.random.PRNGKey(0), tokens))["params"]
     params = _seeded(like)
     rows = 0
+    layer = jax.jit(lambda p, x: reference.trunk_layer(p, x, _sizes(),
+                                                       "float32"))
     for sequence in tokens:
         x = params["embed"]["embedding"][sequence]
         for name in ("layer0", "layer1"):
-            x, _, here = reference.trunk_layer(params[name], x, _sizes(),
-                                               "float32")
+            x, _, here = layer(params[name], x)
             assert here.shape == (2,)
             rows += int(here.sum())
-    _, sown = trunk.apply({"params": params}, tokens, mutable=SOWN)
+    _, sown = jax.jit(lambda p: trunk.apply({"params": p}, tokens,
+                                            mutable=SOWN))(params)
     stats = sum(jax.tree_util.tree_leaves(sown[trunk_lib.ROUTING]))
     assert rows == int(stats[0]) > 0
 
 
-def test_three_optimizer_steps_match_the_reference():
+def test_three_optimizer_steps_match_the_reference(training):
     from byol_tpu.optim.factory import extract_sgdm_state
-    _, mesh, state, step = _training()
+    _, mesh, state, step = training
     like = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
         (state.params, state.batch_stats))
     params, target, stats = weights_sparse_trunk.make_weights(
         *like, 11, copies=2)
     params0 = jax.device_get(params)
-    state = state.replace(params=params, target_params=target,
-                          batch_stats=stats)
+    state = _copy(state).replace(params=params, target_params=target,
+                                 batch_stats=stats)
     batches = _batches(3)
     losses, index_losses, first = [], [], None
     for i, b in enumerate(batches):
@@ -491,9 +505,10 @@ def test_lars_adapts_the_indexers_kernels_and_leaves_the_gains_alone():
     assert dsa["q_norm"]["scale"] is False and dsa["k_norm"]["scale"] is False
 
 
-def test_the_step_stamps_dsa_counts_the_selection_and_averages_two_views():
+def test_the_step_stamps_dsa_counts_the_selection_and_averages_two_views(
+        training):
     from byol_tpu.training.steps import _forward_views
-    net, mesh, state, step = _training(telemetry="step")
+    net, mesh, state, step = training
     host = _batches(1)[0]
     batch = shard_batch_to_mesh(dict(host), mesh)
     with mesh:
@@ -510,7 +525,7 @@ def test_the_step_stamps_dsa_counts_the_selection_and_averages_two_views():
             for fuse in (True, False)]
     for name in (trunk_lib.LAYER_LOSS, trunk_lib.SELECTION):
         np.testing.assert_allclose(sown[1][name], sown[0][name], rtol=1e-5)
-    _, metrics = step(state, batch)
+    _, metrics = step(_copy(state), batch)
     # two layers x (2 views x 4 sequences): every causal pair scored, six
     # keys a query kept
     rows = 2 * 2 * BATCH

@@ -374,10 +374,10 @@ def test_selected_attention_falls_back_to_jax_numpy(
     assert "tpu_custom_call" not in text and " while(" in text
 
 
-def _compile_train_step(topo, rcfg, batch):
+def _compile_train_step(topo, rcfg, batch, view=None):
     """The jitted step (``--fuse-views``, bf16, LARS), built from the
     compile plan exactly as setup_training wires it, compiled for one
-    described chip."""
+    described chip; ``view``: one view's struct where it is no image."""
     from byol_tpu.core.precision import get_policy
     from byol_tpu.parallel.compile_plan import build_plan
     from byol_tpu.training.build import (build_net, build_tx,
@@ -396,7 +396,8 @@ def _compile_train_step(topo, rcfg, batch):
         make_train_step(net, tx, step_config(rcfg), get_policy(True),
                         mesh=mesh),
         plan.state_sharding(state))
-    view = jax.ShapeDtypeStruct((batch, IMAGE, IMAGE, 3), jnp.float32)
+    if view is None:
+        view = jax.ShapeDtypeStruct((batch, IMAGE, IMAGE, 3), jnp.float32)
     views = {"view1": view, "view2": view,
              "label": jax.ShapeDtypeStruct((batch,), jnp.int32)}
     with mesh:
@@ -459,3 +460,49 @@ def test_vitb16_train_step_keeps_attention_on_chip(no_persistent_cache, topo,
     cost = cost[0] if isinstance(cost, (list, tuple)) else cost
     assert cost["bytes accessed"] < 115e9, cost["bytes accessed"]
     assert 0 < _program_bytes(compiled) < 7 * 2 ** 30
+
+
+# ---------------------------------------------------------------------------
+# the short-convolution trunk's whole step at its cell's sizes
+# (benchmarks/workloads/lfm2_train_b4_s4096.json): what the chip run relies on
+# ---------------------------------------------------------------------------
+
+def test_lfm2_train_step_fits_and_keeps_its_scopes(no_persistent_cache, topo,
+                                                   monkeypatch):
+    """``lfm2_train_b4_s4096``'s step, lowered as on a TPU
+    (``sum_copies.applies`` asks ``jax.default_backend()``) from the
+    configuration file's own flags: it fits the chip, the four routing
+    layers' combines are 12 ``sum_copies`` kernels (target, online, dispatch
+    backward; the recomputed forward's feeds no gradient) and as many in the
+    fallback's slabs, which no usual step runs; no ``[.., S, S]`` array is
+    written, a score tile is 2 sequences' (``GatedAttentionSizes.group``),
+    and the ops carry the ``shortconv`` scopes."""
+    import json
+    import os
+    import re
+    from benchmarks.drivers.train_tokens import program_config
+    from byol_tpu.core import config as config_lib
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "byol_lfm2_24b_a2b_ep8.json")) as f:
+        conf = json.load(f)
+    batch, seq = conf["per_chip_batch"], conf["seq_len"]
+    rcfg = config_lib.resolve(
+        program_config(conf, seed=0, chips=1),
+        num_train_samples=conf["schedule"]["steps_per_epoch"] * batch,
+        num_test_samples=batch, output_size=conf["num_classes"],
+        input_shape=(seq,))
+    compiled = _compile_train_step(
+        topo, rcfg, batch, jax.ShapeDtypeStruct((batch, seq), jnp.int32))
+    print(f"lfm2 step: {_program_bytes(compiled) / 2 ** 30:.2f} GiB")
+    assert 4 * 2 ** 30 < _program_bytes(compiled) < V5E_HBM_BYTES, \
+        f"{_program_bytes(compiled) / 2 ** 30:.2f} GiB"
+    text = compiled.as_text()
+    assert len(re.findall(r"custom-call\([^\n]*sum_copies", text)) == 2 * 12
+    assert not re.search(rf"\[[\d,]*{seq},{seq}\]", text)
+    assert "f32[2,8,4,512,512]" in text  # the core's tiles of one block pair
+    assert "f32[8,8,4,512,512]" not in text
+    for scope in ("shortconv/proj", "shortconv/core", "gqa/core", "/ffn/",
+                  "moe/experts/combine"):
+        assert scope in text, scope
