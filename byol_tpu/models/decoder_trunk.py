@@ -114,9 +114,11 @@ class GatedAttentionSizes:
     rope_theta: float
     block: int = 512                 # the program's own: keys a block
     output_gate: bool = True         # sigmoid(gate) on the core's output
-    # the program's own: sequences the core takes at a time (0 = all).  A
-    # score tile is ``(group, H, block, block)`` float32 and the compiler
-    # keeps some twenty of them alive: at 32 heads and 8 sequences 5 GB
+    # the program's own, and the core's ``jax.numpy`` lowering's alone (the
+    # kernels of ops/causal_attention.py take the whole batch): sequences a
+    # pass (0 = all).  There a score tile is ``(group, H, block, block)``
+    # float32 in HBM and the compiler keeps some twenty of them alive: at 32
+    # heads and 8 sequences 5 GB
     group: int = 0
 
 
@@ -445,18 +447,10 @@ class GatedAttention(nn.Module):
         cos, sin = half_rotary_tables(z.rope_theta, z.rotary_dim, s)
         q = apply_half_rotary(norm("q_norm")(q), cos, sin)
         k = apply_half_rotary(norm("k_norm")(k), cos, sin)
-        core = functools.partial(blockwise_causal_attention,
-                                 scale=dh ** -0.5, block=z.block)
         with jax.named_scope("core"):
             q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-            if z.group and b > z.group and b % z.group == 0:
-                grouped = lambda x: x.reshape(
-                    (b // z.group, z.group) + x.shape[1:])
-                out = jax.lax.map(lambda xs: core(*xs),
-                                  (grouped(q), grouped(k), grouped(v)))
-                out = out.reshape((b,) + out.shape[2:])
-            else:
-                out = core(q, k, v)
+            out = blockwise_causal_attention(
+                q, k, v, scale=dh ** -0.5, block=z.block, group=z.group)
         out = out.transpose(0, 2, 1, 3)
         if gate is not None:
             out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)

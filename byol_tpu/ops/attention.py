@@ -16,9 +16,15 @@ so the model swaps between them by name without re-plumbing:
                 (:func:`packed_kernel_applies`): same arithmetic, one
                 kernel forward and one backward;
   ``blockwise`` (:func:`blockwise_causal_attention`, the decoder trunk's
-                gated grouped-query layers) — the same arithmetic, causal,
-                over blocks of keys with a running max and sum, forward and
-                backward, so that no ``[S, S]`` array exists at any length;
+                grouped-query layers, gated or plain) — the same arithmetic,
+                causal, over blocks of keys with a running max and sum,
+                forward and backward, so that no ``[S, S]`` array exists at
+                any length.  Where the program lowers for a TPU and the
+                shapes allow (``causal_attention.applies``: heads of 64, 128
+                or 256), the Pallas kernels of ops/causal_attention.py: a
+                tile's scores and weights never leave VMEM; elsewhere plain
+                ``jax.numpy`` — ``[B,Hkv,G,block,block]`` float32 tiles
+                through HBM;
   ``selected`` (:func:`selected_attention`, the decoder trunk's
                 sparse-attention layers) — the same again with each query's
                 softmax over a SET of its causal keys
@@ -180,7 +186,8 @@ _blockwise_causal.defvjp(_blockwise_causal_fwd, _blockwise_causal_bwd)
 def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
                                v: jnp.ndarray, *,
                                scale: Optional[float] = None,
-                               block: int = 512) -> jnp.ndarray:
+                               block: int = 512,
+                               group: int = 0) -> jnp.ndarray:
     """Causal softmax attention whose memory is linear in S, forward and
     backward: ``(B, Hq, S, D)`` queries on ``(B, Hkv, S, D)`` keys and
     values, each key/value head shared by ``Hq / Hkv`` consecutive query
@@ -188,17 +195,38 @@ def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
     running max and sum; nothing larger than one ``(B, Hq, block, block)``
     tile of scores is ever held, block pairs above the diagonal are
     skipped, and the backward recomputes the tiles from ``q, k`` and the
-    saved log-sum-exp (``jax.custom_vjp``).  Plain ``jax.numpy``, not a
-    kernel: every tile crosses HBM once (ROADMAP R2).  Statistics in
-    float32, products in the input dtype."""
+    saved log-sum-exp (``jax.custom_vjp``).  Statistics in float32, products
+    in the input dtype.  Two lowerings of one arithmetic, chosen from what
+    the code can see (``ops/causal_attention.applies``): where the program
+    lowers for a TPU, ``block`` is a multiple of 128, a head is 64 wide or a
+    multiple of 128 and the working set fits VMEM, the Pallas kernels
+    ``causal_attention_fwd`` / ``causal_attention_bwd`` of
+    ops/causal_attention.py over the whole batch — a tile's scores, weights
+    and their cotangents live and die in VMEM; everywhere else (the CPU, the
+    tiny presets, odd shapes) plain ``jax.numpy``, the block pairs unrolled
+    in Python, every ``(B, Hkv, G, block, block)`` float32 tile through HBM
+    — which is also the tests' oracle for the kernels.  ``group`` > 0 is the
+    ``jax.numpy`` lowering's alone: that many sequences a pass (a
+    ``lax.map``; where it divides ``B``), because the compiler keeps some
+    twenty tiles alive at once."""
+    from byol_tpu.ops import causal_attention as kernels     # imports this
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     if hq % hkv:
         raise ValueError(f"{hq} query heads do not share {hkv} key heads")
     if scale is None:
         scale = d ** -0.5
-    out = _blockwise_causal(q.reshape(b, hkv, hq // hkv, s, d), k, v,
-                            float(scale), int(block))
+    grouped = q.reshape(b, hkv, hq // hkv, s, d)
+    body = lambda *qkv: _blockwise_causal(*qkv, float(scale), int(block))
+    if d == v.shape[-1] and kernels.applies(block, d, s, hq, hkv, q.dtype):
+        out = kernels.attend(grouped, k, v, scale=scale, block=block)
+    elif group and b > group and b % group == 0:
+        split = lambda x: x.reshape((b // group, group) + x.shape[1:])
+        out = jax.lax.map(lambda qkv: body(*qkv),
+                          (split(grouped), split(k), split(v)))
+        out = out.reshape((b,) + out.shape[2:])
+    else:
+        out = body(grouped, k, v)
     return out.reshape(b, hq, s, v.shape[-1])
 
 

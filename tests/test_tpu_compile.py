@@ -235,6 +235,89 @@ def test_blockwise_causal_attention_writes_no_square_at_4096(
     assert ",512,512]" in text                    # tiles of one block pair
 
 
+def _causal_core_text(one_chip, monkeypatch, *, backend, heads, kv_heads,
+                      dim, seq=4096, block=512, batch=8):
+    """The causal core, forward and backward, compiled for the described v5e
+    as ``backend`` would lower it, a cell's whole batch a call."""
+    from byol_tpu.ops.attention import blockwise_causal_attention
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    like = lambda h: jax.ShapeDtypeStruct((batch, h, seq, dim), jnp.bfloat16,
+                                          sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(jnp.square(blockwise_causal_attention(
+            q, k, v, block=block, group=2).astype(jnp.float32)))
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        like(heads), like(kv_heads), like(kv_heads)).compile().as_text()
+
+
+def _float32_squares(text, block=512):
+    """Float32 ``[.., block, block]`` arrays of three dimensions or more: a
+    score tile has a head in front (a plain ``f32[512,512]`` is the
+    compiler's slice of a ``[2048, 512]`` projection)."""
+    import re
+    return re.findall(rf"f32\[[\d,]+,{block},{block}\]", text)
+
+
+@pytest.mark.parametrize("heads,kv_heads,dim", [
+    (16, 2, 256),       # qwen3next_train_b4_s4096: (8, 16, 4096, 256)
+    (32, 8, 64),        # lfm2_train_b4_s4096: (8, 32, 4096, 64)
+    (32, 4, 128)])
+def test_causal_attention_kernels_at_the_published_sizes(
+        no_persistent_cache, one_chip, monkeypatch, heads, kv_heads, dim):
+    """On a TPU the core is ``causal_attention_fwd`` and
+    ``causal_attention_bwd`` over all 8 sequences at once: no float32
+    ``(.., 512, 512)`` array and no loop is left outside them."""
+    text = _causal_core_text(one_chip, monkeypatch, backend="tpu",
+                             heads=heads, kv_heads=kv_heads, dim=dim)
+    assert _core_kernel_calls(text, "causal_attention") == [1, 1]
+    assert not _float32_squares(text)
+    assert " while(" not in text
+
+
+@pytest.mark.parametrize("backend,sizes", [
+    ("cpu", dict(heads=32, kv_heads=8, dim=64)),  # not lowered for a TPU
+    ("tpu", dict(heads=32, kv_heads=8, dim=96)),  # 3/4 of a lane tile a head
+])                  # (at 2,048 tokens: 10 unrolled block pairs compile sooner)
+def test_causal_attention_falls_back_to_jax_numpy(
+        no_persistent_cache, one_chip, monkeypatch, backend, sizes):
+    """Another backend and shapes the kernels do not take run the
+    ``jax.numpy`` body, two sequences a pass: no kernel in the text."""
+    text = _causal_core_text(one_chip, monkeypatch, backend=backend,
+                             seq=2048, batch=4, **sizes)
+    assert _core_kernel_calls(text, "causal_attention") == [0, 0]
+    assert "tpu_custom_call" not in text and _float32_squares(text)
+
+
+def test_hybrid_attention_layer_keeps_its_tiles_on_chip(
+        no_persistent_cache, one_chip, monkeypatch):
+    """``qwen3next_train_b4_s4096``'s attention layer (16 query on 2 key
+    heads of 256, an output gate) at the cell's 8 sequences, lowered as on a
+    TPU, forward and backward: one kernel each under ``core``, no float32
+    ``(.., 512, 512)`` array anywhere in the layer."""
+    from byol_tpu.models import decoder_trunk
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    z = decoder_trunk.QWEN3_NEXT_80B_A3B
+    layer = decoder_trunk.GatedAttention(
+        z.gated_attention, heads=16, kv_heads=2, eps=z.rms_norm_eps,
+        dtype=jnp.bfloat16, zero_centred=True)
+    h = jax.ShapeDtypeStruct((8, 4096, z.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                            jax.ShapeDtypeStruct((1, 512, z.hidden_size),
+                                                 jnp.bfloat16))
+    params = _with(params, one_chip)
+
+    def loss(params, h):
+        return jnp.sum(jnp.square(layer.apply(params, h).astype(
+            jnp.float32)))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, h).compile().as_text()
+    assert _core_kernel_calls(text, "causal_attention") == [1, 1]
+    assert not _float32_squares(text)
+    assert "core" in text and " while(" not in text
+
+
 def test_chunked_delta_rule_scans_chunks_not_tokens(no_persistent_cache,
                                                     one_chip):
     from byol_tpu.models.gated_delta import chunked_delta_rule
@@ -340,12 +423,12 @@ def _core_text(one_chip, monkeypatch, *, backend, block=512, dim=128,
     ).compile().as_text()
 
 
-def _core_kernel_calls(text):
+def _core_kernel_calls(text, stem="selected_attention"):
     """Custom calls of the forward and of the backward kernel (a frame of
     the text's metadata may hold either name too)."""
     import re
-    return [len(re.findall(rf"custom-call\([^\n]*selected_attention_{way}",
-                           text)) for way in ("fwd", "bwd")]
+    return [len(re.findall(rf"custom-call\([^\n]*{stem}_{way}", text))
+            for way in ("fwd", "bwd")]
 
 
 def test_selected_attention_kernels_at_the_published_sizes(
@@ -475,8 +558,10 @@ def test_lfm2_train_step_fits_and_keeps_its_scopes(no_persistent_cache, topo,
     layers' combines are 12 ``sum_copies`` kernels (target, online, dispatch
     backward; the recomputed forward's feeds no gradient) and as many in the
     fallback's slabs, which no usual step runs; no ``[.., S, S]`` array is
-    written, a score tile is 2 sequences' (``GatedAttentionSizes.group``),
-    and the ops carry the ``shortconv`` scopes."""
+    written, the attention layer's core is 3 ``causal_attention_fwd`` + 1
+    ``causal_attention_bwd`` kernels and no float32 score tile, of 2
+    sequences (``GatedAttentionSizes.group``, the ``jax.numpy`` lowering's)
+    or of 8, is left in HBM, and the ops carry the ``shortconv`` scopes."""
     import json
     import os
     import re
@@ -501,8 +586,11 @@ def test_lfm2_train_step_fits_and_keeps_its_scopes(no_persistent_cache, topo,
     text = compiled.as_text()
     assert len(re.findall(r"custom-call\([^\n]*sum_copies", text)) == 2 * 12
     assert not re.search(rf"\[[\d,]*{seq},{seq}\]", text)
-    assert "f32[2,8,4,512,512]" in text  # the core's tiles of one block pair
+    # the attention layer's core: target, online, recomputed; one backward
+    assert _core_kernel_calls(text, "causal_attention") == [3, 1]
+    assert "f32[2,8,4,512,512]" not in text   # no score tile crosses HBM
     assert "f32[8,8,4,512,512]" not in text
+    assert not _float32_squares(text)
     for scope in ("shortconv/proj", "shortconv/core", "gqa/core", "/ffn/",
                   "moe/experts/combine"):
         assert scope in text, scope
