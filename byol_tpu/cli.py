@@ -68,9 +68,10 @@ def build_parser() -> argparse.ArgumentParser:
                    help="a backbone of models/registry.py: resnet*, vit_*, "
                         "or a decoder trunk over token ids (--task "
                         "synth_tokens): xing4_29b_a4b, qwen3_next_80b_a3b, "
-                        "keye_vl2_30b_a3b, lfm2_24b_a2b and their test-size "
-                        "twins decoder_trunk_tiny, hybrid_trunk_tiny, "
-                        "sparse_trunk_tiny, shortconv_trunk_tiny")
+                        "keye_vl2_30b_a3b, lfm2_24b_a2b, joyai_llm_flash and "
+                        "their test-size twins decoder_trunk_tiny, "
+                        "hybrid_trunk_tiny, sparse_trunk_tiny, "
+                        "shortconv_trunk_tiny, latent_trunk_tiny")
     m.add_argument("--representation-size", type=int, default=None,
                    help="derived from the arch registry unless overridden")
     m.add_argument("--projection-size", type=int, default=256)

@@ -12,7 +12,9 @@ travels.  Mechanisms nothing else in ``models/`` has:
   low-rank query and key/value paths with an RMSNorm on each latent, a
   rotary part (YaRN-scaled) shared by all heads beside a per-head
   un-rotated part, value heads narrower than query/key heads, causal mask,
-  softmax scale ``1/sqrt(d_qk) * m^2`` with ``m`` YaRN's ``mscale``;
+  softmax scale ``1/sqrt(d_qk) * m^2`` with ``m`` YaRN's ``mscale``; past
+  two blocks of keys the core is blockwise (ops/attention.py), the rotary
+  key handed over ONCE for all heads;
 - **an expert layer that is told its share**: the router scores ALL
   published experts (sigmoid scores, ``noaux_tc`` selection bias, top-k,
   normalised and scaled weights); this chip holds experts ``[lo, lo + E)``
@@ -50,8 +52,9 @@ where a part divides over fewer chips than the experts do (a vocabulary
 over 8 of 16, heads over none), it says so there too.
 
 Device-trace scopes (``DecoderTrunk.trace_scopes``; ``TRACE_SCOPES`` for a
-latent-attention trunk, ``HYBRID_SCOPES`` for a patterned one: ``gdn`` with
-``proj``, ``conv``, ``core``, ``gate_norm``; ``gqa`` with ``core``; the
+latent-attention trunk: ``mla`` with ``core``; ``HYBRID_SCOPES`` for a
+patterned one: ``gdn`` with ``proj``, ``conv``, ``core``, ``gate_norm``;
+``gqa`` with ``core``; the
 ``moe`` scopes; ``SPARSE_SCOPES`` for a sparse-attention one: ``dsa`` with
 ``index``, ``select``, ``core``, ``index_loss``; ``SHORTCONV_SCOPES`` for one
 with short convolutions: ``shortconv`` with ``proj``, ``core``; ``gqa`` with
@@ -86,7 +89,7 @@ from byol_tpu.ops.attention import (blockwise_causal_attention,
 
 _MOE_SCOPES = ("moe/route", "moe/experts", "moe/experts/combine",
                "moe/shared")
-TRACE_SCOPES = ("mla",) + _MOE_SCOPES + ("mhc", "ffn")
+TRACE_SCOPES = ("mla", "mla/core") + _MOE_SCOPES + ("mhc", "ffn")
 HYBRID_SCOPES = ("gdn", "gdn/proj", "gdn/conv", "gdn/core", "gdn/gate_norm",
                  "gqa", "gqa/core") + _MOE_SCOPES
 SPARSE_SCOPES = ("dsa", "dsa/index", "dsa/select", "dsa/core",
@@ -151,6 +154,7 @@ class TrunkSizes:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    attention_block: int = 512       # the program's own: keys a block
     intermediate_size: int           # dense FFN width
     n_routed_experts: int
     moe_intermediate_size: int
@@ -344,7 +348,22 @@ def _dense(features, dtype, name):
 
 class LatentAttention(nn.Module):
     """MLA over the heads this chip holds; ``q_a`` / ``kv_a`` are whole,
-    ``q_b`` / ``kv_b`` / ``o`` are the held heads' slices."""
+    ``q_b`` / ``kv_b`` / ``o`` are the held heads' slices.
+
+    The core (scope ``core``: ``mla/attn/core`` on the device), by a rule on
+    shapes alone.  A sequence of MORE than two blocks of keys (``2 x
+    sizes.attention_block``) takes ``blockwise_causal_attention``: ``q_nope,
+    k_nope`` a head, values narrower than keys, and the rotary part as its
+    ``shared`` pair — the ONE rotary key of all heads handed over as ``(B, S,
+    d_rope)``, never copied a head; no ``[.., S, S]`` array at any length
+    (all 32 heads at 4,096 keys: 17 GB of scores a layer otherwise).  Up to
+    two blocks it stays the dense form, op for op the program it was: a
+    row's scores are then at most four tiles, and ``xing4_train_b8_s1024``
+    (4 held heads, 1,024 keys: 256 MiB of scores, the core about 1% of its
+    operations) was accepted and tuned on that program — its lowered step
+    differs from PR 39's by the stamped scope name alone, so nothing there
+    can slow, and no cell sits between the two sides of the rule to say
+    where the blockwise core starts to win (ROADMAP Queue 2, item 12)."""
 
     sizes: TrunkSizes
     heads: int
@@ -365,17 +384,28 @@ class LatentAttention(nn.Module):
         kv = _dense(heads * (dn + dv), self.dtype, "kv_b")(c_kv)
         kv = kv.reshape(b, s, heads, dn + dv)
         cos, sin = rotary_tables(z, s)
-        q = jnp.concatenate(
-            [q[..., :dn], apply_rotary(q[..., dn:], cos, sin)], axis=-1)
-        k_rope = apply_rotary(k_rope[:, :, None, :], cos, sin)
-        k = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, heads, dr))],
-            axis=-1)
+        rotated = lambda x: apply_rotary(x, cos, sin)
         scale = z.qk_head_dim ** -0.5 * yarn_mscale(
             z.rope_factor, z.rope_mscale_all_dim) ** 2
-        out = dense_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                              kv[..., dn:].transpose(0, 2, 1, 3),
-                              scale=scale, causal=True)
+        by_head = lambda x: x.transpose(0, 2, 1, 3)
+        if s > 2 * z.attention_block:
+            q_rope = rotated(q[..., dn:])
+            k_rope = rotated(k_rope[:, :, None, :])[:, :, 0]
+            core = lambda: blockwise_causal_attention(
+                by_head(q[..., :dn]), by_head(kv[..., :dn]),
+                by_head(kv[..., dn:]), scale=scale, block=z.attention_block,
+                shared=(by_head(q_rope), k_rope))
+        else:              # op for op the program it was: see the docstring
+            q = jnp.concatenate([q[..., :dn], rotated(q[..., dn:])], axis=-1)
+            k_rope = rotated(k_rope[:, :, None, :])
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, heads, dr))],
+                axis=-1)
+            core = lambda: dense_attention(
+                by_head(q), by_head(k), by_head(kv[..., dn:]), scale=scale,
+                causal=True)
+        with jax.named_scope("core"):
+            out = core()
         out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * dv)
         return _dense(z.hidden_size, self.dtype, "o")(out)
 
@@ -1016,6 +1046,36 @@ TINY = TrunkSizes(
     hc_eps=1e-6, hc_clamp=30.0, rms_norm_eps=1e-6, rope_theta=10000.0,
     rope_factor=64.0, rope_original_max_position=16, rope_beta_fast=32.0,
     rope_beta_slow=1.0, rope_mscale=1.0, rope_mscale_all_dim=1.0)
+
+# JoyAI-LLM-Flash (48B-A2.7B), from its public config.json (``model_type:
+# joyai_llm_flash``): 40 layers of latent attention on ONE residual stream —
+# 32 heads, 128 + 64 wide query/key heads, 128-wide values, plain rotary at
+# theta 3.2e7 on pairs (2i, 2i+1) (``rope_interleave``; ``rope_scaling``
+# null) — the first dense (SwiGLU of 7,168), the others sparse: 256 experts
+# of width 768, top-8, sigmoid scores with the ``noaux_tc`` bias (one group),
+# ``norm_topk_prob``, ``routed_scaling_factor`` 2.5, one shared expert.  LM
+# head and multi-token-prediction module are not built.
+JOYAI_LLM_FLASH = TrunkSizes(
+    hidden_size=2048, num_hidden_layers=40, first_k_dense_replace=1,
+    num_attention_heads=32, q_lora_rank=1536, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    intermediate_size=7168, n_routed_experts=256, moe_intermediate_size=768,
+    num_experts_per_tok=8, n_shared_experts=1, routed_scaling_factor=2.5,
+    norm_topk_prob=True, vocab_size=129280, rms_norm_eps=1e-6,
+    rope_theta=32e6)
+
+# The one-stream latent-attention trunk at test size
+# (tests/test_latent_trunk.py): value heads narrower than key heads, and at
+# 20 tokens three blocks of keys a row, the last one short, so the CPU runs
+# the blockwise core.
+LATENT_TINY = TrunkSizes(
+    hidden_size=32, num_hidden_layers=3, first_k_dense_replace=1,
+    num_attention_heads=4, q_lora_rank=24, kv_lora_rank=16,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=8,
+    attention_block=8, intermediate_size=64, n_routed_experts=16,
+    moe_intermediate_size=16, num_experts_per_tok=4, n_shared_experts=1,
+    routed_scaling_factor=2.5, norm_topk_prob=True, vocab_size=128,
+    rms_norm_eps=1e-6, rope_theta=32e6)
 
 # Qwen3-Next-80B-A3B-Instruct, from its public config.json: 48 layers, every
 # fourth gated attention and the others Gated DeltaNet, every layer sparse

@@ -111,6 +111,7 @@ def _register_decoder_trunks() -> None:
     # keye_vl2_30b_a3b (the language model):
     # huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B, config.json
     # lfm2_24b_a2b: huggingface.co/LiquidAI/LFM2-24B-A2B, config.json
+    # joyai_llm_flash: huggingface.co/jdopensource/JoyAI-LLM-Flash, config.json
     for name, sizes in (("xing4_29b_a4b", trunk_lib.XING4_29B_A4B),
                         ("decoder_trunk_tiny", trunk_lib.TINY),
                         ("qwen3_next_80b_a3b", trunk_lib.QWEN3_NEXT_80B_A3B),
@@ -118,7 +119,9 @@ def _register_decoder_trunks() -> None:
                         ("keye_vl2_30b_a3b", trunk_lib.KEYE_VL2_30B_A3B),
                         ("sparse_trunk_tiny", trunk_lib.SPARSE_TINY),
                         ("lfm2_24b_a2b", trunk_lib.LFM2_24B_A2B),
-                        ("shortconv_trunk_tiny", trunk_lib.SHORTCONV_TINY)):
+                        ("shortconv_trunk_tiny", trunk_lib.SHORTCONV_TINY),
+                        ("joyai_llm_flash", trunk_lib.JOYAI_LLM_FLASH),
+                        ("latent_trunk_tiny", trunk_lib.LATENT_TINY)):
         def factory(dtype=jnp.float32, small_inputs=False, _z=sizes,
                     layer_share="0/1", trunk_depth="", **kw):
             del small_inputs

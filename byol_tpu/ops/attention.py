@@ -21,8 +21,10 @@ so the model swaps between them by name without re-plumbing:
                 forward and backward, so that no ``[S, S]`` array exists at
                 any length.  Where the program lowers for a TPU and the
                 shapes allow (``causal_attention.applies``: heads of 64, 128
-                or 256), the Pallas kernels of ops/causal_attention.py: a
-                tile's scores and weights never leave VMEM; elsewhere plain
+                or 256; a value width of its own; a part of the key shared
+                by all heads — latent attention's 128 + 64 against 128), the
+                Pallas kernels of ops/causal_attention.py: a tile's scores
+                and weights never leave VMEM; elsewhere plain
                 ``jax.numpy`` — ``[B,Hkv,G,block,block]`` float32 tiles
                 through HBM;
   ``selected`` (:func:`selected_attention`, the decoder trunk's
@@ -187,47 +189,66 @@ def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
                                v: jnp.ndarray, *,
                                scale: Optional[float] = None,
                                block: int = 512,
-                               group: int = 0) -> jnp.ndarray:
+                               group: int = 0,
+                               shared=None) -> jnp.ndarray:
     """Causal softmax attention whose memory is linear in S, forward and
     backward: ``(B, Hq, S, D)`` queries on ``(B, Hkv, S, D)`` keys and
-    values, each key/value head shared by ``Hq / Hkv`` consecutive query
-    heads and never repeated in memory.  Blockwise over the keys with a
-    running max and sum; nothing larger than one ``(B, Hq, block, block)``
-    tile of scores is ever held, block pairs above the diagonal are
-    skipped, and the backward recomputes the tiles from ``q, k`` and the
-    saved log-sum-exp (``jax.custom_vjp``).  Statistics in float32, products
-    in the input dtype.  Two lowerings of one arithmetic, chosen from what
-    the code can see (``ops/causal_attention.applies``): where the program
-    lowers for a TPU, ``block`` is a multiple of 128, a head is 64 wide or a
-    multiple of 128 and the working set fits VMEM, the Pallas kernels
+    ``(B, Hkv, S, Dv)`` values (``Dv`` need not be ``D``: latent attention's
+    values are narrower than its keys), each key/value head shared by ``Hq /
+    Hkv`` consecutive query heads and never repeated in memory; returns
+    ``(B, Hq, S, Dv)``.  ``shared = (q_s (B, Hq, S, r), k_s (B, S, r))``
+    adds ``q_s . k_s`` to every score: a part of the head whose KEY is one
+    vector for all heads (latent attention's rotary key), handed over once
+    and never copied a head; ``scale`` defaults to ``(D + r)^-1/2``.
+    Blockwise over the keys with a running max and sum; nothing larger than
+    one ``(B, Hq, block, block)`` tile of scores is ever held, block pairs
+    above the diagonal are skipped, and the backward recomputes the tiles
+    from ``q, k`` and the saved log-sum-exp (``jax.custom_vjp``).
+    Statistics in float32, products in the input dtype.  Two lowerings of
+    one arithmetic, chosen from what the code can see
+    (``ops/causal_attention.applies``): where the program lowers for a TPU,
+    ``block`` is a multiple of 128, each of ``D``, ``Dv`` and ``r`` is 64 or
+    a multiple of 128 and the working set fits VMEM, the Pallas kernels
     ``causal_attention_fwd`` / ``causal_attention_bwd`` of
     ops/causal_attention.py over the whole batch — a tile's scores, weights
-    and their cotangents live and die in VMEM; everywhere else (the CPU, the
-    tiny presets, odd shapes) plain ``jax.numpy``, the block pairs unrolled
+    and their cotangents live and die in VMEM, the shared part a second
+    product a tile; everywhere else (the CPU, the tiny presets, odd shapes
+    such as one 192-wide key) plain ``jax.numpy``, the block pairs unrolled
     in Python, every ``(B, Hkv, G, block, block)`` float32 tile through HBM
-    — which is also the tests' oracle for the kernels.  ``group`` > 0 is the
+    and the shared part joined to every head's ``q`` and ``k`` first —
+    which is also the tests' oracle for the kernels.  ``group`` > 0 is the
     ``jax.numpy`` lowering's alone: that many sequences a pass (a
     ``lax.map``; where it divides ``B``), because the compiler keeps some
     twenty tiles alive at once."""
     from byol_tpu.ops import causal_attention as kernels     # imports this
     b, hq, s, d = q.shape
-    hkv = k.shape[1]
+    hkv, dv = k.shape[1], v.shape[-1]
     if hq % hkv:
         raise ValueError(f"{hq} query heads do not share {hkv} key heads")
+    r = shared[0].shape[-1] if shared is not None else 0
     if scale is None:
-        scale = d ** -0.5
-    grouped = q.reshape(b, hkv, hq // hkv, s, d)
+        scale = (d + r) ** -0.5
+    group_heads = lambda x: x.reshape((b, hkv, hq // hkv) + x.shape[2:])
+    if kernels.applies(block, d, s, hq, hkv, q.dtype, vdim=dv, shared=r):
+        out = kernels.attend(
+            group_heads(q), k, v, scale=scale, block=block,
+            shared=None if shared is None else (group_heads(shared[0]),
+                                                shared[1]))
+        return out.reshape(b, hq, s, dv)
+    if shared is not None:
+        q = jnp.concatenate([q, shared[0]], axis=-1)
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            shared[1][:, None], (b, hkv, s, r))], axis=-1)
+    grouped = group_heads(q)
     body = lambda *qkv: _blockwise_causal(*qkv, float(scale), int(block))
-    if d == v.shape[-1] and kernels.applies(block, d, s, hq, hkv, q.dtype):
-        out = kernels.attend(grouped, k, v, scale=scale, block=block)
-    elif group and b > group and b % group == 0:
+    if group and b > group and b % group == 0:
         split = lambda x: x.reshape((b // group, group) + x.shape[1:])
         out = jax.lax.map(lambda qkv: body(*qkv),
                           (split(grouped), split(k), split(v)))
         out = out.reshape((b,) + out.shape[2:])
     else:
         out = body(grouped, k, v)
-    return out.reshape(b, hq, s, v.shape[-1])
+    return out.reshape(b, hq, s, dv)
 
 
 # ---- attention over a per-query set of keys --------------------------------

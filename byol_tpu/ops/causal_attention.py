@@ -26,10 +26,22 @@ timings behind each choice) WITHOUT a selection:
   ``T(8,128)(2,1)``, in HBM too, where the ``jax.numpy`` body's arrays were
   as wide.  At 64 the products half-fill the 128-deep matrix unit: a tile
   costs what it costs at 128;
+- a key width and a value width (PR 40).  ``q, k`` are ``dim`` wide, ``v``
+  and the output ``vdim``; each follows the rule above alone (latent
+  attention: 128 + 64 against 128).  And a SHARED part: ``attend(..,
+  shared=(q_s, k_s))`` adds ``q_s k_s^T`` to a tile's scores, ``q_s (B, Hkv,
+  G, S, r)`` a head's own, ``k_s (B, S, r)`` ONE for every key head (latent
+  attention's rotary key) — a second product a tile, 64 deep, instead of a
+  192-wide head padded to two lane tiles with that key copied to every head
+  in HBM (what the chip said of the two is in PERF.md section 6, PR 40).
+  Backward ``d_q_s`` is the head's own and ``d_k_s`` the SUM over every head:
+  its float32 ``(S, r)`` block stays resident over a sequence's heads and
+  pairs, so the head axis of that grid is walked in order;
 - bf16 (the input dtype's) operands, float32 accumulation and statistics, the
-  weights rounded before ``P V`` and ``d_scores`` before its two products, as
+  weights rounded before ``P V`` and ``d_scores`` before its products, as
   the ``jax.numpy`` body does; float32 inputs multiply at
-  ``Precision.HIGHEST``.  Residuals ``q, k, v, out, lse``.
+  ``Precision.HIGHEST``.  Residuals ``q, k, v, out, lse`` (and the shared
+  pair).
 
 ``interpret=True`` (default off-TPU) runs the same kernels under the Pallas
 interpreter so CPU tests exercise identical code paths.
@@ -49,41 +61,73 @@ from byol_tpu.ops.attention import _MASKED, causal_pairs
 from byol_tpu.ops.common import LANES
 from byol_tpu.ops.selected_attention import (_NN, _NT, _TN, VMEM_LIMIT_BYTES,
                                              _dot)
-from byol_tpu.ops.selected_attention import _vmem_bytes as _vmem_bytes_masked
 
 
 def _vmem_bytes(block: int, dim: int, seq_len: int, group: int,
-                itemsize: int, forward: bool) -> int:
-    """``selected_attention``'s count less the mask's block (twice: double
-    buffering); a head narrower than the 128 lanes takes a whole lane tile
-    of VMEM."""
-    return _vmem_bytes_masked(block, -(-dim // LANES) * LANES, seq_len,
-                              group, itemsize, forward) - 2 * block * block
+                itemsize: int, forward: bool, *, vdim: Optional[int] = None,
+                shared: int = 0) -> int:
+    """A kernel's blocks twice (double buffering), its scratch and the
+    float32 squares of the head in hand — ``selected_attention``'s count
+    without a mask, at a key width ``dim`` (+ ``shared``) and a value width
+    ``vdim``; a width that does not fill its last 128 lanes takes the whole
+    lane tile of VMEM."""
+    tiles = lambda d: -(-d // LANES) * LANES
+    key_lanes = tiles(dim) + tiles(shared)
+    value_lanes = tiles(dim if vdim is None else vdim)
+    q_rows, o_rows = group * block * key_lanes, group * block * value_lanes
+    slabs = block * (key_lanes + value_lanes) * itemsize    # k, v (, k_s)
+    square = 4 * block * block       # one float32 (block, block) value
+    if forward:
+        blocks = (q_rows + o_rows) * itemsize + slabs \
+            + 4 * group * block                             # q, o; lse
+        scratch = 4 * o_rows + 2 * 4 * group * block        # acc; stats
+        live = 3                          # scores, weights, their bf16 copy
+    else:
+        blocks = ((2 * q_rows + o_rows) * itemsize + slabs  # q, dq; dO
+                  + 8 * group * block                       # lse, delta
+                  + 4 * seq_len * (key_lanes + value_lanes))  # d_k, d_v,
+        scratch = 4 * q_rows
+        live = 5                          # ... and d_weights, d_scores
+    return 2 * blocks + scratch + (1 + live) * square     # 1: the bias
+
+
+def _width_ok(dim: int) -> bool:
+    """A head fills whole lane tiles or exactly half of one (64: what
+    compiles, tests/test_tpu_compile.py)."""
+    return dim > 0 and (dim % LANES == 0 or 2 * dim == LANES)
 
 
 def supported(block: int, dim: int, seq_len: int, group: int = 1,
-              itemsize: int = 2) -> bool:
-    """Shapes the kernels take: a block's tokens fill whole 128-lane tiles, a
-    head fills whole lane tiles or exactly half of one (64: what compiles,
-    tests/test_tpu_compile.py), whole blocks, and the backward's working set
-    — the float32 ``d_k, d_v`` of one key head's sequence among it — fits."""
+              itemsize: int = 2, *, vdim: Optional[int] = None,
+              shared: int = 0) -> bool:
+    """Shapes the kernels take: a block's tokens fill whole 128-lane tiles,
+    each of the key width, the value width and the shared part (0 = none)
+    one :func:`_width_ok` takes, whole blocks, and the backward's working
+    set — the float32 ``d_k, d_v`` of one key head's sequence among it —
+    fits."""
+    vdim = dim if vdim is None else vdim
     return (block > 0 and block % LANES == 0
-            and dim > 0 and (dim % LANES == 0 or 2 * dim == LANES)
+            and _width_ok(dim) and _width_ok(vdim)
+            and (shared == 0 or _width_ok(shared))
             and seq_len > 0 and seq_len % block == 0 and group > 0
-            and max(_vmem_bytes(block, dim, seq_len, group, itemsize, fwd)
+            and max(_vmem_bytes(block, dim, seq_len, group, itemsize, fwd,
+                                vdim=vdim, shared=shared)
                     for fwd in (True, False)) <= VMEM_LIMIT_BYTES)
 
 
 def applies(block: int, dim: int, seq_len: int, heads: int, kv_heads: int,
-            dtype=jnp.bfloat16, *, backend: Optional[str] = None) -> bool:
+            dtype=jnp.bfloat16, *, vdim: Optional[int] = None,
+            shared: int = 0, backend: Optional[str] = None) -> bool:
     """Whether ``blockwise_causal_attention`` runs as the kernels — decided
     from what the code can see, never by a flag: the program lowers for a
-    TPU, the query heads share the key heads evenly and the shapes are ones
+    TPU, the query heads share the key heads evenly and the shapes (key
+    width ``dim``, value width ``vdim``, a ``shared`` part or none) are ones
     the kernels take."""
     backend = jax.default_backend() if backend is None else backend
     return (backend == "tpu" and kv_heads > 0 and heads % kv_heads == 0
             and supported(block, dim, seq_len, heads // kv_heads,
-                          jnp.dtype(dtype).itemsize))
+                          jnp.dtype(dtype).itemsize, vdim=vdim,
+                          shared=shared))
 
 
 # ---- the kernels -----------------------------------------------------------
@@ -105,17 +149,26 @@ def _on_and_under_the_diagonal(i, j, bias_ref, tile):
         tile(bias_ref)
 
 
-def _scores(k_ref, q, scale, bias):
-    scores = _dot(k_ref[...], q, _NT) * scale
+def _scores(k_ref, q, scale, bias, shared=None):
+    """``(bk, bq)`` float32; ``shared``: the head's ``q_s`` and the ``k_s``
+    ref, whose product is the scores' second term."""
+    scores = _dot(k_ref[...], q, _NT)
+    if shared is not None:
+        q_s, ks_ref = shared
+        scores = scores + _dot(ks_ref[...], q_s, _NT)
+    scores = scores * scale
     return scores if bias is None else scores + bias[...]
 
 
-def _fwd_kernel(q_of_ref, k_of_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                top_ref, total_ref, acc_ref, bias_ref, *, scale: float):
-    """Scores ``[keys, queries]``.  Refs: ``q, o (G, bq, D)``; ``k, v (bk,
-    D)``; ``lse (G, bq)``; scratch: every head's running max and sum, a lane
-    row a head, ``(G, bq)``, the float32 accumulators ``(G, bq, D)`` and a
-    diagonal tile's bias."""
+def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, shared: bool):
+    """Scores ``[keys, queries]``.  Refs: ``q (G, bq, D)``; ``k (bk, D)``;
+    ``v (bk, Dv)``; with a shared part ``q_s (G, bq, r)``, ``k_s (bk, r)``;
+    ``o (G, bq, Dv)``; ``lse (G, bq)``; scratch: every head's running max
+    and sum, a lane row a head, ``(G, bq)``, the float32 accumulators ``(G,
+    bq, Dv)`` and a diagonal tile's bias."""
+    q_ref, k_ref, v_ref, *refs = refs
+    qs_ref, ks_ref = refs[:2] if shared else (None, None)
+    o_ref, lse_ref, top_ref, total_ref, acc_ref, bias_ref = refs[-6:]
     pair = pl.program_id(2)
     i, j = q_of_ref[pair], k_of_ref[pair]
     group, _, dim = acc_ref.shape
@@ -127,14 +180,15 @@ def _fwd_kernel(q_of_ref, k_of_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def column(row):
-        """``(1, bq)`` -> ``(bq, D)``, a row's value on every lane: its
+        """``(1, bq)`` -> ``(bq, Dv)``, a row's value on every lane: its
         broadcast down a lane tile's worth of sublanes, turned."""
         lanes = max(dim, LANES)
         return jnp.broadcast_to(row, (lanes, row.shape[1])).T[:, :dim]
 
     def head(h, bias):
         at = pl.ds(h, 1)
-        scores = _scores(k_ref, q_ref[h], scale, bias)
+        scores = _scores(k_ref, q_ref[h], scale, bias,
+                         (qs_ref[h], ks_ref) if shared else None)
         top = top_ref[at, :]
         new_top = jnp.maximum(top, jnp.max(scores, axis=0, keepdims=True))
         weights = jnp.exp(scores - new_top)
@@ -159,13 +213,24 @@ def _fwd_kernel(q_of_ref, k_of_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 o_ref.dtype)
 
 
-def _bwd_kernel(q_of_ref, k_of_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
-                do_ref, dq_ref, dk_ref, dv_ref, dq_acc_ref, bias_ref, *,
-                scale: float):
-    """Everything ``[keys, queries]``.  Refs: ``q, dO, dq (G, bq, D)``; ``k, v
-    (bk, D)``; ``lse, delta (G, bq)``; ``dk, dv (S, D)`` float32, one key
-    head's, resident over all its pairs; scratch: the float32 ``dq`` of the
-    query block and a diagonal tile's bias."""
+def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, shared: bool):
+    """Everything ``[keys, queries]``.  Refs: ``q, dq (G, bq, D)``; ``dO (G,
+    bq, Dv)``; ``k (bk, D)``; ``v (bk, Dv)``; ``lse, delta (G, bq)``; ``dk
+    (S, D)``, ``dv (S, Dv)`` float32, one key head's, resident over all its
+    pairs; with a shared part ``q_s, dq_s (G, bq, r)``, ``k_s (bk, r)`` and
+    ``dk_s (S, r)`` float32, ONE SEQUENCE's, resident over all its heads and
+    pairs; scratch: the float32 ``dq`` (and ``dq_s``) of the query block and
+    a diagonal tile's bias."""
+    refs = iter(refs)
+    take = lambda n, present=True: [
+        next(refs) if present else None for _ in range(n)]
+    q_ref, k_ref, v_ref = take(3)
+    qs_ref, ks_ref = take(2, shared)
+    lse_ref, delta_ref, do_ref, dq_ref, dk_ref, dv_ref = take(6)
+    dqs_ref, dks_ref = take(2, shared)
+    dq_acc_ref, = take(1)
+    dqs_acc_ref, = take(1, shared)
+    bias_ref, = take(1)
     pair = pl.program_id(2)
     i, j = q_of_ref[pair], k_of_ref[pair]
     group, bk = q_ref.shape[0], k_ref.shape[0]
@@ -176,19 +241,31 @@ def _bwd_kernel(q_of_ref, k_of_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
         dk_ref[...] = jnp.zeros_like(dk_ref)
         dv_ref[...] = jnp.zeros_like(dv_ref)
 
+    if shared:
+        @pl.when((pair == 0) & (pl.program_id(1) == 0))
+        def _next_sequence():
+            dks_ref[...] = jnp.zeros_like(dks_ref)
+
     @pl.when(j == 0)
     def _next_rows():
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
+        if shared:
+            dqs_acc_ref[...] = jnp.zeros_like(dqs_acc_ref)
 
     def head(h, bias):
         q, d_out = q_ref[h], do_ref[h]
         lse, delta = lse_ref[pl.ds(h, 1), :], delta_ref[pl.ds(h, 1), :]
-        weights = jnp.exp(_scores(k_ref, q, scale, bias) - lse)
+        weights = jnp.exp(_scores(
+            k_ref, q, scale, bias,
+            (qs_ref[h], ks_ref) if shared else None) - lse)
         dv_ref[keys, :] += _dot(weights.astype(d_out.dtype), d_out, _NN)
         d_weights = _dot(v_ref[...], d_out, _NT)
         d_scores = (weights * (d_weights - delta) * scale).astype(q.dtype)
         dk_ref[keys, :] += _dot(d_scores, q, _NN)
         dq_acc_ref[h] += _dot(d_scores, k_ref[...], _TN)
+        if shared:
+            dks_ref[keys, :] += _dot(d_scores, qs_ref[h], _NN)
+            dqs_acc_ref[h] += _dot(d_scores, ks_ref[...], _TN)
 
     def tile(bias):
         for h in range(group):      # side by side: selected_attention.py
@@ -199,48 +276,68 @@ def _bwd_kernel(q_of_ref, k_of_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
     @pl.when(j == i)
     def _finish():
         dq_ref[...] = dq_acc_ref[...].astype(dq_ref.dtype)
+        if shared:
+            dqs_ref[...] = dqs_acc_ref[...].astype(dqs_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _call(forward, scale, block, interpret, q, k, v, *rest):
-    """One ``pallas_call`` over ``(batch, key head, causal pair)``.  ``q``
-    (and ``dO``): ``(B, Hkv, G, S, D)``; ``k, v``: ``(B, Hkv, S, D)``; ``lse,
-    delta``: ``(B, Hkv, G, S)`` float32.  Jitted so that a model's passes
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3),
+                   static_argnames=("shared",))
+def _call(forward, scale, block, interpret, q, k, v, *rest,
+          shared: bool = False):
+    """One ``pallas_call`` over ``(batch, key head, causal pair)``.  ``q``:
+    ``(B, Hkv, G, S, D)``; ``k``: ``(B, Hkv, S, D)``; ``v``: ``(B, Hkv, S,
+    Dv)``; ``dO`` and the output ``(B, Hkv, G, S, Dv)``; ``lse, delta``:
+    ``(B, Hkv, G, S)`` float32; ``shared``: ``rest`` starts with ``q_s (B,
+    Hkv, G, S, r)`` and ``k_s (B, S, r)``.  Jitted so that a model's passes
     share one trace and lowering of each kernel."""
     b, hkv, g, s, d = q.shape
+    dv = v.shape[-1]
+    r = rest[0].shape[-1] if shared else 0
     q_of, k_of = causal_pairs(s // block)
     # index maps: (batch, key head, pair, q_of, k_of)
-    rows = pl.BlockSpec((None, None, g, block, d),
-                        lambda n, h, p, qo, ko: (n, h, 0, qo[p], 0))
-    slab = pl.BlockSpec((None, None, block, d),
-                        lambda n, h, p, qo, ko: (n, h, ko[p], 0))
+    rows = lambda w: pl.BlockSpec((None, None, g, block, w),
+                                  lambda n, h, p, qo, ko: (n, h, 0, qo[p], 0))
+    slab = lambda w: pl.BlockSpec((None, None, block, w),
+                                  lambda n, h, p, qo, ko: (n, h, ko[p], 0))
+    # the shared key and its cotangent: no head axis
+    slab_s = pl.BlockSpec((None, block, r),
+                          lambda n, h, p, qo, ko: (n, ko[p], 0))
     row_stat = pl.BlockSpec((None, None, g, block),
                             lambda n, h, p, qo, ko: (n, h, 0, qo[p]))
     stat = jax.ShapeDtypeStruct((b, hkv, g, s), jnp.float32)
+    like = lambda w: jax.ShapeDtypeStruct((b, hkv, g, s, w), q.dtype)
     square = pltpu.VMEM((block, block), jnp.float32)
-    per_head = pltpu.VMEM((g, block, d), jnp.float32)
+    per_head = lambda w: pltpu.VMEM((g, block, w), jnp.float32)
+    in_specs = [rows(d), slab(d), slab(dv)] + (
+        [rows(r), slab_s] if shared else [])
     if forward:
         kernel, name = _fwd_kernel, "causal_attention_fwd"
-        in_specs = [rows, slab, slab]
-        outs = [(rows, jax.ShapeDtypeStruct(q.shape, q.dtype)),
-                (row_stat, stat)]
+        outs = [(rows(dv), like(dv)), (row_stat, stat)]
         stats = pltpu.VMEM((g, block), jnp.float32)
-        scratch = [stats, stats, per_head, square]
+        scratch = [stats, stats, per_head(dv), square]
     else:
         kernel, name = _bwd_kernel, "causal_attention_bwd"
-        in_specs = [rows, slab, slab, row_stat, row_stat, rows]
-        whole = pl.BlockSpec((None, None, s, d),
-                             lambda n, h, p, qo, ko: (n, h, 0, 0))
-        summed = jax.ShapeDtypeStruct(k.shape, jnp.float32)
-        outs = [(rows, jax.ShapeDtypeStruct(q.shape, q.dtype)),
-                (whole, summed), (whole, summed)]
-        scratch = [per_head, square]
+        in_specs += [row_stat, row_stat, rows(dv)]
+        whole = lambda w: pl.BlockSpec((None, None, s, w),
+                                       lambda n, h, p, qo, ko: (n, h, 0, 0))
+        outs = [(rows(d), like(d)),
+                (whole(d), jax.ShapeDtypeStruct(k.shape, jnp.float32)),
+                (whole(dv), jax.ShapeDtypeStruct(v.shape, jnp.float32))]
+        scratch = [per_head(d)]
+        if shared:
+            outs += [(rows(r), like(r)),
+                     (pl.BlockSpec((None, s, r),
+                                   lambda n, h, p, qo, ko: (n, 0, 0)),
+                      jax.ShapeDtypeStruct((b, s, r), jnp.float32))]
+            scratch += [per_head(r)]
+        scratch += [square]
     arrays = (q, k, v) + rest
     formed = b * hkv * g * len(q_of) * block * block      # pairs, every head
+    depth = (d + r + dv) if forward else 3 * (d + r) + 2 * dv
     moved = sum(a.size * a.dtype.itemsize for a in arrays) + sum(
         out.size * out.dtype.itemsize for _, out in outs)
     return pl.pallas_call(
-        functools.partial(kernel, scale=scale),
+        functools.partial(kernel, scale=scale, shared=shared),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, hkv, len(q_of)),
@@ -249,44 +346,55 @@ def _call(forward, scale, block, interpret, q, k, v, *rest):
             scratch_shapes=scratch),
         out_shape=[out for _, out in outs],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # d_k_s is summed over the heads in its resident block: that
+            # grid walks a sequence's heads in order
+            dimension_semantics=(
+                "parallel", "arbitrary" if shared and not forward
+                else "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         cost_estimate=pl.CostEstimate(
-            flops=2 * (2 if forward else 5) * formed * d,
-            transcendentals=formed, bytes_accessed=moved),
+            flops=2 * formed * depth, transcendentals=formed,
+            bytes_accessed=moved),
         interpret=interpret,
         name=name,
     )(jnp.asarray(q_of), jnp.asarray(k_of), *arrays)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _attend(q, k, v, scale, block, interpret):
-    return _call(True, scale, block, interpret, q, k, v)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _attend(q, k, v, shared, scale, block, interpret):
+    return _call(True, scale, block, interpret, q, k, v, *shared,
+                 shared=bool(shared))[0]
 
 
-def _attend_fwd(q, k, v, scale, block, interpret):
-    out, lse = _call(True, scale, block, interpret, q, k, v)
-    return out, (q, k, v, out, lse)
+def _attend_fwd(q, k, v, shared, scale, block, interpret):
+    out, lse = _call(True, scale, block, interpret, q, k, v, *shared,
+                     shared=bool(shared))
+    return out, (q, k, v, shared, out, lse)
 
 
 def _attend_bwd(scale, block, interpret, residuals, d_out):
-    q, k, v, out, lse = residuals
+    q, k, v, shared, out, lse = residuals
     # sum_k w (dw) of the softmax's backward is rowsum(dO . O)
     delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
-    d_q, d_k, d_v = _call(False, scale, block, interpret, q, k, v, lse,
-                          delta, d_out.astype(q.dtype))
-    return d_q, d_k.astype(k.dtype), d_v.astype(v.dtype)
+    d_q, d_k, d_v, *d_shared = _call(
+        False, scale, block, interpret, q, k, v, *shared, lse, delta,
+        d_out.astype(q.dtype), shared=bool(shared))
+    if shared:
+        d_shared[1] = d_shared[1].astype(shared[1].dtype)
+    return d_q, d_k.astype(k.dtype), d_v.astype(v.dtype), tuple(d_shared)
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
-def attend(q, k, v, *, scale: float, block: int,
+def attend(q, k, v, *, scale: float, block: int, shared=None,
            interpret: Optional[bool] = None):
-    """``q``: ``(B, Hkv, G, S, D)``; ``k, v``: ``(B, Hkv, S, D)``, ``S`` whole
-    blocks.  Returns ``out`` like ``q`` — what ``ops/attention.
-    _blockwise_causal`` returns, differentiable w.r.t. ``q, k, v``."""
-    return _attend(q, k, v, float(scale), int(block),
+    """``q``: ``(B, Hkv, G, S, D)``; ``k``: ``(B, Hkv, S, D)``; ``v``: ``(B,
+    Hkv, S, Dv)``, ``S`` whole blocks; ``shared``: None or ``(q_s (B, Hkv,
+    G, S, r), k_s (B, S, r))``.  Returns ``out (B, Hkv, G, S, Dv)`` — what
+    ``ops/attention._blockwise_causal`` returns, differentiable w.r.t. ``q,
+    k, v`` and the shared pair."""
+    return _attend(q, k, v, tuple(shared) if shared is not None else (),
+                   float(scale), int(block),
                    ops_common.resolve_interpret(interpret))
-

@@ -275,6 +275,37 @@ def test_causal_attention_kernels_at_the_published_sizes(
     assert " while(" not in text
 
 
+@pytest.mark.parametrize("dim,rope", [
+    (128, 64),     # two products a tile, the rotary key [B, S, 64] once
+    (256, 0),      # the 192 of a head padded to two lane tiles
+])
+def test_latent_attention_core_at_the_published_sizes(
+        no_persistent_cache, one_chip, monkeypatch, dim, rope):
+    """``joyai_train_b4_s4096``'s core — 8 sequences x 32 heads x 4,096 keys,
+    192-wide keys on 128-wide values, one head a key head — lowered as on a
+    TPU, forward and backward with the shared key's gradient summed over
+    the heads in the kernel: one kernel each, no float32 ``(.., 512, 512)``
+    array and no loop outside them; a 192-wide operand as it stands is not
+    one the kernels take."""
+    from byol_tpu.ops import causal_attention
+    from byol_tpu.ops.attention import blockwise_causal_attention
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    like = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                               sharding=one_chip)
+    heads = lambda width: like(8, 32, 4096, width)
+    shared = (heads(rope), like(8, 4096, rope)) if rope else None
+
+    def loss(q, k, v, shared):
+        return jnp.sum(jnp.square(blockwise_causal_attention(
+            q, k, v, block=512, shared=shared).astype(jnp.float32)))
+    text = jax.jit(jax.grad(
+        loss, argnums=(0, 1, 2, 3) if rope else (0, 1, 2))).lower(
+            heads(dim), heads(dim), heads(128), shared).compile().as_text()
+    assert _core_kernel_calls(text, "causal_attention") == [1, 1]
+    assert not _float32_squares(text) and " while(" not in text
+    assert not causal_attention.applies(512, 192, 4096, 32, 32, vdim=128)
+
+
 @pytest.mark.parametrize("backend,sizes", [
     ("cpu", dict(heads=32, kv_heads=8, dim=64)),  # not lowered for a TPU
     ("tpu", dict(heads=32, kv_heads=8, dim=96)),  # 3/4 of a lane tile a head
