@@ -20,6 +20,11 @@ call sites name the capability, not the file:
   forward and backward: what ``ops/attention.selected_attention`` runs on a
   TPU at blocks and heads of 128 lanes (``models/decoder_trunk.
   SparseAttention``).
+- :mod:`byol_tpu.ops.key_selection` (``search_rows``, ``applies``) — the
+  exact top-k search in front of that core: a query block's row of index
+  score tiles held in VMEM for the 32 counting passes that build each
+  row's threshold, the set written tile by tile with ties from the left:
+  what ``select_top_keys`` runs on a TPU at blocks of 128 lanes.
 - :mod:`byol_tpu.ops.sum_copies` (``by_token``, ``sum_copies``, ``applies``)
   — the expert layer's combine (and its dispatch's backward) as ONE gather
   of the held rows into token order and a one-hot segment-sum kernel on the

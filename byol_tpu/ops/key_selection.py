@@ -21,17 +21,32 @@ then over those tiles (:func:`_over_rows`).  Entries above the diagonal of
 a diagonal tile are there and mean nothing; :func:`causal_tiles` says
 which.  ``S`` is a multiple of ``block`` (the layer pads).
 
-Plain ``jax.numpy``; float32 scores and statistics.
+Plain ``jax.numpy``, float32 scores and statistics — but for the SEARCH of
+:func:`select_top_keys` (the ``need``-th largest value of each row in 32
+counting passes, then the keys that reach it), which has two lowerings of
+one algorithm, chosen from what the code can see (:func:`applies`): where
+the program lowers for a TPU the Pallas kernel ``top_keys_search``
+(:func:`search_rows`), everywhere else :func:`_top_of_rows`, which is also
+the tests' oracle.  The ``jax.numpy`` body reads the searched tiles from HBM
+34 times a call and more where a row has ties (218 MB a time at 8 x 4,096
+tokens: 11.8 ms, PERF.md section 5, PR 40); the kernel reads them twice and
+counts over a row held in VMEM.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from byol_tpu.ops import common as ops_common
 from byol_tpu.ops.attention import _MASKED, _slab, causal_pairs
+from byol_tpu.ops.common import LANES
+from byol_tpu.ops.selected_attention import VMEM_LIMIT_BYTES
 
 _SIGN = np.uint32(0x80000000)      # numpy: nothing touches a backend at import
 
@@ -146,20 +161,249 @@ def _top_of_rows(scores, causal, need, bounds):
                         lambda: at_least)
 
 
+# ---- the search as a kernel ------------------------------------------------
+
+_ROWS = 256        # keys a trip of the counting loop takes (32 sublane
+                   # groups) where a tile's keys are whole such trips
+
+
+def _vmem_bytes(block: int, blocks: int) -> int:
+    """The longest row's tiles as ordered words, the arriving tile and the
+    leaving int8 one twice each (double buffering), three values a query
+    down the sublanes, and the live squares of a step: taking a tile in (the
+    tile with its zeros made one, its word, the ordered word, that turned,
+    that masked) or writing one (the ordered word, two masks, the bf16 ties
+    and the triangle they meet, the float32 ranks)."""
+    square = block * block
+    return (blocks + 2 + 6) * 4 * square + 2 * square + 3 * 4 * block * LANES
+
+
+def applies(block: int, blocks: int, *, backend: Optional[str] = None) -> bool:
+    """Whether :func:`select_top_keys` searches as the kernel — decided from
+    what the code can see, never by a flag: the program lowers for a TPU, a
+    tile fills whole 128-lane tiles both ways (it is turned) and the longest
+    row, ``blocks`` tiles, fits VMEM beside the kernel's working set.  At
+    blocks of 512 the count is 17 MiB at 4,096 tokens and 25 MiB at 8,192
+    (a row of 16 tiles): both fit ``VMEM_LIMIT_BYTES``, as does every
+    sequence up to 19,456 tokens; a longer one runs the ``jax.numpy``
+    body."""
+    backend = jax.default_backend() if backend is None else backend
+    return (backend == "tpu" and block > 0 and block % LANES == 0
+            and _vmem_bytes(block, blocks) <= VMEM_LIMIT_BYTES)
+
+
+def _steps(blocks: int, first: int):
+    """The kernel's steps a sequence, five int32 arrays: the step's ``(query
+    block, key block)``; whether it WRITES its tile of the set (1) or takes
+    its tile of the scores in (0); the tile of the scores it reads and the
+    tile of the set its output block is.  A query block before ``first`` only
+    writes; a later one takes its tiles in, searches on the last of them, and
+    then writes them, left to right.  A step that reads nothing new names the
+    tile the next reading step reads, and one that writes nothing the tile
+    the next writing step writes: neither moves anything."""
+    tile = lambda i, j: i * (i + 1) // 2 + j
+    rows = []
+    for i in range(blocks):
+        if i >= first:
+            rows += [(i, j, 0, tile(i, j), tile(i, 0)) for j in range(i + 1)]
+        rows += [(i, j, 1, tile(max(i, first), j if i >= first else 0),
+                  tile(i, j)) for j in range(i + 1)]
+    return tuple(jnp.asarray(column, jnp.int32) for column in zip(*rows))
+
+
+def _search_kernel(q_of_ref, k_of_ref, writes_ref, reads_ref, set_ref,
+                   scores_ref, need_ref, kept_ref, bits_ref, floor_ref,
+                   room_ref, before_ref, *, first: int):
+    """One step a tile (:func:`_steps`).  Refs: ``scores (bq, bk)`` float32,
+    the step's tile; ``need (1, bq)`` int32; ``kept (bq, bk)`` int8, its tile
+    of the set; scratch ``bits (blocks * bk, bq)`` int32: the row's tiles so
+    far, TURNED — keys down the sublanes, so a query's count is a sum of
+    whole registers and its candidate a lane — as :func:`_ordered_bits` with
+    the top bit flipped (the floats' order in SIGNED words, the compare the
+    vector unit has; not causal: the least word); and, a query a sublane
+    (the same value on all 128 lanes) for the writing steps: ``floor`` int32,
+    the row's threshold as such a word; ``room`` float32, how many keys AT
+    the threshold the row still takes; ``before`` float32, how many of them
+    the tiles written so far held."""
+    step = pl.program_id(1)
+    i, j = q_of_ref[step], k_of_ref[step]
+    bq, bk = scores_ref.shape
+    lowest = jnp.iinfo(jnp.int32).min
+    column = lambda row: jnp.broadcast_to(row, (LANES, bq)).T   # (bq, LANES)
+    across = lambda ref: jnp.tile(ref[...], (1, bk // LANES))   # (bq, bk)
+
+    def ordered():
+        x = scores_ref[...]
+        word = jax.lax.bitcast_convert_type(jnp.where(x == 0.0, 0.0, x),
+                                            jnp.int32)
+        return jnp.where(word < 0, word ^ 0x7FFFFFFF, word)
+
+    def search():
+        """The 32 counting trips over the row the scratch holds, and a 33rd
+        for the keys over the threshold."""
+        need = need_ref[...]
+        rows = _ROWS if bk % _ROWS == 0 else LANES
+        trips = (i + 1) * (bk // rows)
+
+        def counted(reaches, floor):
+            """``(1, bq)``: how many of each query's words ``reaches`` its
+            ``floor``."""
+            against = jnp.broadcast_to(floor, (8, bq))
+
+            def count(trip, counts):
+                # two running sums, so that an add does not wait for the
+                # one before it
+                base = pl.multiple_of(trip * rows, rows)
+                for group in range(rows // 8):
+                    words = bits_ref[pl.ds(base + 8 * group, 8), :]
+                    counts[group % 2] += jnp.where(reaches(words, against),
+                                                   1, 0)
+                return counts
+
+            zero = jnp.zeros((8, bq), jnp.int32)
+            even, odd = jax.lax.fori_loop(0, trips, count, [zero, zero])
+            return jnp.sum(even + odd, axis=0, keepdims=True)
+
+        def refine(bit, least):
+            candidate = least | (jnp.int32(1) << (31 - bit))
+            enough = counted(jnp.greater_equal, candidate ^ lowest) >= need
+            return jnp.where(enough, candidate, least)
+
+        floor = jax.lax.fori_loop(0, 32, refine,
+                                  jnp.zeros((1, bq), jnp.int32)) ^ lowest
+        floor_ref[...] = column(floor)
+        room_ref[...] = column(
+            (need - counted(jnp.greater, floor)).astype(jnp.float32))
+
+    @pl.when(writes_ref[step] == 0)
+    def _take_in():
+        turned = ordered().T                                    # (bk, bq)
+        here = pl.ds(pl.multiple_of(j * bk, bk), bk)
+
+        @pl.when(j < i)
+        def _under():
+            bits_ref[here, :] = turned
+
+        @pl.when(j == i)
+        def _on():
+            key = jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+            query = jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+            bits_ref[here, :] = jnp.where(key <= query, turned, lowest)
+            search()
+
+    @pl.when(writes_ref[step] == 1)
+    def _write():
+        query = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        key = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        # under the diagonal every key is causal
+        causal = key <= query + jnp.where(j < i, bk, 0)
+
+        @pl.when(i < first)
+        def _all():
+            kept_ref[...] = causal.astype(jnp.int8)
+
+        @pl.when(i >= first)
+        def _largest():
+            @pl.when(j == 0)
+            def _start():
+                before_ref[...] = jnp.zeros_like(before_ref)
+
+            words, floor = ordered(), across(floor_ref)
+            # keys at the threshold are taken from the left: a key's rank
+            # among them is a product with a triangle of ones, exact in
+            # float32, and the tiles before this one have counted theirs
+            tie = causal & (words == floor)
+            ties = jnp.where(tie, 1.0, 0.0).astype(jnp.bfloat16)
+            earlier = jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 0)
+            later = jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 1)
+            rank = jnp.dot(
+                ties, jnp.where(earlier <= later, 1.0, 0.0).astype(
+                    jnp.bfloat16), preferred_element_type=jnp.float32)
+            taken = tie & (rank + across(before_ref) <= across(room_ref))
+            kept_ref[...] = ((causal & (words > floor)) | taken).astype(
+                jnp.int8)
+            before_ref[...] += jnp.dot(
+                ties, jnp.ones((bk, LANES), jnp.bfloat16),
+                preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _search(scores, need, first, interpret):
+    tiles, batch, block = scores.shape[:3]
+    blocks = _blocks(tiles)
+    steps = _steps(blocks, first)
+    searched = (tiles - first * (first + 1) // 2) * batch * block * block
+    a_query = lambda kind: pltpu.VMEM((block, LANES), kind)
+    return pl.pallas_call(
+        functools.partial(_search_kernel, first=first),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(steps),
+            grid=(batch, len(steps[0])),
+            in_specs=[
+                pl.BlockSpec((None, None, block, block),
+                             lambda n, s, qo, ko, wr, rd, st: (rd[s], n, 0, 0)),
+                pl.BlockSpec((None, 1, block), lambda n, s, qo, *_: (
+                    jnp.maximum(qo[s] - first, 0), 0, 0))],
+            out_specs=pl.BlockSpec(
+                (None, None, block, block),
+                lambda n, s, qo, ko, wr, rd, st: (st[s], n, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((blocks * block, block), jnp.int32),
+                            a_query(jnp.int32), a_query(jnp.float32),
+                            a_query(jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(scores.shape, jnp.int8),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=(33 * 3 + 32 + 2 * (block + LANES)) * searched,
+            transcendentals=0,
+            bytes_accessed=2 * 4 * searched + scores.size),
+        interpret=interpret,
+        name="top_keys_search",
+    )(*steps, scores, need.astype(jnp.int32)[:, None, :])
+
+
+def search_rows(scores, need, first: int, *,
+                interpret: Optional[bool] = None):
+    """The kernel ``top_keys_search``: the tiles of ``I`` (``(P, B, block,
+    block)`` float32) and ``(blocks - first, block)`` how many to keep of
+    each row from query block ``first`` on -> the tiles of the set (``(P, B,
+    block, block)`` bool; every causal key before ``first``), bit for bit
+    :func:`_top_of_rows`'s: exactly ``need`` causal keys a row, the largest,
+    a tie to the lower index.  One program step a tile, grid ``(B, steps)``:
+    a row's tiles arrive one by one through the pipeline, are turned into
+    ordered words in VMEM — the diagonal tile's mask from two iotas, no
+    ``causal`` operand — and the counting trips run there on the row's last
+    tile; then the tiles arrive once more, left to right, and leave as the
+    set, the keys AT the threshold ranked on the matrix unit.  A searched
+    tile is read from HBM TWICE, where the ``jax.numpy`` loop reads it 34
+    times, and nothing of the tiles' size is written but the set."""
+    return _search(scores, need, int(first),
+                   ops_common.resolve_interpret(interpret)) != 0
+
+
 def select_top_keys(scores, topk: int, *, block: int = 512):
     """The tiles of ``I`` -> the tiles of the set, bool.  A query block
     none of whose queries has more than ``topk`` causal keys keeps them all
-    and is not searched.  No gradient passes (a set has none)."""
+    and is not searched.  No gradient passes (a set has none).  Two
+    lowerings of one algorithm, chosen by :func:`applies`: the kernel
+    (:func:`search_rows`) or the ``jax.numpy`` body below, the tests'
+    oracle; the set is the same bits either way."""
     batch = scores.shape[1]
     blocks = _blocks(scores.shape[0])
     free = min(topk // block, blocks)      # query blocks that keep all
+    how_many = lambda: jnp.minimum(
+        jnp.arange(free * block, blocks * block) + 1, topk)
+    if free < blocks and applies(block, blocks):
+        return search_rows(jax.lax.stop_gradient(scores),
+                           how_many().reshape(blocks - free, block), free)
     kept = []
     if free:
         kept.append(jnp.broadcast_to(
             causal_tiles(free, block)[:, None],
             (free * (free + 1) // 2, batch, block, block)))
     if free < blocks:
-        need = jnp.minimum(jnp.arange(free * block, blocks * block) + 1, topk)
+        need = how_many()
         kept.append(_top_of_rows(
             jax.lax.stop_gradient(scores[free * (free + 1) // 2:]),
             causal_tiles(blocks, block, free),

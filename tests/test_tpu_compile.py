@@ -488,6 +488,45 @@ def test_selected_attention_falls_back_to_jax_numpy(
     assert "tpu_custom_call" not in text and " while(" in text
 
 
+def _select_text(one_chip, monkeypatch, *, backend, block=512, seq=4096,
+                 topk=2048):
+    """``select_top_keys`` over the tiles of 8 sequences' index scores,
+    compiled for the described v5e as ``backend`` would lower it — the
+    published sizes of ``keye_train_b4_s4096``."""
+    from byol_tpu.ops import key_selection
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    blocks = seq // block
+    return jax.jit(lambda scores: key_selection.select_top_keys(
+        scores, topk, block=block)).lower(jax.ShapeDtypeStruct(
+            (blocks * (blocks + 1) // 2, 8, block, block), jnp.float32,
+            sharding=one_chip)).compile().as_text()
+
+
+@pytest.mark.parametrize("seq", [4096, 8192])     # rows of 8 and of 16 tiles
+def test_top_keys_search_kernel_at_the_published_sizes(
+        no_persistent_cache, one_chip, monkeypatch, seq):
+    """The search, ties and all, is ONE ``top_keys_search`` kernel: no loop
+    and no branch is left in the program, and no uint32 or int32 array of
+    the tiles' size (the ordered bits live and die in VMEM)."""
+    import re
+    text = _select_text(one_chip, monkeypatch, backend="tpu", seq=seq)
+    assert len(re.findall(r"custom-call\([^\n]*top_keys_search", text)) == 1
+    assert " while(" not in text and " conditional(" not in text
+    assert not re.search(r"[us]32\[[\d,]*512,512\]", text)
+
+
+@pytest.mark.parametrize("backend,sizes", [
+    ("cpu", {}),                                  # not lowered for a TPU
+    ("tpu", dict(block=96, seq=4032, topk=2016)),     # 3/4 of a lane tile
+])
+def test_top_keys_search_falls_back_to_jax_numpy(
+        no_persistent_cache, one_chip, monkeypatch, backend, sizes):
+    """Another backend and a block the kernel does not take run the
+    ``fori_loop`` of 32 trips: no kernel in the text."""
+    text = _select_text(one_chip, monkeypatch, backend=backend, **sizes)
+    assert "tpu_custom_call" not in text and " while(" in text
+
+
 def _compile_train_step(topo, rcfg, batch, view=None):
     """The jitted step (``--fuse-views``, bf16, LARS), built from the
     compile plan exactly as setup_training wires it, compiled for one
