@@ -32,8 +32,25 @@ multiples of 128 that fit VMEM, the kernels ``delta_wy_fwd`` /
 live and die in VMEM, the keys are read per KEY head and never repeated;
 everywhere else (``HYBRID_TINY``, every CPU run) plain ``jax.numpy``,
 :func:`_chunked_rule` with :func:`unit_lower_inverse`, which is also the
-tests' oracle for the kernels.  The scan, the projections, the convolution
-and the gated norm are plain ``jax.numpy`` on both.
+tests' oracle for the kernels.  The scan and the projections are plain
+``jax.numpy`` on both.
+
+``qkvz`` is ONE product whose columns stand in the order the stages read
+them: the kernel keeps the published per-key-head layout (the parameter
+tree, the seeded weights and a checkpoint are the published ones) and its
+columns are reordered on the WEIGHT to ``[q | k | v | z]``, each over all
+key heads, so that ``mixed`` and ``gate`` are column ranges of the product
+and no activation is cut per key head and put together again.
+
+The two ELEMENTWISE stages round the rule — the convolution with its SiLU,
+the gated norm — have two lowerings, chosen as the rule's are
+(``ops/gdn_passes.applies``: a TPU, 4 taps, channels and a value head of
+whole 128-lane tiles, a sequence's column block within VMEM, bf16 or
+float32): the kernels ``conv_silu_fwd|bwd`` and ``gated_norm_fwd|bwd`` of
+``ops/gdn_passes.py``, one pass over HBM each, which read their columns of
+``qkvz`` where they lie; or :func:`causal_conv` with ``nn.silu`` and
+:func:`gated_rms_norm` on those column ranges, plain ``jax.numpy``, the
+tests' oracle (and ``decoder_trunk.ShortConv``'s only convolution).
 
 Device-trace scopes, inside the layer's ``gdn``: ``proj``, ``conv``,
 ``core``, ``gate_norm``.
@@ -46,7 +63,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from byol_tpu.ops import delta_rule
+from byol_tpu.ops import delta_rule, gdn_passes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,6 +156,15 @@ def causal_conv(x, taps):
     k, s = taps.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     return sum(padded[:, j:j + s] * taps[j] for j in range(k))
+
+
+def gated_rms_norm(out, gate, gain, eps: float, dtype):
+    """``rmsnorm(out) * gain * silu(gate)`` over the last axis, the
+    statistics and the products float32, the result in ``dtype``."""
+    out = out.astype(jnp.float32)
+    out = out * jax.lax.rsqrt(
+        jnp.mean(jnp.square(out), -1, keepdims=True) + eps)
+    return (out * gain * nn.silu(gate.astype(jnp.float32))).astype(dtype)
 
 
 def chunked_delta_rule(q, k, v, g, beta, *, chunk: int, dtype=jnp.float32,
@@ -281,6 +307,18 @@ def _decay_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
 
 
+class _Kernel(nn.Module):
+    """``nn.Dense``'s kernel under ``nn.Dense``'s name and initializer,
+    handed over as it is: for a product with its columns in another order."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self, width: int):
+        return self.param("kernel", nn.linear.default_kernel_init,
+                          (width, self.features), jnp.float32)
+
+
 class GatedDeltaNet(nn.Module):
     """One Gated DeltaNet mixer over the heads this chip holds: ``(B, S, D)
     -> (B, S, D)``."""
@@ -298,22 +336,32 @@ class GatedDeltaNet(nn.Module):
         hk, hv, dk, dv = (self.key_heads, self.value_heads, z.key_head_dim,
                           z.value_head_dim)
         r = hv // hk                                  # value heads a key head
+        convolved = 2 * hk * dk + hv * dv         # q, k, v: what ``conv`` meets
+        fused = gdn_passes.applies(s, convolved, dv, dt, taps=z.conv_kernel)
         with jax.named_scope("proj"):
-            qkvz = _dense(2 * hk * dk + 2 * hv * dv, dt, "qkvz")(x)
-            ba = _dense(2 * hv, dt, "ba")(x)
-            # per key head: q(d_k) k(d_k) v(r d_v) z(r d_v); b(r) a(r)
-            qkvz = qkvz.reshape(b, s, hk, 2 * dk + 2 * r * dv)
-            ba = ba.reshape(b, s, hk, 2 * r)
+            # the kernel as published, per key head: q(d_k) k(d_k) v(r d_v)
+            # z(r d_v); the product with its columns in the order the stages
+            # read them — [q | k | v | z], each over all key heads — so that
+            # ``mixed`` and ``gate`` are column ranges of it: the columns
+            # move on the WEIGHT (50 MB), never on the activations
+            heads = _Kernel(convolved + hv * dv, name="qkvz")(d).reshape(
+                d, hk, -1)
+            cuts = (0, dk, 2 * dk, 2 * dk + r * dv, 2 * dk + 2 * r * dv)
+            qkvz = jnp.dot(x.astype(dt), jnp.concatenate(
+                [heads[..., lo:hi].reshape(d, -1)
+                 for lo, hi in zip(cuts, cuts[1:])], axis=1).astype(dt))
+            # per key head: b(r) a(r)
+            ba = _dense(2 * hv, dt, "ba")(x).reshape(b, s, hk, 2 * r)
             flat = lambda t: t.reshape(b, s, -1)
-            mixed = jnp.concatenate(
-                [flat(qkvz[..., :dk]), flat(qkvz[..., dk:2 * dk]),
-                 flat(qkvz[..., 2 * dk:2 * dk + r * dv])], axis=-1)
-            gate = qkvz[..., 2 * dk + r * dv:].reshape(b, s, hv, dv)
             b_, a_ = flat(ba[..., :r]), flat(ba[..., r:])    # (B, S, H_v)
         with jax.named_scope("conv"):
             taps = self.param("conv", nn.initializers.lecun_normal(),
-                              (z.conv_kernel, mixed.shape[-1]), jnp.float32)
-            mixed = nn.silu(causal_conv(mixed, taps.astype(dt)))
+                              (z.conv_kernel, convolved), jnp.float32)
+            if fused:       # reads its columns of ``qkvz`` where they lie
+                mixed = gdn_passes.conv_silu(qkvz, taps)
+            else:
+                mixed = nn.silu(causal_conv(qkvz[..., :convolved],
+                                            taps.astype(dt)))
         with jax.named_scope("core"):
             a_log = self.param("A_log", _decay_init, (hv,), jnp.float32)
             dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,),
@@ -333,9 +381,12 @@ class GatedDeltaNet(nn.Module):
         with jax.named_scope("gate_norm"):
             gain = self.param("scale", nn.initializers.ones, (dv,),
                               jnp.float32)
-            out = out.astype(jnp.float32)
-            out = out * jax.lax.rsqrt(
-                jnp.mean(jnp.square(out), -1, keepdims=True) + self.eps)
-            out = (out * gain * nn.silu(gate.astype(jnp.float32))).astype(dt)
+            if fused:
+                out = gdn_passes.gated_norm(out, qkvz, gain, self.eps,
+                                            column=convolved)
+            else:
+                out = gated_rms_norm(
+                    out, qkvz[..., convolved:].reshape(b, s, hv, dv), gain,
+                    self.eps, dt)
         with jax.named_scope("proj"):
             return _dense(d, dt, "o")(out.reshape(b, s, hv * dv))

@@ -14,6 +14,12 @@ call sites name the capability, not the file:
   chunked gated delta rule's within-chunk stage (a ``C x C`` unit
   triangular system a chunk, solved in VMEM), forward and backward: what
   ``models/gated_delta.py`` runs on a TPU at chunks and heads of 128.
+- :mod:`byol_tpu.ops.gdn_passes` (``conv_silu``, ``gated_norm``,
+  ``applies``) — that layer's two elementwise stages, the depthwise causal
+  convolution with its SiLU and the gated RMS norm, each ONE pass over HBM
+  forward and one backward, float32 on a few rows in registers: what
+  ``models/gated_delta.GatedDeltaNet`` runs on a TPU at channels and heads
+  of whole 128-lane tiles.
 - :mod:`byol_tpu.ops.selected_attention` (``attend``, ``applies``) — sparse
   attention's core, grouped-query softmax over each query's SELECTED causal
   keys a ``block x block`` tile at a time with the tile's squares in VMEM,

@@ -434,6 +434,78 @@ def test_delta_rule_falls_back_to_jax_numpy(no_persistent_cache, one_chip,
     assert "delta_wy" not in text and "tpu_custom_call" not in text
 
 
+def _gdn_layer_text(one_chip, monkeypatch, *, width):
+    """``qwen3next_train_b4_s4096``'s Gated DeltaNet layer (16 key and 32
+    value heads, four taps, chunk 128) at heads ``width`` wide over ``[2,
+    4096, 2048]`` in bf16, lowered as on a TPU, forward and backward."""
+    from byol_tpu.models import decoder_trunk, gated_delta
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    z = decoder_trunk.QWEN3_NEXT_80B_A3B
+    sizes = dataclasses.replace(z.gated_delta, key_head_dim=width,
+                                value_head_dim=width)
+    layer = gated_delta.GatedDeltaNet(
+        sizes, sizes.num_key_heads, sizes.num_value_heads, z.rms_norm_eps,
+        jnp.bfloat16)
+    h = jax.ShapeDtypeStruct((2, 4096, z.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    params = _with(jax.eval_shape(
+        layer.init, jax.random.PRNGKey(0),
+        jax.ShapeDtypeStruct((1, 128, z.hidden_size), jnp.bfloat16)),
+        one_chip)
+
+    def loss(params, h):
+        return jnp.sum(jnp.square(layer.apply(params, h).astype(
+            jnp.float32)))
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, h).compile().as_text()
+
+
+_GDN_PASSES = ("conv_silu_fwd", "conv_silu_bwd", "gated_norm_fwd",
+               "gated_norm_bwd")
+
+
+def _float32_stage_arrays(text):
+    """Instructions under ``conv`` or ``gate_norm`` that are not the kernels
+    and compute on a float32 array of the stage's size."""
+    import re
+    whole = re.compile(r"f32\[\d+,4096,(8192|4096|32,128)\]")
+    return [line for line in text.splitlines()
+            if " = " in line and whole.search(line.split(" = ", 1)[1])
+            and re.search(r'op_name="[^"]*/(conv|gate_norm)/', line)
+            and "custom-call(" not in line]
+
+
+def test_gdn_elementwise_stages_are_one_kernel_each(no_persistent_cache,
+                                                    one_chip, monkeypatch):
+    """At the published widths the convolution with its SiLU and the gated
+    norm are one kernel forward and one backward; no padded copy of the
+    convolution's input and no float32 array of a stage's size outside
+    them; and they read their columns out of the ONE product ``[q | k | v |
+    z]``: no activation is ever held per key head, ``[.., 16, 768]``."""
+    import re
+    text = _gdn_layer_text(one_chip, monkeypatch, width=128)
+    calls = [len(re.findall(rf"custom-call\([^\n]*{name}", text))
+             for name in _GDN_PASSES]
+    assert calls == [1, 1, 1, 1], calls
+    assert not re.search(r"\[\d+,4099,8192\]", text)
+    assert not _float32_stage_arrays(text), _float32_stage_arrays(text)[:3]
+    assert "bf16[2,4096,12288]" in text
+    assert not re.search(r"\[(4096,\d+|\d+,4096),16,768\]", text)
+
+
+def test_gdn_elementwise_stages_fall_back_to_jax_numpy(no_persistent_cache,
+                                                       one_chip, monkeypatch):
+    """Heads 64 wide half-fill a lane tile: the ``jax.numpy`` bodies, whose
+    float32 arrays the check above does see — on column ranges of the same
+    ONE product, no activation per key head here either."""
+    import re
+    text = _gdn_layer_text(one_chip, monkeypatch, width=64)
+    assert not any(name in text for name in _GDN_PASSES)
+    assert _float32_stage_arrays(text)
+    assert "bf16[2,4096,6144]" in text
+    assert not re.search(r"\[(4096,\d+|\d+,4096),16,384\]", text)
+
+
 def _core_text(one_chip, monkeypatch, *, backend, block=512, dim=128,
                seq=4096):
     """Sparse attention's core, forward and backward, compiled for the
