@@ -499,16 +499,14 @@ def main():
             get_spec(arch_override)
         except ValueError as e:
             raise SystemExit(f"bench: {e}")
-    # Attention backend for ViT archs (--attn dense|flash|ring): lets the
-    # Pallas flash kernel A/B against XLA dense on the same ladder.
+    # Attention backend for ViT archs (--attn dense|ring).
     attn_impl = "dense"
     if "--attn" in sys.argv[1:]:
         i = sys.argv.index("--attn") + 1
-        if i >= len(sys.argv) or sys.argv[i] not in ("dense", "flash",
-                                                     "ring"):
+        if i >= len(sys.argv) or sys.argv[i] not in ("dense", "ring"):
             # fail fast like --arch: a typo here would otherwise record
             # every ladder rung as "did not fit" (trace-time error)
-            raise SystemExit("usage: bench.py --attn dense|flash|ring")
+            raise SystemExit("usage: bench.py --attn dense|ring")
         attn_impl = sys.argv[i]
     global _PARTIAL_PATH
     if arch_override and arch_override != "resnet50":

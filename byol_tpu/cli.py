@@ -260,7 +260,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "conv as an MXU-friendly 4x4/1 rearrangement "
                         "(identical numerics and checkpoints)")
     x.add_argument("--attn-impl", type=str, default="dense",
-                   choices=("dense", "flash", "ring"),
+                   choices=("dense", "ring"),
                    help="ViT attention backend")
     x.add_argument("--pooling", type=str, default="cls",
                    choices=("cls", "gap"), help="ViT feature pooling")

@@ -115,8 +115,8 @@ class ModelConfig:
                                         # 'space_to_depth' (identical numerics,
                                         # MXU-friendly 4x4/1 rearrangement).
     attn_impl: str = "dense"            # ViT attention backend: 'dense'
-                                        # (XLA), 'flash' (Pallas), 'ring'
-                                        # (sequence-parallel over the mesh).
+                                        # or 'ring' (sequence-parallel over
+                                        # the mesh).
     pooling: str = "cls"                # ViT feature pooling: 'cls' | 'gap'.
     layer_share: str = "0/1"            # decoder trunk: 'i/n' = this chip is
                                         # chip i of the n that share every

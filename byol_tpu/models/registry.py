@@ -90,7 +90,7 @@ def _register_vit() -> None:
                     _h=heads, _p=patch, **kw):
             del small_inputs  # BN-free path: no resnet stem knobs apply
             # kw passes through ViT-specific knobs: attn_impl ('dense' |
-            # 'flash' | 'ring'), remat, pooling.
+            # 'ring'), remat, pooling.
             return vit_lib.ViT(width=_w, depth=_d, num_heads=_h, patch_size=_p,
                                dtype=dtype, **kw)
         register(name, BackboneSpec(factory=factory, feature_dim=width,

@@ -15,8 +15,7 @@ TPU-native choices:
 - attention behind :func:`byol_tpu.ops.attention.get_attention_fn`:
   ``dense`` (exact softmax attention over the whole sequence) for 224px
   ViT-B — 197 tokens, no sequence parallelism warranted (SURVEY.md §5.7) —
-  ``flash`` (Pallas) or ``ring`` (sequence-parallel over the mesh) for
-  long-sequence configs.  ``dense`` as two XLA einsums is NOT the right
+  or ``ring`` (sequence-parallel over the mesh) for long-sequence configs.  ``dense`` as two XLA einsums is NOT the right
   answer at 197 tokens: the scores cross HBM and every head layout is a
   copy, half of the step's bytes (PERF.md §5, PR 28).  So where
   :func:`byol_tpu.ops.attention.packed_kernel_applies` (a TPU, a sequence

@@ -6,7 +6,6 @@ tier-1 runs the real kernel code) plus the shared plumbing in
 :mod:`byol_tpu.ops.common`.  The public kernel API is re-exported here so
 call sites name the capability, not the file:
 
-- :func:`flash_attention` — tiled online-softmax attention (ViT backend).
 - :func:`packed_self_attention` — whole-sequence softmax attention over the
   packed ``qkv``, forward and backward, for sequences that fit VMEM (what
   ``attn_impl='dense'`` runs on a TPU at ViT-B/16's 197 tokens).
@@ -20,12 +19,13 @@ call sites name the capability, not the file:
   forward and one backward, float32 on a few rows in registers: what
   ``models/gated_delta.GatedDeltaNet`` runs on a TPU at channels and heads
   of whole 128-lane tiles.
-- :mod:`byol_tpu.ops.selected_attention` (``attend``, ``applies``) — sparse
-  attention's core, grouped-query softmax over each query's SELECTED causal
-  keys a ``block x block`` tile at a time with the tile's squares in VMEM,
-  forward and backward: what ``ops/attention.selected_attention`` runs on a
-  TPU at blocks and heads of 128 lanes (``models/decoder_trunk.
-  SparseAttention``).
+- :mod:`byol_tpu.ops.causal_attention` (``attend``, ``applies``) — the
+  tiled causal core, grouped-query softmax a ``block x block`` tile at a
+  time with the tile's squares in VMEM, forward and backward, over every
+  causal key or, with ``selected=``, over each query's SELECTED ones: what
+  ``ops/attention.blockwise_causal_attention`` and ``selected_attention``
+  run on a TPU at blocks of 128 lanes and heads of 64, 128 or 256
+  (``models/decoder_trunk``'s grouped-query, latent and sparse attention).
 - :mod:`byol_tpu.ops.key_selection` (``search_rows``, ``applies``) — the
   exact top-k search in front of that core: a query block's row of index
   score tiles held in VMEM for the 32 counting passes that build each
@@ -41,11 +41,10 @@ call sites name the capability, not the file:
   convert/crop/flip/jitter/grayscale, blur as an MXU conv on the output.
 """
 from byol_tpu.ops.common import LANES, resolve_interpret
-from byol_tpu.ops.flash_attention import flash_attention
 from byol_tpu.ops.fused_augment import crop_weight_mats, fused_two_view
 from byol_tpu.ops.packed_attention import packed_self_attention
 
 __all__ = [
-    "LANES", "resolve_interpret", "flash_attention",
-    "packed_self_attention", "crop_weight_mats", "fused_two_view",
+    "LANES", "resolve_interpret", "packed_self_attention",
+    "crop_weight_mats", "fused_two_view",
 ]

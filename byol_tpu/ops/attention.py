@@ -31,15 +31,12 @@ so the model swaps between them by name without re-plumbing:
                 sparse-attention layers) — the same again with each query's
                 softmax over a SET of its causal keys
                 (ops/key_selection.py finds it).  Where the program lowers
-                for a TPU and the shapes allow, the Pallas kernels of
-                ops/selected_attention.py (``selected_attention.applies``):
-                a tile's scores and weights never leave VMEM; elsewhere
-                plain ``jax.numpy``, the block pairs walked by loops the
+                for a TPU and the shapes allow, the same Pallas kernels
+                with the set as one more operand (``causal_attention.
+                applies(.., selected=True)``); elsewhere plain
+                ``jax.numpy``, the block pairs walked by loops the
                 compiler keeps rolled — ``[B,Hkv,G,block,block]`` float32
                 tiles through HBM;
-  ``flash``   — Pallas blockwise-softmax kernel (ops/flash_attention.py),
-                for long sequences where the S x S score matrix shouldn't hit
-                HBM;
   ``ring``    — sequence-parallel blockwise attention over the mesh's
                 ``sequence`` axis (parallel/ring_attention.py), for sequences
                 sharded across chips.
@@ -57,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from byol_tpu.ops import packed_attention
+from byol_tpu.ops.common import MASKED
 from byol_tpu.parallel.mesh import DATA_AXIS
 
 
@@ -83,9 +81,6 @@ def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
 
 
-_MASKED = -1e30      # finite: exp(_MASKED - max) is 0, never inf - inf
-
-
 def _block_bounds(seq_len: int, block: int):
     return [(lo, min(lo + block, seq_len))
             for lo in range(0, seq_len, block)]
@@ -100,7 +95,7 @@ def _block_scores(q_blk, k_blk, scale, q_lo, k_lo):
     if k_lo + bk - 1 > q_lo:                    # some key lies after a query
         visible = (q_lo + jnp.arange(bq))[:, None] >= \
             (k_lo + jnp.arange(bk))[None, :]
-        scores = jnp.where(visible, scores, _MASKED)
+        scores = jnp.where(visible, scores, MASKED)
     return scores
 
 
@@ -230,7 +225,7 @@ def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
         scale = (d + r) ** -0.5
     group_heads = lambda x: x.reshape((b, hkv, hq // hkv) + x.shape[2:])
     if kernels.applies(block, d, s, hq, hkv, q.dtype, vdim=dv, shared=r):
-        out = kernels.attend(
+        out, _ = kernels.attend(
             group_heads(q), k, v, scale=scale, block=block,
             shared=None if shared is None else (group_heads(shared[0]),
                                                 shared[1]))
@@ -279,7 +274,7 @@ def _kept_scores(q_blk, k_blk, scale, keep):
     attends."""
     scores = jnp.einsum("bhgqd,bhkd->bhgqk", q_blk, k_blk,
                         preferred_element_type=jnp.float32) * scale
-    return jnp.where(keep[:, None, None], scores, _MASKED)
+    return jnp.where(keep[:, None, None], scores, MASKED)
 
 
 def _tile(selected, i, j):
@@ -297,7 +292,7 @@ def _selected_fwd(q, k, v, selected, scale, block):
     """``q``: ``(B, Hkv, G, S, D)``; ``k, v``: ``(B, Hkv, S, D)``;
     ``selected``: ``(P, B, block, block)`` bool.  A query block at a time
     over the key blocks it can see, with a running max and sum.  A row none
-    of whose keys in a tile is kept carries ``_MASKED`` as its max until a
+    of whose keys in a tile is kept carries ``MASKED`` as its max until a
     kept key comes, whose ``keep`` factor then zeroes what went before:
     every row keeps a key somewhere."""
     b, hkv, g, s, _ = q.shape
@@ -321,7 +316,7 @@ def _selected_fwd(q, k, v, selected, scale, block):
 
         top, total, acc = jax.lax.fori_loop(
             0, i + 1, key_block,
-            (jnp.full(rows, _MASKED, jnp.float32),
+            (jnp.full(rows, MASKED, jnp.float32),
              jnp.zeros(rows, jnp.float32),
              jnp.zeros(rows + v.shape[-1:], jnp.float32)))
         return (acc / total[..., None]).astype(q.dtype), top + jnp.log(total)
@@ -397,18 +392,16 @@ def selected_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     the output and each row's log-sum-exp over its keys (``(B, Hq, S)``
     float32, for :func:`kept_probabilities`).  Masked-dense: a tile none of
     whose keys is kept is still formed; a tile above the diagonal never.
-    Two lowerings of one arithmetic, chosen from what the code can see
-    (``ops/selected_attention.applies``): where the program lowers for a TPU
-    and ``block`` and the head width are multiples of 128 whose working set
-    fits VMEM, the Pallas kernels ``selected_attention_fwd`` /
-    ``selected_attention_bwd`` of ops/selected_attention.py — a tile's
-    scores, weights and their cotangents live and die in VMEM; everywhere
+    Two lowerings of one arithmetic, chosen by :func:`blockwise_causal_
+    attention`'s rule (``ops/causal_attention.applies(.., selected=True)``):
+    its Pallas kernels with the set as one more operand
+    (``selected_attention_fwd`` / ``selected_attention_bwd``); everywhere
     else (the CPU, the tiny presets, odd shapes) plain ``jax.numpy`` under
     ``lax`` loops — a query block at a time (``lax.map``), its key blocks
     under a ``fori_loop`` — which is also the tests' oracle for the
     kernels.  Forward and backward on both (``jax.custom_vjp``, scores
     recomputed from the saved log-sum-exp)."""
-    from byol_tpu.ops import selected_attention as kernels   # imports this
+    from byol_tpu.ops import causal_attention as kernels     # imports this
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     if hq % hkv or s % block:
@@ -417,9 +410,10 @@ def selected_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if scale is None:
         scale = d ** -0.5
     grouped = q.reshape(b, hkv, hq // hkv, s, d)
-    if d == v.shape[-1] and kernels.applies(block, d, s, hq, hkv, q.dtype):
-        out, lse = kernels.attend(grouped, k, v, selected, scale=scale,
-                                  block=block)
+    if kernels.applies(block, d, s, hq, hkv, q.dtype, vdim=v.shape[-1],
+                       selected=True):
+        out, lse = kernels.attend(grouped, k, v, scale=scale, block=block,
+                                  selected=selected)
     else:
         out, lse = _selected(grouped, k, v, selected, float(scale),
                              int(block))
@@ -476,11 +470,7 @@ def packed_kernel_applies(batch: int, seq_len: int, num_heads: int,
 def get_attention_fn(impl: str) -> Callable:
     if impl == "dense":
         return dense_attention
-    if impl == "flash":
-        from byol_tpu.ops.flash_attention import flash_attention
-        return flash_attention
     if impl == "ring":
         from byol_tpu.parallel.ring_attention import ring_attention
         return ring_attention
-    raise ValueError(f"unknown attention impl {impl!r}; "
-                     f"known: dense, flash, ring")
+    raise ValueError(f"unknown attention impl {impl!r}; known: dense, ring")

@@ -1,25 +1,67 @@
-"""Causal grouped-query attention — forward and backward kernels.
+"""Tiled causal grouped-query attention — forward and backward kernels.
 
-``attend(q, k, v, scale=, block=)`` is the second lowering of
-``ops/attention.blockwise_causal_attention``: the same block pairs, the same
-running max and sum, the same five products backward, with a pair's scores,
-weights, ``d_weights`` and ``d_scores`` in VMEM instead of four float32
-``(B, Hkv, G, block, block)`` arrays in HBM (PERF.md section 5, PR 38: 159 of
-the core's 183 ms a step were fusions over those tiles).  It is
-ops/selected_attention.py (PR 34; its docstring has the design and the chip
-timings behind each choice) WITHOUT a selection:
+``attend(q, k, v, scale=, block=, selected=, shared=)`` is the kernel
+lowering of both blockwise cores of ops/attention.py: of
+``blockwise_causal_attention`` (every causal key) and, with ``selected``, of
+``selected_attention`` (each query's softmax over the causal keys
+``selected`` marks for it, the same set for every head).  Both return the
+output and each row's log-sum-exp.  It exists because of what the compiler
+does with the ``jax.numpy`` form (PERF.md section 5, PR 33 and PR 38): per
+block pair the scores, the weights and, backward, ``d_weights`` and
+``d_scores`` were each a whole float32 ``(B, Hkv, G, block, block)`` array
+in HBM — 268 MB at the published sizes, some 650 GB a step for 27 TFLOP of
+products under a selection, 159 of the plain core's 183 ms a step.  Here a
+tile's squares live and die in VMEM.  Still MASKED-DENSE: every tile on or
+under the diagonal is formed whatever it keeps, a tile above it never.
 
-- grid ``(B, Hkv, causal pair)``, :func:`causal_pairs` as scalar prefetch: no
-  step lies above the diagonal; the ``G`` query heads of a key head are one
-  program, traced side by side; a tile's squares are held ``[keys,
-  queries]``, so a query's max and sum run down the sublanes and its
-  statistics are lane rows; ONE backward kernel of five products, ``d_k,
-  d_v`` of a key head's whole sequence resident in float32;
-- no mask operand.  Only a tile ON the diagonal (``j == i``) masks anything,
-  and its mask is the same lower triangle every time: made from two iotas
-  into a float32 bias (``0`` / ``-1e30``, which in float32 IS ``where(visible,
-  score, -1e30)``) on those steps alone; a tile under the diagonal adds
-  nothing;
+Design (see /opt/skills/guides/pallas_guide.md):
+- grid ``(B, Hkv, causal pair)``: ``causal_pairs`` goes in as scalar
+  prefetch and the PAIR is the innermost axis, so the grid has no step above
+  the diagonal and the block index maps read ``i, j`` of a step from SMEM
+  (pair ``(i, j)`` is tile ``i (i + 1) / 2 + j`` of ops/attention.py's TILE
+  layout);
+- a program holds the ``G`` query heads of one key head: ``k`` and ``v`` are
+  fetched once for the ``G`` of them, traced side by side (a Python loop), so
+  that one head's products overlap another's passes: 20.5 -> 18.6 ms a call
+  of 8 sequences (chip runs, PR 34);
+- what a tile masks is an additive float32 bias, ``0`` where the query sees
+  the key, ``-1e30`` where not, which in float32 IS ``where(visible, score,
+  -1e30)`` (a score is lost whole under ``1e30``'s rounding).  Where it
+  comes from is decided while the kernel is TRACED, from whether there is a
+  selection.  With one, ``selected`` arrives in the tile layout, ``(P, B,
+  block, block)``, and a pair's tile by its own ``BlockSpec``: it has no
+  head axis, so it crosses HBM once a key head, as ``int8`` (``bool``
+  operands lower badly), and becomes the bias once a program.  Without one
+  there is no mask operand: only a tile ON the diagonal (``j == i``) masks
+  anything, and its mask is the same lower triangle every time, made from
+  two iotas on those steps alone; a tile under the diagonal adds nothing;
+- both kernels hold a tile's squares TRANSPOSED, ``[keys, queries]``: what
+  is taken over a query's keys — the running max and sum — then runs down
+  the sublanes, an elementwise pass of the vector unit, and a query's
+  statistics are lane rows broadcast down the sublanes.  With scores
+  ``[queries, keys]`` the forward made 128 cross-lane reductions a head
+  and tile and took 18.6 ms a call; transposed 11.6 (PR 34);
+- forward: running max, sum and the float32 accumulator of every head stay
+  in VMEM scratch across the key blocks of a query block (``j = 0 .. i``:
+  the innermost axis walks them in order), the output and the log-sum-exp
+  are written at ``j == i``.  Only ``P V`` contracts the leading axis, and
+  the accumulator ``[queries, Dv]`` is rescaled by the statistics' row
+  turned into a column.  A row none of whose keys in a tile is kept
+  carries ``-1e30`` as its max, weighs that tile's keys 1 each, and loses
+  all of it to the ``exp(old max - new max) = 0`` of the first kept key —
+  the arithmetic of ``ops/attention._selected_fwd``;
+- backward: ONE kernel, five products a tile (the usual pair of kernels
+  recomputes the scores in each: seven), no reduction at all: the rows'
+  log-sum-exp and ``delta = rowsum(dO . O)`` (made outside: one fused pass
+  over ``dO, O``) come in as lane rows, ``d_v = P^T dO`` and ``d_k = dS^T
+  q`` are plain products, only ``d_q = dS k`` contracts the leading axis —
+  16.4 ms a call, 96% of the matrix unit's peak (PR 34).  ``d_q`` of a
+  query block accumulates in scratch over its key blocks and is rounded at
+  ``j == i``; ``d_k, d_v`` of ONE key head's whole sequence stay resident as
+  the kernel's float32 output blocks ``(S, D)``, ``(S, Dv)`` across all its
+  pairs — summed there over the ``G`` query heads and the query blocks — and
+  are rounded once outside.  Those blocks are what bounds the sequence
+  (:func:`supported`): 16 bytes a token and lane column;
 - head widths.  A block's last dimension is the array's whole head: 128 and
   256 fill lane tiles, 64 is half of one and is PADDED to a tile — in VMEM
   (:func:`_vmem_bytes` counts 128) and, by the TPU's tiled layout
@@ -39,9 +81,9 @@ timings behind each choice) WITHOUT a selection:
   pairs, so the head axis of that grid is walked in order;
 - bf16 (the input dtype's) operands, float32 accumulation and statistics, the
   weights rounded before ``P V`` and ``d_scores`` before its products, as
-  the ``jax.numpy`` body does; float32 inputs multiply at
-  ``Precision.HIGHEST``.  Residuals ``q, k, v, out, lse`` (and the shared
-  pair).
+  the ``jax.numpy`` bodies do; float32 inputs multiply at
+  ``Precision.HIGHEST``.  Residuals ``q, k, v, out, lse`` (and the mask and
+  the shared pair).
 
 ``interpret=True`` (default off-TPU) runs the same kernels under the Pallas
 interpreter so CPU tests exercise identical code paths.
@@ -56,26 +98,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from byol_tpu.ops import common as ops_common
-from byol_tpu.ops.attention import _MASKED, causal_pairs
-from byol_tpu.ops.common import LANES
-from byol_tpu.ops.selected_attention import (_NN, _NT, _TN, VMEM_LIMIT_BYTES,
-                                             _dot)
+from byol_tpu.ops.attention import causal_pairs
+from byol_tpu.ops.common import (LANES, MASKED, NN, NT, TN, VMEM_LIMIT_BYTES,
+                                 dot, resolve_interpret)
 
 
 def _vmem_bytes(block: int, dim: int, seq_len: int, group: int,
                 itemsize: int, forward: bool, *, vdim: Optional[int] = None,
-                shared: int = 0) -> int:
+                shared: int = 0, selected: bool = False) -> int:
     """A kernel's blocks twice (double buffering), its scratch and the
-    float32 squares of the head in hand — ``selected_attention``'s count
-    without a mask, at a key width ``dim`` (+ ``shared``) and a value width
-    ``vdim``; a width that does not fill its last 128 lanes takes the whole
-    lane tile of VMEM."""
+    float32 squares of the head in hand, at a key width ``dim`` (+
+    ``shared``) and a value width ``vdim``; a width that does not fill its
+    last 128 lanes takes the whole lane tile of VMEM; ``selected``: a pair's
+    int8 mask among the blocks."""
     tiles = lambda d: -(-d // LANES) * LANES
     key_lanes = tiles(dim) + tiles(shared)
     value_lanes = tiles(dim if vdim is None else vdim)
     q_rows, o_rows = group * block * key_lanes, group * block * value_lanes
-    slabs = block * (key_lanes + value_lanes) * itemsize    # k, v (, k_s)
+    slabs = (block * (key_lanes + value_lanes) * itemsize   # k, v (, k_s)
+             + (block * block if selected else 0))          # (, the mask)
     square = 4 * block * block       # one float32 (block, block) value
     if forward:
         blocks = (q_rows + o_rows) * itemsize + slabs \
@@ -99,7 +140,7 @@ def _width_ok(dim: int) -> bool:
 
 def supported(block: int, dim: int, seq_len: int, group: int = 1,
               itemsize: int = 2, *, vdim: Optional[int] = None,
-              shared: int = 0) -> bool:
+              shared: int = 0, selected: bool = False) -> bool:
     """Shapes the kernels take: a block's tokens fill whole 128-lane tiles,
     each of the key width, the value width and the shared part (0 = none)
     one :func:`_width_ok` takes, whole blocks, and the backward's working
@@ -111,32 +152,40 @@ def supported(block: int, dim: int, seq_len: int, group: int = 1,
             and (shared == 0 or _width_ok(shared))
             and seq_len > 0 and seq_len % block == 0 and group > 0
             and max(_vmem_bytes(block, dim, seq_len, group, itemsize, fwd,
-                                vdim=vdim, shared=shared)
+                                vdim=vdim, shared=shared, selected=selected)
                     for fwd in (True, False)) <= VMEM_LIMIT_BYTES)
 
 
 def applies(block: int, dim: int, seq_len: int, heads: int, kv_heads: int,
             dtype=jnp.bfloat16, *, vdim: Optional[int] = None,
-            shared: int = 0, backend: Optional[str] = None) -> bool:
-    """Whether ``blockwise_causal_attention`` runs as the kernels — decided
-    from what the code can see, never by a flag: the program lowers for a
-    TPU, the query heads share the key heads evenly and the shapes (key
-    width ``dim``, value width ``vdim``, a ``shared`` part or none) are ones
-    the kernels take."""
+            shared: int = 0, selected: bool = False,
+            backend: Optional[str] = None) -> bool:
+    """Whether ``blockwise_causal_attention`` (``selected``:
+    ``selected_attention``) runs as the kernels — decided from what the code
+    can see, never by a flag: the program lowers for a TPU, the query heads
+    share the key heads evenly and the shapes (key width ``dim``, value
+    width ``vdim``, a ``shared`` part or none) are ones the kernels take."""
     backend = jax.default_backend() if backend is None else backend
     return (backend == "tpu" and kv_heads > 0 and heads % kv_heads == 0
             and supported(block, dim, seq_len, heads // kv_heads,
                           jnp.dtype(dtype).itemsize, vdim=vdim,
-                          shared=shared))
+                          shared=shared, selected=selected))
 
 
 # ---- the kernels -----------------------------------------------------------
 
-def _on_and_under_the_diagonal(i, j, bias_ref, tile):
-    """``tile(bias)`` for the step's pair: ``bias`` is None under the
-    diagonal and, on it, ``bias_ref`` holding ``(bk, bq)`` float32, 0 where
-    the query sees the key (same block: its row in the tile is not after the
-    query's column), ``_MASKED`` where not."""
+def _with_the_pairs_bias(i, j, keep_ref, bias_ref, tile):
+    """``tile(bias)`` for the step's pair, ``bias`` a ``(bk, bq)`` float32
+    ref, 0 where the query sees the key and ``MASKED`` where not, or None
+    where it sees them all.  With a selection (``keep_ref (bq, bk)`` int8:
+    1 = kept) every pair has one, its tile turned ``[keys, queries]``.
+    Without (None), only the pair ON the diagonal has: same block, so a
+    key's row in the tile is not after the query's column."""
+    if keep_ref is not None:
+        bias_ref[...] = ((1.0 - keep_ref[...].astype(jnp.float32))
+                         * MASKED).T
+        return tile(bias_ref)
+
     @pl.when(j < i)
     def _under():
         tile(None)
@@ -145,37 +194,49 @@ def _on_and_under_the_diagonal(i, j, bias_ref, tile):
     def _on():
         key = jax.lax.broadcasted_iota(jnp.int32, bias_ref.shape, 0)
         query = jax.lax.broadcasted_iota(jnp.int32, bias_ref.shape, 1)
-        bias_ref[...] = jnp.where(key <= query, 0.0, _MASKED)
+        bias_ref[...] = jnp.where(key <= query, 0.0, MASKED)
         tile(bias_ref)
 
 
 def _scores(k_ref, q, scale, bias, shared=None):
     """``(bk, bq)`` float32; ``shared``: the head's ``q_s`` and the ``k_s``
     ref, whose product is the scores' second term."""
-    scores = _dot(k_ref[...], q, _NT)
+    scores = dot(k_ref[...], q, NT)
     if shared is not None:
         q_s, ks_ref = shared
-        scores = scores + _dot(ks_ref[...], q_s, _NT)
+        scores = scores + dot(ks_ref[...], q_s, NT)
     scores = scores * scale
     return scores if bias is None else scores + bias[...]
 
 
-def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, shared: bool):
+def _taker(refs):
+    """``take(n, present=True)``: the next ``n`` of a kernel's refs, or
+    ``n`` Nones for operands this call does not have."""
+    refs = iter(refs)
+    return lambda n, present=True: [
+        next(refs) if present else None for _ in range(n)]
+
+
+def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
+                shared: bool):
     """Scores ``[keys, queries]``.  Refs: ``q (G, bq, D)``; ``k (bk, D)``;
-    ``v (bk, Dv)``; with a shared part ``q_s (G, bq, r)``, ``k_s (bk, r)``;
-    ``o (G, bq, Dv)``; ``lse (G, bq)``; scratch: every head's running max
-    and sum, a lane row a head, ``(G, bq)``, the float32 accumulators ``(G,
-    bq, Dv)`` and a diagonal tile's bias."""
-    q_ref, k_ref, v_ref, *refs = refs
-    qs_ref, ks_ref = refs[:2] if shared else (None, None)
-    o_ref, lse_ref, top_ref, total_ref, acc_ref, bias_ref = refs[-6:]
+    ``v (bk, Dv)``; with a selection ``keep (bq, bk)`` int8; with a shared
+    part ``q_s (G, bq, r)``, ``k_s (bk, r)``; ``o (G, bq, Dv)``; ``lse (G,
+    bq)``; scratch: every head's running max and sum, a lane row a head,
+    ``(G, bq)``, the float32 accumulators ``(G, bq, Dv)`` and a tile's
+    bias."""
+    take = _taker(refs)
+    q_ref, k_ref, v_ref = take(3)
+    keep_ref, = take(1, selected)
+    qs_ref, ks_ref = take(2, shared)
+    o_ref, lse_ref, top_ref, total_ref, acc_ref, bias_ref = take(6)
     pair = pl.program_id(2)
     i, j = q_of_ref[pair], k_of_ref[pair]
     group, _, dim = acc_ref.shape
 
     @pl.when(j == 0)
     def _start():
-        top_ref[...] = jnp.full_like(top_ref, _MASKED)
+        top_ref[...] = jnp.full_like(top_ref, MASKED)
         total_ref[...] = jnp.zeros_like(total_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -196,14 +257,14 @@ def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, shared: bool):
         total_ref[at, :] = total_ref[at, :] * keep + jnp.sum(
             weights, axis=0, keepdims=True)
         top_ref[at, :] = new_top
-        acc_ref[h] = acc_ref[h] * column(keep) + _dot(
-            weights.astype(v_ref.dtype), v_ref[...], _TN)
+        acc_ref[h] = acc_ref[h] * column(keep) + dot(
+            weights.astype(v_ref.dtype), v_ref[...], TN)
 
     def tile(bias):
-        for h in range(group):      # side by side: selected_attention.py
+        for h in range(group):      # side by side: the module docstring
             head(h, bias)
 
-    _on_and_under_the_diagonal(i, j, bias_ref, tile)
+    _with_the_pairs_bias(i, j, keep_ref, bias_ref, tile)
 
     @pl.when(j == i)
     def _finish():
@@ -213,18 +274,18 @@ def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, shared: bool):
                 o_ref.dtype)
 
 
-def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, shared: bool):
+def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
+                shared: bool):
     """Everything ``[keys, queries]``.  Refs: ``q, dq (G, bq, D)``; ``dO (G,
-    bq, Dv)``; ``k (bk, D)``; ``v (bk, Dv)``; ``lse, delta (G, bq)``; ``dk
-    (S, D)``, ``dv (S, Dv)`` float32, one key head's, resident over all its
-    pairs; with a shared part ``q_s, dq_s (G, bq, r)``, ``k_s (bk, r)`` and
-    ``dk_s (S, r)`` float32, ONE SEQUENCE's, resident over all its heads and
-    pairs; scratch: the float32 ``dq`` (and ``dq_s``) of the query block and
-    a diagonal tile's bias."""
-    refs = iter(refs)
-    take = lambda n, present=True: [
-        next(refs) if present else None for _ in range(n)]
+    bq, Dv)``; ``k (bk, D)``; ``v (bk, Dv)``; with a selection ``keep (bq,
+    bk)`` int8; ``lse, delta (G, bq)``; ``dk (S, D)``, ``dv (S, Dv)``
+    float32, one key head's, resident over all its pairs; with a shared part
+    ``q_s, dq_s (G, bq, r)``, ``k_s (bk, r)`` and ``dk_s (S, r)`` float32,
+    ONE SEQUENCE's, resident over all its heads and pairs; scratch: the
+    float32 ``dq`` (and ``dq_s``) of the query block and a tile's bias."""
+    take = _taker(refs)
     q_ref, k_ref, v_ref = take(3)
+    keep_ref, = take(1, selected)
     qs_ref, ks_ref = take(2, shared)
     lse_ref, delta_ref, do_ref, dq_ref, dk_ref, dv_ref = take(6)
     dqs_ref, dks_ref = take(2, shared)
@@ -258,20 +319,20 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, shared: bool):
         weights = jnp.exp(_scores(
             k_ref, q, scale, bias,
             (qs_ref[h], ks_ref) if shared else None) - lse)
-        dv_ref[keys, :] += _dot(weights.astype(d_out.dtype), d_out, _NN)
-        d_weights = _dot(v_ref[...], d_out, _NT)
+        dv_ref[keys, :] += dot(weights.astype(d_out.dtype), d_out, NN)
+        d_weights = dot(v_ref[...], d_out, NT)
         d_scores = (weights * (d_weights - delta) * scale).astype(q.dtype)
-        dk_ref[keys, :] += _dot(d_scores, q, _NN)
-        dq_acc_ref[h] += _dot(d_scores, k_ref[...], _TN)
+        dk_ref[keys, :] += dot(d_scores, q, NN)
+        dq_acc_ref[h] += dot(d_scores, k_ref[...], TN)
         if shared:
-            dks_ref[keys, :] += _dot(d_scores, qs_ref[h], _NN)
-            dqs_acc_ref[h] += _dot(d_scores, ks_ref[...], _TN)
+            dks_ref[keys, :] += dot(d_scores, qs_ref[h], NN)
+            dqs_acc_ref[h] += dot(d_scores, ks_ref[...], TN)
 
     def tile(bias):
-        for h in range(group):      # side by side: selected_attention.py
+        for h in range(group):      # side by side: the module docstring
             head(h, bias)
 
-    _on_and_under_the_diagonal(i, j, bias_ref, tile)
+    _with_the_pairs_bias(i, j, keep_ref, bias_ref, tile)
 
     @pl.when(j == i)
     def _finish():
@@ -280,25 +341,27 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, shared: bool):
             dqs_ref[...] = dqs_acc_ref[...].astype(dqs_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3),
-                   static_argnames=("shared",))
-def _call(forward, scale, block, interpret, q, k, v, *rest,
-          shared: bool = False):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _call(forward, scale, block, interpret, q, k, v, keep, shared, *rest):
     """One ``pallas_call`` over ``(batch, key head, causal pair)``.  ``q``:
     ``(B, Hkv, G, S, D)``; ``k``: ``(B, Hkv, S, D)``; ``v``: ``(B, Hkv, S,
-    Dv)``; ``dO`` and the output ``(B, Hkv, G, S, Dv)``; ``lse, delta``:
-    ``(B, Hkv, G, S)`` float32; ``shared``: ``rest`` starts with ``q_s (B,
-    Hkv, G, S, r)`` and ``k_s (B, S, r)``.  Jitted so that a model's passes
-    share one trace and lowering of each kernel."""
+    Dv)``; ``keep``: None or ``(P, B, block, block)`` int8; ``shared``:
+    ``()`` or ``(q_s (B, Hkv, G, S, r), k_s (B, S, r))``; ``rest``, backward:
+    ``lse, delta (B, Hkv, G, S)`` float32 and ``dO``, like the output ``(B,
+    Hkv, G, S, Dv)``.  Jitted so that a model's passes share one trace and
+    lowering of each kernel."""
     b, hkv, g, s, d = q.shape
     dv = v.shape[-1]
-    r = rest[0].shape[-1] if shared else 0
+    selected, r = keep is not None, shared[0].shape[-1] if shared else 0
     q_of, k_of = causal_pairs(s // block)
     # index maps: (batch, key head, pair, q_of, k_of)
     rows = lambda w: pl.BlockSpec((None, None, g, block, w),
                                   lambda n, h, p, qo, ko: (n, h, 0, qo[p], 0))
     slab = lambda w: pl.BlockSpec((None, None, block, w),
                                   lambda n, h, p, qo, ko: (n, h, ko[p], 0))
+    # the pair's mask: no head axis
+    tile = pl.BlockSpec((None, None, block, block),
+                        lambda n, h, p, qo, ko: (p, n, 0, 0))
     # the shared key and its cotangent: no head axis
     slab_s = pl.BlockSpec((None, block, r),
                           lambda n, h, p, qo, ko: (n, ko[p], 0))
@@ -309,14 +372,15 @@ def _call(forward, scale, block, interpret, q, k, v, *rest,
     square = pltpu.VMEM((block, block), jnp.float32)
     per_head = lambda w: pltpu.VMEM((g, block, w), jnp.float32)
     in_specs = [rows(d), slab(d), slab(dv)] + (
-        [rows(r), slab_s] if shared else [])
+        [tile] if selected else []) + ([rows(r), slab_s] if shared else [])
+    stem = "selected_attention" if selected else "causal_attention"
     if forward:
-        kernel, name = _fwd_kernel, "causal_attention_fwd"
+        kernel, name = _fwd_kernel, stem + "_fwd"
         outs = [(rows(dv), like(dv)), (row_stat, stat)]
         stats = pltpu.VMEM((g, block), jnp.float32)
         scratch = [stats, stats, per_head(dv), square]
     else:
-        kernel, name = _bwd_kernel, "causal_attention_bwd"
+        kernel, name = _bwd_kernel, stem + "_bwd"
         in_specs += [row_stat, row_stat, rows(dv)]
         whole = lambda w: pl.BlockSpec((None, None, s, w),
                                        lambda n, h, p, qo, ko: (n, h, 0, 0))
@@ -331,13 +395,14 @@ def _call(forward, scale, block, interpret, q, k, v, *rest,
                       jax.ShapeDtypeStruct((b, s, r), jnp.float32))]
             scratch += [per_head(r)]
         scratch += [square]
-    arrays = (q, k, v) + rest
+    arrays = (q, k, v) + ((keep,) if selected else ()) + shared + rest
     formed = b * hkv * g * len(q_of) * block * block      # pairs, every head
     depth = (d + r + dv) if forward else 3 * (d + r) + 2 * dv
     moved = sum(a.size * a.dtype.itemsize for a in arrays) + sum(
         out.size * out.dtype.itemsize for _, out in outs)
     return pl.pallas_call(
-        functools.partial(kernel, scale=scale, shared=shared),
+        functools.partial(kernel, scale=scale, selected=selected,
+                          shared=bool(shared)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, hkv, len(q_of)),
@@ -360,41 +425,46 @@ def _call(forward, scale, block, interpret, q, k, v, *rest,
     )(jnp.asarray(q_of), jnp.asarray(k_of), *arrays)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _attend(q, k, v, shared, scale, block, interpret):
-    return _call(True, scale, block, interpret, q, k, v, *shared,
-                 shared=bool(shared))[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _attend(q, k, v, keep, shared, scale, block, interpret):
+    """``(out, log-sum-exp)``; the second takes no cotangent."""
+    return _attend_fwd(q, k, v, keep, shared, scale, block, interpret)[0]
 
 
-def _attend_fwd(q, k, v, shared, scale, block, interpret):
-    out, lse = _call(True, scale, block, interpret, q, k, v, *shared,
-                     shared=bool(shared))
-    return out, (q, k, v, shared, out, lse)
+def _attend_fwd(q, k, v, keep, shared, scale, block, interpret):
+    out, lse = _call(True, scale, block, interpret, q, k, v, keep, shared)
+    return (out, lse), (q, k, v, keep, shared, out, lse)
 
 
-def _attend_bwd(scale, block, interpret, residuals, d_out):
-    q, k, v, shared, out, lse = residuals
+def _attend_bwd(scale, block, interpret, residuals, cotangents):
+    q, k, v, keep, shared, out, lse = residuals
+    d_out, _ = cotangents
     # sum_k w (dw) of the softmax's backward is rowsum(dO . O)
     delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
     d_q, d_k, d_v, *d_shared = _call(
-        False, scale, block, interpret, q, k, v, *shared, lse, delta,
-        d_out.astype(q.dtype), shared=bool(shared))
+        False, scale, block, interpret, q, k, v, keep, shared, lse, delta,
+        d_out.astype(q.dtype))
     if shared:
         d_shared[1] = d_shared[1].astype(shared[1].dtype)
-    return d_q, d_k.astype(k.dtype), d_v.astype(v.dtype), tuple(d_shared)
+    return (d_q, d_k.astype(k.dtype), d_v.astype(v.dtype), None,
+            tuple(d_shared))
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
-def attend(q, k, v, *, scale: float, block: int, shared=None,
+def attend(q, k, v, *, scale: float, block: int, selected=None, shared=None,
            interpret: Optional[bool] = None):
     """``q``: ``(B, Hkv, G, S, D)``; ``k``: ``(B, Hkv, S, D)``; ``v``: ``(B,
-    Hkv, S, Dv)``, ``S`` whole blocks; ``shared``: None or ``(q_s (B, Hkv,
-    G, S, r), k_s (B, S, r))``.  Returns ``out (B, Hkv, G, S, Dv)`` — what
-    ``ops/attention._blockwise_causal`` returns, differentiable w.r.t. ``q,
-    k, v`` and the shared pair."""
-    return _attend(q, k, v, tuple(shared) if shared is not None else (),
+    Hkv, S, Dv)``, ``S`` whole blocks; ``selected``: None (every causal key)
+    or ``(P, B, block, block)`` bool, the tile layout; ``shared``: None or
+    ``(q_s (B, Hkv, G, S, r), k_s (B, S, r))``.  Returns ``out (B, Hkv, G,
+    S, Dv)`` and the rows' log-sum-exp ``(B, Hkv, G, S)`` float32 — what
+    ``ops/attention._blockwise_causal`` and ``_selected`` return,
+    differentiable w.r.t. ``q, k, v`` and the shared pair."""
+    return _attend(q, k, v,
+                   None if selected is None else selected.astype(jnp.int8),
+                   () if shared is None else tuple(shared),
                    float(scale), int(block),
-                   ops_common.resolve_interpret(interpret))
+                   resolve_interpret(interpret))

@@ -69,7 +69,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from byol_tpu.ops import common as ops_common
-from byol_tpu.ops.common import LANES
+from byol_tpu.ops.common import LANES, NN, NT, TN
 
 HIGHEST = jax.lax.Precision.HIGHEST
 # Side of the diagonal blocks that forward substitution inverts; the matrix
@@ -96,10 +96,6 @@ _LIVE_WIDE = (3, 5)
 MAX_TILE = 8                     # chunks a program holds
 
 SUBLANES = 8                     # rows of a float32 tile
-
-_NT = ((1,), (1,))      # a @ b^T
-_NN = ((1,), (0,))      # a @ b
-_TN = ((0,), (0,))      # a^T @ b
 
 
 def _vmem_bytes(chunk: int, dk: int, dv: int, tile: int, itemsize: int,
@@ -194,9 +190,9 @@ def _inverse(lower, level):
             # rows are whole sublane tiles: half the rows through the unit
             halves = inverse.reshape(c // (2 * s), 2, s, c)
             low = halves[:, 1].reshape(c // 2, c)
-        first = _dot(low, quarter, _NN)
+        first = _dot(low, quarter, NN)
         yield
-        low = low - _dot(first, inverse, _NN)
+        low = low - _dot(first, inverse, NN)
         yield
         inverse = low if whole else jnp.concatenate(
             [halves[:, :1], low.reshape(c // (2 * s), 1, s, c)],
@@ -225,7 +221,7 @@ def _cumsum(row, c, reverse=False):
     matrix unit's rows are 8 at least)."""
     rows, cols = _iotas(c)
     ones = (rows >= cols if reverse else rows <= cols).astype(jnp.float32)
-    return _dot(jnp.broadcast_to(row, (8, c)), ones, _NN)[:1]
+    return _dot(jnp.broadcast_to(row, (8, c)), ones, NN)[:1]
 
 
 def _gates(g_row, beta_row, c):
@@ -249,7 +245,7 @@ def _system(k, v, beta, gamma, exact):
     k_beta = k.astype(jnp.float32) * beta
     rhs = jnp.concatenate([v.astype(jnp.float32) * beta,
                            k_beta * jnp.exp(gamma)], axis=1)
-    return k_beta, rhs, _dot(k_beta.astype(k.dtype), k, _NT, exact)
+    return k_beta, rhs, _dot(k_beta.astype(k.dtype), k, NT, exact)
 
 
 def _forward_chunk(q, k, v, g_row, beta_row, level):
@@ -260,8 +256,8 @@ def _forward_chunk(q, k, v, g_row, beta_row, level):
     _, rhs, gram = _system(k, v, beta, gamma, exact)
     yield
     inverse = yield from _inverse(gram * decay, level)
-    solved = _dot(inverse, rhs, _NN)
-    within = _dot(q, k, _NT, exact) * decay
+    solved = _dot(inverse, rhs, NN)
+    within = _dot(q, k, NT, exact) * decay
     q_in = q.astype(jnp.float32) * jnp.exp(gamma)
     k_out = k.astype(jnp.float32) * jnp.exp(left)
     return (solved[:, :dv], solved[:, dv:], within, q_in, k_out, gamma_row,
@@ -285,18 +281,18 @@ def _backward_chunk(q, k, v, g_row, beta_row, inverse, d_u, d_w, d_within,
     # [u | w] = T rhs
     d_solved = jnp.concatenate([d_u, f32(d_w)], axis=1)
     turned = inverse.T
-    d_rhs = _dot(turned, d_solved, _NN)
-    d_inverse = _dot(d_solved, rhs, _NT)
+    d_rhs = _dot(turned, d_solved, NN)
+    d_inverse = _dot(d_solved, rhs, NT)
     yield
     # T = (I + A)^-1, A = strict_tril(gram . decay)
-    d_lower = _dot(turned, d_inverse, _NN)
+    d_lower = _dot(turned, d_inverse, NN)
     yield
     rows, cols = _iotas(c)
-    d_lower = jnp.where(rows > cols, -_dot(d_lower, turned, _NN), 0.0)
+    d_lower = jnp.where(rows > cols, -_dot(d_lower, turned, NN), 0.0)
     yield
     # within = (q k^T) . decay
     d_scores = f32(d_within)
-    d_decay = d_lower * gram + d_scores * _dot(q, k, _NT, exact)
+    d_decay = d_lower * gram + d_scores * _dot(q, k, NT, exact)
     d_gram = (d_lower * decay).astype(dt)
     d_scores = (d_scores * decay).astype(dt)
     # decay = exp(gamma_i - gamma_j): zero above the diagonal, so is this
@@ -305,12 +301,12 @@ def _backward_chunk(q, k, v, g_row, beta_row, inverse, d_u, d_w, d_within,
     d_gamma_row = d_gamma_row - jnp.sum(d_exponent, axis=0, keepdims=True)
 
     d_k_grow = d_rhs[:, dv:]                       # of k beta e^gamma
-    d_k_beta = _dot(d_gram, k, _NN, exact) + d_k_grow * grow
+    d_k_beta = _dot(d_gram, k, NN, exact) + d_k_grow * grow
     d_v_beta = d_rhs[:, :dv]
-    d_k = (_dot(d_gram, k_beta.astype(dt), _TN, exact)
-           + _dot(d_scores, q, _TN, exact)
+    d_k = (_dot(d_gram, k_beta.astype(dt), TN, exact)
+           + _dot(d_scores, q, TN, exact)
            + d_k_beta * beta + f32(d_k_out) * fade)
-    d_q = _dot(d_scores, k, _NN, exact) + f32(d_q_in) * grow
+    d_q = _dot(d_scores, k, NN, exact) + f32(d_q_in) * grow
     d_v = d_v_beta * beta
     d_beta = lanes(d_k_beta * k32) + lanes(d_v_beta * v32)
     # e^gamma scales k beta (in rhs) and q; e^(gamma_C - gamma) scales k

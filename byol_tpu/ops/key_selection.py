@@ -44,9 +44,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from byol_tpu.ops import common as ops_common
-from byol_tpu.ops.attention import _MASKED, _slab, causal_pairs
-from byol_tpu.ops.common import LANES
-from byol_tpu.ops.selected_attention import VMEM_LIMIT_BYTES
+from byol_tpu.ops.attention import _slab, causal_pairs
+from byol_tpu.ops.common import LANES, MASKED, VMEM_LIMIT_BYTES
 
 _SIGN = np.uint32(0x80000000)      # numpy: nothing touches a backend at import
 
@@ -438,7 +437,7 @@ def index_loss(scores, probabilities, selected, seq_len: int):
     over = lambda per_tile, reduce: _to_tiles(
         _over_rows(per_tile, bounds, reduce), bounds)
     p = jax.lax.stop_gradient(jnp.where(selected, probabilities, 0.0))
-    kept = jnp.where(selected, scores, _MASKED)
+    kept = jnp.where(selected, scores, MASKED)
     top = jax.lax.stop_gradient(over(jnp.max(kept, axis=-1), jnp.max))
     log_q = kept - top - jnp.log(over(
         jnp.sum(jnp.exp(kept - top), axis=-1), jnp.sum))

@@ -54,14 +54,13 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from byol_tpu.ops import common as ops_common
-from byol_tpu.ops.common import LANES
+from byol_tpu.ops.common import (LANES, MASKED, NN, NT, TN,
+                                 VMEM_LIMIT_BYTES, dot)
 from byol_tpu.parallel.mesh import DATA_AXIS
 
-NEG_INF = -1e30          # not -inf: exp() of a masked column stays 0, not NaN
-MAX_PADDED_SEQ = 512     # [S,S] float32 tiles and the row blocks fit VMEM
-# What a program may take of VMEM (v5e holds 128 MiB, the compiler's default
-# scope is 16): row blocks double-buffered plus the [S,S] float32 tiles.
-VMEM_LIMIT_BYTES = 48 * 2 ** 20
+# [S,S] float32 tiles and the double-buffered row blocks fit the VMEM a
+# program may ask for (``VMEM_LIMIT_BYTES``)
+MAX_PADDED_SEQ = 512
 _BLOCK_BYTES = 12 * 2 ** 20      # budget for the double-buffered row blocks
 ROW_ALIGN = 16                   # rows of a streamed tile: bf16 packs 16
 
@@ -119,16 +118,6 @@ def _pick(y, heads):
     return out
 
 
-def _dot(a, b, contract):
-    return jax.lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
-_NT = ((1,), (1,))      # a @ b^T
-_NN = ((1,), (0,))      # a @ b
-_TN = ((0,), (0,))      # a^T @ b
-
-
 def _tiles(ref, i, cols, rows_ok, rows):
     """Image ``i``'s first ``rows`` rows of a column block, zero past the
     sequence (those rows lie outside the array: uninitialised VMEM)."""
@@ -153,11 +142,11 @@ def _fwd_kernel(qkv_ref, o_ref, *, seq_len: int, width: int, head_dim: int,
             q = _tiles(qkv_ref, i, cq, rows_ok, qr)
             k = _tiles(qkv_ref, i, ck, rows_ok, sp)
             v = _tiles(qkv_ref, i, cv, rows_ok, sp)
-            s = _dot(_stack(q, heads), k, _NT) * scale
-            s = jnp.where(key_ok, s, NEG_INF)
+            s = dot(_stack(q, heads), k, NT) * scale
+            s = jnp.where(key_ok, s, MASKED)
             e = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
             inv = 1.0 / jnp.sum(e, axis=1, keepdims=True)
-            out = _pick(_dot(e.astype(v.dtype), v, _NN) * inv, heads)
+            out = _pick(dot(e.astype(v.dtype), v, NN) * inv, heads)
             o_ref[i, :qr, cq] = out.astype(o_ref.dtype)
         return carry
 
@@ -183,17 +172,17 @@ def _bwd_kernel(qkv_ref, do_ref, dqkv_ref, *, seq_len: int, width: int,
             do = _tiles(do_ref, i, cq, rows_ok, sp)
             k_h = _stack(_tiles(qkv_ref, i, ck, rows_ok, kr), heads)
             v_h = _stack(_tiles(qkv_ref, i, cv, rows_ok, kr), heads)
-            s = (_dot(k_h, q, _NT) * scale).reshape(tile)
-            s = jnp.where(key_ok, s, NEG_INF)
+            s = (dot(k_h, q, NT) * scale).reshape(tile)
+            s = jnp.where(key_ok, s, MASKED)
             e = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
             p = e * (1.0 / jnp.sum(e, axis=1, keepdims=True))
-            dp = _dot(v_h, do, _NT).reshape(tile)
+            dp = dot(v_h, do, NT).reshape(tile)
             delta = jnp.sum(p * dp, axis=1, keepdims=True)
             ds = (p * (dp - delta) * scale).astype(q.dtype).reshape(-1, sp)
             p = p.astype(do.dtype).reshape(-1, sp)
-            dq = _dot(ds, k_h, _TN)            # sums over the heads too
-            dk = _pick(_dot(ds, q, _NN), heads)
-            dv = _pick(_dot(p, do, _NN), heads)
+            dq = dot(ds, k_h, TN)            # sums over the heads too
+            dk = _pick(dot(ds, q, NN), heads)
+            dv = _pick(dot(p, do, NN), heads)
             dqkv_ref[i, :, cq] = dq.astype(dqkv_ref.dtype)
             dqkv_ref[i, :kr, ck] = dk.astype(dqkv_ref.dtype)
             dqkv_ref[i, :kr, cv] = dv.astype(dqkv_ref.dtype)

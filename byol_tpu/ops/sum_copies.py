@@ -55,16 +55,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from byol_tpu.ops import common as ops_common
-from byol_tpu.ops.common import LANES
+from byol_tpu.ops.common import LANES, VMEM_LIMIT_BYTES
 
 BLOCK = 128         # tokens an output block
 WINDOW = 256        # rows a fetched window
 # Tiles read on the chip (PR 37, chains at the three cells' shapes, ms a
 # call): 128 x 256 0.48 / 0.62 / 0.36, 256 x 256 0.53 / 0.70 / 0.39, 128 x 128
 # 0.52 / 0.70 / 0.38, 512 x 512 0.68 / 0.85 / 0.55.
-# What a program may take of VMEM (a v5e holds 128 MiB, the compiler's
-# default scope is 16): at rows of 3,584 bf16 the count is 9 MiB.
-VMEM_LIMIT_BYTES = 48 * 2 ** 20
+# At rows of 3,584 bf16 :func:`_vmem_bytes` counts 9 MiB of the 48 a program
+# may ask for (``VMEM_LIMIT_BYTES``).
 
 _FIRST, _LAST, _REAL = 1, 2, 4          # an item's flags
 

@@ -29,11 +29,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from byol_tpu.ops import common as ops_common
+from byol_tpu.ops.common import MASKED
 from byol_tpu.parallel.mesh import (DATA_AXIS, SEQUENCE_AXIS,
                                     ambient_mesh)
-
-NEG_INF = -1e30
-
 
 def ring_attention_local(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          axis_name: str = SEQUENCE_AXIS) -> jnp.ndarray:
@@ -64,7 +62,7 @@ def ring_attention_local(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return m_next, l_next, acc * alpha + pv, k_nxt, v_nxt
 
     b, h, s_loc, d = q.shape
-    m0 = jnp.full((b, h, s_loc, 1), NEG_INF, jnp.float32)
+    m0 = jnp.full((b, h, s_loc, 1), MASKED, jnp.float32)
     l0 = jnp.zeros((b, h, s_loc, 1), jnp.float32)
     acc0 = jnp.zeros((b, h, s_loc, d), jnp.float32)
     _, l, acc, _, _ = jax.lax.fori_loop(

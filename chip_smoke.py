@@ -27,11 +27,10 @@ What runs (one chip), through the entry points a user calls:
    cache, so its warm-up time next to the first one's is cold vs warm.
 3. ``--phase probe`` (this file, in a child): step time ending in
    ``block_until_ready`` and ending in a scalar readback, peak device
-   memory, served-vs-offline embedding difference, and the kernel phases —
-   ``--fused-augment on`` (raw uint8 256->224), ViT-B/16 ``--attn-impl
-   flash`` through the serve builder — each next to its un-fused arm on
-   the same seed and batch, each asserting ``tpu_custom_call`` in the
-   compiled program.  No ``interpret=``
+   memory, served-vs-offline embedding difference, and the kernel phase —
+   ``--fused-augment on`` (raw uint8 256->224) next to its un-fused arm on
+   the same seed and batch, asserting ``tpu_custom_call`` in the compiled
+   program.  No ``interpret=``
    is passed anywhere: on a TPU backend the kernels compile for the chip.
 
 PROCESS RULE.  A chip belongs to one process at a time.  The parent (this
@@ -48,9 +47,8 @@ one-vs-four-device losses must agree per step within
 ``LOSS_RTOL``/``LOSS_ATOL`` below —
 tests/test_fused_augment.py (2e-4), tests/test_zero1.py and
 tests/test_train_step.py (1e-5 .. 1e-4) widened to bf16's 2^-8 relative
-rounding; flash-vs-dense and served-vs-offline embeddings within
-``EMBED_RTOL`` of the largest embedding magnitude (tests/test_attention.py
-uses 2e-2 in bf16).
+rounding; served-vs-offline embeddings within ``EMBED_RTOL`` of the largest
+embedding magnitude (tests/test_attention.py uses 2e-2 in bf16).
 """
 from __future__ import annotations
 
@@ -78,12 +76,10 @@ EMBED_RTOL = 2e-2
 # stand-in the CPU rehearsal uses to walk the same control flow.
 FULL = dict(arch="resnet50", image=224, raw=256, batch=256, head=4096,
             proj=256, samples=512, epochs=3, smoke=48, streams=4,
-            min_bucket=8, max_batch=64, vit="vit_b16", vit_batch=64,
-            steps=3, timing_steps=5)
+            min_bucket=8, max_batch=64, steps=3, timing_steps=5)
 TINY = dict(arch="resnet18", image=32, raw=36, batch=16, head=64, proj=32,
             samples=64, epochs=2, smoke=8, streams=2, min_bucket=8,
-            max_batch=16, vit="vit_s16", vit_batch=8, steps=3,
-            timing_steps=1)
+            max_batch=16, steps=3, timing_steps=1)
 
 RESULT_TAG = "CHIP_SMOKE_RESULT "        # child -> parent, one JSON object
 
@@ -494,9 +490,8 @@ def child_probe(s: dict, seed: int, rehearsal: bool, checkpoint: str) -> int:
         ("fused-augment", lambda: _kernel_arm(
             s, seed, "fused-augment", step_aug + ["--fused-augment", "on"],
             step_aug, raw=True)),
-        # serving: served vs offline, and the flash kernel
-        ("serve-parity", lambda: _serve_parity(s, seed, checkpoint)),
-        ("flash-attention", lambda: _flash_phase(s, seed)))
+        # serving: served vs offline
+        ("serve-parity", lambda: _serve_parity(s, seed, checkpoint)))
     for name, phase in phases:
         try:        # a phase that dies must not hide the ones after it
             ok = phase()
@@ -597,45 +592,6 @@ def _serve_parity(s: dict, seed: int, checkpoint: str) -> bool:
         f"at {n}; tolerance {tol:.3g}); embeddings finite: {finite}")
     svc.batcher.close()
     return finite and max(same_shape, padded) <= tol
-
-
-def _flash_phase(s: dict, seed: int) -> bool:
-    import numpy as np
-    vit = dict(s, arch=s["vit"], batch=s["vit_batch"])
-    bucket = ["--min-bucket", str(s["vit_batch"]),
-              "--max-batch", str(s["vit_batch"])]
-    rng = np.random.RandomState(seed)
-    images = rng.rand(s["vit_batch"], s["image"], s["image"],
-                      3).astype(np.float32)
-    out, text, secs = {}, {}, {}
-    for impl in ("dense", "flash"):
-        args = _serve_args(vit, seed, bucket + ["--attn-impl", impl])
-        try:
-            svc = _service(args, "")       # random init from --seed
-        except Exception as e:
-            say(f"flash-attention: {impl} refused — {type(e).__name__}: "
-                f"{str(e)[:600]}")
-            return False
-        eng = svc.engine
-        out[impl] = eng.embed(images)
-        text[impl] = eng._executables[s["vit_batch"]].as_text()
-        secs[impl] = sum(eng.compile_seconds.values())
-        svc.batcher.close()
-        _release(eng)
-    diff = float(np.max(np.abs(out["flash"] - out["dense"])))
-    tol = EMBED_RTOL * float(np.max(np.abs(out["dense"])))
-    has_kernel = "tpu_custom_call" in text["flash"]
-    # on a TPU ``dense`` at this length is ops/packed_attention.py's kernel
-    packed = "packed_attention_fwd" in text["dense"]
-    ok = bool(np.isfinite(out["flash"]).all()) and diff <= tol
-    say(f"flash-attention: {s['vit']} forward through the serve builder, "
-        f"batch {s['vit_batch']}; compiled dense {secs['dense']:.1f}s "
-        f"({'the packed-qkv kernel' if packed else 'einsums'}) / "
-        f"flash {secs['flash']:.1f}s; tpu_custom_call "
-        f"{'present' if has_kernel else 'ABSENT'}; max |flash - dense| "
-        f"{diff:.3g} (tolerance {tol:.3g} = {EMBED_RTOL} of the largest "
-        f"|embedding|): {'ok' if ok else 'MISMATCH'}")
-    return ok and has_kernel
 
 
 def _collectives(text: str) -> dict:

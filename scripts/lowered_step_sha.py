@@ -11,10 +11,11 @@ runs, no chip — through the compile plan's ``jit_train_step``, as
 ``setup_training`` wires it and ``benchmarks/rehearse_v5e.py`` lowers it
 (PERF.md section 6 quotes these hashes since PR 26; PR 28's hash of the
 token cell, ``74f1c09e…``, was of ``rehearse_v5e_tokens.py``'s bare
-``jax.jit`` instead).  A ViT is lowered with ``jax.default_backend``
-patched to ``"tpu"``, so that its attention is the kernel path the chip
-runs, as ``test_vitb16_train_step_keeps_attention_on_chip`` does
-(tests/test_tpu_compile.py).
+``jax.jit`` instead).  Every cell is lowered with ``jax.default_backend``
+patched to ``"tpu"``, so that what the ``applies`` functions of ops/ choose
+is the kernel path the chip runs, as the whole-step cases of
+tests/test_tpu_compile.py do: the text of a cell in ``KERNEL_CELLS`` must
+hold ``tpu_custom_call``s, or the script stops.
 
 Two hashes a cell.  ``text`` is of the text as it is.  A Pallas kernel's
 serialized MLIR carries its debug locations — the checkout's PATH and the
@@ -36,6 +37,9 @@ import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 KERNEL_BODY = r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22'
+# cells whose trunk runs a tiled causal kernel (ops/causal_attention.py)
+KERNEL_CELLS = ("qwen3next_train_b4_s4096", "keye_train_b4_s4096",
+                "lfm2_train_b4_s4096", "joyai_train_b4_s4096")
 
 
 def _sha(text: str) -> str:
@@ -126,14 +130,11 @@ def main() -> None:
                                         topology_name="v5e:2x2")
     with open("BENCHMARK.json") as f:
         cells = args.cells or [w["name"] for w in json.load(f)["workloads"]]
-    real_backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"     # what ops/*.applies ask
     for name in cells:
-        if name.startswith("vit"):
-            jax.default_backend = lambda: "tpu"
-        try:
-            text = lowered_text(name, topo)
-        finally:
-            jax.default_backend = real_backend
+        text = lowered_text(name, topo)
+        if name in KERNEL_CELLS and "tpu_custom_call" not in text:
+            raise SystemExit(f"{name}: no kernel in the lowered step")
         if args.dump:
             with open(os.path.join(args.dump, name + ".txt"), "w") as f:
                 f.write(text)

@@ -1,6 +1,6 @@
 """GL109 near-miss: pallas_call WITH the interpret= fallback plumbed.
 
-The in-tree pattern (ops/flash_attention.py, ops/fused_update.py): the
+The in-tree pattern (ops/packed_attention.py, ops/fused_update.py): the
 caller-facing wrapper resolves ``interpret`` from config/backend detection
 and passes it through, so CPU environments run the identical kernel under
 the Pallas interpreter.

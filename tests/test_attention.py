@@ -1,7 +1,6 @@
-"""Attention backends: dense oracle vs Pallas flash vs ring (sequence-
-parallel).  All three share one signature (ops/attention.py) — these tests
-pin their numerical equivalence, which is what lets the ViT swap impls by
-config name."""
+"""Attention backends: dense oracle vs ring (sequence-parallel).  Both
+share one signature (ops/attention.py) — these tests pin their numerical
+equivalence, which is what lets the ViT swap impls by config name."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,47 +30,6 @@ def test_dense_matches_float64_reference():
     q, k, v = _qkv(jax.random.PRNGKey(0))
     np.testing.assert_allclose(dense_attention(q, k, v),
                                _reference(q, k, v), rtol=1e-5, atol=1e-5)
-
-
-def test_flash_matches_dense_aligned():
-    from byol_tpu.ops.flash_attention import flash_attention
-    q, k, v = _qkv(jax.random.PRNGKey(1), s=128, d=16)
-    out = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
-    np.testing.assert_allclose(out, dense_attention(q, k, v),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_flash_masks_padded_keys():
-    """S=197 (the ViT-B/224 token count) is not block-aligned: padded key
-    positions must not leak probability mass."""
-    from byol_tpu.ops.flash_attention import flash_attention
-    q, k, v = _qkv(jax.random.PRNGKey(2), b=1, h=2, s=197, d=16)
-    out = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
-    assert out.shape == q.shape
-    np.testing.assert_allclose(out, dense_attention(q, k, v),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_flash_bf16():
-    from byol_tpu.ops.flash_attention import flash_attention
-    q, k, v = _qkv(jax.random.PRNGKey(3), s=64, d=16, dtype=jnp.bfloat16)
-    out = flash_attention(q, k, v, block_q=32, block_k=32, interpret=True)
-    ref = dense_attention(q, k, v)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=2e-2, atol=2e-2)
-
-
-def test_flash_long_sequence_streams_kv():
-    """S=4096 with 128-blocks: 32 K tiles walked on the grid.  At the old
-    whole-K-resident layout this shape held the full padded K/V per program;
-    the grid-streamed kernel must still match the dense oracle exactly
-    (round-2 verdict: VMEM residency capped usable sequence length)."""
-    from byol_tpu.ops.flash_attention import flash_attention
-    q, k, v = _qkv(jax.random.PRNGKey(8), b=1, h=1, s=4096, d=8)
-    out = flash_attention(q, k, v, block_q=128, block_k=128, interpret=True)
-    np.testing.assert_allclose(out, dense_attention(q, k, v),
-                               rtol=1e-5, atol=1e-5)
 
 
 def test_ring_matches_dense_shard_map(mesh_dp_sp):
@@ -106,26 +64,11 @@ def test_ring_requires_sequence_axis():
 
 def test_get_attention_fn_registry():
     assert get_attention_fn("dense") is dense_attention
-    from byol_tpu.ops.flash_attention import flash_attention
-    assert get_attention_fn("flash") is flash_attention
     from byol_tpu.parallel.ring_attention import ring_attention
     assert get_attention_fn("ring") is ring_attention
-    with pytest.raises(ValueError, match="unknown"):
-        get_attention_fn("bogus")
-
-
-def test_vit_with_flash_matches_dense():
-    """ViT forward with attn_impl='flash' equals attn_impl='dense' on the
-    same params — the swap is purely an implementation choice."""
-    from byol_tpu.models.vit import ViT
-    x = jax.random.uniform(jax.random.PRNGKey(7), (2, 32, 32, 3))
-    dense_vit = ViT(width=32, depth=1, num_heads=4, patch_size=8)
-    flash_vit = ViT(width=32, depth=1, num_heads=4, patch_size=8,
-                    attn_impl="flash")
-    variables = dense_vit.init(jax.random.PRNGKey(0), x)
-    np.testing.assert_allclose(flash_vit.apply(variables, x),
-                               dense_vit.apply(variables, x),
-                               rtol=1e-4, atol=1e-5)
+    for gone in ("flash", "bogus"):     # the forward-only kernel: ISSUE 44
+        with pytest.raises(ValueError, match="unknown"):
+            get_attention_fn(gone)
 
 
 # ---------------------------------------------------------------------------

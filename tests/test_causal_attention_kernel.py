@@ -1,4 +1,5 @@
-"""The kernels of the causal grouped-query core (ops/causal_attention.py)
+"""The kernels of the causal grouped-query core (ops/causal_attention.py
+without a selection; with one: tests/test_selected_attention_kernel.py)
 against the ``jax.numpy`` body of ``ops/attention.blockwise_causal_attention``,
 on the CPU under the Pallas interpreter: ``blockwise_causal_attention`` chooses
 the kernels from the backend and the shapes, so the tests answer
@@ -78,7 +79,7 @@ def test_the_kernels_are_the_jnp_body(monkeypatch, dim, group, blocks, dtype):
             np.linalg.norm(f32(w)), name
     grouped = q.reshape(BATCH, KV_HEADS, group, seq, dim)
     scale = dim ** -0.5
-    lse = kernels._call(True, scale, BLOCK, True, grouped, k, v)[1]
+    lse = kernels._call(True, scale, BLOCK, True, grouped, k, v, None, ())[1]
     assert lse.shape == (BATCH, KV_HEADS, group, seq)
     assert lse.dtype == jnp.float32          # a row a head, whatever q is
     want_lse = attention._blockwise_causal_fwd(grouped, k, v, scale,
@@ -87,25 +88,32 @@ def test_the_kernels_are_the_jnp_body(monkeypatch, dim, group, blocks, dtype):
 
 
 @pytest.mark.parametrize(
-    "block,dim,seq,heads,kv_heads,dtype,backend,taken", [
-        (512, 64, 4096, 32, 8, "bfloat16", "tpu", True),    # lfm2's
-        (512, 256, 4096, 16, 2, "bfloat16", "tpu", True),   # qwen3next's
-        (512, 128, 4096, 32, 4, "bfloat16", "tpu", True),
-        (128, 64, 256, 4, 4, "float32", "tpu", True),
-        (512, 64, 4096, 32, 8, "bfloat16", "cpu", False),   # not for a TPU
-        (512, 256, 4096, 16, 2, "bfloat16", "cpu", False),
-        (8, 8, 24, 4, 2, "float32", "tpu", False),          # the tiny presets
-        (512, 96, 4096, 32, 8, "bfloat16", "tpu", False),   # 3/4 lane tile
-        (512, 32, 4096, 32, 8, "bfloat16", "tpu", False),   # a quarter
-        (96, 128, 4032, 32, 4, "bfloat16", "tpu", False),   # block: 3/4 tile
-        (512, 64, 4000, 32, 8, "bfloat16", "tpu", False),   # a short block
-        (512, 64, 4096, 32, 5, "bfloat16", "tpu", False),   # heads unshared
-        (512, 256, 16384, 16, 2, "bfloat16", "tpu", False),  # d_k, d_v of a
-    ])                                  # key head's sequence outgrow VMEM
+    "block,dim,seq,heads,kv_heads,dtype,backend,selected,taken", [
+        (512, 64, 4096, 32, 8, "bfloat16", "tpu", False, True),   # lfm2's
+        (512, 256, 4096, 16, 2, "bfloat16", "tpu", False, True),  # qwen3next
+        (512, 128, 4096, 32, 4, "bfloat16", "tpu", False, True),
+        (128, 64, 256, 4, 4, "float32", "tpu", False, True),
+        (512, 64, 4096, 32, 8, "bfloat16", "cpu", False, False),  # no TPU
+        (512, 256, 4096, 16, 2, "bfloat16", "cpu", False, False),
+        (8, 8, 24, 4, 2, "float32", "tpu", False, False),   # the tiny presets
+        (512, 96, 4096, 32, 8, "bfloat16", "tpu", False, False),  # 3/4 tile
+        (512, 32, 4096, 32, 8, "bfloat16", "tpu", False, False),  # a quarter
+        (96, 128, 4032, 32, 4, "bfloat16", "tpu", False, False),  # block 3/4
+        (512, 64, 4000, 32, 8, "bfloat16", "tpu", False, False),  # short block
+        (512, 64, 4096, 32, 5, "bfloat16", "tpu", False, False),  # unshared
+        (512, 256, 16384, 16, 2, "bfloat16", "tpu", False, False),  # d_k, d_v
+        # of a key head's sequence outgrow VMEM.  With a selection (one rule
+        # of shapes for both uses; more: test_selected_attention_kernel.py):
+        (512, 128, 4096, 32, 4, "bfloat16", "tpu", True, True),   # keye's
+        (512, 64, 4096, 32, 4, "bfloat16", "tpu", True, True),    # compiles:
+        # test_tpu_compile.py::test_selected_attention_kernels_at_the_...
+        (512, 128, 4096, 32, 4, "bfloat16", "cpu", True, False),  # no TPU
+    ])
 def test_the_kernels_are_chosen_from_backend_and_shapes(
-        block, dim, seq, heads, kv_heads, dtype, backend, taken):
+        block, dim, seq, heads, kv_heads, dtype, backend, selected, taken):
     assert kernels.applies(block, dim, seq, heads, kv_heads,
-                           jnp.dtype(dtype), backend=backend) is taken
+                           jnp.dtype(dtype), selected=selected,
+                           backend=backend) is taken
 
 
 def test_a_narrow_head_counts_a_whole_lane_tile_of_vmem():

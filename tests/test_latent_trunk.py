@@ -195,12 +195,10 @@ def test_the_kernels_learn_a_key_width_and_a_value_width(
         dim, vdim, shared, backend, taken):
     assert kernels.applies(512, dim, 4096, 32, 32, jnp.bfloat16, vdim=vdim,
                            shared=shared, backend=backend) is taken
-    # the count at one width is what it was: selected_attention's less the
-    # mask's block, twice
-    from byol_tpu.ops import selected_attention
+    # what a selection adds to the count is the mask's int8 block, twice
     for fwd in (True, False):
         assert kernels._vmem_bytes(512, 128, 4096, 8, 2, fwd) == \
-            selected_attention._vmem_bytes(512, 128, 4096, 8, 2, fwd) \
+            kernels._vmem_bytes(512, 128, 4096, 8, 2, fwd, selected=True) \
             - 2 * 512 * 512
     # all 32 heads at 4,096 keys, one head a program: well inside 48 MiB
     assert kernels._vmem_bytes(512, 128, 4096, 1, 2, False, vdim=128,

@@ -170,13 +170,6 @@ def _pallas_names(fn, *args):
     return walk(jax.make_jaxpr(fn)(*args).jaxpr, [])
 
 
-def _flash():
-    from byol_tpu.ops import flash_attention
-    q = jnp.ones((1, 2, 16, 8))
-    return _pallas_names(
-        lambda q: flash_attention(q, q, q, interpret=True), q)
-
-
 def _two_view():
     from byol_tpu.ops import fused_two_view
     images = jnp.zeros((2, RAW, RAW, 3), jnp.uint8)
@@ -192,10 +185,25 @@ def _packed_attention():
         packed_self_attention(x, 2, interpret=True))), qkv)
 
 
+def _tiled_causal_attention(selected):
+    """The one kernel pair of ops/causal_attention.py: named after whether
+    the call has a selection."""
+    from byol_tpu.ops import causal_attention
+    q, kv = jnp.ones((1, 1, 2, 16, 8)), jnp.ones((1, 1, 16, 8))
+    keep = jnp.ones((3, 1, 8, 8), bool) if selected else None
+    return _pallas_names(jax.grad(lambda q: jnp.sum(causal_attention.attend(
+        q, kv, kv, scale=1.0, block=8, selected=keep,
+        interpret=True)[0])), q)
+
+
 @pytest.mark.parametrize("entry,expected", [
-    (_flash, ["flash_attention"]),
     (_two_view, ["fused_two_view"]),
     (_packed_attention, ["packed_attention_fwd", "packed_attention_bwd"]),
-], ids=["flash_attention", "fused_two_view", "packed_self_attention"])
+    (lambda: _tiled_causal_attention(False),
+     ["causal_attention_fwd", "causal_attention_bwd"]),
+    (lambda: _tiled_causal_attention(True),
+     ["selected_attention_fwd", "selected_attention_bwd"]),
+], ids=["fused_two_view", "packed_self_attention", "causal_attention",
+        "selected_attention"])
 def test_each_pallas_call_carries_its_name(entry, expected):
     assert entry() == expected

@@ -5,7 +5,7 @@ environment runs them:
 
 1. **Kernels outside ``byol_tpu/ops/``.**  A ``pl.pallas_call`` inlined in
    a model or training module bypasses the in-tree kernel discipline
-   (ops/flash_attention.py, ops/packed_attention.py): the interpret fallback,
+   (ops/packed_attention.py, ops/causal_attention.py): the interpret fallback,
    the tiling/docstring conventions, and the one place reviewers audit for
    TPU lowering constraints.  The kernel still traces fine — the drift
    only shows up when someone greps ops/ for "every kernel we ship" and
@@ -60,7 +60,7 @@ class PallasInterpretRule(Rule):
             if in_pkg and not in_ops:
                 findings.append(self.finding(
                     f, node, "pl.pallas_call outside byol_tpu/ops/ — "
-                    "kernels live in ops/ (the flash_attention/fused_augment "
+                    "kernels live in ops/ (the packed_attention/fused_augment "
                     "pattern: interpret fallback, tiling conventions, one "
                     "auditable home for TPU lowering constraints)"))
             kwarg_names = {kw.arg for kw in node.keywords}
