@@ -38,8 +38,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "sklearn, works offline) | fake | synth "
                         "(procedural learnable dataset, works offline) | "
                         "synth_tokens (seeded id sequences, two views by "
-                        "independent 15%% token masking; needs a token "
-                        "--arch and --seq-len)")
+                        "independent 15%% token masking — for a "
+                        "block-diffusion --arch by two noisings [noised | "
+                        "clean], a rate a block; needs a token --arch and "
+                        "--seq-len)")
     t.add_argument("--batch-size", type=int, default=4096,
                    help="GLOBAL batch size")
     t.add_argument("--epochs", type=int, default=3000)
@@ -56,7 +58,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="dataset size for --task synth (test = 1/10th); "
                         "0 = default 20000")
     t.add_argument("--seq-len", type=int, default=0,
-                   help="positions per sample for --task synth_tokens")
+                   help="ids per sample for --task synth_tokens (a "
+                        "block-diffusion trunk reads twice as many "
+                        "positions: each sample noised and clean)")
     t.add_argument("--valid-fraction", type=float, default=0.0,
                    help="hold out this fraction of train as a validation "
                         "split (num_valid_samples contract, reference "
@@ -68,10 +72,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="a backbone of models/registry.py: resnet*, vit_*, "
                         "or a decoder trunk over token ids (--task "
                         "synth_tokens): xing4_29b_a4b, qwen3_next_80b_a3b, "
-                        "keye_vl2_30b_a3b, lfm2_24b_a2b, joyai_llm_flash and "
-                        "their test-size twins decoder_trunk_tiny, "
-                        "hybrid_trunk_tiny, sparse_trunk_tiny, "
-                        "shortconv_trunk_tiny, latent_trunk_tiny")
+                        "keye_vl2_30b_a3b, lfm2_24b_a2b, joyai_llm_flash, "
+                        "sdar_30b_a3b and their test-size twins "
+                        "decoder_trunk_tiny, hybrid_trunk_tiny, "
+                        "sparse_trunk_tiny, shortconv_trunk_tiny, "
+                        "latent_trunk_tiny, blockdiff_trunk_tiny")
     m.add_argument("--representation-size", type=int, default=None,
                    help="derived from the arch registry unless overridden")
     m.add_argument("--projection-size", type=int, default=256)

@@ -290,13 +290,22 @@ def _raw_pipeline(images: np.ndarray, labels: np.ndarray, *,
 
 
 def _token_pipeline(ids: np.ndarray, labels: np.ndarray, *, batch_size: int,
-                    mask_id: int, seed: int, shuffle: bool
+                    mask_id: int, seed: int, shuffle: bool,
+                    diffusion_block: int = 0
                     ) -> Callable[[int], Iterator[Batch]]:
     """epoch -> batches ``{'view1', 'view2': int32 (B, S), 'label'}``: two
-    views of the same sequences under independent 15% masking, reseeded
-    per epoch (drop-remainder, as every pipeline here).  Each batch is
-    made under the host span ``TOKEN_FEED_SPAN``."""
+    views of the same sequences under independent 15% masking — or, for a
+    block-diffusion trunk (``diffusion_block`` > 0), two independent
+    noisings ``[noised | clean]``, ``(B, 2 S)``, a rate a block
+    (``readers.noise_blocks``) — reseeded per epoch (drop-remainder, as
+    every pipeline here).  Each batch is made under the host span
+    ``TOKEN_FEED_SPAN``."""
     from byol_tpu.observability import spans as spans_lib
+    if diffusion_block:
+        view = lambda rows, rng: readers.noise_blocks(
+            rows, rng, mask_id, diffusion_block)
+    else:
+        view = lambda rows, rng: readers.mask_tokens(rows, rng, mask_id)
 
     def make(epoch: int) -> Iterator[Batch]:
         rng = np.random.RandomState((seed * 7919 + epoch) % (2 ** 31 - 1))
@@ -304,8 +313,8 @@ def _token_pipeline(ids: np.ndarray, labels: np.ndarray, *, batch_size: int,
         for i in range(0, len(order) - batch_size + 1, batch_size):
             with spans_lib.span(spans_lib.TOKEN_FEED_SPAN):
                 rows = order[i:i + batch_size]
-                batch = {"view1": readers.mask_tokens(ids[rows], rng, mask_id),
-                         "view2": readers.mask_tokens(ids[rows], rng, mask_id),
+                batch = {"view1": view(ids[rows], rng),
+                         "view2": view(ids[rows], rng),
                          "label": labels[rows].astype(np.int32)}
             yield batch
     return make
@@ -315,7 +324,7 @@ def _token_loader(cfg: Config, *, host_batch: int, num_samples: int,
                   index: int, count: int, shard_eval: bool) -> LoaderBundle:
     """``--task synth_tokens``: seeded id sequences for a backbone that
     takes tokens (models/registry.py ``input_kind``)."""
-    from byol_tpu.models.registry import held_vocab_rows
+    from byol_tpu.models.registry import get_spec, held_vocab_rows
     if cfg.task.seq_len < 1:
         raise ValueError("--task synth_tokens needs --seq-len")
     if cfg.task.augment_placement != "loader" or \
@@ -333,14 +342,17 @@ def _token_loader(cfg: Config, *, host_batch: int, num_samples: int,
     x_trs, y_trs = _shard_arrays(x_tr, y_tr, index, count)
     if shard_eval:
         x_te, y_te = _shard_arrays(x_te, y_te, index, count)
+    # a block-diffusion trunk reads [noised | clean]: twice --seq-len ids
+    diffusion_block = get_spec(cfg.model.arch).diffusion_block
     pipe = lambda x, y, shuffle: _token_pipeline(
         x, y, batch_size=host_batch, mask_id=vocab - 1, seed=seed,
-        shuffle=shuffle)
+        shuffle=shuffle, diffusion_block=diffusion_block)
     return LoaderBundle(
         make_train_iter=pipe(x_trs, y_trs, True),
         make_test_iter=pipe(x_te, y_te, False),
         make_train_eval_iter=pipe(x_trs, y_trs, False),
-        input_shape=(cfg.task.seq_len,), num_train_samples=n_train,
+        input_shape=(cfg.task.seq_len * (2 if diffusion_block else 1),),
+        num_train_samples=n_train,
         num_test_samples=n_test, output_size=n_classes,
         eval_sharded=shard_eval and count > 1)
 

@@ -211,6 +211,21 @@ def mask_tokens(ids: np.ndarray, rng: np.random.RandomState, mask_id: int,
                     ids).astype(np.int32)
 
 
+def noise_blocks(ids: np.ndarray, rng: np.random.RandomState, mask_id: int,
+                 block: int) -> np.ndarray:
+    """One block-diffusion view of ``ids (N, L)``: ``[noised | clean]``, ``(N,
+    2 L)`` — the ids once with every position of block ``p // block``
+    replaced by ``mask_id`` with probability ``t``, one ``t ~ U(0, 1)`` a
+    block and sample (arXiv 2503.09573, the linear schedule without its
+    clipping), and once as they are."""
+    n, length = ids.shape
+    blocks = -(-length // block)
+    draws = rng.rand(n, blocks + length)       # a rate a block | a position's
+    rate = np.repeat(draws[:, :blocks], block, axis=1)[:, :length]
+    noised = np.where(draws[:, blocks:] < rate, np.int32(mask_id), ids)
+    return np.concatenate([noised, ids], axis=1).astype(np.int32)
+
+
 def load_digits_img(data_dir: str = "", train: bool = True,
                     download: bool = False) -> Arrays:
     """Real handwritten-digit images (sklearn's bundled UCI digits), no

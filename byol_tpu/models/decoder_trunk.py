@@ -58,7 +58,8 @@ patterned one: ``gdn`` with ``proj``, ``conv``, ``core``, ``gate_norm``;
 ``moe`` scopes; ``SPARSE_SCOPES`` for a sparse-attention one: ``dsa`` with
 ``index``, ``select``, ``core``, ``index_loss``; ``SHORTCONV_SCOPES`` for one
 with short convolutions: ``shortconv`` with ``proj``, ``core``; ``gqa`` with
-``core``; ``ffn``): ``mla``, ``moe/route``,
+``core``; ``ffn``; ``BLOCKDIFF_SCOPES`` for a block-diffusion one:
+``blockdiff`` with ``core``): ``mla``, ``moe/route``,
 ``moe/experts`` (and in it ``combine``: the sum of a token's copies, forward
 and as the dispatch's backward), ``moe/shared``, ``mhc`` (and ``ffn`` for a
 leading dense layer) inside every layer; the train step stamps them beside
@@ -83,7 +84,8 @@ from byol_tpu.core import remat as remat_lib
 from byol_tpu.models.gated_delta import (GatedDeltaNet, GatedDeltaSizes,
                                           causal_conv)
 from byol_tpu.ops import key_selection, sum_copies
-from byol_tpu.ops.attention import (blockwise_causal_attention,
+from byol_tpu.ops.attention import (block_diffusion_tiles,
+                                    blockwise_causal_attention,
                                     dense_attention, kept_probabilities,
                                     selected_attention)
 
@@ -96,6 +98,7 @@ SPARSE_SCOPES = ("dsa", "dsa/index", "dsa/select", "dsa/core",
                  "dsa/index_loss") + _MOE_SCOPES
 SHORTCONV_SCOPES = ("shortconv", "shortconv/proj", "shortconv/core", "gqa",
                     "gqa/core") + _MOE_SCOPES + ("ffn",)
+BLOCKDIFF_SCOPES = ("blockdiff", "blockdiff/core") + _MOE_SCOPES
 # the expert layer's fallback (a step whose load passes twice the nominal
 # one) forms its rows whole under this size and in slabs from it on
 WHOLE_FALLBACK_BYTES = 1 << 29
@@ -179,6 +182,10 @@ class TrunkSizes:
     # BUILT, in order; () = one of the rules above
     layer_mixers: Tuple[str, ...] = ()
     conv_taps: int = 0               # of a 'shortconv' layer's convolution
+    # block diffusion (arXiv 2503.09573): ids a block; 0 = none.  Every
+    # layer's mixer is then ``gated_attention`` under the block-diffusion
+    # training mask ('blockdiff') and a row of ids is ``[noised | clean]``
+    diffusion_block: int = 0
     scoring_func: str = "sigmoid"    # 'sigmoid' (noaux_tc bias) | 'softmax'
     shared_expert_gate: bool = False     # sigmoid(x w_s) on the shared expert
     zero_centred_norm: bool = False      # gains are 1 + w, w from zeros
@@ -199,6 +206,8 @@ class TrunkSizes:
         """The token mixer of layer ``layer``: its scope's name."""
         if self.layer_mixers:
             return self.layer_mixers[layer]
+        if self.diffusion_block:
+            return "blockdiff"
         if self.sparse_attention is not None:
             return "dsa"
         if not self.full_attention_interval:
@@ -446,7 +455,14 @@ class GatedAttention(nn.Module):
     ``rotary_dim`` dims of a head rotate, ``kv_heads`` key/value heads serve
     ``heads`` query heads, and the core's output goes through ``o``.  With
     ``sizes.output_gate`` ``q`` comes with a gate of its own width per head
-    and ``out = softmax(.) v * sigmoid(gate)``."""
+    and ``out = softmax(.) v * sigmoid(gate)``.
+
+    ``diffusion_block`` > 0: the block-diffusion training forward.  A row is
+    ``[noised | clean]``, ``S = 2 L``; the n-th noised and the n-th clean
+    row both stand at position ``n`` (the rotary tables repeat), and the
+    core runs under ``block_diffusion_tiles`` instead of the causal rule:
+    a clean query sees the clean keys of its own and earlier blocks, a
+    noised one the clean keys of earlier blocks and its own noised block."""
 
     sizes: GatedAttentionSizes
     heads: int
@@ -454,6 +470,7 @@ class GatedAttention(nn.Module):
     eps: float = 1e-6
     dtype: jnp.dtype = jnp.float32
     zero_centred: bool = True
+    diffusion_block: int = 0
 
     @nn.compact
     def __call__(self, h):
@@ -474,13 +491,25 @@ class GatedAttention(nn.Module):
             b, s, self.kv_heads, dh)
         norm = lambda name: RMSNorm(self.eps, dt, self.zero_centred,
                                     name=name)
-        cos, sin = half_rotary_tables(z.rope_theta, z.rotary_dim, s)
+        tiles = None                                       # causal
+        if self.diffusion_block:
+            if s % (2 * z.block):
+                raise ValueError(
+                    f"a block-diffusion row is [noised | clean], each half "
+                    f"whole tiles of {z.block}; got {s} ids")
+            cos, sin = (jnp.tile(t, (2, 1)) for t in half_rotary_tables(
+                z.rope_theta, z.rotary_dim, s // 2))
+            tiles = block_diffusion_tiles(s // (2 * z.block),
+                                          self.diffusion_block)
+        else:
+            cos, sin = half_rotary_tables(z.rope_theta, z.rotary_dim, s)
         q = apply_half_rotary(norm("q_norm")(q), cos, sin)
         k = apply_half_rotary(norm("k_norm")(k), cos, sin)
         with jax.named_scope("core"):
             q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
             out = blockwise_causal_attention(
-                q, k, v, scale=dh ** -0.5, block=z.block, group=z.group)
+                q, k, v, scale=dh ** -0.5, block=z.block, group=z.group,
+                tiles=tiles)
         out = out.transpose(0, 2, 1, 3)
         if gate is not None:
             out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
@@ -931,18 +960,20 @@ class TrunkLayer(nn.Module):
                 return _write_streams(streams, h_res, h_post, y)
 
         def attention(x):
-            # ``gdn``, ``gqa``, ``dsa`` and ``shortconv`` are modules named
-            # after their scope, as ``moe`` is
+            # ``gdn``, ``gqa``, ``blockdiff``, ``dsa`` and ``shortconv`` are
+            # modules named after their scope, as ``moe`` is
             if self.mixer == "gdn":
                 d = z.gated_delta
                 return GatedDeltaNet(
                     d, heads(d.num_key_heads), heads(d.num_value_heads),
                     z.rms_norm_eps, dt, name="gdn")(x)
-            if self.mixer == "gqa":
+            if self.mixer in ("gqa", "blockdiff"):
                 a = z.gated_attention
                 return GatedAttention(
                     a, heads(a.num_heads), heads(a.num_kv_heads),
-                    z.rms_norm_eps, dt, z.zero_centred_norm, name="gqa")(x)
+                    z.rms_norm_eps, dt, z.zero_centred_norm,
+                    z.diffusion_block if self.mixer == "blockdiff" else 0,
+                    name=self.mixer)(x)
             if self.mixer == "shortconv":
                 return ShortConv(z.conv_taps, dt, name="shortconv")(x)
             if self.mixer == "dsa":
@@ -966,7 +997,9 @@ class TrunkLayer(nn.Module):
 
 
 class DecoderTrunk(nn.Module):
-    """Feature extractor: ``(B, S) int32 -> (B, hidden)``."""
+    """Feature extractor: ``(B, S) int32 -> (B, hidden)``, the mean over the
+    positions of the final-norm hidden states (a block-diffusion trunk: over
+    the noised half of its ``[noised | clean]`` rows)."""
 
     sizes: TrunkSizes
     share: LayerShare = LayerShare()
@@ -978,6 +1011,8 @@ class DecoderTrunk(nn.Module):
     def trace_scopes(self) -> Tuple[str, ...]:
         if "shortconv" in self.sizes.layer_mixers:
             return SHORTCONV_SCOPES
+        if self.sizes.diffusion_block:
+            return BLOCKDIFF_SCOPES
         if self.sizes.sparse_attention is not None:
             return SPARSE_SCOPES
         return HYBRID_SCOPES if self.sizes.full_attention_interval \
@@ -1018,6 +1053,8 @@ class DecoderTrunk(nn.Module):
             self.dtype)
         hidden = RMSNorm(z.rms_norm_eps, self.dtype, z.zero_centred_norm,
                          name="final_norm")(hidden)
+        if z.diffusion_block:       # the NOISED half: what the loss reads
+            hidden = hidden[:, :hidden.shape[1] // 2]
         return jnp.mean(hidden.astype(jnp.float32), axis=1).astype(self.dtype)
 
 
@@ -1161,6 +1198,39 @@ LFM2_24B_A2B = TrunkSizes(
         num_heads=32, num_kv_heads=8, head_dim=64, rotary_dim=64,
         rope_theta=1e6, output_gate=False, group=2),
     scoring_func="sigmoid", rms_norm_eps=1e-5)
+
+# SDAR-30B-A3B-Chat, from its public config.json (``model_type: sdar_moe``):
+# 48 layers, every one grouped-query attention (32 query on 4 key/value
+# heads of 128, per-head ``q``/``k`` RMS norms with plain gains, rotary theta
+# 1e6 over the whole head, no gate) and sparse (128 experts of width 768,
+# top-8, softmax scores, ``norm_topk_prob``, NO shared expert) — the
+# Qwen3-30B-A3B block — TRAINED BY BLOCK DIFFUSION (arXiv 2503.09573 section
+# 3, kept by arXiv 2510.06303): every sequence passes as ``[noised | clean]``
+# under the three-part mask of ``block_diffusion_tiles``.  The block length
+# is in no key of the config: 4, the release's generation default (an
+# assumption the benchmark's configuration file lists).  The LM head is not
+# built.
+SDAR_30B_A3B = TrunkSizes(
+    hidden_size=2048, num_hidden_layers=48, first_k_dense_replace=0,
+    intermediate_size=6144, n_routed_experts=128, moe_intermediate_size=768,
+    num_experts_per_tok=8, n_shared_experts=0, norm_topk_prob=True,
+    vocab_size=151936, diffusion_block=4,
+    gated_attention=GatedAttentionSizes(
+        num_heads=32, num_kv_heads=4, head_dim=128, rotary_dim=128,
+        rope_theta=1e6, output_gate=False),
+    scoring_func="softmax", rms_norm_eps=1e-6)
+
+# The block-diffusion trunk at test size (tests/test_blockdiff_trunk.py): at
+# 16 ids a sample a row is 32, two tiles of 8 a half, two blocks of 4 a tile.
+BLOCKDIFF_TINY = TrunkSizes(
+    hidden_size=32, num_hidden_layers=2, first_k_dense_replace=0,
+    intermediate_size=64, n_routed_experts=8, moe_intermediate_size=16,
+    num_experts_per_tok=3, n_shared_experts=0, norm_topk_prob=True,
+    vocab_size=128, diffusion_block=4,
+    gated_attention=GatedAttentionSizes(
+        num_heads=4, num_kv_heads=2, head_dim=16, rotary_dim=16,
+        rope_theta=1e6, block=8, output_gate=False),
+    scoring_func="softmax", rms_norm_eps=1e-6)
 
 # The short-convolution trunk at test size (tests/test_shortconv_trunk.py):
 # 7 published layers, 2 dense, attention at ``i % 4 == 2``; cut ``1+4`` it

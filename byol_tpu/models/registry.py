@@ -28,6 +28,9 @@ class BackboneSpec:
     # layer)`` (models/decoder_trunk.py)
     input_kind: str = "image"
     vocab_size: int = 0                  # published vocabulary ('tokens')
+    # 'tokens': > 0 = a sample is ``[noised | clean]``, 2 x seq_len ids, the
+    # noised half masked at one rate a block of this many ids
+    diffusion_block: int = 0
 
 
 _REGISTRY: Dict[str, BackboneSpec] = {}
@@ -112,6 +115,7 @@ def _register_decoder_trunks() -> None:
     # huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B, config.json
     # lfm2_24b_a2b: huggingface.co/LiquidAI/LFM2-24B-A2B, config.json
     # joyai_llm_flash: huggingface.co/jdopensource/JoyAI-LLM-Flash, config.json
+    # sdar_30b_a3b: huggingface.co/JetLM/SDAR-30B-A3B-Chat, config.json
     for name, sizes in (("xing4_29b_a4b", trunk_lib.XING4_29B_A4B),
                         ("decoder_trunk_tiny", trunk_lib.TINY),
                         ("qwen3_next_80b_a3b", trunk_lib.QWEN3_NEXT_80B_A3B),
@@ -121,7 +125,9 @@ def _register_decoder_trunks() -> None:
                         ("lfm2_24b_a2b", trunk_lib.LFM2_24B_A2B),
                         ("shortconv_trunk_tiny", trunk_lib.SHORTCONV_TINY),
                         ("joyai_llm_flash", trunk_lib.JOYAI_LLM_FLASH),
-                        ("latent_trunk_tiny", trunk_lib.LATENT_TINY)):
+                        ("latent_trunk_tiny", trunk_lib.LATENT_TINY),
+                        ("sdar_30b_a3b", trunk_lib.SDAR_30B_A3B),
+                        ("blockdiff_trunk_tiny", trunk_lib.BLOCKDIFF_TINY)):
         def factory(dtype=jnp.float32, small_inputs=False, _z=sizes,
                     layer_share="0/1", trunk_depth="", **kw):
             del small_inputs
@@ -134,7 +140,8 @@ def _register_decoder_trunks() -> None:
         register(name, BackboneSpec(
             factory=factory, feature_dim=sizes.hidden_size,
             has_batchnorm=False, input_kind="tokens",
-            vocab_size=sizes.vocab_size))
+            vocab_size=sizes.vocab_size,
+            diffusion_block=sizes.diffusion_block))
 
 
 _register_decoder_trunks()

@@ -16,10 +16,13 @@ so the model swaps between them by name without re-plumbing:
                 (:func:`packed_kernel_applies`): same arithmetic, one
                 kernel forward and one backward;
   ``blockwise`` (:func:`blockwise_causal_attention`, the decoder trunk's
-                grouped-query layers, gated or plain) — the same arithmetic,
-                causal, over blocks of keys with a running max and sum,
-                forward and backward, so that no ``[S, S]`` array exists at
-                any length.  Where the program lowers for a TPU and the
+                grouped-query layers, gated or plain) — the same arithmetic
+                under a visibility rule known at trace time (causal; the
+                block-diffusion training mask), as a list of tile pairs
+                with a kind each (:class:`TilePairs`), over blocks of keys
+                with a running max and sum, forward and backward, so that no
+                ``[S, S]`` array exists at any length and no tile without a
+                visible pair is formed.  Where the program lowers for a TPU and the
                 shapes allow (``causal_attention.applies``: heads of 64, 128
                 or 256; a value width of its own; a part of the key shared
                 by all heads — latent attention's 128 + 64 against 128), the
@@ -47,7 +50,8 @@ module exists because long-context support is first-class in the rebuild.
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional
+import itertools
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -81,48 +85,119 @@ def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
 
 
-def _block_bounds(seq_len: int, block: int):
-    return [(lo, min(lo + block, seq_len))
-            for lo in range(0, seq_len, block)]
+# ---- a visibility rule known at trace time: tile pairs with a kind each ----
+#
+# Both lowerings of the blockwise core walk ONE list: ``(query tile, key
+# tile, kind)``, tiles of ``block`` rows, only the tiles that hold a visible
+# pair in it, a query tile's pairs side by side.  A kind says which pairs of
+# a tile are visible, from the rows' OFFSETS inside their tiles counted in
+# blocks of ``span`` rows, ``beta(r) = r // span``:
+FULL = 0           # every pair
+NOT_AFTER = 1      # beta(key) <= beta(query); span 1: the causal diagonal
+BEFORE = 2         # beta(key) <  beta(query)
+SAME = 3           # beta(key) == beta(query)
+VISIBLE = {NOT_AFTER: lambda key, query: key <= query,
+           BEFORE: lambda key, query: key < query,
+           SAME: lambda key, query: key == query}
+# ... and the same three as bounds ``lo <= beta(query) - beta(key) <= hi``
+# (what a kernel that learns a pair's kind only when it runs compares with)
+FAR = 1 << 30
+BOUNDS = {NOT_AFTER: (0, FAR), BEFORE: (1, FAR), SAME: (0, 0)}
 
 
-def _block_scores(q_blk, k_blk, scale, q_lo, k_lo):
-    """``(B, Hkv, G, bq, bk)`` float32 scores of one block pair, keys after
-    the query masked where the pair touches the diagonal."""
+class TilePairs(NamedTuple):
+    """The list, hashable (it is static wherever it goes)."""
+
+    q_of: Tuple[int, ...]
+    k_of: Tuple[int, ...]
+    kind: Tuple[int, ...]
+    span: int = 1
+
+
+def causal_pairs(blocks: int):
+    """``(query block, key block)`` of every tile on or under the diagonal,
+    as two int32 arrays (pair ``(i, j)`` is tile ``i (i + 1) / 2 + j``)."""
+    q_of = np.repeat(np.arange(blocks), np.arange(1, blocks + 1))
+    k_of = np.concatenate([np.arange(i + 1) for i in range(blocks)])
+    return q_of.astype(np.int32), k_of.astype(np.int32)
+
+
+@functools.lru_cache(maxsize=None)
+def causal_tiles(blocks: int) -> TilePairs:
+    """Causal attention: :func:`causal_pairs`, the diagonal ``NOT_AFTER``."""
+    q_of, k_of = (x.tolist() for x in causal_pairs(blocks))
+    return TilePairs(tuple(q_of), tuple(k_of), tuple(
+        NOT_AFTER if j == i else FULL for i, j in zip(q_of, k_of)))
+
+
+@functools.lru_cache(maxsize=None)
+def block_diffusion_tiles(blocks: int, span: int) -> TilePairs:
+    """The block-diffusion training forward (arXiv 2503.09573, its
+    vectorized mask): a row is ``[noised | clean]``, each half ``blocks``
+    tiles, row ``n`` of either half at position ``n``, ``beta`` over blocks
+    of ``span`` positions (``span`` divides the tile and is shorter).  A
+    clean query sees the clean keys with ``beta(key) <= beta(query)``; a
+    noised query the clean keys with ``beta(key) < beta(query)`` and the
+    noised keys of its own block; nothing else.  ``blocks^2 + 2 blocks``
+    tiles of the ``(2 blocks)^2``; every row's last pair holds its own
+    block, so every row sees a key.  ``span`` 1 with the clean half alone
+    is :func:`causal_tiles`."""
+    n, rows = blocks, []
+    for i in range(n):                                   # noised queries
+        rows += [(i, n + j, FULL) for j in range(i)]
+        rows += [(i, n + i, BEFORE), (i, i, SAME)]
+    for i in range(n):                                   # clean queries
+        rows += [(n + i, n + j, FULL) for j in range(i)]
+        rows += [(n + i, n + i, NOT_AFTER)]
+    return TilePairs(*(tuple(column) for column in zip(*rows)), span=span)
+
+
+def _tile_rows(tiles: TilePairs):
+    """``[(query tile, [(key tile, kind), ..])]``, query tiles in order."""
+    rows = [(i, [(j, kind) for _, j, kind in group])
+            for i, group in itertools.groupby(
+                zip(tiles.q_of, tiles.k_of, tiles.kind), key=lambda t: t[0])]
+    if [i for i, _ in rows] != list(range(len(rows))):
+        raise ValueError("a query tile's pairs lie side by side, the query "
+                         "tiles in order")
+    return rows
+
+
+def _tile_scores(q_blk, k_blk, scale, kind, span):
+    """``(B, Hkv, G, bq, bk)`` float32 scores of one tile, what its kind
+    hides masked."""
     scores = jnp.einsum("bhgqd,bhkd->bhgqk", q_blk, k_blk,
                         preferred_element_type=jnp.float32) * scale
-    bq, bk = q_blk.shape[-2], k_blk.shape[-2]
-    if k_lo + bk - 1 > q_lo:                    # some key lies after a query
-        visible = (q_lo + jnp.arange(bq))[:, None] >= \
-            (k_lo + jnp.arange(bk))[None, :]
-        scores = jnp.where(visible, scores, MASKED)
+    if kind != FULL:
+        query = (jnp.arange(q_blk.shape[-2]) // span)[:, None]
+        key = (jnp.arange(k_blk.shape[-2]) // span)[None, :]
+        scores = jnp.where(VISIBLE[kind](key, query), scores, MASKED)
     return scores
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _blockwise_causal(q, k, v, scale, block):
-    return _blockwise_causal_fwd(q, k, v, scale, block)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _blockwise(q, k, v, scale, block, tiles):
+    return _blockwise_fwd(q, k, v, scale, block, tiles)[0]
 
 
-def _blockwise_causal_fwd(q, k, v, scale, block):
+def _blockwise_fwd(q, k, v, scale, block, tiles):
     """``q``: ``(B, Hkv, G, S, D)``; ``k, v``: ``(B, Hkv, S, D)``.  One
-    query block at a time over the key blocks it can see, with a running
-    max and sum; a block pair wholly above the diagonal is never formed."""
-    bounds = _block_bounds(q.shape[-2], block)
+    query tile at a time over the key tiles ``tiles`` lists for it, with a
+    running max and sum; no other tile is formed.  (A row that sees nothing
+    in a tile weighs its keys 1 each until a visible key's max wipes them:
+    ``exp(MASKED - max) = 0``.)"""
+    rows = lambda x, i: x[..., i * block:(i + 1) * block, :]
     outs, lses = [], []
-    for q_lo, q_hi in bounds:
-        q_blk = q[..., q_lo:q_hi, :]
+    for i, keys in _tile_rows(tiles):
+        q_blk = rows(q, i)
         top = total = acc = None
-        for k_lo, k_hi in bounds:
-            if k_lo >= q_hi:
-                break
-            scores = _block_scores(q_blk, k[..., k_lo:k_hi, :], scale,
-                                   q_lo, k_lo)
+        for j, kind in keys:
+            scores = _tile_scores(q_blk, rows(k, j), scale, kind, tiles.span)
             here = jnp.max(scores, axis=-1)
             new_top = here if top is None else jnp.maximum(top, here)
             weights = jnp.exp(scores - new_top[..., None])
             part = jnp.einsum("bhgqk,bhkd->bhgqd", weights.astype(v.dtype),
-                              v[..., k_lo:k_hi, :],
+                              rows(v, j),
                               preferred_element_type=jnp.float32)
             if top is None:
                 total, acc = jnp.sum(weights, axis=-1), part
@@ -137,32 +212,32 @@ def _blockwise_causal_fwd(q, k, v, scale, block):
     return out, (q, k, v, out, jnp.concatenate(lses, axis=-1))
 
 
-def _blockwise_causal_bwd(scale, block, residuals, d_out):
-    """The same block pairs again: scores recomputed from ``q, k`` and the
-    saved log-sum-exp, five products a pair."""
+def _blockwise_bwd(scale, block, tiles, residuals, d_out):
+    """The same tiles again: scores recomputed from ``q, k`` and the saved
+    log-sum-exp, five products a tile."""
     q, k, v, out, lse = residuals
-    bounds = _block_bounds(q.shape[-2], block)
+    rows = lambda x, i: x[..., i * block:(i + 1) * block, :]
     # sum_k w (dw) of the softmax's backward is rowsum(dO . O)
     delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
-    d_k, d_v, d_q = [None] * len(bounds), [None] * len(bounds), []
+    blocks = -(-k.shape[-2] // block)
+    d_k, d_v, d_q = [None] * blocks, [None] * blocks, []
     add = lambda old, new: new if old is None else old + new
-    for q_lo, q_hi in bounds:
-        q_blk, do_blk = q[..., q_lo:q_hi, :], d_out[..., q_lo:q_hi, :]
+    for i, keys in _tile_rows(tiles):
+        q_blk, do_blk = rows(q, i), rows(d_out, i)
+        stat = lambda x: x[..., i * block:(i + 1) * block, None]
         dq_blk = None
-        for j, (k_lo, k_hi) in enumerate(bounds):
-            if k_lo >= q_hi:
-                break
-            k_blk, v_blk = k[..., k_lo:k_hi, :], v[..., k_lo:k_hi, :]
+        for j, kind in keys:
+            k_blk, v_blk = rows(k, j), rows(v, j)
             weights = jnp.exp(
-                _block_scores(q_blk, k_blk, scale, q_lo, k_lo)
-                - lse[..., q_lo:q_hi, None])
+                _tile_scores(q_blk, k_blk, scale, kind, tiles.span)
+                - stat(lse))
             d_v[j] = add(d_v[j], jnp.einsum(
                 "bhgqk,bhgqd->bhkd", weights.astype(v.dtype), do_blk,
                 preferred_element_type=jnp.float32))
             d_weights = jnp.einsum("bhgqd,bhkd->bhgqk", do_blk, v_blk,
                                    preferred_element_type=jnp.float32)
-            d_scores = (weights * (d_weights - delta[..., q_lo:q_hi, None])
+            d_scores = (weights * (d_weights - stat(delta))
                         * scale).astype(q.dtype)
             dq_blk = add(dq_blk, jnp.einsum(
                 "bhgqk,bhkd->bhgqd", d_scores, k_blk,
@@ -177,7 +252,7 @@ def _blockwise_causal_bwd(scale, block, residuals, d_out):
             together(d_v, v))
 
 
-_blockwise_causal.defvjp(_blockwise_causal_fwd, _blockwise_causal_bwd)
+_blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)
 
 
 def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
@@ -185,22 +260,28 @@ def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
                                scale: Optional[float] = None,
                                block: int = 512,
                                group: int = 0,
-                               shared=None) -> jnp.ndarray:
-    """Causal softmax attention whose memory is linear in S, forward and
-    backward: ``(B, Hq, S, D)`` queries on ``(B, Hkv, S, D)`` keys and
-    ``(B, Hkv, S, Dv)`` values (``Dv`` need not be ``D``: latent attention's
-    values are narrower than its keys), each key/value head shared by ``Hq /
-    Hkv`` consecutive query heads and never repeated in memory; returns
-    ``(B, Hq, S, Dv)``.  ``shared = (q_s (B, Hq, S, r), k_s (B, S, r))``
+                               shared=None,
+                               tiles: Optional[TilePairs] = None
+                               ) -> jnp.ndarray:
+    """Softmax attention under a visibility rule known at trace time —
+    causal unless ``tiles`` says otherwise — whose memory is linear in S,
+    forward and backward: ``(B, Hq, S, D)`` queries on ``(B, Hkv, S, D)``
+    keys and ``(B, Hkv, S, Dv)`` values (``Dv`` need not be ``D``: latent
+    attention's values are narrower than its keys), each key/value head
+    shared by ``Hq / Hkv`` consecutive query heads and never repeated in
+    memory; returns ``(B, Hq, S, Dv)``.  ``tiles``: the rule as a
+    :class:`TilePairs` over tiles of ``block`` rows (None:
+    :func:`causal_tiles`; :func:`block_diffusion_tiles`); only the tiles it
+    lists are formed.  ``shared = (q_s (B, Hq, S, r), k_s (B, S, r))``
     adds ``q_s . k_s`` to every score: a part of the head whose KEY is one
     vector for all heads (latent attention's rotary key), handed over once
     and never copied a head; ``scale`` defaults to ``(D + r)^-1/2``.
     Blockwise over the keys with a running max and sum; nothing larger than
-    one ``(B, Hq, block, block)`` tile of scores is ever held, block pairs
-    above the diagonal are skipped, and the backward recomputes the tiles
-    from ``q, k`` and the saved log-sum-exp (``jax.custom_vjp``).
+    one ``(B, Hq, block, block)`` tile of scores is ever held, and the
+    backward recomputes the tiles from ``q, k`` and the saved log-sum-exp
+    (``jax.custom_vjp``).
     Statistics in float32, products in the input dtype.  Two lowerings of
-    one arithmetic, chosen from what the code can see
+    one arithmetic over one list, chosen from what the code can see
     (``ops/causal_attention.applies``): where the program lowers for a TPU,
     ``block`` is a multiple of 128, each of ``D``, ``Dv`` and ``r`` is 64 or
     a multiple of 128 and the working set fits VMEM, the Pallas kernels
@@ -208,7 +289,7 @@ def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
     ops/causal_attention.py over the whole batch — a tile's scores, weights
     and their cotangents live and die in VMEM, the shared part a second
     product a tile; everywhere else (the CPU, the tiny presets, odd shapes
-    such as one 192-wide key) plain ``jax.numpy``, the block pairs unrolled
+    such as one 192-wide key) plain ``jax.numpy``, the tile pairs unrolled
     in Python, every ``(B, Hkv, G, block, block)`` float32 tile through HBM
     and the shared part joined to every head's ``q`` and ``k`` first —
     which is also the tests' oracle for the kernels.  ``group`` > 0 is the
@@ -220,13 +301,20 @@ def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
     hkv, dv = k.shape[1], v.shape[-1]
     if hq % hkv:
         raise ValueError(f"{hq} query heads do not share {hkv} key heads")
+    if tiles is None:
+        tiles = causal_tiles(-(-s // block))
+    elif (max(tiles.q_of + tiles.k_of) + 1) * block != s \
+            or block % tiles.span:
+        raise ValueError(f"{s} rows are not the tiles of {block} the rule "
+                         f"lists, or its span {tiles.span} does not divide "
+                         "a tile")
     r = shared[0].shape[-1] if shared is not None else 0
     if scale is None:
         scale = (d + r) ** -0.5
     group_heads = lambda x: x.reshape((b, hkv, hq // hkv) + x.shape[2:])
     if kernels.applies(block, d, s, hq, hkv, q.dtype, vdim=dv, shared=r):
         out, _ = kernels.attend(
-            group_heads(q), k, v, scale=scale, block=block,
+            group_heads(q), k, v, scale=scale, block=block, tiles=tiles,
             shared=None if shared is None else (group_heads(shared[0]),
                                                 shared[1]))
         return out.reshape(b, hq, s, dv)
@@ -235,7 +323,7 @@ def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
         k = jnp.concatenate([k, jnp.broadcast_to(
             shared[1][:, None], (b, hkv, s, r))], axis=-1)
     grouped = group_heads(q)
-    body = lambda *qkv: _blockwise_causal(*qkv, float(scale), int(block))
+    body = lambda *qkv: _blockwise(*qkv, float(scale), int(block), tiles)
     if group and b > group and b % group == 0:
         split = lambda x: x.reshape((b // group, group) + x.shape[1:])
         out = jax.lax.map(lambda qkv: body(*qkv),
@@ -255,13 +343,6 @@ def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
 # pair ``(i, j)`` is tile ``i (i + 1) / 2 + j``).  No head axis.  Every loop
 # over pairs is a ``lax`` loop whose body is traced ONCE: the program's size
 # does not grow with ``S``.
-
-def causal_pairs(blocks: int):
-    """``(query block, key block)`` of every tile, as two int32 arrays."""
-    q_of = np.repeat(np.arange(blocks), np.arange(1, blocks + 1))
-    k_of = np.concatenate([np.arange(i + 1) for i in range(blocks)])
-    return q_of.astype(np.int32), k_of.astype(np.int32)
-
 
 def _slab(x, i, block: int, axis: int = -2):
     """Block ``i`` (traced) of ``x`` along ``axis``."""

@@ -98,7 +98,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from byol_tpu.ops.attention import causal_pairs
+from byol_tpu.ops.attention import (BOUNDS, FULL, NOT_AFTER, VISIBLE,
+                                    TilePairs, causal_tiles)
 from byol_tpu.ops.common import (LANES, MASKED, NN, NT, TN, VMEM_LIMIT_BYTES,
                                  dot, resolve_interpret)
 
@@ -174,28 +175,84 @@ def applies(block: int, dim: int, seq_len: int, heads: int, kv_heads: int,
 
 # ---- the kernels -----------------------------------------------------------
 
-def _with_the_pairs_bias(i, j, keep_ref, bias_ref, tile):
+FIRST, LAST = 4, 8      # beside a pair's kind in the third prefetched array
+
+
+def _flags(tiles: TilePairs):
+    """What a step of the grid is — its pair's kind, whether it is its query
+    tile's first pair, its last — as one int a pair, or None where the list
+    is the lower triangle (:func:`causal_tiles`): there the pair's own ``(i,
+    j)`` say all three (``j == 0``, ``j == i``, ``NOT_AFTER`` at ``j ==
+    i``), and the kernels read them from it."""
+    q_of = tiles.q_of
+    if tiles == causal_tiles(max(q_of) + 1):
+        return None
+    first = [n == 0 or q_of[n - 1] != i for n, i in enumerate(q_of)]
+    last = first[1:] + [True]
+    return tuple(kind | FIRST * a | LAST * z
+                 for kind, a, z in zip(tiles.kind, first, last))
+
+
+def _step(pair, q_of_ref, k_of_ref, flags_ref):
+    """``j, first, last, when, bounds``: the step's key tile, and as thunks
+    (each use traces its own comparison) whether its pair is its query
+    tile's first, its last, and ``when``: which of the tile's TWO bodies is
+    the pair's — ``FULL``, or masked.  On the lower triangle the masked kind
+    is known while the kernel is traced (``NOT_AFTER``: ``bounds`` None);
+    elsewhere it is the pair's own and comes as ``bounds``, two scalars
+    ``lo <= beta(query) - beta(key) <= hi`` — ONE masked body whatever the
+    kinds in the list: a body a kind made the backward, five products a head
+    each, 47.5 ms a call where this one takes 19.2 (PERF.md section 6)."""
+    i, j = q_of_ref[pair], k_of_ref[pair]
+    if flags_ref is None:            # the lower triangle: see _flags
+        return j, lambda: j == 0, lambda: j == i, {
+            FULL: lambda: j < i, NOT_AFTER: lambda: j == i}, None
+    flags = flags_ref[pair]
+    kind = flags & 3
+    lo, hi = (sum(jnp.where(kind == k, bounds[n], 0)
+                  for k, bounds in BOUNDS.items()) for n in (0, 1))
+    return (j, lambda: (flags & FIRST) != 0, lambda: (flags & LAST) != 0,
+            {FULL: lambda: kind == FULL, None: lambda: kind != FULL},
+            (lo, hi))
+
+
+def _block_of(row, span: int):
+    """The block of ``span`` rows a row lies in: a shift where ``span`` is a
+    power of two (the vector unit has no integer division)."""
+    if span == 1:
+        return row
+    shift = span.bit_length() - 1
+    return row >> shift if 1 << shift == span else row // span
+
+
+def _with_the_pairs_bias(when, bounds, span, keep_ref, bias_ref, tile):
     """``tile(bias)`` for the step's pair, ``bias`` a ``(bk, bq)`` float32
     ref, 0 where the query sees the key and ``MASKED`` where not, or None
     where it sees them all.  With a selection (``keep_ref (bq, bk)`` int8:
     1 = kept) every pair has one, its tile turned ``[keys, queries]``.
-    Without (None), only the pair ON the diagonal has: same block, so a
-    key's row in the tile is not after the query's column."""
+    Without (None) the pair's KIND says (:func:`_step`): a ``FULL`` tile
+    adds nothing, any other compares two iotas — a key's row and a query's
+    column of the tile, in blocks of ``span`` — on those steps alone."""
     if keep_ref is not None:
         bias_ref[...] = ((1.0 - keep_ref[...].astype(jnp.float32))
                          * MASKED).T
         return tile(bias_ref)
+    for kind, here in when.items():
+        if kind == FULL:
+            pl.when(here())(lambda: tile(None))
+            continue
 
-    @pl.when(j < i)
-    def _under():
-        tile(None)
-
-    @pl.when(j == i)
-    def _on():
-        key = jax.lax.broadcasted_iota(jnp.int32, bias_ref.shape, 0)
-        query = jax.lax.broadcasted_iota(jnp.int32, bias_ref.shape, 1)
-        bias_ref[...] = jnp.where(key <= query, 0.0, MASKED)
-        tile(bias_ref)
+        @pl.when(here())
+        def _masked(kind=kind):
+            key, query = (_block_of(jax.lax.broadcasted_iota(
+                jnp.int32, bias_ref.shape, axis), span) for axis in (0, 1))
+            if bounds is None:
+                visible = VISIBLE[kind](key, query)
+            else:
+                ahead = query - key
+                visible = (ahead >= bounds[0]) & (ahead <= bounds[1])
+            bias_ref[...] = jnp.where(visible, 0.0, MASKED)
+            tile(bias_ref)
 
 
 def _scores(k_ref, q, scale, bias, shared=None):
@@ -218,23 +275,25 @@ def _taker(refs):
 
 
 def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
-                shared: bool):
-    """Scores ``[keys, queries]``.  Refs: ``q (G, bq, D)``; ``k (bk, D)``;
+                shared: bool, flagged: bool, span: int):
+    """Scores ``[keys, queries]``.  Refs: with a list that is not the lower
+    triangle its ``flags`` (scalar prefetch); ``q (G, bq, D)``; ``k (bk, D)``;
     ``v (bk, Dv)``; with a selection ``keep (bq, bk)`` int8; with a shared
     part ``q_s (G, bq, r)``, ``k_s (bk, r)``; ``o (G, bq, Dv)``; ``lse (G,
     bq)``; scratch: every head's running max and sum, a lane row a head,
     ``(G, bq)``, the float32 accumulators ``(G, bq, Dv)`` and a tile's
     bias."""
     take = _taker(refs)
+    flags_ref, = take(1, flagged)
     q_ref, k_ref, v_ref = take(3)
     keep_ref, = take(1, selected)
     qs_ref, ks_ref = take(2, shared)
     o_ref, lse_ref, top_ref, total_ref, acc_ref, bias_ref = take(6)
-    pair = pl.program_id(2)
-    i, j = q_of_ref[pair], k_of_ref[pair]
+    _, first, last, when, bounds = _step(pl.program_id(2), q_of_ref,
+                                         k_of_ref, flags_ref)
     group, _, dim = acc_ref.shape
 
-    @pl.when(j == 0)
+    @pl.when(first())
     def _start():
         top_ref[...] = jnp.full_like(top_ref, MASKED)
         total_ref[...] = jnp.zeros_like(total_ref)
@@ -264,9 +323,9 @@ def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
         for h in range(group):      # side by side: the module docstring
             head(h, bias)
 
-    _with_the_pairs_bias(i, j, keep_ref, bias_ref, tile)
+    _with_the_pairs_bias(when, bounds, span, keep_ref, bias_ref, tile)
 
-    @pl.when(j == i)
+    @pl.when(last())
     def _finish():
         lse_ref[...] = top_ref[...] + jnp.log(total_ref[...])
         for h in range(group):
@@ -275,8 +334,9 @@ def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
 
 
 def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
-                shared: bool):
-    """Everything ``[keys, queries]``.  Refs: ``q, dq (G, bq, D)``; ``dO (G,
+                shared: bool, flagged: bool, span: int):
+    """Everything ``[keys, queries]``.  Refs: with a list that is not the
+    lower triangle its ``flags``; ``q, dq (G, bq, D)``; ``dO (G,
     bq, Dv)``; ``k (bk, D)``; ``v (bk, Dv)``; with a selection ``keep (bq,
     bk)`` int8; ``lse, delta (G, bq)``; ``dk (S, D)``, ``dv (S, Dv)``
     float32, one key head's, resident over all its pairs; with a shared part
@@ -284,6 +344,7 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
     ONE SEQUENCE's, resident over all its heads and pairs; scratch: the
     float32 ``dq`` (and ``dq_s``) of the query block and a tile's bias."""
     take = _taker(refs)
+    flags_ref, = take(1, flagged)
     q_ref, k_ref, v_ref = take(3)
     keep_ref, = take(1, selected)
     qs_ref, ks_ref = take(2, shared)
@@ -293,7 +354,7 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
     dqs_acc_ref, = take(1, shared)
     bias_ref, = take(1)
     pair = pl.program_id(2)
-    i, j = q_of_ref[pair], k_of_ref[pair]
+    j, first, last, when, bounds = _step(pair, q_of_ref, k_of_ref, flags_ref)
     group, bk = q_ref.shape[0], k_ref.shape[0]
     keys = pl.ds(pl.multiple_of(j * bk, bk), bk)
 
@@ -307,7 +368,7 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
         def _next_sequence():
             dks_ref[...] = jnp.zeros_like(dks_ref)
 
-    @pl.when(j == 0)
+    @pl.when(first())
     def _next_rows():
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
         if shared:
@@ -332,18 +393,19 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
         for h in range(group):      # side by side: the module docstring
             head(h, bias)
 
-    _with_the_pairs_bias(i, j, keep_ref, bias_ref, tile)
+    _with_the_pairs_bias(when, bounds, span, keep_ref, bias_ref, tile)
 
-    @pl.when(j == i)
+    @pl.when(last())
     def _finish():
         dq_ref[...] = dq_acc_ref[...].astype(dq_ref.dtype)
         if shared:
             dqs_ref[...] = dqs_acc_ref[...].astype(dqs_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _call(forward, scale, block, interpret, q, k, v, keep, shared, *rest):
-    """One ``pallas_call`` over ``(batch, key head, causal pair)``.  ``q``:
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _call(forward, scale, block, interpret, tiles, q, k, v, keep, shared,
+          *rest):
+    """One ``pallas_call`` over ``(batch, key head, pair of tiles)``.  ``q``:
     ``(B, Hkv, G, S, D)``; ``k``: ``(B, Hkv, S, D)``; ``v``: ``(B, Hkv, S,
     Dv)``; ``keep``: None or ``(P, B, block, block)`` int8; ``shared``:
     ``()`` or ``(q_s (B, Hkv, G, S, r), k_s (B, S, r))``; ``rest``, backward:
@@ -353,20 +415,24 @@ def _call(forward, scale, block, interpret, q, k, v, keep, shared, *rest):
     b, hkv, g, s, d = q.shape
     dv = v.shape[-1]
     selected, r = keep is not None, shared[0].shape[-1] if shared else 0
-    q_of, k_of = causal_pairs(s // block)
-    # index maps: (batch, key head, pair, q_of, k_of)
-    rows = lambda w: pl.BlockSpec((None, None, g, block, w),
-                                  lambda n, h, p, qo, ko: (n, h, 0, qo[p], 0))
-    slab = lambda w: pl.BlockSpec((None, None, block, w),
-                                  lambda n, h, p, qo, ko: (n, h, ko[p], 0))
+    flags = _flags(tiles)
+    if selected and flags is not None:
+        raise ValueError("a selection comes in the lower triangle's layout")
+    lists = (tiles.q_of, tiles.k_of) + (() if flags is None else (flags,))
+    # index maps: (batch, key head, pair, q_of, k_of[, flags])
+    rows = lambda w: pl.BlockSpec(
+        (None, None, g, block, w),
+        lambda n, h, p, qo, ko, *_: (n, h, 0, qo[p], 0))
+    slab = lambda w: pl.BlockSpec(
+        (None, None, block, w), lambda n, h, p, qo, ko, *_: (n, h, ko[p], 0))
     # the pair's mask: no head axis
     tile = pl.BlockSpec((None, None, block, block),
-                        lambda n, h, p, qo, ko: (p, n, 0, 0))
+                        lambda n, h, p, qo, ko, *_: (p, n, 0, 0))
     # the shared key and its cotangent: no head axis
     slab_s = pl.BlockSpec((None, block, r),
-                          lambda n, h, p, qo, ko: (n, ko[p], 0))
+                          lambda n, h, p, qo, ko, *_: (n, ko[p], 0))
     row_stat = pl.BlockSpec((None, None, g, block),
-                            lambda n, h, p, qo, ko: (n, h, 0, qo[p]))
+                            lambda n, h, p, qo, ko, *_: (n, h, 0, qo[p]))
     stat = jax.ShapeDtypeStruct((b, hkv, g, s), jnp.float32)
     like = lambda w: jax.ShapeDtypeStruct((b, hkv, g, s, w), q.dtype)
     square = pltpu.VMEM((block, block), jnp.float32)
@@ -382,8 +448,8 @@ def _call(forward, scale, block, interpret, q, k, v, keep, shared, *rest):
     else:
         kernel, name = _bwd_kernel, stem + "_bwd"
         in_specs += [row_stat, row_stat, rows(dv)]
-        whole = lambda w: pl.BlockSpec((None, None, s, w),
-                                       lambda n, h, p, qo, ko: (n, h, 0, 0))
+        whole = lambda w: pl.BlockSpec(
+            (None, None, s, w), lambda n, h, p, qo, ko, *_: (n, h, 0, 0))
         outs = [(rows(d), like(d)),
                 (whole(d), jax.ShapeDtypeStruct(k.shape, jnp.float32)),
                 (whole(dv), jax.ShapeDtypeStruct(v.shape, jnp.float32))]
@@ -391,21 +457,22 @@ def _call(forward, scale, block, interpret, q, k, v, keep, shared, *rest):
         if shared:
             outs += [(rows(r), like(r)),
                      (pl.BlockSpec((None, s, r),
-                                   lambda n, h, p, qo, ko: (n, 0, 0)),
+                                   lambda n, h, p, qo, ko, *_: (n, 0, 0)),
                       jax.ShapeDtypeStruct((b, s, r), jnp.float32))]
             scratch += [per_head(r)]
         scratch += [square]
     arrays = (q, k, v) + ((keep,) if selected else ()) + shared + rest
-    formed = b * hkv * g * len(q_of) * block * block      # pairs, every head
+    formed = b * hkv * g * len(tiles.q_of) * block * block  # every head
     depth = (d + r + dv) if forward else 3 * (d + r) + 2 * dv
     moved = sum(a.size * a.dtype.itemsize for a in arrays) + sum(
         out.size * out.dtype.itemsize for _, out in outs)
     return pl.pallas_call(
         functools.partial(kernel, scale=scale, selected=selected,
-                          shared=bool(shared)),
+                          shared=bool(shared), flagged=flags is not None,
+                          span=tiles.span),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, hkv, len(q_of)),
+            num_scalar_prefetch=len(lists),
+            grid=(b, hkv, len(tiles.q_of)),
             in_specs=in_specs,
             out_specs=[spec for spec, _ in outs],
             scratch_shapes=scratch),
@@ -422,29 +489,31 @@ def _call(forward, scale, block, interpret, q, k, v, keep, shared, *rest):
             bytes_accessed=moved),
         interpret=interpret,
         name=name,
-    )(jnp.asarray(q_of), jnp.asarray(k_of), *arrays)
+    )(*(jnp.asarray(x, jnp.int32) for x in lists), *arrays)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _attend(q, k, v, keep, shared, scale, block, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _attend(q, k, v, keep, shared, scale, block, interpret, tiles):
     """``(out, log-sum-exp)``; the second takes no cotangent."""
-    return _attend_fwd(q, k, v, keep, shared, scale, block, interpret)[0]
+    return _attend_fwd(q, k, v, keep, shared, scale, block, interpret,
+                       tiles)[0]
 
 
-def _attend_fwd(q, k, v, keep, shared, scale, block, interpret):
-    out, lse = _call(True, scale, block, interpret, q, k, v, keep, shared)
+def _attend_fwd(q, k, v, keep, shared, scale, block, interpret, tiles):
+    out, lse = _call(True, scale, block, interpret, tiles, q, k, v, keep,
+                     shared)
     return (out, lse), (q, k, v, keep, shared, out, lse)
 
 
-def _attend_bwd(scale, block, interpret, residuals, cotangents):
+def _attend_bwd(scale, block, interpret, tiles, residuals, cotangents):
     q, k, v, keep, shared, out, lse = residuals
     d_out, _ = cotangents
     # sum_k w (dw) of the softmax's backward is rowsum(dO . O)
     delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
     d_q, d_k, d_v, *d_shared = _call(
-        False, scale, block, interpret, q, k, v, keep, shared, lse, delta,
-        d_out.astype(q.dtype))
+        False, scale, block, interpret, tiles, q, k, v, keep, shared, lse,
+        delta, d_out.astype(q.dtype))
     if shared:
         d_shared[1] = d_shared[1].astype(shared[1].dtype)
     return (d_q, d_k.astype(k.dtype), d_v.astype(v.dtype), None,
@@ -455,10 +524,13 @@ _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 def attend(q, k, v, *, scale: float, block: int, selected=None, shared=None,
+           tiles: Optional[TilePairs] = None,
            interpret: Optional[bool] = None):
     """``q``: ``(B, Hkv, G, S, D)``; ``k``: ``(B, Hkv, S, D)``; ``v``: ``(B,
-    Hkv, S, Dv)``, ``S`` whole blocks; ``selected``: None (every causal key)
-    or ``(P, B, block, block)`` bool, the tile layout; ``shared``: None or
+    Hkv, S, Dv)``, ``S`` whole blocks; ``tiles``: the visibility rule as the
+    list of tile pairs to form (None: ``causal_tiles``); ``selected``: None
+    (every key the rule shows) or, under the causal rule, ``(P, B, block,
+    block)`` bool, the tile layout; ``shared``: None or
     ``(q_s (B, Hkv, G, S, r), k_s (B, S, r))``.  Returns ``out (B, Hkv, G,
     S, Dv)`` and the rows' log-sum-exp ``(B, Hkv, G, S)`` float32 — what
     ``ops/attention._blockwise_causal`` and ``_selected`` return,
@@ -466,5 +538,6 @@ def attend(q, k, v, *, scale: float, block: int, selected=None, shared=None,
     return _attend(q, k, v,
                    None if selected is None else selected.astype(jnp.int8),
                    () if shared is None else tuple(shared),
-                   float(scale), int(block),
-                   resolve_interpret(interpret))
+                   float(scale), int(block), resolve_interpret(interpret),
+                   causal_tiles(q.shape[3] // block) if tiles is None
+                   else tiles)

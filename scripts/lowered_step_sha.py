@@ -39,7 +39,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 KERNEL_BODY = r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22'
 # cells whose trunk runs a tiled causal kernel (ops/causal_attention.py)
 KERNEL_CELLS = ("qwen3next_train_b4_s4096", "keye_train_b4_s4096",
-                "lfm2_train_b4_s4096", "joyai_train_b4_s4096")
+                "lfm2_train_b4_s4096", "joyai_train_b4_s4096",
+                "sdar_train_b2_s4096")
 
 
 def _sha(text: str) -> str:
@@ -80,7 +81,11 @@ def lowered_text(cell_name: str, topo) -> str:
     chips, tokens = int(cell["chips"]), "seq_len" in conf
     if tokens:
         from benchmarks.drivers.train_tokens import program_config
-        shape = (conf["seq_len"],)
+        from byol_tpu.models.registry import get_spec
+        # a block-diffusion trunk's sample is [noised | clean]: what
+        # data/loader hands the program (the parent's registry has no such)
+        doubled = getattr(get_spec(conf["arch"]), "diffusion_block", 0)
+        shape = (conf["seq_len"] * (2 if doubled else 1),)
     else:
         from benchmarks.drivers.train_loop import program_config
         shape = (conf["image_size"], conf["image_size"], 3)

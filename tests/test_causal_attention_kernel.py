@@ -79,11 +79,13 @@ def test_the_kernels_are_the_jnp_body(monkeypatch, dim, group, blocks, dtype):
             np.linalg.norm(f32(w)), name
     grouped = q.reshape(BATCH, KV_HEADS, group, seq, dim)
     scale = dim ** -0.5
-    lse = kernels._call(True, scale, BLOCK, True, grouped, k, v, None, ())[1]
+    tiles = attention.causal_tiles(blocks)
+    lse = kernels._call(True, scale, BLOCK, True, tiles, grouped, k, v, None,
+                        ())[1]
     assert lse.shape == (BATCH, KV_HEADS, group, seq)
     assert lse.dtype == jnp.float32          # a row a head, whatever q is
-    want_lse = attention._blockwise_causal_fwd(grouped, k, v, scale,
-                                               BLOCK)[1][-1]
+    want_lse = attention._blockwise_fwd(grouped, k, v, scale, BLOCK,
+                                        tiles)[1][-1]
     np.testing.assert_allclose(lse, want_lse, rtol=1e-5, atol=1e-5)
 
 
