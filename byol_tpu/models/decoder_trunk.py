@@ -83,7 +83,7 @@ import jax.numpy as jnp
 from byol_tpu.core import remat as remat_lib
 from byol_tpu.models.gated_delta import (GatedDeltaNet, GatedDeltaSizes,
                                           causal_conv)
-from byol_tpu.ops import key_selection, sum_copies
+from byol_tpu.ops import expert_routing, key_selection, sum_copies
 from byol_tpu.ops.attention import (block_diffusion_tiles,
                                     blockwise_causal_attention,
                                     dense_attention, kept_probabilities,
@@ -700,7 +700,19 @@ class ExpertLayer(nn.Module):
     shared expert.  Every row routed to a held expert is computed.  The
     sizes name the scoring rule (sigmoid scores with a selection bias, or a
     softmax over all experts); from the chosen experts down there is one
-    path."""
+    path.
+
+    ``route`` hands on the k choices of a token (``chosen``, ``weight``) and
+    the dispatch tables of its ``tokens x k`` copies in EXPERT ORDER
+    (``ops/expert_routing.py``: counting kernels on a TPU, ``top_k`` and
+    ``argsort`` elsewhere, the same values).  DEFINED: ``group_sizes`` and
+    ``rows_held`` everywhere; ``place[t, j]``, a copy's sorted row, where a
+    held expert owns the copy (``here_2d``); ``token_of[r]`` and
+    ``weight_of[r]``, a sorted row's token and routing weight, for ``r <
+    rows_held``.  Elsewhere they are only kept in bounds (``weight_of`` is 0
+    there): every use of ``place`` is under ``here_2d &`` or clipped into
+    the window, and rows at or past ``rows_held`` are masked on the way in
+    and out (``valid``)."""
 
     sizes: TrunkSizes
     lo: int
@@ -720,35 +732,35 @@ class ExpertLayer(nn.Module):
                 (d, z.n_routed_experts), jnp.float32)
             logits = jnp.dot(x.astype(jnp.float32), router,
                              precision=jax.lax.Precision.HIGHEST)
-            if z.scoring_func == "softmax":
-                # the k largest ARE the weights: no gather of them
-                weight, chosen = jax.lax.top_k(
-                    jax.nn.softmax(logits, axis=-1), k)
-            else:
-                # noaux_tc: a selection bias that takes no gradient (it
-                # moves which experts are chosen, never their weights)
-                bias = self.param("e_score_correction_bias",
-                                  nn.initializers.zeros,
-                                  (z.n_routed_experts,), jnp.float32)
-                scores = jax.nn.sigmoid(logits)
-                _, chosen = jax.lax.top_k(scores + bias, k)
-                weight = jnp.take_along_axis(scores, chosen, axis=-1)
+            # which lowering the k choices and the tables take: from the
+            # shapes (ops/expert_routing.py)
+            kernel = expert_routing.applies(tokens, z.n_routed_experts, k,
+                                            self.held)
+            with jax.named_scope("choose"):
+                if z.scoring_func == "softmax":
+                    # the k largest ARE the weights
+                    weight, chosen = expert_routing.choose(
+                        jax.nn.softmax(logits, axis=-1), None, k,
+                        kernel=kernel)
+                else:
+                    # noaux_tc: a selection bias that takes no gradient (it
+                    # moves which experts are chosen, never their weights)
+                    bias = self.param("e_score_correction_bias",
+                                      nn.initializers.zeros,
+                                      (z.n_routed_experts,), jnp.float32)
+                    scores = jax.nn.sigmoid(logits)
+                    weight, chosen = expert_routing.choose(
+                        scores + bias, scores, k, kernel=kernel)
             if z.norm_topk_prob and k > 1:
                 weight = weight / (jnp.sum(weight, -1, keepdims=True)
                                    + z.norm_topk_eps)
             weight = weight * z.routed_scaling_factor
-            local = chosen.reshape(-1) - self.lo
-            here = (local >= 0) & (local < self.held)
-            bucket = jnp.where(here, local, self.held)   # the rest sort last
-            order = jnp.argsort(bucket)                  # stable
-            token_of = order // k
-            place = jnp.argsort(order).reshape(tokens, k)   # a copy's row
-            here_2d = here.reshape(tokens, k)
-            weight_of = jnp.where(here, weight.reshape(-1), 0.0)[order]
-            group_sizes = jnp.sum(
-                bucket[:, None] == jnp.arange(self.held,
-                                              dtype=bucket.dtype),
-                axis=0, dtype=jnp.int32)
+            local = chosen - self.lo
+            here_2d = (local >= 0) & (local < self.held)
+            with jax.named_scope("tables"):
+                place, token_of, weight_of, group_sizes = (
+                    expert_routing.tables(chosen, weight, self.lo, self.held,
+                                          kernel=kernel))
             rows_held = jnp.sum(group_sizes)
         with jax.named_scope("experts"):
             w_gate, w_up, w_down = ExpertWeights(
@@ -853,7 +865,7 @@ class ExpertLayer(nn.Module):
         load = group_sizes.astype(jnp.float32)
         self.sow(ROUTING, "stats", jnp.stack([
             rows_held.astype(jnp.float32), jnp.max(load), jnp.mean(load),
-            (jnp.sum(here) - rows_held).astype(jnp.float32)]))
+            (jnp.sum(here_2d) - rows_held).astype(jnp.float32)]))
         if shared is not None:
             routed = routed + shared
         return routed.reshape(b, s, d)

@@ -36,6 +36,12 @@ call sites name the capability, not the file:
   of the held rows into token order and a one-hot segment-sum kernel on the
   matrix unit: what ``models/decoder_trunk._sum_copies`` runs on a TPU where
   the sorted window is shorter than every copy.
+- :mod:`byol_tpu.ops.expert_routing` (``choose``, ``tables``, ``applies``)
+  — the expert layer's routing without a sort: the k choices of a router
+  row as k rounds of maximum over rows held in VMEM, the dispatch tables by
+  counting and an in-VMEM compression of the ``held x tokens`` membership:
+  what ``models/decoder_trunk.ExpertLayer``'s ``route`` runs on a TPU at
+  whole tiles of tokens and a router 64 to 512 wide.
 - :func:`fused_two_view` — the fused uint8→two-view augmentation
   (``--fused-augment on``): one VMEM pass per image for
   convert/crop/flip/jitter/grayscale, blur as an MXU conv on the output.

@@ -136,9 +136,125 @@ _EXPERT_LAYERS = {
 }
 
 
+# the fourth router width (256) and the two cells that share a width: the
+# routing's rule of shapes is asked at all six trunk cells' sizes
+_ROUTED_LAYERS = dict(
+    _EXPERT_LAYERS,
+    joyai_train_b4_s4096=("JOYAI_LLM_FLASH", 16, (8, 4096), False))
+
+
+@pytest.fixture(scope="module")
+def layer_texts():
+    """The compiled texts of the cells' expert layers: one compile a cell."""
+    return {}
+
+
+def _expert_layer_text(cell, one_chip, monkeypatch, texts):
+    """The compiled text of a cell's expert layer, forward and backward,
+    lowered as on a TPU (the kernels' rules of shapes ask
+    ``jax.default_backend()``), kept in ``texts``."""
+    from byol_tpu.models import decoder_trunk as trunk_lib
+    sizes, held, (batch, seq), _ = _ROUTED_LAYERS[cell]
+    z = getattr(trunk_lib, sizes)
+    if cell not in texts:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        layer = trunk_lib.ExpertLayer(z, 0, held, jnp.bfloat16)
+        x = jax.ShapeDtypeStruct((batch, seq, z.hidden_size), jnp.bfloat16,
+                                 sharding=one_chip)
+        params = _with(jax.eval_shape(
+            lambda: layer.init(jax.random.PRNGKey(0),
+                               jnp.zeros((1, 8, z.hidden_size), x.dtype))
+        )["params"], one_chip)
+
+        def loss(p, x):           # not linear: the forward's combine stays
+            return jnp.sum(jnp.square(
+                layer.apply({"params": p}, x).astype(jnp.float32)))
+        texts[cell] = _compile(
+            jax.grad(loss, argnums=(0, 1)), params, x).as_text()
+    return z, texts[cell]
+
+
+def _route_rows(text):
+    """The instructions under ``moe/route`` — in the layer's text the scope
+    ``route`` — fused ones too: (opcode, result type, operands, path)."""
+    import re
+    rows = []
+    for line in text.splitlines():
+        found = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\((.*)$", line)
+        path = re.search(r'op_name="([^"]*)"', line)
+        if found and path and "/route/" in path.group(1):
+            rows.append((found.group(2), found.group(1), found.group(3),
+                         path.group(1)))
+    return rows
+
+
+def _elements(kind):
+    import re
+    return [int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in re.findall(r"\b[a-z]+\d+\[([\d,]*)\]", kind)]
+
+
+@pytest.mark.parametrize("cell", sorted(_ROUTED_LAYERS))
+def test_expert_layer_routes_without_a_sort(no_persistent_cache, one_chip,
+                                            monkeypatch, layer_texts, cell):
+    """At each router width (64, 128, 256, 512), forward and backward: under
+    ``route`` no ``sort``, and no ``gather`` / ``scatter`` that reads,
+    writes or is indexed by ``tokens x k`` or ``tokens x E`` elements; the
+    choices, the tables and ``weight_of``'s backward are one kernel each
+    (PERF.md section 6, PR 46)."""
+    import re
+    from byol_tpu.ops import expert_routing
+    _, held, (batch, seq), _ = _ROUTED_LAYERS[cell]
+    z, text = _expert_layer_text(cell, one_chip, monkeypatch, layer_texts)
+    tokens, k, experts = batch * seq, z.num_experts_per_tok, \
+        z.n_routed_experts
+    assert expert_routing.applies(tokens, experts, k, held, backend="tpu")
+    rows = _route_rows(text)
+    assert len(rows) > 10
+    assert not [row for row in rows if row[0] == "sort"]
+    large = {tokens * k, tokens * experts}
+    assert not [row for row in rows if row[0] in ("gather", "scatter")
+                and large & set(_elements(row[1]) + _elements(row[2]))]
+    for kernel, calls in (("route_choose", 1), ("route_tables", 1),
+                          ("route_tables_bwd", 1)):
+        assert len(re.findall(
+            rf"custom-call\([^\n]*/{kernel}/pallas_call", text)) == calls
+    for scope in ("route/choose/", "route/tables/"):
+        assert scope in text
+
+
+@pytest.mark.parametrize("backend,sizes", [
+    ("cpu", (32768, 512, 10, 32)),               # not lowered for a TPU
+    ("tpu", (32768 + 512, 512, 10, 32)),         # no whole tile of tokens
+    ("tpu", (32768, 96, 4, 8)),                  # a router of 96
+    ("tpu", (2 ** 20, 512, 10, 64)),             # more than VMEM takes
+])
+def test_expert_routing_falls_back_to_jax_numpy(no_persistent_cache, one_chip,
+                                                monkeypatch, backend, sizes):
+    """Another backend and shapes the kernels do not take keep ``top_k`` and
+    ``argsort`` (no kernel in the text), at a small size of the same kind."""
+    from byol_tpu.ops import expert_routing
+    assert not expert_routing.applies(*sizes, backend=backend)
+    assert expert_routing.applies(32768, 512, 10, 32, backend="tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    tokens, experts, k, held = 1024 + sizes[0] % 1024, sizes[1], 4, 8
+    if sizes[0] == 2 ** 20:
+        monkeypatch.setattr(expert_routing, "VMEM_LIMIT_BYTES", 2 ** 16)
+
+    def route(select):
+        kernel = expert_routing.applies(tokens, experts, k, held)
+        weight, chosen = expert_routing.choose(select, None, k,
+                                               kernel=kernel)
+        return expert_routing.tables(chosen, weight, 0, held, kernel=kernel)
+    text = jax.jit(route).lower(jax.ShapeDtypeStruct(
+        (tokens, experts), jnp.float32, sharding=one_chip)
+    ).compile().as_text()
+    assert "tpu_custom_call" not in text and " sort(" in text
+
+
 @pytest.mark.parametrize("cell", sorted(_EXPERT_LAYERS))
 def test_expert_layer_combine_moves_no_relaid_out_copies(
-        no_persistent_cache, one_chip, monkeypatch, cell):
+        no_persistent_cache, one_chip, monkeypatch, layer_texts, cell):
     """At the three trunk cells' shapes, lowered as on a TPU
     (``sum_copies.applies`` asks ``jax.default_backend()``): the combine and
     the dispatch's backward of a window of ``cap`` rows are each ONE gather
@@ -147,22 +263,8 @@ def test_expert_layer_combine_moves_no_relaid_out_copies(
     under the ``combine`` scope, no ``copy`` / ``reshape`` of the hidden
     states (PERF.md section 6, PR 30 and 37).  Where the fallback is one
     product over every copy, that branch keeps the k gathers a sum."""
-    from byol_tpu.models import decoder_trunk as trunk_lib
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    sizes, held, (batch, seq), whole_fallback = _EXPERT_LAYERS[cell]
-    z = getattr(trunk_lib, sizes)
-    layer = trunk_lib.ExpertLayer(z, 0, held, jnp.bfloat16)
-    x = jax.ShapeDtypeStruct((batch, seq, z.hidden_size), jnp.bfloat16,
-                             sharding=one_chip)
-    params = _with(jax.eval_shape(
-        lambda: layer.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 8, z.hidden_size), x.dtype))
-    )["params"], one_chip)
-
-    def loss(p, x):           # not linear: the forward's combine stays
-        return jnp.sum(jnp.square(
-            layer.apply({"params": p}, x).astype(jnp.float32)))
-    text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
+    _, held, (batch, seq), whole_fallback = _EXPERT_LAYERS[cell]
+    z, text = _expert_layer_text(cell, one_chip, monkeypatch, layer_texts)
     tokens, k, d = batch * seq, z.num_experts_per_tok, z.hidden_size
     cap = 2 * tokens * k * held // z.n_routed_experts
     assert f"[{tokens},{k},{d}]" not in text
@@ -720,5 +822,14 @@ def test_lfm2_train_step_fits_and_keeps_its_scopes(no_persistent_cache, topo,
     assert "f32[8,8,4,512,512]" not in text
     assert not _float32_squares(text)
     for scope in ("shortconv/proj", "shortconv/core", "gqa/core", "/ffn/",
-                  "moe/experts/combine"):
+                  "moe/experts/combine", "moe/route/choose",
+                  "moe/route/tables"):
         assert scope in text, scope
+    # the four routing layers without a sort (PR 46): the choices and the
+    # tables a kernel each in target, online and recomputed forward, the
+    # backward of ``weight_of`` one more a layer
+    for kernel, calls in (("route_choose", 12), ("route_tables", 12),
+                          ("route_tables_bwd", 4)):
+        assert len(re.findall(
+            rf"custom-call\([^\n]*/{kernel}/pallas_call", text)) == calls
+    assert not [row for row in _route_rows(text) if row[0] == "sort"]
