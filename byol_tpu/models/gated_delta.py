@@ -21,18 +21,21 @@ triangular system — ``(I + L) [U | W] = [beta V | beta K e^gamma]`` with ``L
 = strict_tril(beta K K^T . decay)`` — whose inverse is built exactly
 (forward substitution on small diagonal blocks, then block elimination:
 nothing cancels where keys repeat, as a power series of ``L`` would);
-between chunks a ``lax.scan`` carries the state, ``S / C`` trips of four
-products.
+between chunks the state is carried, ``S / C`` trips of four products
+(:func:`_chunk_step`).
 
-One algorithm, two lowerings of its WITHIN-CHUNK stage (everything before
-the scan), chosen from what the code can see (``ops/delta_rule.applies``):
-where the program lowers for a TPU and ``chunk``, ``d_k``, ``d_v`` are
-multiples of 128 that fit VMEM, the kernels ``delta_wy_fwd`` /
-``delta_wy_bwd`` of ``ops/delta_rule.py`` — a chunk's ``C x C`` matrices
-live and die in VMEM, the keys are read per KEY head and never repeated;
-everywhere else (``HYBRID_TINY``, every CPU run) plain ``jax.numpy``,
-:func:`_chunked_rule` with :func:`unit_lower_inverse`, which is also the
-tests' oracle for the kernels.  The scan and the projections are plain
+One algorithm, two lowerings, chosen from what the code can see
+(``ops/delta_rule.applies``): where the program lowers for a TPU and
+``chunk``, ``d_k``, ``d_v`` are multiples of 128 that fit VMEM, the kernels
+of ``ops/delta_rule.py`` (:func:`_chunked_rule_kernels`) — ``delta_wy_fwd``
+/ ``delta_wy_bwd`` within a chunk: its ``C x C`` matrices live and die in
+VMEM, the keys are read per KEY head and never repeated; ``delta_scan_fwd``
+/ ``delta_scan_bwd`` between chunks: :func:`_chunk_step` down a column of
+chunks with the states in VMEM, no loop, and the output written as the
+gated norm reads it, ``[B, S, H d_v]``; everywhere else (``HYBRID_TINY``,
+every CPU run) plain ``jax.numpy``, :func:`_chunked_rule` —
+:func:`unit_lower_inverse` and ``lax.scan(_chunk_step)`` — which is also
+the tests' oracle for the kernels.  The projections are plain
 ``jax.numpy`` on both.
 
 ``qkvz`` is ONE product whose columns stand in the order the stages read
@@ -183,9 +186,10 @@ def chunked_delta_rule(q, k, v, g, beta, *, chunk: int, dtype=jnp.float32,
     ``group`` > 0 works on that many sequences at a time (where it divides
     ``B``), each group under ``jax.checkpoint``: the rule's intermediates —
     on the ``jax.numpy`` path several ``(B, H, S / C, C, C)`` and ``(B, H,
-    S, d)`` float32 arrays, with the kernels the scan's operands and one
-    kept inverse — then exist for one group, in the forward and in the
-    backward alike, at the price of one more forward of the rule."""
+    S, d)`` float32 arrays, with the kernels the recurrence's operands, one
+    kept inverse and the chunks' kept states — then exist for one group, in
+    the forward and in the backward alike, at the price of one more forward
+    of the rule."""
     b, s, hk, dk = q.shape
     if delta_rule.applies(min(chunk, s), dk, v.shape[-1], dtype):
         rule = _chunked_rule_kernels
@@ -194,12 +198,16 @@ def chunked_delta_rule(q, k, v, g, beta, *, chunk: int, dtype=jnp.float32,
         if hk != v.shape[2]:          # out here, before the groups
             q, k = (jnp.repeat(x, v.shape[2] // hk, axis=2) for x in (q, k))
     if not group or group >= b or b % group:
-        return rule(q, k, v, g, beta, chunk, dtype)
-    grouped = lambda x: x.reshape((b // group, group) + x.shape[1:])
-    out = jax.lax.map(
-        jax.checkpoint(lambda xs: rule(*xs, chunk, dtype)),
-        tuple(grouped(x) for x in (q, k, v, g, beta)))
-    return out.reshape((b,) + out.shape[2:])
+        out = rule(q, k, v, g, beta, chunk, dtype)
+    else:
+        grouped = lambda x: x.reshape((b // group, group) + x.shape[1:])
+        out = jax.lax.map(
+            jax.checkpoint(lambda xs: rule(*xs, chunk, dtype)),
+            tuple(grouped(x) for x in (q, k, v, g, beta)))
+    # the kernels' is (.., S, H d_v), a head's columns side by side as the
+    # gated norm reads them: split out here, after the groups, the reshape
+    # meets the norm's own and no array is ever laid out per head
+    return out.reshape(v.shape)
 
 
 def _pad_to_chunks(arrays, s, c):
@@ -232,19 +240,16 @@ def _chunk_step(dtype):
 
 
 def _chunked_rule_kernels(q, k, v, g, beta, chunk, dtype):
-    """The rule with its within-chunk stage as the kernels of
-    ``ops/delta_rule.py``: ``q, k`` one per KEY head, no ``(.., C, C)``
-    float32 array outside them; the scan is ``_chunked_rule``'s."""
-    b, s, h, dv = v.shape
-    c = min(chunk, s)
+    """The rule as the kernels of ``ops/delta_rule.py``: ``q, k`` one per
+    KEY head, no ``(.., C, C)`` float32 array outside the within-chunk
+    pair; ``_chunk_step`` down the chunks with the states in VMEM, no loop.
+    Returns ``(B, S, H d_v)``."""
+    s, c = v.shape[1], min(chunk, v.shape[1])
     q, k, v, g, beta = _pad_to_chunks((q, k, v, g, beta), s, c)
     u, w, within, q_in, k_out, gamma = delta_rule.within_chunk(
         q, k, v, g, beta, chunk=c, dtype=dtype)
-    _, out = jax.lax.scan(
-        _chunk_step(dtype), jnp.zeros((b, h, k.shape[-1], dv), jnp.float32),
-        (u, w, within, q_in, k_out, jnp.exp(gamma[..., -1:])))
-    # (N, B, H, C, d_v) -> (B, S, H, d_v)
-    return jnp.moveaxis(out, (0, 3), (1, 2)).reshape(b, -1, h, dv)[:, :s]
+    return delta_rule.between_chunks(
+        u, w, within, q_in, k_out, jnp.exp(gamma[..., -1:]))[:, :s]
 
 
 def _chunked_rule(q, k, v, g, beta, chunk, dtype):

@@ -1,4 +1,6 @@
-"""The gated delta rule's WITHIN-CHUNK stage — forward and backward kernels.
+"""The chunked gated delta rule as two kernel pairs: WITHIN a chunk
+(``delta_wy_fwd`` / ``delta_wy_bwd``) and BETWEEN chunks (``delta_scan_fwd``
+/ ``delta_scan_bwd``).
 
 ``within_chunk(q, k, v, g, beta, chunk=, dtype=)`` is everything
 ``models/gated_delta._chunked_rule`` does between its chunked inputs and its
@@ -54,6 +56,55 @@ a call of 4,096 chunks of 128 with heads of 128 in bf16):
   which the forward that precedes a backward writes once more (float32
   ``[N, B, H, C, C]``, 268 MB a group of 4 sequences, between the two
   kernels only): rebuilding it took the backward from 6.7 to 15.7 ms.
+
+``between_chunks(u, w, within, q_in, k_out, decay)`` is that ``lax.scan``:
+``gated_delta._chunk_step`` from a zero state down a sequence's chunks, on
+``within_chunk``'s results in the layout they have, per value head::
+
+    held = dtype(S)                           delta = u - w held
+    o = q_in held + within dtype(delta)       S <- S decay + k_out^T dtype(delta)
+
+As a ``lax.scan`` a trip was three to five XLA ops with a dynamic slice in
+front of each operand, read AND wrote the ``[B, H, d_k, d_v]`` float32 state
+in HBM, and its autodiff kept ``held``, ``delta`` and the state of every
+trip: 2.31 ms a forward and 9.97 ms a forward with its backward (chip run,
+PR 47: a group of 4 sequences x 4,096 tokens x 32 heads, 32 chunks of 128,
+heads of 128, bf16 — the sizes of every time below).  Design of the pair:
+- the grid is ``(B, H / heads, N)``, the chunk axis last and ``arbitrary``:
+  a float32 VMEM scratch ``(heads, d_k, d_v)`` carries the states down the
+  column of chunks, zeroed where the chunk index is 0; they never reach
+  HBM but where a backward follows, which reads each chunk's INCOMING
+  states (float32 ``[N, B, H, d_k, d_v]``, 268 MB a group of 4 sequences,
+  between the two kernels only — less than the scan's autodiff kept);
+- a trip is ``_chunk_step`` to the letter — operands in ``dtype``, float32
+  accumulation, ``held`` rounded once a chunk, the state float32 — so ``o``
+  is the scan's to the bit on the CPU;
+- a head's two dependent products a chunk wait for each other, the heads
+  do not: a program holds ``heads`` value heads (:func:`_heads`: the most
+  that divide ``H`` and fit, 8 at the published sizes) and traces them
+  breadth first with :func:`_in_step`, as the within-chunk kernels trace
+  their chunks.  At 4 | 8 | 16 heads the forward ran 1.373 | 1.322 | 1.305
+  ms: with even 4 chains in step the kernel is bound by HBM, not by the
+  chain;
+- ``o`` is written as 128-lane column blocks of ``[B, S, H d_v]``, the
+  array the gated norm reads: nothing turns the scan's ``[N, B, H, C,
+  d_v]`` round any more, and ``d_o`` is read the same way;
+- the backward runs the chunks last to first on the cotangent of the
+  handed-on states, float32 in the scratch, and forms ``delta`` again:
+  nine products a chunk and head.  A cotangent that is a product's operand
+  is rounded to ``dtype`` as the scan's transpose rounds it; SUMS of
+  products stay float32 (the scan's transpose rounds each part to
+  ``dtype`` first: a rounding of the gradient's norm apart);
+- VMEM (:func:`_scan_vmem_bytes`): a head's blocks are 224 KB forward (+ 64
+  kept), 480 backward, twice for double buffering, plus the carried
+  states and the temporaries of all heads, which are traced in step: 8
+  heads are 5.0 | 6.0 | 10.0 MiB of the 16 MiB scope; what the chip's
+  compiler took and refused at 16 and 32 heads set the temporaries' count;
+- times: ``delta_scan_fwd`` 1.32 ms a call for 0.94 GB (711 GB/s), 1.78
+  where it keeps the states (1.21 GB, 678 GB/s), ``delta_scan_bwd`` 2.97
+  for 2.01 GB (678 GB/s) — the rate of a plain pass over HBM on a v5e;
+  inside the train step, beside the compiler's asynchronous copies, 1.55,
+  2.0 and 2.99.  ``o`` is the scan's to the bit on the chip too.
 
 ``interpret=True`` (default off-TPU) runs the same kernels under the Pallas
 interpreter so CPU tests exercise identical code paths.
@@ -111,22 +162,30 @@ def _vmem_bytes(chunk: int, dk: int, dv: int, tile: int, itemsize: int,
     return tile * (2 * chunk * per_token + live)
 
 
+def _most(n: int, limit: int, vmem_bytes) -> int:
+    """The largest divisor of ``n`` up to ``limit`` whose count fits."""
+    for size in range(min(limit, n), 1, -1):
+        if n % size == 0 and vmem_bytes(size) <= VMEM_BYTES:
+            return size
+    return 1
+
+
 def _tile(n: int, chunk: int, dk: int, dv: int, itemsize: int,
           forward: bool) -> int:
     """Chunks a program holds: the most that divide ``n`` and fit."""
-    for tile in range(min(MAX_TILE, n), 1, -1):
-        if n % tile == 0 and _vmem_bytes(chunk, dk, dv, tile, itemsize,
-                                         forward) <= VMEM_BYTES:
-            return tile
-    return 1
+    return _most(n, MAX_TILE, lambda tile: _vmem_bytes(
+        chunk, dk, dv, tile, itemsize, forward))
 
 
 def supported(chunk: int, dk: int, dv: int, itemsize: int = 2) -> bool:
     """Shapes the kernels take: a chunk's tokens and a head's width fill
-    whole 128-lane tiles, and one chunk's matrices fit VMEM."""
+    whole 128-lane tiles, and one chunk's matrices — within it, and one
+    head's between chunks — fit VMEM."""
     return (chunk > 0 and chunk % LANES == 0 and dk > 0 and dk % LANES == 0
             and dv > 0 and dv % LANES == 0
-            and _vmem_bytes(chunk, dk, dv, 1, itemsize, False) <= VMEM_BYTES)
+            and _vmem_bytes(chunk, dk, dv, 1, itemsize, False) <= VMEM_BYTES
+            and _scan_vmem_bytes(chunk, dk, dv, 1, itemsize,
+                                 "backward") <= VMEM_BYTES)
 
 
 def applies(chunk: int, dk: int, dv: int, dtype=jnp.bfloat16, *,
@@ -473,3 +532,196 @@ def within_chunk(q, k, v, g, beta, *, chunk: int, dtype=jnp.float32,
         x.astype(jnp.float32).reshape(b, n, chunk, h), 3, 1)[:, :, :, None]
     return _stage(flat(q), flat(k), flat(v), rows(g), rows(beta), hk,
                   ops_common.resolve_interpret(interpret))
+
+
+# ---- the scan between chunks ---------------------------------------------
+
+MAX_HEADS = 8                    # value heads a program of the scan holds
+# float32 values a head holds at once, (forward, backward): (C, d_v) ones
+# and (d_k, d_v) ones — the heads are traced in step, so all of them; from
+# what the chip's compiler took and refused at heads of 128 and 256 wide
+_SCAN_LIVE = ((1, 1), (2, 2))
+
+
+def _scan_vmem_bytes(chunk: int, dk: int, dv: int, heads: int, itemsize: int,
+                     mode: str):
+    """A scan kernel's blocks twice — the five operands and ``o``; the kept
+    states; the backward's cotangents of all of them — the carried states
+    and the heads' temporaries."""
+    forward = mode != "backward"
+    operands = chunk * (4 * dv + itemsize * (3 * dk + chunk))
+    tokens = chunk * dv * itemsize                      # o, or its cotangent
+    state = 4 * dk * dv
+    blocks = operands + tokens + (0 if mode == "forward" else state) + (
+        0 if forward else operands)
+    wide, square = _SCAN_LIVE[not forward]
+    return heads * (2 * blocks + state
+                    + 4 * dv * (wide * chunk + square * dk))
+
+
+def _heads(h: int, chunk: int, dk: int, dv: int, itemsize: int,
+           mode: str) -> int:
+    """Value heads a program of the scan holds: the most that divide ``h``
+    and fit."""
+    return _most(h, MAX_HEADS, lambda heads: _scan_vmem_bytes(
+        chunk, dk, dv, heads, itemsize, mode))
+
+
+def _scan_fwd_kernel(u_ref, w_ref, within_ref, q_in_ref, k_out_ref,
+                     decay_ref, o_ref, *rest):
+    """One chunk of ``heads`` value heads: ``_chunk_step`` to the letter on
+    the states the scratch carries down the column of chunks.  ``rest``:
+    where the backward follows, each chunk's INCOMING states; the scratch."""
+    *kept, state_ref = rest
+    heads, dv, dt = u_ref.shape[0], u_ref.shape[2], w_ref.dtype
+    exact = dt == jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    def head(h):
+        state = state_ref[h]
+        for ref in kept:
+            ref[h] = state
+        held = state.astype(dt)
+        delta = u_ref[h] - _dot(w_ref[h], held, NN, exact)
+        read = _dot(q_in_ref[h], held, NN, exact)
+        yield
+        delta = delta.astype(dt)
+        out = read + _dot(within_ref[h], delta, NN, exact)
+        o_ref[:, h * dv:(h + 1) * dv] = out.astype(o_ref.dtype)
+        state_ref[h] = state * decay_ref[h] + _dot(k_out_ref[h], delta, TN,
+                                                   exact)
+
+    _in_step(head(h) for h in range(heads))
+
+
+def _scan_bwd_kernel(u_ref, w_ref, within_ref, q_in_ref, k_out_ref,
+                     decay_ref, states_ref, do_ref, du_ref, dw_ref,
+                     dwithin_ref, dq_in_ref, dk_out_ref, ddecay_ref,
+                     carried_ref):
+    """The chunks last to first; ``carried_ref``: the cotangent of the
+    states a chunk hands on.  A cotangent that is a product's operand is
+    rounded to ``dtype``, as the transpose of ``_chunk_step`` rounds it;
+    sums of products stay float32."""
+    heads, dv, dt = u_ref.shape[0], u_ref.shape[2], w_ref.dtype
+    exact = dt == jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        carried_ref[...] = jnp.zeros_like(carried_ref)
+
+    def head(h):
+        state, d_next = states_ref[h], carried_ref[h]
+        held, d_handed = state.astype(dt), d_next.astype(dt)
+        d_out = do_ref[:, h * dv:(h + 1) * dv]
+        delta = u_ref[h] - _dot(w_ref[h], held, NN, exact)
+        d_delta = (_dot(within_ref[h], d_out, TN, exact)
+                   + _dot(k_out_ref[h], d_handed, NN, exact))
+        dq_in_ref[h] = _dot(d_out, held, NT, exact).astype(dt)
+        d_state = d_next * decay_ref[h] + _dot(q_in_ref[h], d_out, TN, exact)
+        ddecay_ref[h] = jnp.sum(
+            jnp.sum(d_next * state, axis=1, keepdims=True), axis=0,
+            keepdims=True)
+        yield
+        du_ref[h] = d_delta
+        delta, d_delta = delta.astype(dt), d_delta.astype(dt)
+        dw_ref[h] = (-_dot(d_delta, held, NT, exact)).astype(dt)
+        dwithin_ref[h] = _dot(d_out, delta, NT, exact).astype(dt)
+        dk_out_ref[h] = _dot(delta, d_handed, NT, exact).astype(dt)
+        carried_ref[h] = d_state - _dot(w_ref[h], d_delta, TN, exact)
+
+    _in_step(head(h) for h in range(heads))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _scan_program(mode, heads, interpret, u, w, within, q_in, k_out, decay,
+                  *rest):
+    """One ``pallas_call`` over ``(batch, H / heads, chunk)``, the chunks in
+    turn (backward: last to first) on the states a float32 VMEM scratch
+    carries.  Operands chunk-major as :func:`_call` writes them; ``rest``:
+    the kept states and ``o``'s cotangent."""
+    n, b, h, c, dv = u.shape
+    dk, dt = w.shape[-1], w.dtype
+    forward = mode != "backward"
+    at = (lambda l: l) if forward else (lambda l: n - 1 - l)
+    per_chunk = lambda rows, d, kind: (
+        pl.BlockSpec((None, None, heads, rows, d),
+                     lambda i, j, l: (at(l), i, j, 0, 0)),
+        jax.ShapeDtypeStruct((n, b, h, rows, d), kind))
+    # o: 128-lane column blocks of (B, S, H d_v), as the gated norm reads it
+    tokens = (pl.BlockSpec((None, c, heads * dv),
+                           lambda i, j, l: (i, at(l), j)),
+              jax.ShapeDtypeStruct((b, n * c, h * dv), dt))
+    operands = [per_chunk(c, dv, jnp.float32),    # u
+                per_chunk(c, dk, dt),             # w
+                per_chunk(c, c, dt),              # within
+                per_chunk(c, dk, dt),             # q e^gamma
+                per_chunk(c, dk, dt),             # k e^(gamma_C - gamma)
+                per_chunk(1, 1, jnp.float32)]     # e^gamma_C
+    states = per_chunk(dk, dv, jnp.float32)
+    if forward:
+        kernel, name, ins = _scan_fwd_kernel, "delta_scan_fwd", operands
+        outs = [tokens, states] if mode == "keep" else [tokens]
+    else:
+        kernel, name = _scan_bwd_kernel, "delta_scan_bwd"
+        ins, outs = operands + [states, tokens], operands
+    arrays = (u, w, within, q_in, k_out, decay) + rest
+    moved = sum(a.size * a.dtype.itemsize for a in arrays) + sum(
+        out.size * out.dtype.itemsize for _, out in outs)
+    return pl.pallas_call(
+        kernel,
+        grid=(b, h // heads, n),
+        in_specs=[spec for spec, _ in ins],
+        out_specs=[spec for spec, _ in outs],
+        out_shape=[out for _, out in outs],
+        scratch_shapes=[pltpu.VMEM((heads, dk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * n * h * c * dv * (
+                3 * dk + c if forward else 7 * dk + 2 * c),
+            transcendentals=0, bytes_accessed=moved),
+        interpret=interpret,
+        name=name,
+    )(*arrays)
+
+
+def _scan_call(mode, interpret, u, w, *rest):
+    """:func:`_scan_program` at the most heads that fit."""
+    (_, _, h, c, dv), dk = u.shape, w.shape[-1]
+    return _scan_program(mode, _heads(h, c, dk, dv, w.dtype.itemsize, mode),
+                         interpret, u, w, *rest)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _scan(u, w, within, q_in, k_out, decay, interpret):
+    return _scan_call("forward", interpret, u, w, within, q_in, k_out,
+                      decay)[0]
+
+
+def _scan_fwd(u, w, within, q_in, k_out, decay, interpret):
+    operands = (u, w, within, q_in, k_out, decay)
+    o, states = _scan_call("keep", interpret, *operands)
+    return o, operands + (states,)
+
+
+def _scan_bwd(interpret, residuals, d_o):
+    return tuple(_scan_call("backward", interpret, *residuals, d_o))
+
+
+_scan.defvjp(_scan_fwd, _scan_bwd)
+
+
+def between_chunks(u, w, within, q_in, k_out, decay, *,
+                   interpret: Optional[bool] = None):
+    """The recurrence between chunks, ``lax.scan(gated_delta._chunk_step)``
+    from a zero state, on what :func:`within_chunk` returns: ``u (N, B, H,
+    C, d_v)`` float32; ``w``, ``q_in``, ``k_out`` ``(N, B, H, C, d_k)`` and
+    ``within (N, B, H, C, C)`` in ``dtype``; ``decay = exp(gamma[..., -1:])``
+    ``(N, B, H, 1, 1)`` float32.  Returns ``o (B, N C, H d_v)`` in
+    ``dtype``, a head's columns side by side — differentiable w.r.t. all
+    six."""
+    return _scan(u, w, within, q_in, k_out, decay.astype(jnp.float32),
+                 ops_common.resolve_interpret(interpret))

@@ -59,17 +59,23 @@ def _value_and_grads(monkeypatch, kernels, inputs, **kw):
 TOLERANCE = {"float32": (1e-5, 1e-5), "bfloat16": (4e-3, 1.5e-2)}
 
 
-def _assert_both_paths_agree(monkeypatch, inputs, **kw):
-    want, want_grads = _value_and_grads(monkeypatch, False, inputs, **kw)
-    got, grads = _value_and_grads(monkeypatch, True, inputs, **kw)
-    out_tol, grad_tol = TOLERANCE[jnp.dtype(kw.get("dtype", "float32")).name]
+def _assert_close(dtype, names, got, grads, want, want_grads):
+    """Output and gradients within ``TOLERANCE[dtype]`` of the norms."""
+    out_tol, grad_tol = TOLERANCE[jnp.dtype(dtype).name]
     f32 = lambda x: x.astype(jnp.float32)
     gap = lambda a, b: float(jnp.linalg.norm(f32(a) - f32(b)))
     assert got.shape == want.shape and got.dtype == want.dtype
     assert gap(got, want) <= out_tol * float(jnp.linalg.norm(f32(want)))
-    for name, g, w in zip(NAMES, grads, want_grads):
+    for name, g, w in zip(names, grads, want_grads):
         assert g.shape == w.shape and g.dtype == w.dtype, name
         assert gap(g, w) <= grad_tol * float(jnp.linalg.norm(f32(w))), name
+
+
+def _assert_both_paths_agree(monkeypatch, inputs, **kw):
+    want, want_grads = _value_and_grads(monkeypatch, False, inputs, **kw)
+    got, grads = _value_and_grads(monkeypatch, True, inputs, **kw)
+    _assert_close(kw.get("dtype", "float32"), NAMES, got, grads, want,
+                  want_grads)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -85,6 +91,69 @@ def test_the_kernels_are_the_jnp_path(monkeypatch, seq, chunk, shared, group,
                      dtype=jnp.dtype(dtype))
     _assert_both_paths_agree(monkeypatch, inputs, chunk=chunk, group=group,
                              dtype=jnp.dtype(dtype))
+
+
+def _scan_value_and_grads(between, operands):
+    """``o`` and the six cotangents of ``between(*operands)`` as ONE compiled
+    program."""
+    def loss(*a):
+        o = between(*a)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32))), o
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(6)), has_aux=True))(*operands)
+    return o, grads
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("chunks", [1, 2, 4])
+@pytest.mark.parametrize("key_heads", [
+    2,      # programs of 2 heads (the tile), one or two of them
+    3])     # 3 heads: programs of 1; 6: of 2 — the tile does not divide 3
+@pytest.mark.parametrize("shared", [1, 2])
+def test_the_scan_kernels_are_the_scan(monkeypatch, shared, key_heads, chunks,
+                                       dtype):
+    """``between_chunks`` (``delta_scan_fwd`` / ``delta_scan_bwd`` under the
+    interpreter) against ``lax.scan(_chunk_step)`` on the SAME operands,
+    ``within_chunk``'s: ``o`` and all six cotangents."""
+    monkeypatch.setattr(delta_rule, "MAX_HEADS", 2)
+    dt, chunk = jnp.dtype(dtype), 16
+    q, k, v, g, beta = _inputs(chunks * chunk, key_heads=key_heads,
+                               shared=shared, seed=chunks + key_heads,
+                               dtype=dt)
+    *operands, gamma = delta_rule.within_chunk(q, k, v, g, beta, chunk=chunk,
+                                               dtype=dt)
+    operands.append(jnp.exp(gamma[..., -1:]))
+    (b, s, h, dv), dk = v.shape, k.shape[-1]
+
+    def by_scan(*operands):
+        _, out = jax.lax.scan(gated_delta._chunk_step(dt),
+                              jnp.zeros((b, h, dk, dv), jnp.float32),
+                              operands)
+        return jnp.moveaxis(out, (0, 3), (1, 2)).reshape(b, s, h * dv)
+
+    want, want_grads = _scan_value_and_grads(by_scan, operands)
+    got, grads = _scan_value_and_grads(delta_rule.between_chunks, operands)
+    assert got.dtype == dt and got.shape == (b, s, h * dv)
+    assert [d.dtype for d in grads] == [x.dtype for x in operands]
+    _assert_close(dt, "u w within q_in k_out decay".split(), got, grads,
+                  want, want_grads)
+
+
+@pytest.mark.parametrize("h,mode,heads", [
+    (32, "forward", 8), (32, "keep", 8), (32, "backward", 8),   # the cell's
+    (12, "forward", 6), (7, "backward", 7), (22, "keep", 2), (1, "keep", 1)])
+def test_a_scan_program_holds_the_most_heads_that_divide(h, mode, heads):
+    assert delta_rule._heads(h, 128, 128, 128, 2, mode) == heads
+
+
+def test_a_scan_program_holds_what_fits_vmem():
+    """Heads of 256, float32: the backward's blocks of 8 heads outgrow the
+    16 MiB scope, so it holds fewer than the forward."""
+    fit = lambda mode: delta_rule._heads(32, 128, 128, 256, 4, mode)
+    assert fit("backward") < fit("forward") <= delta_rule.MAX_HEADS
+    for mode in ("forward", "keep", "backward"):
+        assert delta_rule._scan_vmem_bytes(
+            128, 128, 256, fit(mode), 4, mode) <= delta_rule.VMEM_BYTES
 
 
 def test_the_kernels_at_the_published_tile(monkeypatch):

@@ -457,11 +457,27 @@ def test_chunked_delta_rule_scans_chunks_not_tokens(no_persistent_cache,
     assert bounds and max(bounds) == seq // 64
 
 
-def _rule_text(one_chip, monkeypatch, *, backend, chunk=128, heads=32,
-               key_heads=16, dk=128, dv=128, seq=4096):
+@pytest.fixture(scope="module")
+def gdn_texts():
+    """The compiled texts of the rule and of the Gated DeltaNet layer: one
+    compile for the tests that read the same program."""
+    return {}
+
+
+def _rule_text(one_chip, monkeypatch, *, backend, texts=None, **sizes):
     """The rule, forward and backward, compiled for the described v5e as
     ``backend`` would lower it (``chunked_delta_rule`` and the kernels'
-    ``interpret`` default both ask ``jax.default_backend()``)."""
+    ``interpret`` default both ask ``jax.default_backend()``); kept in
+    ``texts`` where one is given."""
+    key = ("rule", backend) + tuple(sorted(sizes.items()))
+    texts = {} if texts is None else texts
+    if key not in texts:
+        texts[key] = _compile_rule(one_chip, monkeypatch, backend, **sizes)
+    return texts[key]
+
+
+def _compile_rule(one_chip, monkeypatch, backend, chunk=128, heads=32,
+                  key_heads=16, dk=128, dv=128, seq=4096):
     from byol_tpu.models.gated_delta import chunked_delta_rule
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     like = lambda *shape, kind=jnp.bfloat16: jax.ShapeDtypeStruct(
@@ -478,14 +494,40 @@ def _rule_text(one_chip, monkeypatch, *, backend, chunk=128, heads=32,
 
 
 def test_delta_rule_kernels_at_the_published_sizes(no_persistent_cache,
-                                                   one_chip, monkeypatch):
+                                                   one_chip, monkeypatch,
+                                                   gdn_texts):
     """``[2, 4096, 32, 128]`` values on 16 key heads, chunk 128, bf16: the
     within-chunk stage is ``delta_wy_fwd`` (the forward, and again under the
     rule's own checkpoint) and ``delta_wy_bwd``; no triangular solve."""
-    text = _rule_text(one_chip, monkeypatch, backend="tpu")
+    text = _rule_text(one_chip, monkeypatch, backend="tpu", texts=gdn_texts)
     assert text.count("delta_wy_fwd") >= 2 and "delta_wy_bwd" in text
     assert "InvertDiagBlocks" not in text
     assert "triangular" not in text.lower()
+
+
+def _kernel_calls(text, name):
+    import re
+    return len(re.findall(rf"custom-call\([^\n]*/{name}/pallas_call", text))
+
+
+def test_delta_rule_scans_the_chunks_in_a_kernel_pair(no_persistent_cache,
+                                                      one_chip, monkeypatch,
+                                                      gdn_texts):
+    """At the published sizes the recurrence between chunks is
+    ``delta_scan_fwd`` (the forward, and again under the rule's own
+    checkpoint, where it keeps the states) and ONE ``delta_scan_bwd``: no
+    loop of ``S / C`` = 32 trips (the ``lax.map`` over the two groups
+    alone), and no float32 ``[.., 128, 128]`` state written into a stacked
+    array trip by trip, as the scan's autodiff kept it."""
+    import re
+    from scripts import hlo_bytes_by_scope
+    text = _rule_text(one_chip, monkeypatch, backend="tpu", texts=gdn_texts)
+    assert _kernel_calls(text, "delta_scan_fwd") >= 2
+    assert _kernel_calls(text, "delta_scan_bwd") == 1
+    bounds = hlo_bytes_by_scope.loop_bounds(hlo_bytes_by_scope.parse(text))
+    assert bounds and set(bounds) == {2}, bounds
+    assert not re.search(
+        r"= f32\[[\d,]*128,128\]\S* dynamic-update-slice\(", text)
 
 
 def test_delta_rule_keeps_its_squares_in_the_kernels(no_persistent_cache,
@@ -520,10 +562,19 @@ def test_delta_rule_falls_back_to_jax_numpy(no_persistent_cache, one_chip,
     assert "delta_wy" not in text and "tpu_custom_call" not in text
 
 
-def _gdn_layer_text(one_chip, monkeypatch, *, width):
+def _gdn_layer_text(one_chip, monkeypatch, *, width, texts=None):
     """``qwen3next_train_b4_s4096``'s Gated DeltaNet layer (16 key and 32
     value heads, four taps, chunk 128) at heads ``width`` wide over ``[2,
-    4096, 2048]`` in bf16, lowered as on a TPU, forward and backward."""
+    4096, 2048]`` in bf16, lowered as on a TPU, forward and backward; kept
+    in ``texts`` where one is given."""
+    texts = {} if texts is None else texts
+    if ("layer", width) not in texts:
+        texts["layer", width] = _compile_gdn_layer(one_chip, monkeypatch,
+                                                   width)
+    return texts["layer", width]
+
+
+def _compile_gdn_layer(one_chip, monkeypatch, width):
     from byol_tpu.models import decoder_trunk, gated_delta
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     z = decoder_trunk.QWEN3_NEXT_80B_A3B
@@ -562,14 +613,15 @@ def _float32_stage_arrays(text):
 
 
 def test_gdn_elementwise_stages_are_one_kernel_each(no_persistent_cache,
-                                                    one_chip, monkeypatch):
+                                                    one_chip, monkeypatch,
+                                                    gdn_texts):
     """At the published widths the convolution with its SiLU and the gated
     norm are one kernel forward and one backward; no padded copy of the
     convolution's input and no float32 array of a stage's size outside
     them; and they read their columns out of the ONE product ``[q | k | v |
     z]``: no activation is ever held per key head, ``[.., 16, 768]``."""
     import re
-    text = _gdn_layer_text(one_chip, monkeypatch, width=128)
+    text = _gdn_layer_text(one_chip, monkeypatch, width=128, texts=gdn_texts)
     calls = [len(re.findall(rf"custom-call\([^\n]*{name}", text))
              for name in _GDN_PASSES]
     assert calls == [1, 1, 1, 1], calls
@@ -577,6 +629,42 @@ def test_gdn_elementwise_stages_are_one_kernel_each(no_persistent_cache,
     assert not _float32_stage_arrays(text), _float32_stage_arrays(text)[:3]
     assert "bf16[2,4096,12288]" in text
     assert not re.search(r"\[(4096,\d+|\d+,4096),16,768\]", text)
+
+
+def _reads_straight_from(text, reader, writer):
+    """Whether the ONE call of the kernel ``reader`` takes an operand that
+    IS a result of the kernel ``writer``: nothing on the way but
+    ``get-tuple-element`` and ``bitcast`` — no ``copy``, ``transpose`` or
+    fusion lays the array out again."""
+    from scripts import hlo_bytes_by_scope
+    comps = hlo_bytes_by_scope.parse(text)
+    rows = {row[0]: row for row in comps[comps[None]]}
+    is_call = lambda row, kernel: (row[2] == "custom-call"
+                                   and f"/{kernel}/pallas_call" in row[4])
+    (call,) = [row for row in rows.values() if is_call(row, reader)]
+    for name in call[3]:
+        while rows[name][2] in ("get-tuple-element", "bitcast"):
+            name = rows[name][3][0]
+        if is_call(rows[name], writer):
+            return True
+    return False
+
+
+def test_gdn_rule_output_meets_the_norm_where_it_lies(no_persistent_cache,
+                                                      one_chip, monkeypatch,
+                                                      gdn_texts):
+    """``delta_scan_fwd`` writes ``o`` as column blocks of ``[B, S, H d_v]``
+    and ``gated_norm_fwd`` reads that array itself; in the backward
+    ``delta_scan_bwd`` reads ``gated_norm_bwd``'s ``d_out`` itself: NO
+    ``transpose`` or ``copy`` of the rule's output between the two, in
+    either direction (the scan's ``[N, B, H, C, d]`` turned there in bf16,
+    7.4 ms a step: PERF.md section 5, PR 47)."""
+    text = _gdn_layer_text(one_chip, monkeypatch, width=128, texts=gdn_texts)
+    assert _reads_straight_from(text, "gated_norm_fwd", "delta_scan_fwd")
+    assert _reads_straight_from(text, "delta_scan_bwd", "gated_norm_bwd")
+    # the reader sees a copy where there is one: the convolution's result
+    # is cut and normalised on its way to ``delta_wy_fwd``
+    assert not _reads_straight_from(text, "delta_wy_fwd", "conv_silu_fwd")
 
 
 def test_gdn_elementwise_stages_fall_back_to_jax_numpy(no_persistent_cache,
