@@ -281,7 +281,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "the other chips would add is left out")
     x.add_argument("--trunk-depth", type=str, default="",
                    help="decoder trunk: 'D+S' builds D leading dense and S "
-                        "expert layers instead of the published depth")
+                        "expert layers instead of the published depth; "
+                        "'A-B' builds the published layers A to B (both "
+                        "counted), each with its published role")
     x.add_argument("--data-backend", type=str, default="tf",
                    choices=("tf", "native", "device"),
                    help="augmentation pipeline: tf.data host, native C++ "

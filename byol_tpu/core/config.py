@@ -125,7 +125,8 @@ class ModelConfig:
                                         # (',vocab=m,heads=m': a part that
                                         # divides over m of the n)
     trunk_depth: str = ""               # decoder trunk: 'D+S' builds D
-                                        # leading dense and S expert layers;
+                                        # leading dense and S expert layers,
+                                        # 'A-B' published layers A to B;
                                         # '' = the published depth
 
 

@@ -44,7 +44,21 @@ travels.  Mechanisms nothing else in ``models/`` has:
   (ops/key_selection.py) — and which sows a loss of its own, the KL that
   teaches the scorer the core's attention (``LAYER_LOSS``; the train step
   adds whatever a layer sows there); with it an expert layer WITHOUT a
-  shared expert.
+  shared expert;
+- **a decoder-hybrid-decoder stack** (SambaY, arXiv 2507.06607: sizes that
+  carry ``hybrid_decoder``): five mixers by a layer's PUBLISHED index —
+  :class:`SelectiveStateSpace` (Mamba-1: a diagonal recurrence over the
+  sequence on a ``[channels, state]`` state a row, ops/selective_scan.py);
+  :class:`DifferentialAttention` (arXiv 2410.05258: the difference of two
+  softmaxes, ``lambda`` learned) under a band of ``window`` keys
+  (``window_tiles``: only the tiles the band touches are formed), under
+  the full triangle, and as CROSS attention on an earlier layer's keys and
+  values; :class:`GatedMemoryUnit`, a gate on an earlier layer's scan
+  output.  With it **layers that hand tensors to later, non-adjacent
+  layers** (``TrunkLayer`` takes and returns what is ``carried`` beside the
+  streams), ``LayerNorm`` with a bias, no positional encoding at all, and a
+  trunk WITHOUT an expert layer (``first_k_dense_replace =
+  num_hidden_layers``: nothing sows ``ROUTING``).
 
 :class:`LayerShare` states ONCE which of the ``of`` chips that share a layer
 this one is; heads, experts and vocabulary rows held follow from it — and
@@ -67,7 +81,10 @@ its phases so the compile cache keys them (training/steps.py).  Each routing
 layer sows ``[rows held, largest load, mean load, rows dropped]`` into the
 ``ROUTING`` collection; the train step sums them over layers.  A
 sparse-attention layer sows ``[causal pairs, selected pairs]`` into
-``SELECTION`` beside them.
+``SELECTION`` beside them, a state-space layer ``STATE_SPACE_FIELDS`` into
+``STATE_SPACE`` and a differential-attention layer ``DIFFERENTIAL_FIELDS``
+into ``DIFFERENTIAL`` (each with a count of the layers that wrote, so that
+the step's sum over layers becomes their mean).
 """
 from __future__ import annotations
 
@@ -83,11 +100,12 @@ import jax.numpy as jnp
 from byol_tpu.core import remat as remat_lib
 from byol_tpu.models.gated_delta import (GatedDeltaNet, GatedDeltaSizes,
                                           causal_conv)
-from byol_tpu.ops import expert_routing, key_selection, sum_copies
+from byol_tpu.ops import (expert_routing, key_selection, selective_scan,
+                          sum_copies)
 from byol_tpu.ops.attention import (block_diffusion_tiles,
                                     blockwise_causal_attention,
                                     dense_attention, kept_probabilities,
-                                    selected_attention)
+                                    selected_attention, window_tiles)
 
 _MOE_SCOPES = ("moe/route", "moe/experts", "moe/experts/combine",
                "moe/shared")
@@ -99,6 +117,17 @@ SPARSE_SCOPES = ("dsa", "dsa/index", "dsa/select", "dsa/core",
 SHORTCONV_SCOPES = ("shortconv", "shortconv/proj", "shortconv/core", "gqa",
                     "gqa/core") + _MOE_SCOPES + ("ffn",)
 BLOCKDIFF_SCOPES = ("blockdiff", "blockdiff/core") + _MOE_SCOPES
+SAMBAY_SCOPES = ("ssm", "ssm/proj", "ssm/conv", "ssm/scan", "ssm/gate",
+                 "diff", "diff/core", "gmu", "ffn")
+# a trunk's scopes by the mixers its layers may have: the first set that
+# holds them all
+SCOPES_BY_MIXERS = (
+    (frozenset({"mla"}), TRACE_SCOPES),
+    (frozenset({"shortconv", "gqa"}), SHORTCONV_SCOPES),
+    (frozenset({"gdn", "gqa"}), HYBRID_SCOPES),
+    (frozenset({"dsa"}), SPARSE_SCOPES),
+    (frozenset({"blockdiff"}), BLOCKDIFF_SCOPES),
+    (frozenset({"ssm", "swa", "diff", "gmu", "xattn"}), SAMBAY_SCOPES))
 # the expert layer's fallback (a step whose load passes twice the nominal
 # one) forms its rows whole under this size and in slabs from it on
 WHOLE_FALLBACK_BYTES = 1 << 29
@@ -107,6 +136,10 @@ ROUTING_FIELDS = ("rows_held", "load_max", "load_mean", "rows_dropped")
 SELECTION = "selection"              # ... of the key-selection counters
 SELECTION_FIELDS = ("causal_pairs", "selected_pairs")
 LAYER_LOSS = "layer_loss"            # ... of the scalar losses layers add
+STATE_SPACE = "state_space"          # ... of a selective scan's step sizes
+STATE_SPACE_FIELDS = ("dt_max", "dt_mean", "decay_min", "layers")
+DIFFERENTIAL = "differential"        # ... of differential attention's lambda
+DIFFERENTIAL_FIELDS = ("lambda_mean", "layers")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,6 +173,23 @@ class SparseAttentionSizes:
     index_head_dim: int              # of the indexer's ONE key head too
     topk: int                        # keys a query keeps
     block: int = 512                 # q_chunk_size = kv_chunk_size
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridDecoderSizes:
+    """The mixers of a decoder-hybrid-decoder stack (SambaY): differential
+    attention, and Mamba-1 at ``expand x hidden`` channels."""
+
+    num_heads: int                   # query heads; PAIRS of them attend
+    num_kv_heads: int
+    head_dim: int
+    window: int                      # keys a self-decoder attention sees
+    state: int                       # d_state
+    conv_taps: int                   # d_conv
+    expand: int
+    dt_rank: int
+    block: int = 512                 # the program's own: keys a tile
+    chunk: int = selective_scan.CHUNK    # ... steps between border states
 
 
 @dataclasses.dataclass(frozen=True, kw_only=True)
@@ -186,6 +236,13 @@ class TrunkSizes:
     # layer's mixer is then ``gated_attention`` under the block-diffusion
     # training mask ('blockdiff') and a row of ids is ``[noised | clean]``
     diffusion_block: int = 0
+    # a decoder-hybrid-decoder stack: ``layer_mixers`` lists 'ssm' | 'swa' |
+    # 'diff' | 'gmu' | 'xattn' (:func:`hybrid_decoder_mixers`), every norm a
+    # LayerNorm with a bias, no position enters anywhere
+    hybrid_decoder: Optional[HybridDecoderSizes] = None
+    # the PUBLISHED index of every layer built, in order; () = 0, 1, 2, ...
+    # (a cut keeps it: differential attention's lambda_0 reads it)
+    layer_index: Tuple[int, ...] = ()
     scoring_func: str = "sigmoid"    # 'sigmoid' (noaux_tc bias) | 'softmax'
     shared_expert_gate: bool = False     # sigmoid(x w_s) on the shared expert
     zero_centred_norm: bool = False      # gains are 1 + w, w from zeros
@@ -215,17 +272,71 @@ class TrunkSizes:
         return "gqa" if (layer + 1) % self.full_attention_interval == 0 \
             else "gdn"
 
+    def published_index(self, layer: int) -> int:
+        return self.layer_index[layer] if self.layer_index else layer
+
     def with_depth(self, dense: int, sparse: int) -> "TrunkSizes":
         """The same trunk cut to its first ``dense`` dense layers and its
-        first ``sparse`` expert layers.  A listed pattern keeps the mixer of
-        each layer kept, by its PUBLISHED index."""
+        first ``sparse`` expert layers (``--trunk-depth D+S``)."""
         first = self.first_k_dense_replace
-        kept = tuple(range(dense)) + tuple(range(first, first + sparse))
+        return self._kept(tuple(range(dense))
+                          + tuple(range(first, first + sparse)))
+
+    def with_layers(self, first: int, last: int) -> "TrunkSizes":
+        """The same trunk cut to its published layers ``first`` to ``last``,
+        both counted (``--trunk-depth A-B``)."""
+        return self._kept(tuple(range(first, last + 1)))
+
+    def cut(self, text: str) -> "TrunkSizes":
+        """``--trunk-depth``: ``D+S`` (:meth:`with_depth`) or ``A-B``
+        (:meth:`with_layers`)."""
+        try:
+            a, b = (int(t) for t in text.split("+" if "+" in text else "-"))
+        except ValueError:
+            raise ValueError(
+                f"trunk depth {text!r} is not 'D+S' (the first D dense and "
+                "the first S expert layers) or 'A-B' (published layers A to "
+                "B)") from None
+        return self.with_depth(a, b) if "+" in text else self.with_layers(a, b)
+
+    def _kept(self, kept: Tuple[int, ...]) -> "TrunkSizes":
+        """The layers ``kept`` (published indices of an uncut trunk).  A
+        listed pattern keeps the mixer of each layer kept, by its PUBLISHED
+        index; every layer keeps that index (``layer_index``) and whether it
+        is dense."""
+        if self.layer_index or not kept or any(
+                not 0 <= i < self.num_hidden_layers for i in kept):
+            raise ValueError(
+                f"layers {kept} are not layers of an uncut trunk of "
+                f"{self.num_hidden_layers}")
         return dataclasses.replace(
-            self, num_hidden_layers=dense + sparse,
-            first_k_dense_replace=dense,
+            self, num_hidden_layers=len(kept),
+            first_k_dense_replace=sum(
+                i < self.first_k_dense_replace for i in kept),
+            layer_index=kept,
             layer_mixers=tuple(self.layer_mixers[i] for i in kept)
             if self.layer_mixers else ())
+
+
+def hybrid_decoder_mixers(layers: int, period: int = 2) -> Tuple[str, ...]:
+    """The mixer of every published layer of a decoder-hybrid-decoder stack
+    of ``layers`` layers (the public ``phi4flash`` modelling code,
+    ``mb_per_layer = period``): layer ``i`` is of the Mamba kind where ``i %
+    period == 0`` and of the attention kind elsewhere.  The first half is the
+    SELF-DECODER: Mamba ('ssm'), attention under the band ('swa').  Layer
+    ``layers / 2`` is the Mamba layer whose scan output is kept, the
+    attention layer after it the ONE full attention layer ('diff'), whose
+    keys and values are kept.  From there on the CROSS-DECODER: a gated
+    memory unit on the kept scan output ('gmu': no scan), cross attention on
+    the kept keys and values ('xattn': a query projection only)."""
+    half = layers // 2
+
+    def mixer(i):
+        if i % period == 0:
+            return "ssm" if i <= half else "gmu"
+        return "swa" if i < half else "diff" if i < half + period \
+            else "xattn"
+    return tuple(mixer(i) for i in range(layers))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -351,8 +462,37 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype)
 
 
+class LayerNorm(nn.Module):
+    """``(x - mean) / sqrt(var + eps) * scale + bias``, statistics in
+    float32."""
+
+    eps: float = 1e-5
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],),
+                          jnp.float32)
+        x32 = x.astype(jnp.float32)
+        centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        y = centred * jax.lax.rsqrt(
+            jnp.mean(jnp.square(centred), axis=-1, keepdims=True) + self.eps)
+        return (y * scale + bias).astype(self.dtype)
+
+
 def _dense(features, dtype, name):
     return nn.Dense(features, use_bias=False, dtype=dtype, name=name)
+
+
+def _trunk_norm(sizes, dtype, name):
+    """The norm of a trunk's residual stream: RMSNorm, or, in a
+    decoder-hybrid-decoder stack, LayerNorm with a bias."""
+    if sizes.hybrid_decoder is not None:
+        return LayerNorm(sizes.rms_norm_eps, dtype, name=name)
+    return RMSNorm(sizes.rms_norm_eps, dtype, sizes.zero_centred_norm,
+                   name=name)
 
 
 class LatentAttention(nn.Module):
@@ -601,6 +741,158 @@ class SparseAttention(nn.Module):
                     scale=dh ** -0.5, block=blk), selected, s))
         out = out[:, :, :s].transpose(0, 2, 1, 3)
         return _dense(d, dt, "o")(out.reshape(b, s, z.num_heads * dh))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Mamba's own: the inverse softplus of a step ``~ logU(1e-3, 0.1)``."""
+    step = jnp.exp(jax.random.uniform(key, shape, dtype) * (
+        math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+class SelectiveStateSpace(nn.Module):
+    """A Mamba-1 mixer (arXiv 2312.00752; the public ``phi4flash`` modelling
+    code): ``[a | z] = x W_in``; ``u = silu(conv(a) + b_c)`` (depthwise,
+    causal, zeros before the row); ``[dt | B | C] = u W_x``; ``delta =
+    softplus(dt W_dt + b_dt)``; ``A = -exp(A_log)``; the selective scan
+    (ops/selective_scan.py) gives ``m``; the mixer's output is ``(m *
+    silu(z)) W_out``.  Returns the output AND ``m``, before its gate: what
+    the gated memory units of later layers read.  ``delta``, ``A``, the
+    state and the scan's sums are float32."""
+
+    sizes: HybridDecoderSizes
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        z, dt = self.sizes, self.dtype
+        inner, n = z.expand * x.shape[-1], z.state
+        f32 = lambda t: t.astype(jnp.float32)
+        with jax.named_scope("proj"):
+            mixed = _dense(2 * inner, dt, "in_proj")(x)
+        with jax.named_scope("conv"):
+            taps = self.param("taps", nn.initializers.lecun_normal(),
+                              (z.conv_taps, inner), jnp.float32)
+            conv_bias = self.param("conv_bias", nn.initializers.zeros,
+                                   (inner,), jnp.float32)
+            u = nn.silu(f32(causal_conv(mixed[..., :inner], taps.astype(dt)))
+                        + conv_bias).astype(dt)
+        with jax.named_scope("proj"):
+            low = _dense(z.dt_rank + 2 * n, dt, "x_proj")(u)
+            dt_bias = self.param("dt_bias", _dt_bias_init, (inner,),
+                                 jnp.float32)
+            delta = jax.nn.softplus(f32(_dense(inner, dt, "dt_proj")(
+                low[..., :z.dt_rank])) + dt_bias)
+        with jax.named_scope("scan"):
+            a_log = self.param(
+                "A_log", lambda *_: jnp.log(jnp.broadcast_to(
+                    jnp.arange(1, n + 1, dtype=jnp.float32), (inner, n))))
+            skip = self.param("D", nn.initializers.ones, (inner,),
+                              jnp.float32)
+            a = -jnp.exp(a_log)
+            m = selective_scan.selective_scan(
+                u, delta, a, f32(low[..., z.dt_rank:z.dt_rank + n]),
+                f32(low[..., z.dt_rank + n:]), skip, chunk=z.chunk)
+            # the smallest exp(delta A): where a state forgets at once
+            fastest = jnp.min(delta * jnp.min(a, axis=1))
+            self.sow(STATE_SPACE, "steps", jnp.stack([
+                jnp.max(delta), jnp.mean(delta), jnp.exp(fastest),
+                jnp.ones((), jnp.float32)]))
+        with jax.named_scope("gate"):
+            gated = (m * nn.silu(f32(mixed[..., inner:]))).astype(dt)
+        with jax.named_scope("proj"):
+            return _dense(x.shape[-1], dt, "out_proj")(gated), m.astype(dt)
+
+
+class GatedMemoryUnit(nn.Module):
+    """``(m * silu(x W_in)) W_out``, ``m`` an earlier layer's scan output,
+    position by position (SambaY, arXiv 2507.06607 section 2): no scan, no
+    convolution."""
+
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, m):
+        dt = self.dtype
+        gate = _dense(m.shape[-1], dt, "in_proj")(x)
+        gated = (m.astype(jnp.float32)
+                 * nn.silu(gate.astype(jnp.float32))).astype(dt)
+        return _dense(x.shape[-1], dt, "out_proj")(gated)
+
+
+def lambda_init(layer: int) -> float:
+    """Differential attention's ``lambda_0`` of a layer, by its PUBLISHED
+    index (arXiv 2410.05258 section 2.1)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+class DifferentialAttention(nn.Module):
+    """Differential attention (arXiv 2410.05258, as the public ``phi4flash``
+    modelling code runs it on grouped heads): the ``H`` query heads are ``H /
+    2`` PAIRS ``(q1_i, q2_i)``, the ``Hkv`` key heads ``Hkv / 2`` pairs, the
+    value heads ``Hkv / 2`` heads twice as wide, ``[v1_j | v2_j]``; pair
+    ``i`` reads key/value pair ``i // (H / Hkv)``; ``o_i = softmax(q1_i
+    k1_j^T / sqrt(d)) v_j - lambda softmax(q2_i k2_j^T / sqrt(d)) v_j``,
+    ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_0``; ``o_i <- (1 -
+    lambda_0) rmsnorm(o_i)`` with a gain of the doubled width; the pairs'
+    outputs are read as ``H`` heads again and go through ``o``.  No rotary,
+    no position.  ``window`` > 0: a query sees the ``window`` latest keys
+    (``window_tiles``); 0: every causal key.  ``kv``: None — this layer
+    projects its own keys and values and hands them on — or the ``(k, v)``
+    an earlier layer handed on (CROSS attention: a query projection only).
+
+    The core is ONE call of ``blockwise_causal_attention``: the two
+    softmaxes of a pair are two of its key heads (``Hkv`` of them, keys as
+    they lie) on the pair's value head, repeated; the query heads are turned
+    ``(pair, softmax, query of the group)`` for it.  Statistics and
+    ``lambda`` float32."""
+
+    sizes: HybridDecoderSizes
+    layer: int                       # PUBLISHED index: lambda_0 reads it
+    window: int = 0
+    eps: float = 1e-5
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, kv=None):
+        z, dt = self.sizes, self.dtype
+        b, s, d = x.shape
+        h, hkv, dh = z.num_heads, z.num_kv_heads, z.head_dim
+        pairs, group = hkv // 2, h // hkv
+        if kv is None:
+            qkv = _dense((h + 2 * hkv) * dh, dt, "qkv")(x)
+            q = qkv[..., :h * dh]
+            k = qkv[..., h * dh:(h + hkv) * dh].reshape(
+                b, s, hkv, dh).transpose(0, 2, 1, 3)
+            v = qkv[..., (h + hkv) * dh:].reshape(
+                b, s, pairs, 2 * dh).transpose(0, 2, 1, 3)
+        else:
+            q = _dense(h * dh, dt, "q")(x)
+            k, v = kv
+        vector = lambda name: self.param(
+            name, nn.initializers.normal(stddev=0.1), (dh,), jnp.float32)
+        lambda_0 = lambda_init(self.layer)
+        lam = (jnp.exp(jnp.sum(vector("lambda_q1") * vector("lambda_k1")))
+               - jnp.exp(jnp.sum(vector("lambda_q2") * vector("lambda_k2")))
+               + lambda_0)
+        self.sow(DIFFERENTIAL, "lambda",
+                 jnp.stack([lam, jnp.ones((), jnp.float32)]))
+        with jax.named_scope("core"):
+            # query head 2 (group j + g) + c -> (j, c, g)
+            q = q.reshape(b, s, pairs, group, 2, dh).transpose(
+                0, 2, 4, 3, 1, 5).reshape(b, h, s, dh)
+            tiles = None if not self.window or self.window >= s else \
+                window_tiles(-(-s // z.block), self.window, z.block)
+            out = blockwise_causal_attention(
+                q, k, jnp.repeat(v, 2, axis=1), scale=dh ** -0.5,
+                block=z.block, tiles=tiles)
+            out = out.reshape(b, pairs, 2, group, s, 2 * dh).astype(
+                jnp.float32)
+            out = out[:, :, 0] - lam * out[:, :, 1]
+        out = RMSNorm(self.eps, dt, name="subln")(out) * jnp.asarray(
+            1.0 - lambda_0, dt)
+        out = out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * dh)
+        return _dense(d, dt, "o")(out), (k, v)
 
 
 class GatedMLP(nn.Module):
@@ -945,22 +1237,38 @@ def _write_streams(streams, h_res, h_post, y):
 class TrunkLayer(nn.Module):
     """A token mixer, then a dense FFN or the expert layer, each read from
     and written to the residual streams through its own hyper-connection —
-    or, with one stream, added to it."""
+    or, with one stream, added to it.  Beside the streams a layer takes and
+    returns what is ``carried`` from layer to layer, a dict of tensors that
+    LATER, non-adjacent layers read (a decoder-hybrid-decoder stack: ``m``, a
+    state-space layer's scan output, which every gated memory unit after it
+    reads; ``k`` and ``v``, the full attention layer's, which every cross
+    attention layer after it reads).  Under the remat wrap a carried tensor
+    is a block's output and later blocks' input: its cotangent is the sum
+    over the layers that read it.  Every other trunk carries ``{}``."""
 
     sizes: TrunkSizes
     share: LayerShare
     dense: bool
     dtype: jnp.dtype = jnp.float32
     mixer: str = "mla"               # TrunkSizes.mixer(i)
+    index: int = 0                   # TrunkSizes.published_index(i)
 
     @nn.compact
-    def __call__(self, streams):
+    def __call__(self, streams, carried):
         z, dt = self.sizes, self.dtype
         heads = lambda n: self.share.held(n, "attention heads")[1]
+        handed = dict(carried)
+
+        def taken(name):
+            if name not in carried:
+                raise ValueError(
+                    f"published layer {self.index} ({self.mixer!r}) reads "
+                    f"{name!r} of an earlier layer, and no layer built "
+                    "before it hands one on: the cut leaves that layer out")
+            return carried[name]
 
         def sublayer(streams, name, fn):
-            norm = RMSNorm(z.rms_norm_eps, dt, z.zero_centred_norm,
-                           name=f"{name}_norm")
+            norm = _trunk_norm(z, dt, f"{name}_norm")
             if len(streams) == 1:                   # plain residual
                 return (streams[0] + fn(norm(streams[0])),)
             with jax.named_scope("mhc"):
@@ -972,8 +1280,9 @@ class TrunkLayer(nn.Module):
                 return _write_streams(streams, h_res, h_post, y)
 
         def attention(x):
-            # ``gdn``, ``gqa``, ``blockdiff``, ``dsa`` and ``shortconv`` are
-            # modules named after their scope, as ``moe`` is
+            # ``gdn``, ``gqa``, ``blockdiff``, ``dsa``, ``shortconv``,
+            # ``ssm``, ``diff`` and ``gmu`` are modules named after their
+            # scope, as ``moe`` is
             if self.mixer == "gdn":
                 d = z.gated_delta
                 return GatedDeltaNet(
@@ -991,6 +1300,23 @@ class TrunkLayer(nn.Module):
             if self.mixer == "dsa":
                 return SparseAttention(z.sparse_attention, z.rms_norm_eps,
                                        dt, name="dsa")(x)
+            if self.mixer == "ssm":
+                out, handed["m"] = SelectiveStateSpace(
+                    z.hybrid_decoder, dt, name="ssm")(x)
+                return out
+            if self.mixer == "gmu":
+                return GatedMemoryUnit(dt, name="gmu")(x, taken("m"))
+            if self.mixer in ("swa", "diff", "xattn"):
+                # one scope for band, full and cross: the layer is in the path
+                a = z.hybrid_decoder
+                out, kv = DifferentialAttention(
+                    a, self.index, a.window if self.mixer == "swa" else 0,
+                    z.rms_norm_eps, dt, name="diff")(
+                        x, (taken("k"), taken("v"))
+                        if self.mixer == "xattn" else None)
+                if self.mixer == "diff":
+                    handed["k"], handed["v"] = kv
+                return out
             with jax.named_scope("mla"):
                 return LatentAttention(z, heads(z.num_attention_heads), dt,
                                        name="attn")(x)
@@ -1005,13 +1331,17 @@ class TrunkLayer(nn.Module):
 
         streams = sublayer(streams, "attn", attention)
         streams = sublayer(streams, "ffn", feed_forward)
-        return tuple(remat_lib.tag_block_out(x) for x in streams)
+        return tuple(remat_lib.tag_block_out(x) for x in streams), handed
 
 
 class DecoderTrunk(nn.Module):
     """Feature extractor: ``(B, S) int32 -> (B, hidden)``, the mean over the
     positions of the final-norm hidden states (a block-diffusion trunk: over
-    the noised half of its ``[noised | clean]`` rows)."""
+    the noised half of its ``[noised | clean]`` rows).  The embedding, then
+    the layers the sizes list — each a token mixer and a dense FFN or the
+    expert layer on the residual streams, under the remat wrap, and each
+    handed what earlier layers carry forward for it (``TrunkLayer``) — then
+    one more norm."""
 
     sizes: TrunkSizes
     share: LayerShare = LayerShare()
@@ -1021,14 +1351,10 @@ class DecoderTrunk(nn.Module):
 
     @property
     def trace_scopes(self) -> Tuple[str, ...]:
-        if "shortconv" in self.sizes.layer_mixers:
-            return SHORTCONV_SCOPES
-        if self.sizes.diffusion_block:
-            return BLOCKDIFF_SCOPES
-        if self.sizes.sparse_attention is not None:
-            return SPARSE_SCOPES
-        return HYBRID_SCOPES if self.sizes.full_attention_interval \
-            else TRACE_SCOPES
+        z = self.sizes
+        mixers = {z.mixer(i) for i in range(z.num_hidden_layers)}
+        return next(scopes for known, scopes in SCOPES_BY_MIXERS
+                    if mixers <= known)
 
     @property
     def feature_dim(self) -> int:
@@ -1056,15 +1382,18 @@ class DecoderTrunk(nn.Module):
         layer = remat_lib.wrap_block(
             TrunkLayer,
             remat_lib.resolve_policy_name(self.remat, self.remat_policy))
+        # what a layer hands to later, non-adjacent layers travels beside
+        # the streams (TrunkLayer): nothing, in most trunks
+        carried = {}
         for i in range(z.num_hidden_layers):
-            streams = layer(z, self.share, i < z.first_k_dense_replace,
-                            self.dtype, z.mixer(i),
-                            name=f"layer{i}")(streams)
+            streams, carried = layer(
+                z, self.share, i < z.first_k_dense_replace, self.dtype,
+                z.mixer(i), z.published_index(i),
+                name=f"layer{i}")(streams, carried)
         # exit: the streams are summed
         hidden = sum(x.astype(jnp.float32) for x in streams).astype(
             self.dtype)
-        hidden = RMSNorm(z.rms_norm_eps, self.dtype, z.zero_centred_norm,
-                         name="final_norm")(hidden)
+        hidden = _trunk_norm(z, self.dtype, "final_norm")(hidden)
         if z.diffusion_block:       # the NOISED half: what the loss reads
             hidden = hidden[:, :hidden.shape[1] // 2]
         return jnp.mean(hidden.astype(jnp.float32), axis=1).astype(self.dtype)
@@ -1259,3 +1588,38 @@ SHORTCONV_TINY = TrunkSizes(
         num_heads=4, num_kv_heads=2, head_dim=8, rotary_dim=8,
         rope_theta=1e6, block=8, output_gate=False, group=2),
     scoring_func="sigmoid", rms_norm_eps=1e-5)
+
+# Phi-4-mini-flash-reasoning, from its public config.json (``model_type:
+# phi4flash``; the architecture is SambaY, arXiv 2507.06607): 32 layers,
+# hidden 2,560, every layer dense (SwiGLU of 10,240, no bias), LayerNorm
+# with a bias at eps 1e-5, no positional encoding; ``mb_per_layer`` 2: even
+# layers of the Mamba kind (inner 5,120, state 16, 4 taps, ``dt_rank`` 160),
+# odd ones differential attention (40 query on 20 key/value heads of 64);
+# layers 0-15 the self-decoder (the attention under a band of 512 keys),
+# 16 and 17 the layers whose scan output and whose keys and values are kept,
+# 18-31 the cross-decoder (``hybrid_decoder_mixers``).  The tied LM head is
+# not built.
+PHI4_MINI_FLASH = TrunkSizes(
+    hidden_size=2560, num_hidden_layers=32, first_k_dense_replace=32,
+    intermediate_size=10240, n_routed_experts=0, moe_intermediate_size=0,
+    num_experts_per_tok=0, n_shared_experts=0, norm_topk_prob=False,
+    vocab_size=200064, layer_mixers=hybrid_decoder_mixers(32),
+    hybrid_decoder=HybridDecoderSizes(
+        num_heads=40, num_kv_heads=20, head_dim=64, window=512, state=16,
+        conv_taps=4, expand=2, dt_rank=160),
+    rms_norm_eps=1e-5)
+
+# The decoder-hybrid-decoder trunk at test size (tests/test_sambay_trunk.py):
+# 12 published layers — (ssm, swa) x 3 | ssm, diff | (gmu, xattn) x 2 — cut
+# ``5-9`` all five kinds, cut ``5-11`` two readers of each kept tensor; a
+# window of 8 keys at tiles of 8, so that at 32 positions the band forms 2 of
+# a row's 4 tiles.
+SAMBAY_TINY = TrunkSizes(
+    hidden_size=64, num_hidden_layers=12, first_k_dense_replace=12,
+    intermediate_size=96, n_routed_experts=0, moe_intermediate_size=0,
+    num_experts_per_tok=0, n_shared_experts=0, norm_topk_prob=False,
+    vocab_size=128, layer_mixers=hybrid_decoder_mixers(12),
+    hybrid_decoder=HybridDecoderSizes(
+        num_heads=4, num_kv_heads=2, head_dim=16, window=8, state=4,
+        conv_taps=4, expand=2, dt_rank=4, block=8, chunk=8),
+    rms_norm_eps=1e-5)

@@ -116,6 +116,8 @@ def _register_decoder_trunks() -> None:
     # lfm2_24b_a2b: huggingface.co/LiquidAI/LFM2-24B-A2B, config.json
     # joyai_llm_flash: huggingface.co/jdopensource/JoyAI-LLM-Flash, config.json
     # sdar_30b_a3b: huggingface.co/JetLM/SDAR-30B-A3B-Chat, config.json
+    # phi4_mini_flash:
+    # huggingface.co/microsoft/Phi-4-mini-flash-reasoning, config.json
     for name, sizes in (("xing4_29b_a4b", trunk_lib.XING4_29B_A4B),
                         ("decoder_trunk_tiny", trunk_lib.TINY),
                         ("qwen3_next_80b_a3b", trunk_lib.QWEN3_NEXT_80B_A3B),
@@ -127,13 +129,14 @@ def _register_decoder_trunks() -> None:
                         ("joyai_llm_flash", trunk_lib.JOYAI_LLM_FLASH),
                         ("latent_trunk_tiny", trunk_lib.LATENT_TINY),
                         ("sdar_30b_a3b", trunk_lib.SDAR_30B_A3B),
-                        ("blockdiff_trunk_tiny", trunk_lib.BLOCKDIFF_TINY)):
+                        ("blockdiff_trunk_tiny", trunk_lib.BLOCKDIFF_TINY),
+                        ("phi4_mini_flash", trunk_lib.PHI4_MINI_FLASH),
+                        ("sambay_tiny", trunk_lib.SAMBAY_TINY)):
         def factory(dtype=jnp.float32, small_inputs=False, _z=sizes,
                     layer_share="0/1", trunk_depth="", **kw):
             del small_inputs
             if trunk_depth:
-                dense, sparse = (int(t) for t in trunk_depth.split("+"))
-                _z = _z.with_depth(dense, sparse)
+                _z = _z.cut(trunk_depth)
             return trunk_lib.DecoderTrunk(
                 sizes=_z, share=trunk_lib.LayerShare.parse(layer_share),
                 dtype=dtype, **kw)

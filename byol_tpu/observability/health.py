@@ -44,6 +44,12 @@ Signals (one float32 each, ``len(HEALTH_FIELDS)`` total):
   largest and the mean load of a held expert, rows the expert product did
   not cover (always 0: the layer has no capacity) — each summed over the
   layers that route; 0 for a backbone without sparse experts.
+- ``ssm_dt_max`` / ``ssm_dt_mean`` / ``ssm_decay_min``: a selective scan's
+  step ``delta`` after its softplus (what decides whether the state
+  forgets) and the smallest ``exp(delta A)`` (underflow shows here first),
+  the mean over the state-space layers; ``diff_lambda_mean``: differential
+  attention's ``lambda``, the mean over its layers; 0 for a backbone with
+  neither.
 """
 from __future__ import annotations
 
@@ -75,10 +81,18 @@ HEALTH_FIELDS: Tuple[str, ...] = (
     "moe_load_max",
     "moe_load_mean",
     "moe_rows_dropped",
+    # a selective scan's step sizes and differential attention's lambda,
+    # means over the layers that have them (STATE_SPACE_FIELDS,
+    # DIFFERENTIAL_FIELDS); 0 elsewhere
+    "ssm_dt_max",
+    "ssm_dt_mean",
+    "ssm_decay_min",
+    "diff_lambda_mean",
 )
 
 # fields a packer may leave out (they read 0): what only some backbones have
-OPTIONAL_FIELDS = frozenset(k for k in HEALTH_FIELDS if k.startswith("moe_"))
+OPTIONAL_FIELDS = frozenset(
+    k for k in HEALTH_FIELDS if k.startswith(("moe_", "ssm_", "diff_")))
 
 _EPS = 1e-12
 
@@ -167,9 +181,10 @@ def health_stats(*, grads: Any, updates: Any, params: Any,
     live across the accumulation scan, defeating the scan's memory win).
     ``trust_ratios`` is ``optim.lars.trust_ratio_vector(grads, params_pre)``
     — the per-layer-group ratios the LARS transform applies.
-    ``routing`` maps the ``moe_*`` fields to the online forward's routing
-    counters (training/steps.py); a backbone that routes nothing passes
-    none and they read 0.
+    ``routing`` maps the ``OPTIONAL_FIELDS`` (``moe_*``, ``ssm_*``,
+    ``diff_*``) to the counters the online forward's layers sowed
+    (training/steps.py); a backbone without such layers passes none and
+    they read 0.
     """
     param_norm = global_norm(params)
     drift = global_norm(jax.tree_util.tree_map(

@@ -17,8 +17,9 @@ so the model swaps between them by name without re-plumbing:
                 kernel forward and one backward;
   ``blockwise`` (:func:`blockwise_causal_attention`, the decoder trunk's
                 grouped-query layers, gated or plain) — the same arithmetic
-                under a visibility rule known at trace time (causal; the
-                block-diffusion training mask), as a list of tile pairs
+                under a visibility rule known at trace time (causal; causal
+                under a band; the block-diffusion training mask), as a list
+                of tile pairs
                 with a kind each (:class:`TilePairs`), over blocks of keys
                 with a running max and sum, forward and backward, so that no
                 ``[S, S]`` array exists at any length and no tile without a
@@ -96,6 +97,7 @@ FULL = 0           # every pair
 NOT_AFTER = 1      # beta(key) <= beta(query); span 1: the causal diagonal
 BEFORE = 2         # beta(key) <  beta(query)
 SAME = 3           # beta(key) == beta(query)
+WITHIN = 4         # lo <= beta(query) - beta(key) <= hi, the PAIR's own bounds
 VISIBLE = {NOT_AFTER: lambda key, query: key <= query,
            BEFORE: lambda key, query: key < query,
            SAME: lambda key, query: key == query}
@@ -106,12 +108,15 @@ BOUNDS = {NOT_AFTER: (0, FAR), BEFORE: (1, FAR), SAME: (0, 0)}
 
 
 class TilePairs(NamedTuple):
-    """The list, hashable (it is static wherever it goes)."""
+    """The list, hashable (it is static wherever it goes).  ``bounds``: a
+    ``(lo, hi)`` a pair where the list has ``WITHIN`` pairs (a band), ``()``
+    otherwise."""
 
     q_of: Tuple[int, ...]
     k_of: Tuple[int, ...]
     kind: Tuple[int, ...]
     span: int = 1
+    bounds: Tuple[Tuple[int, int], ...] = ()
 
 
 def causal_pairs(blocks: int):
@@ -152,26 +157,59 @@ def block_diffusion_tiles(blocks: int, span: int) -> TilePairs:
     return TilePairs(*(tuple(column) for column in zip(*rows)), span=span)
 
 
+@functools.lru_cache(maxsize=None)
+def window_tiles(blocks: int, window: int, block: int) -> TilePairs:
+    """Causal attention under a band: a query at position ``t`` sees the
+    keys ``r`` with ``0 <= t - r < window``, over ``blocks`` tiles of
+    ``block`` rows.  Query tile ``i`` forms the key tiles ``i - d`` with ``d
+    block <= window + block - 2`` and no other: 2 of a row's 16 at a window
+    of one tile.  A tile ``d`` back holds a visible pair at every offset
+    where ``-d block <= offset(query) - offset(key) <= window - 1 - d
+    block``: ``FULL`` where no offset of the tile fails that, ``WITHIN``
+    with those bounds elsewhere (the diagonal among them: its upper bound
+    binds only under a window shorter than a tile).  Every row's last pair
+    is its own tile, so every row sees a key.  A window that covers the
+    row is :func:`causal_tiles`."""
+    if window < 1:
+        raise ValueError(f"a window of {window} keys")
+    rows = []
+    for i in range(blocks):
+        for j in range(max(0, i - (window + block - 2) // block), i + 1):
+            lo, hi = (j - i) * block, window - 1 + (j - i) * block
+            whole = lo <= 1 - block and hi >= block - 1
+            rows.append((i, j, FULL if whole else WITHIN, (lo, hi)))
+    q_of, k_of, kind, bounds = (tuple(column) for column in zip(*rows))
+    return TilePairs(q_of, k_of, kind, bounds=bounds)
+
+
 def _tile_rows(tiles: TilePairs):
-    """``[(query tile, [(key tile, kind), ..])]``, query tiles in order."""
-    rows = [(i, [(j, kind) for _, j, kind in group])
+    """``[(query tile, [(key tile, rule), ..])]``, query tiles in order; a
+    pair's rule is its kind or, ``WITHIN``, its ``(lo, hi)``."""
+    rules = [tiles.bounds[n] if kind == WITHIN else kind
+             for n, kind in enumerate(tiles.kind)]
+    rows = [(i, [(j, rule) for _, j, rule in group])
             for i, group in itertools.groupby(
-                zip(tiles.q_of, tiles.k_of, tiles.kind), key=lambda t: t[0])]
+                zip(tiles.q_of, tiles.k_of, rules), key=lambda t: t[0])]
     if [i for i, _ in rows] != list(range(len(rows))):
         raise ValueError("a query tile's pairs lie side by side, the query "
                          "tiles in order")
     return rows
 
 
-def _tile_scores(q_blk, k_blk, scale, kind, span):
-    """``(B, Hkv, G, bq, bk)`` float32 scores of one tile, what its kind
-    hides masked."""
+def _tile_scores(q_blk, k_blk, scale, rule, span):
+    """``(B, Hkv, G, bq, bk)`` float32 scores of one tile, what its rule (a
+    kind, or a ``WITHIN`` pair's ``(lo, hi)``) hides masked."""
     scores = jnp.einsum("bhgqd,bhkd->bhgqk", q_blk, k_blk,
                         preferred_element_type=jnp.float32) * scale
-    if kind != FULL:
+    if rule != FULL:
         query = (jnp.arange(q_blk.shape[-2]) // span)[:, None]
         key = (jnp.arange(k_blk.shape[-2]) // span)[None, :]
-        scores = jnp.where(VISIBLE[kind](key, query), scores, MASKED)
+        if isinstance(rule, tuple):
+            ahead = query - key
+            visible = (ahead >= rule[0]) & (ahead <= rule[1])
+        else:
+            visible = VISIBLE[rule](key, query)
+        scores = jnp.where(visible, scores, MASKED)
     return scores
 
 
@@ -271,8 +309,8 @@ def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
     shared by ``Hq / Hkv`` consecutive query heads and never repeated in
     memory; returns ``(B, Hq, S, Dv)``.  ``tiles``: the rule as a
     :class:`TilePairs` over tiles of ``block`` rows (None:
-    :func:`causal_tiles`; :func:`block_diffusion_tiles`); only the tiles it
-    lists are formed.  ``shared = (q_s (B, Hq, S, r), k_s (B, S, r))``
+    :func:`causal_tiles`; :func:`block_diffusion_tiles`;
+    :func:`window_tiles`); only the tiles it lists are formed.  ``shared = (q_s (B, Hq, S, r), k_s (B, S, r))``
     adds ``q_s . k_s`` to every score: a part of the head whose KEY is one
     vector for all heads (latent attention's rotary key), handed over once
     and never copied a head; ``scale`` defaults to ``(D + r)^-1/2``.

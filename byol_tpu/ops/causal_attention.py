@@ -99,7 +99,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from byol_tpu.ops.attention import (BOUNDS, FULL, NOT_AFTER, VISIBLE,
-                                    TilePairs, causal_tiles)
+                                    WITHIN, TilePairs, causal_tiles)
 from byol_tpu.ops.common import (LANES, MASKED, NN, NT, TN, VMEM_LIMIT_BYTES,
                                  dot, resolve_interpret)
 
@@ -189,11 +189,13 @@ def _flags(tiles: TilePairs):
         return None
     first = [n == 0 or q_of[n - 1] != i for n, i in enumerate(q_of)]
     last = first[1:] + [True]
-    return tuple(kind | FIRST * a | LAST * z
+    # a WITHIN pair's bounds come beside the flags: any masked kind does
+    return tuple((NOT_AFTER if kind == WITHIN else kind)
+                 | FIRST * a | LAST * z
                  for kind, a, z in zip(tiles.kind, first, last))
 
 
-def _step(pair, q_of_ref, k_of_ref, flags_ref):
+def _step(pair, q_of_ref, k_of_ref, flags_ref, bounds_refs=None):
     """``j, first, last, when, bounds``: the step's key tile, and as thunks
     (each use traces its own comparison) whether its pair is its query
     tile's first, its last, and ``when``: which of the tile's TWO bodies is
@@ -202,15 +204,20 @@ def _step(pair, q_of_ref, k_of_ref, flags_ref):
     elsewhere it is the pair's own and comes as ``bounds``, two scalars
     ``lo <= beta(query) - beta(key) <= hi`` — ONE masked body whatever the
     kinds in the list: a body a kind made the backward, five products a head
-    each, 47.5 ms a call where this one takes 19.2 (PERF.md section 6)."""
+    each, 47.5 ms a call where this one takes 19.2 (PERF.md section 6).  A
+    list with ``WITHIN`` pairs (a band) brings every pair's two scalars as
+    two more prefetched arrays (``bounds_refs``)."""
     i, j = q_of_ref[pair], k_of_ref[pair]
     if flags_ref is None:            # the lower triangle: see _flags
         return j, lambda: j == 0, lambda: j == i, {
             FULL: lambda: j < i, NOT_AFTER: lambda: j == i}, None
     flags = flags_ref[pair]
     kind = flags & 3
-    lo, hi = (sum(jnp.where(kind == k, bounds[n], 0)
-                  for k, bounds in BOUNDS.items()) for n in (0, 1))
+    if bounds_refs is not None:
+        lo, hi = (ref[pair] for ref in bounds_refs)
+    else:
+        lo, hi = (sum(jnp.where(kind == k, bounds[n], 0)
+                      for k, bounds in BOUNDS.items()) for n in (0, 1))
     return (j, lambda: (flags & FIRST) != 0, lambda: (flags & LAST) != 0,
             {FULL: lambda: kind == FULL, None: lambda: kind != FULL},
             (lo, hi))
@@ -275,9 +282,10 @@ def _taker(refs):
 
 
 def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
-                shared: bool, flagged: bool, span: int):
+                shared: bool, flagged: bool, span: int, bounded: bool = False):
     """Scores ``[keys, queries]``.  Refs: with a list that is not the lower
-    triangle its ``flags`` (scalar prefetch); ``q (G, bq, D)``; ``k (bk, D)``;
+    triangle its ``flags`` and, ``bounded``, its pairs' ``lo`` and ``hi``
+    (scalar prefetch); ``q (G, bq, D)``; ``k (bk, D)``;
     ``v (bk, Dv)``; with a selection ``keep (bq, bk)`` int8; with a shared
     part ``q_s (G, bq, r)``, ``k_s (bk, r)``; ``o (G, bq, Dv)``; ``lse (G,
     bq)``; scratch: every head's running max and sum, a lane row a head,
@@ -285,12 +293,13 @@ def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
     bias."""
     take = _taker(refs)
     flags_ref, = take(1, flagged)
+    bounds_refs = take(2) if bounded else None
     q_ref, k_ref, v_ref = take(3)
     keep_ref, = take(1, selected)
     qs_ref, ks_ref = take(2, shared)
     o_ref, lse_ref, top_ref, total_ref, acc_ref, bias_ref = take(6)
     _, first, last, when, bounds = _step(pl.program_id(2), q_of_ref,
-                                         k_of_ref, flags_ref)
+                                         k_of_ref, flags_ref, bounds_refs)
     group, _, dim = acc_ref.shape
 
     @pl.when(first())
@@ -334,7 +343,7 @@ def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
 
 
 def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
-                shared: bool, flagged: bool, span: int):
+                shared: bool, flagged: bool, span: int, bounded: bool = False):
     """Everything ``[keys, queries]``.  Refs: with a list that is not the
     lower triangle its ``flags``; ``q, dq (G, bq, D)``; ``dO (G,
     bq, Dv)``; ``k (bk, D)``; ``v (bk, Dv)``; with a selection ``keep (bq,
@@ -345,6 +354,7 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
     float32 ``dq`` (and ``dq_s``) of the query block and a tile's bias."""
     take = _taker(refs)
     flags_ref, = take(1, flagged)
+    bounds_refs = take(2) if bounded else None
     q_ref, k_ref, v_ref = take(3)
     keep_ref, = take(1, selected)
     qs_ref, ks_ref = take(2, shared)
@@ -354,7 +364,8 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
     dqs_acc_ref, = take(1, shared)
     bias_ref, = take(1)
     pair = pl.program_id(2)
-    j, first, last, when, bounds = _step(pair, q_of_ref, k_of_ref, flags_ref)
+    j, first, last, when, bounds = _step(pair, q_of_ref, k_of_ref, flags_ref,
+                                         bounds_refs)
     group, bk = q_ref.shape[0], k_ref.shape[0]
     keys = pl.ds(pl.multiple_of(j * bk, bk), bk)
 
@@ -419,7 +430,9 @@ def _call(forward, scale, block, interpret, tiles, q, k, v, keep, shared,
     if selected and flags is not None:
         raise ValueError("a selection comes in the lower triangle's layout")
     lists = (tiles.q_of, tiles.k_of) + (() if flags is None else (flags,))
-    # index maps: (batch, key head, pair, q_of, k_of[, flags])
+    if tiles.bounds:                    # a band: every pair's lo and hi
+        lists += tuple(zip(*tiles.bounds))
+    # index maps: (batch, key head, pair, q_of, k_of[, flags[, lo, hi]])
     rows = lambda w: pl.BlockSpec(
         (None, None, g, block, w),
         lambda n, h, p, qo, ko, *_: (n, h, 0, qo[p], 0))
@@ -469,7 +482,7 @@ def _call(forward, scale, block, interpret, tiles, q, k, v, keep, shared,
     return pl.pallas_call(
         functools.partial(kernel, scale=scale, selected=selected,
                           shared=bool(shared), flagged=flags is not None,
-                          span=tiles.span),
+                          span=tiles.span, bounded=bool(tiles.bounds)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(lists),
             grid=(b, hkv, len(tiles.q_of)),

@@ -43,10 +43,13 @@ PER_EXPERT = "per_expert"
 EXPERT_MODULE = "experts"
 # leaves that are gains, biases or buffers whatever their rank: the
 # hyper-connection scalars and static maps (``b_res`` is n x n), the
-# router's selection bias, every norm's scale
+# router's selection bias, every norm's scale; a selective state-space
+# layer's ``A_log`` (channels x state: the logarithm of a decay rate, which
+# Mamba's own training neither decays nor rescales) and the taps of its
+# depthwise convolution (taps x channels: a few numbers a channel)
 UNADAPTED_LEAVES = frozenset({
     "scale", "bias", "alpha_pre", "alpha_post", "alpha_res", "b_pre",
-    "b_post", "b_res", "e_score_correction_bias"})
+    "b_post", "b_res", "e_score_correction_bias", "A_log", "taps"})
 
 
 def default_exclusion_mask(params) -> Any:

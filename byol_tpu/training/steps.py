@@ -76,9 +76,11 @@ from jax.experimental.xla_metadata import set_xla_metadata
 from byol_tpu.core import rng as rng_lib
 from byol_tpu.core.precision import Policy, FP32
 from byol_tpu.data import device_augment
-from byol_tpu.models.decoder_trunk import (LAYER_LOSS, ROUTING,
-                                           ROUTING_FIELDS, SELECTION,
-                                           SELECTION_FIELDS)
+from byol_tpu.models.decoder_trunk import (DIFFERENTIAL,
+                                           DIFFERENTIAL_FIELDS, LAYER_LOSS,
+                                           ROUTING, ROUTING_FIELDS, SELECTION,
+                                           SELECTION_FIELDS, STATE_SPACE,
+                                           STATE_SPACE_FIELDS)
 from byol_tpu.objectives.byol_loss import loss_function
 from byol_tpu.objectives.metrics import cross_entropy, topk_accuracy
 from byol_tpu.observability import health as health_lib
@@ -184,7 +186,11 @@ class StepConfig:
 
 
 # The collections a backbone's layers may sow into during a training forward.
-SOWN = (ROUTING, SELECTION, LAYER_LOSS)
+SOWN = (ROUTING, SELECTION, LAYER_LOSS, STATE_SPACE, DIFFERENTIAL)
+# ... and those whose last field counts the layers (and views) that wrote:
+# the sum over them becomes their mean, under these prefixes
+MEANS_OVER_LAYERS = (("_ssm_", STATE_SPACE, STATE_SPACE_FIELDS),
+                     ("_diff_", DIFFERENTIAL, DIFFERENTIAL_FIELDS))
 
 
 def _forward_views(net, params, batch_stats, aug1, aug2, *, train: bool,
@@ -196,10 +202,12 @@ def _forward_views(net, params, batch_stats, aug1, aug2, *, train: bool,
     ``sown`` holds, for each collection of ``SOWN`` a layer of the backbone
     wrote to (models/decoder_trunk.py), the sum over layers and views of
     what it wrote: the routing counters (``ROUTING_FIELDS``), the
-    key-selection counters (``SELECTION_FIELDS``), and ``LAYER_LOSS``, the
-    scalar losses layers add to the step's — each a mean over the rows of
-    its forward, so two unfused views' are averaged.  A backbone that sows
-    nothing leaves it empty.
+    key-selection counters (``SELECTION_FIELDS``), a selective scan's step
+    sizes and differential attention's lambda (``STATE_SPACE_FIELDS``,
+    ``DIFFERENTIAL_FIELDS``: each with the count of what was summed), and
+    ``LAYER_LOSS``, the scalar losses layers add to the step's — each a
+    mean over the rows of its forward, so two unfused views' are averaged.
+    A backbone that sows nothing leaves it empty.
     """
     variables = {"params": params, "batch_stats": batch_stats}
     # flax BatchNorm writes running stats whenever train=True, so the
@@ -344,9 +352,8 @@ def apply_update(state: TrainState, grads, new_bs, metrics, *,
             grads=grads, updates=updates, params=fresh_params,
             target_params=new_target, loss=metrics["loss_mean"],
             collapse=collapse, trust_ratios=trust,
-            routing={f"moe_{name}": metrics[f"_moe_{name}"]
-                     for name in ROUTING_FIELDS
-                     if f"_moe_{name}" in metrics})
+            routing={key[1:]: value for key, value in metrics.items()
+                     if key[1:] in health_lib.OPTIONAL_FIELDS})
 
     # One real attribute on one scalar add.  The persistent compilation
     # cache keys a program with its debug info stripped, scope names
@@ -498,6 +505,12 @@ def make_train_step(net, tx: optax.GradientTransformation, scfg: StepConfig,
                 if collection in sown:
                     metrics.update({prefix + name: sown[collection][i]
                                     for i, name in enumerate(fields)})
+            for prefix, collection, fields in MEANS_OVER_LAYERS:
+                if collection in sown:
+                    metrics.update({
+                        prefix + name: sown[collection][i]
+                        / sown[collection][-1]
+                        for i, name in enumerate(fields[:-1])})
             return total, (new_bs, metrics)
 
         grads, (new_bs, metrics) = jax.grad(
