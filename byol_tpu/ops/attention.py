@@ -326,7 +326,10 @@ def blockwise_causal_attention(q: jnp.ndarray, k: jnp.ndarray,
     ``causal_attention_fwd`` / ``causal_attention_bwd`` of
     ops/causal_attention.py over the whole batch — a tile's scores, weights
     and their cotangents live and die in VMEM, the shared part a second
-    product a tile; everywhere else (the CPU, the tiny presets, odd shapes
+    product a tile; a program holds a key head's ``Hq / Hkv`` query heads
+    and, where those are fewer than four, several key heads
+    (``causal_attention.key_heads``: four of latent attention's, the shared
+    key fetched once for them); everywhere else (the CPU, the tiny presets, odd shapes
     such as one 192-wide key) plain ``jax.numpy``, the tile pairs unrolled
     in Python, every ``(B, Hkv, G, block, block)`` float32 tile through HBM
     and the shared part joined to every head's ``q`` and ``k`` first —

@@ -15,15 +15,29 @@ tile's squares live and die in VMEM.  Still MASKED-DENSE: every tile on or
 under the diagonal is formed whatever it keeps, a tile above it never.
 
 Design (see /opt/skills/guides/pallas_guide.md):
-- grid ``(B, Hkv, causal pair)``: ``causal_pairs`` goes in as scalar
+- grid ``(B, Hkv / n, causal pair)``: ``causal_pairs`` goes in as scalar
   prefetch and the PAIR is the innermost axis, so the grid has no step above
   the diagonal and the block index maps read ``i, j`` of a step from SMEM
   (pair ``(i, j)`` is tile ``i (i + 1) / 2 + j`` of ops/attention.py's TILE
   layout);
-- a program holds the ``G`` query heads of one key head: ``k`` and ``v`` are
-  fetched once for the ``G`` of them, traced side by side (a Python loop), so
-  that one head's products overlap another's passes: 20.5 -> 18.6 ms a call
-  of 8 sequences (chip runs, PR 34);
+- a program holds ``n`` key heads, each with its ``G`` query heads: ``k`` and
+  ``v`` are fetched once for a key head's ``G``, the ``n G`` heads traced
+  side by side (a Python loop), so that one head's products overlap
+  another's passes: 20.5 -> 18.6 ms a call of 8 sequences when ``G`` = 8
+  were so held (chip runs, PR 34).  ``n`` is 1 wherever ``G`` fills a
+  program — four heads and more run at one pace (PERF.md section 5) — and
+  the blocks' head dimension is then squeezed away, the kernels those of
+  PR 34–45 to the byte.  Where ``G`` alone leaves a program short
+  (:func:`key_heads`: latent attention has ONE query head a key head, a
+  lone ``[512, 512]`` tile a step whose chain of products and passes has
+  nothing to overlap with) ``n`` is the largest divisor of ``Hkv`` with ``n
+  G <= 4`` whose blocks fit VMEM by :func:`_vmem_bytes`, forward and
+  backward each for itself (the backward's resident ``d_k, d_v`` grow with
+  ``n``): every block's head extent grows from 1 to ``n`` through its
+  ``BlockSpec``, nothing is reshaped or copied outside, and what has no
+  head axis — the shared key, the pair's bias — is fetched or made ONCE for
+  the ``n G`` heads.  A shape takes it in every call or in none: no flag
+  (what the chip said is in PERF.md section 6, PR 49);
 - what a tile masks is an additive float32 bias, ``0`` where the query sees
   the key, ``-1e30`` where not, which in float32 IS ``where(visible, score,
   -1e30)`` (a score is lost whole under ``1e30``'s rounding).  Where it
@@ -78,7 +92,8 @@ Design (see /opt/skills/guides/pallas_guide.md):
   in HBM (what the chip said of the two is in PERF.md section 6, PR 40).
   Backward ``d_q_s`` is the head's own and ``d_k_s`` the SUM over every head:
   its float32 ``(S, r)`` block stays resident over a sequence's heads and
-  pairs, so the head axis of that grid is walked in order;
+  pairs — added to by the ``n`` heads of a program, then by the next
+  program's — so the head axis of that grid is walked in order;
 - bf16 (the input dtype's) operands, float32 accumulation and statistics, the
   weights rounded before ``P V`` and ``d_scores`` before its products, as
   the ``jax.numpy`` bodies do; float32 inputs multiply at
@@ -106,17 +121,22 @@ from byol_tpu.ops.common import (LANES, MASKED, NN, NT, TN, VMEM_LIMIT_BYTES,
 
 def _vmem_bytes(block: int, dim: int, seq_len: int, group: int,
                 itemsize: int, forward: bool, *, vdim: Optional[int] = None,
-                shared: int = 0, selected: bool = False) -> int:
+                shared: int = 0, selected: bool = False,
+                heads: int = 1) -> int:
     """A kernel's blocks twice (double buffering), its scratch and the
     float32 squares of the head in hand, at a key width ``dim`` (+
-    ``shared``) and a value width ``vdim``; a width that does not fill its
-    last 128 lanes takes the whole lane tile of VMEM; ``selected``: a pair's
-    int8 mask among the blocks."""
+    ``shared``) and a value width ``vdim``, ``heads`` key heads a program
+    (the shared key, its cotangent and the mask have no head axis: once); a
+    width that does not fill its last 128 lanes takes the whole lane tile of
+    VMEM; ``selected``: a pair's int8 mask among the blocks."""
     tiles = lambda d: -(-d // LANES) * LANES
-    key_lanes = tiles(dim) + tiles(shared)
+    own_lanes, shared_lanes = tiles(dim), tiles(shared)
     value_lanes = tiles(dim if vdim is None else vdim)
-    q_rows, o_rows = group * block * key_lanes, group * block * value_lanes
-    slabs = (block * (key_lanes + value_lanes) * itemsize   # k, v (, k_s)
+    group *= heads                        # the query heads of a program
+    q_rows = group * block * (own_lanes + shared_lanes)
+    o_rows = group * block * value_lanes
+    slab_lanes = heads * (own_lanes + value_lanes) + shared_lanes
+    slabs = (block * slab_lanes * itemsize                  # k, v (, k_s)
              + (block * block if selected else 0))          # (, the mask)
     square = 4 * block * block       # one float32 (block, block) value
     if forward:
@@ -127,10 +147,30 @@ def _vmem_bytes(block: int, dim: int, seq_len: int, group: int,
     else:
         blocks = ((2 * q_rows + o_rows) * itemsize + slabs  # q, dq; dO
                   + 8 * group * block                       # lse, delta
-                  + 4 * seq_len * (key_lanes + value_lanes))  # d_k, d_v,
+                  + 4 * seq_len * slab_lanes)               # d_k, d_v,
         scratch = 4 * q_rows
         live = 5                          # ... and d_weights, d_scores
     return 2 * blocks + scratch + (1 + live) * square     # 1: the bias
+
+
+# The heads a program wants side by side: the smallest group that runs at the
+# grouped pace (PERF.md section 5: 1.26 us a head-tile at G = 4, 1.91 at 1).
+HEADS_A_PROGRAM = 4
+
+
+def key_heads(block: int, dim: int, seq_len: int, group: int, kv_heads: int,
+              itemsize: int, forward: bool, *, vdim: Optional[int] = None,
+              shared: int = 0, selected: bool = False) -> int:
+    """How many KEY heads — each with its ``group`` query heads — a program
+    of the forward (the backward) kernel holds: where the group alone leaves
+    a program short of :data:`HEADS_A_PROGRAM` heads, the largest divisor
+    ``n`` of ``kv_heads`` with ``n * group`` within it whose
+    :func:`_vmem_bytes` fits; 1 for every group of 4 and more."""
+    fits = lambda n: _vmem_bytes(
+        block, dim, seq_len, group, itemsize, forward, vdim=vdim,
+        shared=shared, selected=selected, heads=n) <= VMEM_LIMIT_BYTES
+    return next((n for n in range(HEADS_A_PROGRAM // group, 1, -1)
+                 if kv_heads % n == 0 and fits(n)), 1)
 
 
 def _width_ok(dim: int) -> bool:
@@ -262,15 +302,24 @@ def _with_the_pairs_bias(when, bounds, span, keep_ref, bias_ref, tile):
             tile(bias_ref)
 
 
-def _scores(k_ref, q, scale, bias, shared=None):
+def _scores(k_ref, q, scale, bias, shared=None, where=...):
     """``(bk, bq)`` float32; ``shared``: the head's ``q_s`` and the ``k_s``
-    ref, whose product is the scores' second term."""
-    scores = dot(k_ref[...], q, NT)
+    ref, whose product is the scores' second term; ``where``: the key
+    head's block in ``k_ref``."""
+    scores = dot(k_ref[where], q, NT)
     if shared is not None:
         q_s, ks_ref = shared
         scores = scores + dot(ks_ref[...], q_s, NT)
     scores = scores * scale
     return scores if bias is None else scores + bias[...]
+
+
+def _in_key_head(heads: int):
+    """``at(kh, *index)``: ``index`` into key head ``kh``'s part of a block
+    or scratch ref — the index itself where a program holds ONE key head (a
+    ref's head dimension is squeezed away), else behind the head's own (ONE
+    index, not a view of a view: Mosaic slices no 64-wide ref)."""
+    return lambda kh, *index: index if heads == 1 else (kh,) + index
 
 
 def _taker(refs):
@@ -282,7 +331,8 @@ def _taker(refs):
 
 
 def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
-                shared: bool, flagged: bool, span: int, bounded: bool = False):
+                shared: bool, flagged: bool, span: int, bounded: bool = False,
+                heads: int = 1):
     """Scores ``[keys, queries]``.  Refs: with a list that is not the lower
     triangle its ``flags`` and, ``bounded``, its pairs' ``lo`` and ``hi``
     (scalar prefetch); ``q (G, bq, D)``; ``k (bk, D)``;
@@ -290,7 +340,8 @@ def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
     part ``q_s (G, bq, r)``, ``k_s (bk, r)``; ``o (G, bq, Dv)``; ``lse (G,
     bq)``; scratch: every head's running max and sum, a lane row a head,
     ``(G, bq)``, the float32 accumulators ``(G, bq, Dv)`` and a tile's
-    bias."""
+    bias.  With ``heads`` > 1 key heads a program every one of them but
+    ``keep``, ``k_s`` and the bias has that many as its leading axis."""
     take = _taker(refs)
     flags_ref, = take(1, flagged)
     bounds_refs = take(2) if bounded else None
@@ -300,7 +351,8 @@ def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
     o_ref, lse_ref, top_ref, total_ref, acc_ref, bias_ref = take(6)
     _, first, last, when, bounds = _step(pl.program_id(2), q_of_ref,
                                          k_of_ref, flags_ref, bounds_refs)
-    group, _, dim = acc_ref.shape
+    group, _, dim = acc_ref.shape[-3:]
+    at = _in_key_head(heads)
 
     @pl.when(first())
     def _start():
@@ -314,36 +366,41 @@ def _fwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
         lanes = max(dim, LANES)
         return jnp.broadcast_to(row, (lanes, row.shape[1])).T[:, :dim]
 
-    def head(h, bias):
-        at = pl.ds(h, 1)
-        scores = _scores(k_ref, q_ref[h], scale, bias,
-                         (qs_ref[h], ks_ref) if shared else None)
-        top = top_ref[at, :]
+    def head(kh, h, bias):
+        own, whole = at(kh, h), at(kh, ...)
+        row = at(kh, pl.ds(h, 1), slice(None))
+        scores = _scores(k_ref, q_ref[own], scale, bias,
+                         (qs_ref[own], ks_ref) if shared else None, whole)
+        top = top_ref[row]
         new_top = jnp.maximum(top, jnp.max(scores, axis=0, keepdims=True))
         weights = jnp.exp(scores - new_top)
         keep = jnp.exp(top - new_top)
-        total_ref[at, :] = total_ref[at, :] * keep + jnp.sum(
+        total_ref[row] = total_ref[row] * keep + jnp.sum(
             weights, axis=0, keepdims=True)
-        top_ref[at, :] = new_top
-        acc_ref[h] = acc_ref[h] * column(keep) + dot(
-            weights.astype(v_ref.dtype), v_ref[...], TN)
+        top_ref[row] = new_top
+        acc_ref[own] = acc_ref[own] * column(keep) + dot(
+            weights.astype(v_ref.dtype), v_ref[whole], TN)
 
     def tile(bias):
-        for h in range(group):      # side by side: the module docstring
-            head(h, bias)
+        for kh in range(heads):     # side by side: the module docstring
+            for h in range(group):
+                head(kh, h, bias)
 
     _with_the_pairs_bias(when, bounds, span, keep_ref, bias_ref, tile)
 
     @pl.when(last())
     def _finish():
         lse_ref[...] = top_ref[...] + jnp.log(total_ref[...])
-        for h in range(group):
-            o_ref[h] = (acc_ref[h] / column(total_ref[h:h + 1, :])).astype(
-                o_ref.dtype)
+        for kh in range(heads):
+            for h in range(group):
+                o_ref[at(kh, h)] = (acc_ref[at(kh, h)] / column(
+                    total_ref[at(kh, slice(h, h + 1), slice(None))])).astype(
+                        o_ref.dtype)
 
 
 def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
-                shared: bool, flagged: bool, span: int, bounded: bool = False):
+                shared: bool, flagged: bool, span: int, bounded: bool = False,
+                heads: int = 1):
     """Everything ``[keys, queries]``.  Refs: with a list that is not the
     lower triangle its ``flags``; ``q, dq (G, bq, D)``; ``dO (G,
     bq, Dv)``; ``k (bk, D)``; ``v (bk, Dv)``; with a selection ``keep (bq,
@@ -351,7 +408,9 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
     float32, one key head's, resident over all its pairs; with a shared part
     ``q_s, dq_s (G, bq, r)``, ``k_s (bk, r)`` and ``dk_s (S, r)`` float32,
     ONE SEQUENCE's, resident over all its heads and pairs; scratch: the
-    float32 ``dq`` (and ``dq_s``) of the query block and a tile's bias."""
+    float32 ``dq`` (and ``dq_s``) of the query block and a tile's bias.  With
+    ``heads`` > 1 key heads a program every one of them but ``keep``, ``k_s``,
+    ``dk_s`` and the bias has that many as its leading axis."""
     take = _taker(refs)
     flags_ref, = take(1, flagged)
     bounds_refs = take(2) if bounded else None
@@ -366,8 +425,9 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
     pair = pl.program_id(2)
     j, first, last, when, bounds = _step(pair, q_of_ref, k_of_ref, flags_ref,
                                          bounds_refs)
-    group, bk = q_ref.shape[0], k_ref.shape[0]
+    group, bk = q_ref.shape[-3], k_ref.shape[-2]
     keys = pl.ds(pl.multiple_of(j * bk, bk), bk)
+    at = _in_key_head(heads)
 
     @pl.when(pair == 0)
     def _start():
@@ -385,24 +445,27 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
         if shared:
             dqs_acc_ref[...] = jnp.zeros_like(dqs_acc_ref)
 
-    def head(h, bias):
-        q, d_out = q_ref[h], do_ref[h]
-        lse, delta = lse_ref[pl.ds(h, 1), :], delta_ref[pl.ds(h, 1), :]
+    def head(kh, h, bias):
+        own, whole = at(kh, h), at(kh, ...)
+        row, rows = at(kh, pl.ds(h, 1), slice(None)), at(kh, keys, slice(None))
+        q, d_out = q_ref[own], do_ref[own]
+        lse, delta = lse_ref[row], delta_ref[row]
         weights = jnp.exp(_scores(
             k_ref, q, scale, bias,
-            (qs_ref[h], ks_ref) if shared else None) - lse)
-        dv_ref[keys, :] += dot(weights.astype(d_out.dtype), d_out, NN)
-        d_weights = dot(v_ref[...], d_out, NT)
+            (qs_ref[own], ks_ref) if shared else None, whole) - lse)
+        dv_ref[rows] += dot(weights.astype(d_out.dtype), d_out, NN)
+        d_weights = dot(v_ref[whole], d_out, NT)
         d_scores = (weights * (d_weights - delta) * scale).astype(q.dtype)
-        dk_ref[keys, :] += dot(d_scores, q, NN)
-        dq_acc_ref[h] += dot(d_scores, k_ref[...], TN)
+        dk_ref[rows] += dot(d_scores, q, NN)
+        dq_acc_ref[own] += dot(d_scores, k_ref[whole], TN)
         if shared:
-            dks_ref[keys, :] += dot(d_scores, qs_ref[h], NN)
-            dqs_acc_ref[h] += dot(d_scores, ks_ref[...], TN)
+            dks_ref[keys, :] += dot(d_scores, qs_ref[own], NN)
+            dqs_acc_ref[own] += dot(d_scores, ks_ref[...], TN)
 
     def tile(bias):
-        for h in range(group):      # side by side: the module docstring
-            head(h, bias)
+        for kh in range(heads):     # side by side: the module docstring
+            for h in range(group):
+                head(kh, h, bias)
 
     _with_the_pairs_bias(when, bounds, span, keep_ref, bias_ref, tile)
 
@@ -416,7 +479,8 @@ def _bwd_kernel(q_of_ref, k_of_ref, *refs, scale: float, selected: bool,
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
 def _call(forward, scale, block, interpret, tiles, q, k, v, keep, shared,
           *rest):
-    """One ``pallas_call`` over ``(batch, key head, pair of tiles)``.  ``q``:
+    """One ``pallas_call`` over ``(batch, key heads, pair of tiles)``, ``n``
+    key heads a program (:func:`key_heads`).  ``q``:
     ``(B, Hkv, G, S, D)``; ``k``: ``(B, Hkv, S, D)``; ``v``: ``(B, Hkv, S,
     Dv)``; ``keep``: None or ``(P, B, block, block)`` int8; ``shared``:
     ``()`` or ``(q_s (B, Hkv, G, S, r), k_s (B, S, r))``; ``rest``, backward:
@@ -426,43 +490,50 @@ def _call(forward, scale, block, interpret, tiles, q, k, v, keep, shared,
     b, hkv, g, s, d = q.shape
     dv = v.shape[-1]
     selected, r = keep is not None, shared[0].shape[-1] if shared else 0
+    heads = key_heads(block, d, s, g, hkv, q.dtype.itemsize, forward,
+                      vdim=dv, shared=r, selected=selected)
+    # a block's extent over the key heads: squeezed away where it is one
+    of_heads = None if heads == 1 else heads
     flags = _flags(tiles)
     if selected and flags is not None:
         raise ValueError("a selection comes in the lower triangle's layout")
     lists = (tiles.q_of, tiles.k_of) + (() if flags is None else (flags,))
     if tiles.bounds:                    # a band: every pair's lo and hi
         lists += tuple(zip(*tiles.bounds))
-    # index maps: (batch, key head, pair, q_of, k_of[, flags[, lo, hi]])
+    # index maps: (batch, n key heads, pair, q_of, k_of[, flags[, lo, hi]])
     rows = lambda w: pl.BlockSpec(
-        (None, None, g, block, w),
+        (None, of_heads, g, block, w),
         lambda n, h, p, qo, ko, *_: (n, h, 0, qo[p], 0))
     slab = lambda w: pl.BlockSpec(
-        (None, None, block, w), lambda n, h, p, qo, ko, *_: (n, h, ko[p], 0))
+        (None, of_heads, block, w),
+        lambda n, h, p, qo, ko, *_: (n, h, ko[p], 0))
     # the pair's mask: no head axis
     tile = pl.BlockSpec((None, None, block, block),
                         lambda n, h, p, qo, ko, *_: (p, n, 0, 0))
     # the shared key and its cotangent: no head axis
     slab_s = pl.BlockSpec((None, block, r),
                           lambda n, h, p, qo, ko, *_: (n, ko[p], 0))
-    row_stat = pl.BlockSpec((None, None, g, block),
+    row_stat = pl.BlockSpec((None, of_heads, g, block),
                             lambda n, h, p, qo, ko, *_: (n, h, 0, qo[p]))
     stat = jax.ShapeDtypeStruct((b, hkv, g, s), jnp.float32)
     like = lambda w: jax.ShapeDtypeStruct((b, hkv, g, s, w), q.dtype)
     square = pltpu.VMEM((block, block), jnp.float32)
-    per_head = lambda w: pltpu.VMEM((g, block, w), jnp.float32)
+    a_head = lambda *shape: pltpu.VMEM(   # scratch: laid out as the blocks
+        (() if heads == 1 else (heads,)) + shape, jnp.float32)
+    per_head = lambda w: a_head(g, block, w)
     in_specs = [rows(d), slab(d), slab(dv)] + (
         [tile] if selected else []) + ([rows(r), slab_s] if shared else [])
     stem = "selected_attention" if selected else "causal_attention"
     if forward:
         kernel, name = _fwd_kernel, stem + "_fwd"
         outs = [(rows(dv), like(dv)), (row_stat, stat)]
-        stats = pltpu.VMEM((g, block), jnp.float32)
+        stats = a_head(g, block)
         scratch = [stats, stats, per_head(dv), square]
     else:
         kernel, name = _bwd_kernel, stem + "_bwd"
         in_specs += [row_stat, row_stat, rows(dv)]
         whole = lambda w: pl.BlockSpec(
-            (None, None, s, w), lambda n, h, p, qo, ko, *_: (n, h, 0, 0))
+            (None, of_heads, s, w), lambda n, h, p, qo, ko, *_: (n, h, 0, 0))
         outs = [(rows(d), like(d)),
                 (whole(d), jax.ShapeDtypeStruct(k.shape, jnp.float32)),
                 (whole(dv), jax.ShapeDtypeStruct(v.shape, jnp.float32))]
@@ -482,10 +553,11 @@ def _call(forward, scale, block, interpret, tiles, q, k, v, keep, shared,
     return pl.pallas_call(
         functools.partial(kernel, scale=scale, selected=selected,
                           shared=bool(shared), flagged=flags is not None,
-                          span=tiles.span, bounded=bool(tiles.bounds)),
+                          span=tiles.span, bounded=bool(tiles.bounds),
+                          heads=heads),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(lists),
-            grid=(b, hkv, len(tiles.q_of)),
+            grid=(b, hkv // heads, len(tiles.q_of)),
             in_specs=in_specs,
             out_specs=[spec for spec, _ in outs],
             scratch_shapes=scratch),

@@ -30,6 +30,7 @@ from __future__ import annotations
 import argparse
 import base64
 import hashlib
+import importlib
 import json
 import os
 import re
@@ -40,7 +41,7 @@ KERNEL_BODY = r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22'
 # cells whose trunk runs a tiled causal kernel (ops/causal_attention.py)
 KERNEL_CELLS = ("qwen3next_train_b4_s4096", "keye_train_b4_s4096",
                 "lfm2_train_b4_s4096", "joyai_train_b4_s4096",
-                "sdar_train_b2_s4096")
+                "sdar_train_b2_s4096", "phi4flash_train_b2_s8192")
 
 
 def _sha(text: str) -> str:
@@ -80,7 +81,11 @@ def lowered_text(cell_name: str, topo) -> str:
         conf = json.load(f)
     chips, tokens = int(cell["chips"]), "seq_len" in conf
     if tokens:
-        from benchmarks.drivers.train_tokens import program_config
+        from benchmarks.drivers import train_tokens
+        # a driver with a depth check of its own (phi4flash's "15-19")
+        program_config = getattr(
+            importlib.import_module("benchmarks.drivers." + cell["driver"]),
+            "program_config", train_tokens.program_config)
         from byol_tpu.models.registry import get_spec
         # a block-diffusion trunk's sample is [noised | clean]: what
         # data/loader hands the program (the parent's registry has no such)

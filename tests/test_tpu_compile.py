@@ -372,7 +372,10 @@ def test_latent_attention_core_at_the_published_sizes(
     TPU, forward and backward with the shared key's gradient summed over
     the heads in the kernel: one kernel each, no float32 ``(.., 512, 512)``
     array and no loop outside them; a 192-wide operand as it stands is not
-    one the kernels take."""
+    one the kernels take.  The TPU's compiler takes them at the key heads a
+    program ``key_heads`` gives from its VMEM count — FOUR forward, TWO
+    backward: a count that is too optimistic fails here, not on the chip."""
+    import re
     from byol_tpu.ops import causal_attention
     from byol_tpu.ops.attention import blockwise_causal_attention
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -384,12 +387,20 @@ def test_latent_attention_core_at_the_published_sizes(
     def loss(q, k, v, shared):
         return jnp.sum(jnp.square(blockwise_causal_attention(
             q, k, v, block=512, shared=shared).astype(jnp.float32)))
-    text = jax.jit(jax.grad(
-        loss, argnums=(0, 1, 2, 3) if rope else (0, 1, 2))).lower(
-            heads(dim), heads(dim), heads(128), shared).compile().as_text()
+    grad = jax.grad(loss, argnums=(0, 1, 2, 3) if rope else (0, 1, 2))
+    operands = heads(dim), heads(dim), heads(128), shared
+    text = jax.jit(grad).lower(*operands).compile().as_text()
     assert _core_kernel_calls(text, "causal_attention") == [1, 1]
     assert not _float32_squares(text) and " while(" not in text
     assert not causal_attention.applies(512, 192, 4096, 32, 32, vdim=128)
+    held = [causal_attention.key_heads(512, dim, 4096, 1, 32, 2, forward,
+                                       vdim=128, shared=rope)
+            for forward in (True, False)]
+    assert held == [4, 2]       # at either form of the 192-wide key
+    # ... and those are the programs that compiled: (8, 32 / n, 36 pairs)
+    assert set(re.findall(r"grid=\((\d+), (\d+), 36\)", str(
+        jax.make_jaxpr(grad)(*operands)))) == {
+            ("8", str(32 // n)) for n in held}
 
 
 @pytest.mark.parametrize("backend,sizes", [
